@@ -433,6 +433,28 @@ class LaunchRunner:
 
     # ------------------------------------------------------------ execution
 
+    def _operands(self, it0: int):
+        """Operands of the compiled scan for the window starting at
+        iteration ``it0`` (``_launch_impl`` order), plus the host
+        feature-mask rows they were built from.  One place builds them, so
+        ``run()`` and a caller that lowers the program for inspection
+        (``self._fn.lower(*args)``, chip_smoke.py) cannot drift."""
+        from .sampling import BaggingStrategy
+
+        b = self._b
+        its = jnp.asarray(np.arange(it0, it0 + self._n, dtype=np.int32))
+        fm_rows = [
+            b._feature_mask_np_for(it) for it in range(it0, it0 + self._n)
+        ]
+        fms = jnp.asarray(np.stack(fm_rows))
+        is_bagging = isinstance(b._sampler, BaggingStrategy)
+        bag0 = b._sampler._mask if is_bagging else jnp.zeros((1,), jnp.float32)
+        fixed = getattr(b, "_fixed_row_mask", None)
+        fixed_arg = fixed if fixed is not None else jnp.zeros((1,), jnp.float32)
+        return (
+            b._score, b._rng, bag0, its, fms, b._bins, b._ones_mask, fixed_arg,
+        ), fm_rows
+
     def run(self) -> Tuple[int, bool]:
         """One launch: up to N iterations on device, then host replay of
         the packed trees through the serial commit path.  Returns
@@ -480,19 +502,12 @@ class LaunchRunner:
         wd = getattr(b, "_watchdog", None)
         it0 = int(b._iter)
         S = self._n
-        its = jnp.asarray(np.arange(it0, it0 + S, dtype=np.int32))
-        fm_rows = []
-        for it in range(it0, it0 + S):
-            m = b._feature_mask_np_for(it)
+        args, fm_rows = self._operands(it0)
+        for m in fm_rows:
             b._note_live_plane(
                 None if m.all() else m, int(b._bins.shape[1])
             )
-            fm_rows.append(m)
-        fms = jnp.asarray(np.stack(fm_rows))
         is_bagging = isinstance(b._sampler, BaggingStrategy)
-        bag0 = b._sampler._mask if is_bagging else jnp.zeros((1,), jnp.float32)
-        fixed = getattr(b, "_fixed_row_mask", None)
-        fixed_arg = fixed if fixed is not None else jnp.zeros((1,), jnp.float32)
 
         compiles_before = _compile_count()
         tracer = get_tracer()
@@ -512,16 +527,7 @@ class LaunchRunner:
         try:
             try:
                 with ses.phase("launch"):
-                    carry, ys = self._fn(
-                        b._score,
-                        b._rng,
-                        bag0,
-                        its,
-                        fms,
-                        b._bins,
-                        b._ones_mask,
-                        fixed_arg,
-                    )
+                    carry, ys = self._fn(*args)
                     score, rng, bag, finished_dev, bad_dev = carry
                     # donated score: rebind before anything can raise
                     b._score = score

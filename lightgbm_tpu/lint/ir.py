@@ -997,9 +997,7 @@ def trace_entry(spec: EntrySpec) -> TracedEntry:
         walk_jaxpr(jaxpr, facts)
         x64_wide: List[WideDtypeFact] = []
         if spec.x64_strict:
-            from jax.experimental import enable_x64
-
-            with enable_x64():
+            with jax.enable_x64(True):
                 jaxpr64 = jax.make_jaxpr(fn)(*args, **kwargs)
             f64 = TraceFacts()
             walk_jaxpr(jaxpr64, f64)

@@ -15,6 +15,8 @@ _tried = False
 
 
 def _build(src: Path, out: Path) -> bool:
+    """g++ ``src`` into ``out``; a failure is logged (once per process —
+    ``load_native`` tries once) and the caller falls back to NumPy."""
     # no -march=native: the cached .so may be shared across hosts (NFS,
     # container images) and a binary search gains little from wide SIMD.
     # compile to a temp file and os.replace: concurrent builders (the
@@ -27,10 +29,12 @@ def _build(src: Path, out: Path) -> bool:
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
         if r.returncode != 0 or not tmp.exists():
+            _warn_build_failed(r.stderr.decode("utf-8", "replace")[-500:])
             return False
         os.replace(tmp, out)
         return True
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _warn_build_failed(f"{type(e).__name__}: {e}")
         return False
     finally:
         if tmp.exists():
@@ -38,6 +42,16 @@ def _build(src: Path, out: Path) -> bool:
                 tmp.unlink()
             except OSError:
                 pass
+
+
+def _warn_build_failed(why: str) -> None:
+    from ..utils.log import log_warning
+
+    log_warning(
+        "native binning helper did not build (g++ -O3 -fopenmp "
+        f"native/binning.cpp): {why.strip() or 'no compiler output'}; "
+        "binning falls back to NumPy (same results, slower ingest)"
+    )
 
 
 def load_native() -> Optional[ctypes.CDLL]:

@@ -39,11 +39,7 @@ import jax.numpy as jnp
 from ...obs.jit import instrumented_jit
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 ROW_TILE = 1024

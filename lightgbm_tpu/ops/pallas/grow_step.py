@@ -60,11 +60,7 @@ import jax.numpy as jnp
 
 from ...obs.jit import instrumented_jit
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .partition import T, W, _partition_window, read_aliased_tile
 from .seg import (
@@ -265,8 +261,12 @@ def fused_grow_step_pallas(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
+            # member axis squeezed out of a [K, 1, bmt] table (see
+            # seg_partition_pallas_batch: a (1, bmt) block of (K, bmt) is
+            # not a legal Mosaic block)
             pl.BlockSpec(
-                (1, bmt), lambda i, pt: (i, 0), memory_space=pltpu.VMEM
+                (None, 1, bmt), lambda i, pt: (i, 0, 0),
+                memory_space=pltpu.VMEM,
             ),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -307,7 +307,7 @@ def fused_grow_step_pallas(
         input_output_aliases={3: 0},
         interpret=interpret,
     )(scal.astype(jnp.int32), scales.astype(jnp.float32),
-      live.astype(jnp.int32), seg, catmask, tri, gl_arr)
+      live.astype(jnp.int32), seg, catmask.reshape(k, 1, bmt), tri, gl_arr)
     hist = combine_hist_raw(
         raw, scales.astype(jnp.float32), f=f, bpad=bpad, group=group,
         num_bins=num_bins, quantized=quantized,
@@ -413,6 +413,8 @@ def fused_grow_step(
     args = (seg, sbegins, cnts, feats, tbins, dls, nanbs, iscats, catmasks,
             scales, live)
     if jax.default_backend() != "tpu":
+        # no TPU in this process: the XLA oracle without tracing the Pallas
+        # branch, or the interpret-mode kernel under the _INTERPRET test hook
         if _INTERPRET:
             return _pallas(*args, interpret=True)
         return _xla(*args)

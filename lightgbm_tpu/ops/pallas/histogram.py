@@ -40,19 +40,7 @@ import jax.numpy as jnp
 
 from ...obs.jit import instrumented_jit
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = (
-    getattr(pltpu, "CompilerParams", None)
-    or getattr(pltpu, "TPUCompilerParams", None)
-    if pltpu is not None
-    else None
-)
+from jax.experimental.pallas import tpu as pltpu
 
 _TILE_ROWS = 1024
 _TARGET_LANES = 2048  # FG*B_pad per matmul
@@ -149,7 +137,7 @@ def tile_pallas_histogram(
         scratch_shapes=[pltpu.VMEM((tr, group * bpad), scratch_dtype)],
         interpret=interpret,
         compiler_params=(
-            _CompilerParams(dimension_semantics=("arbitrary",))
+            pltpu.CompilerParams(dimension_semantics=("arbitrary",))
             if not interpret
             else None
         ),
@@ -170,10 +158,6 @@ def histogram_pallas(
     n, f = bins.shape
     if f == 0:  # all-constant datasets: platform_dependent traces all branches
         return jnp.zeros((0, num_bins, 3), jnp.float32)
-    if pltpu is None:  # no TPU pallas support in this install
-        from ..histogram import leaf_histogram_segment
-
-        return leaf_histogram_segment(bins, grad, hess, mask, num_bins)
     from .seg import combine_hist_raw
 
     ghc = jnp.stack([grad * mask, hess * mask, mask], axis=1)  # [N, 3]

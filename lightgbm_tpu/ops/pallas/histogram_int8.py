@@ -31,11 +31,6 @@ import jax.numpy as jnp
 from ...obs.jit import instrumented_jit
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 from .histogram import tile_pallas_histogram
 from .seg import QMAX, combine_hist_raw
 
@@ -120,10 +115,6 @@ def histogram_pallas_int8(
     n, f = bins.shape
     if f == 0:
         return jnp.zeros((0, num_bins, 3), jnp.float32)
-    if pltpu is None:  # pragma: no cover
-        from ..histogram import leaf_histogram_segment
-
-        return leaf_histogram_segment(bins, grad, hess, mask, num_bins)
     ghc = int8_digit_rows(grad, hess, mask, g_scale, h_scale)
     out, bpad = tile_pallas_histogram(
         bins, ghc, num_bins, _hist_kernel_int8, jnp.int8, jnp.int32, interpret

@@ -51,11 +51,7 @@ import jax.numpy as jnp
 from ...obs.jit import instrumented_jit
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from .seg import COL_ALIGN, used_lanes
 
@@ -529,8 +525,13 @@ def seg_partition_pallas_batch(
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
             # one catmask row per program, so the kernel body sees the same
-            # [1, bmt] block the serial call passes
-            pl.BlockSpec((1, bmt), lambda i: (i, 0), memory_space=pltpu.VMEM),
+            # [1, bmt] block the serial call passes.  The table rides as
+            # [K, 1, bmt] with the member axis squeezed: Mosaic rejects a
+            # (1, bmt) block of a (K, bmt) array (second-minor block dim
+            # must be a multiple of 8 or the full dim)
+            pl.BlockSpec(
+                (None, 1, bmt), lambda i: (i, 0, 0), memory_space=pltpu.VMEM
+            ),
             pl.BlockSpec(memory_space=pltpu.VMEM),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -558,5 +559,5 @@ def seg_partition_pallas_batch(
         ],
         input_output_aliases={1: 0},
         interpret=interpret,
-    )(scal.astype(jnp.int32), seg, catmask, tri, gl_arr)
+    )(scal.astype(jnp.int32), seg, catmask.reshape(k, 1, bmt), tri, gl_arr)
     return seg_new, nl[:, 0]

@@ -83,11 +83,7 @@ import jax.numpy as jnp
 from ...obs.jit import instrumented_jit
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128  # hard cap on packed planes (128 i16 sublane budget)
 TILE = 512  # rows per DMA tile in seg_hist
@@ -725,8 +721,9 @@ def seg_hist(seg, scal, *, f: int, num_bins: int, n_pad: int,
         else jnp.ones((2,), jnp.float32)
     )
     if jax.default_backend() != "tpu":
-        # no TPU registered: older jax lowers every platform_dependent
-        # branch and the Pallas one cannot lower for CPU
+        # no TPU in this process: take the reference path without tracing
+        # the Pallas branch, or the interpret-mode kernel under the
+        # _INTERPRET test hook
         if _INTERPRET:
             return seg_hist_pallas(
                 seg, scal, scales, live, f=f, num_bins=num_bins, n_pad=n_pad,

@@ -32,11 +32,7 @@ import jax.numpy as jnp
 from ...obs.jit import instrumented_jit
 from jax import lax
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _EPS = 1e-15
 _NEG = float("-inf")  # plain float: a jnp scalar would be captured as a
@@ -58,10 +54,12 @@ def _digits3(x):
 
 
 def _split_scan_kernel(
-    par_ref,  # SMEM [4] f32: parent g, h, cnt, pad
-    num_ref,  # SMEM [F] i32: total bins per feature (incl. NaN bin)
-    nanb_ref,  # SMEM [F] i32: NaN-bin index, -1 if none
-    mask_ref,  # SMEM [F] f32: feature mask (col sampling / interaction)
+    par_ref,  # VMEM [8, bpad] f32: rows 0-2 = parent g, h, cnt on every lane
+    num_ref,  # VMEM [fpad, bpad] i32: row fj = feature fj's total bins
+    #           (incl. NaN bin) on every lane
+    nanb_ref,  # VMEM [fpad, bpad] i32: NaN-bin index, -1 if none
+    mask_ref,  # VMEM [fpad, bpad] f32: feature mask (col sampling /
+    #            interaction)
     hist_ref,  # VMEM [3, F * bpad] f32 (g, h, count — plane-major)
     tri_ref,  # VMEM [bpad, bpad] bf16: tri[j, i] = (j <= i)
     out_ref,  # VMEM [fpad, 128] f32: per-feature
@@ -74,9 +72,16 @@ def _split_scan_kernel(
     min_data: int,
     min_hess: float,
 ):
-    pg = par_ref[0]
-    ph = par_ref[1]
-    pc = par_ref[2]
+    # The per-leaf / per-feature scalars ride as lane-broadcast VMEM rows,
+    # not SMEM: the grower calls this kernel under jax.vmap (both children
+    # of a split, all 2K children of a batched step), and the Pallas
+    # batching rule turns a whole-array SMEM operand into a (squeezed, n)
+    # block that the Mosaic lowering rejects.  Every use below is an
+    # elementwise op against a [1, bpad] row, so a pre-broadcast row gives
+    # bit-identical results to the scalar it replaces.
+    pg = par_ref[0:1, :]
+    ph = par_ref[1:2, :]
+    pc = par_ref[2:3, :]
     iota_l = lax.broadcasted_iota(jnp.int32, (1, bpad), 1)
     iota_f32 = iota_l.astype(jnp.float32)
     iota_o = lax.broadcasted_iota(jnp.int32, (1, 128), 1)
@@ -94,9 +99,9 @@ def _split_scan_kernel(
         gb = hist_ref[0:1, sl]  # [1, bpad] f32
         hb = hist_ref[1:2, sl]
         cb = hist_ref[2:3, sl]
-        nb = nanb_ref[fj]
-        nbins = num_ref[fj]
-        fm = mask_ref[fj]
+        nb = nanb_ref[fj : fj + 1, :]  # [1, bpad], same value per lane
+        nbins = num_ref[fj : fj + 1, :]
+        fm = mask_ref[fj : fj + 1, :]
 
         # NaN-bin stats out, ordered cumsum over the rest (split.py:148-158)
         is_nan = (iota_l == nb).astype(jnp.float32)  # nb = -1 matches nothing
@@ -120,7 +125,7 @@ def _split_scan_kernel(
         cc = cum[6:7] + cum[7:8] + cum[8:9]
 
         # candidate validity: threshold t in [0, num_ordered - 2]
-        has_nan = 1 - ((nb >> 31) & 1)  # i32 0/1, no scalar-bool select
+        has_nan = 1 - ((nb >> 31) & 1)  # i32 0/1 row
         num_ordered = nbins - has_nan
         base_ok = ((iota_l < num_ordered - 1).astype(jnp.float32)) * fm
 
@@ -139,7 +144,7 @@ def _split_scan_kernel(
         gain_r = dir_gain(cg, ch, cc, 1.0)  # missing -> right
         gain_l = dir_gain(
             cg + nan_g, ch + nan_h, cc + nan_c,
-            jnp.float32(has_nan),  # only distinct when a NaN bin exists
+            has_nan.astype(jnp.float32),  # only distinct with a NaN bin
         )
 
         m_r = jnp.max(gain_r)
@@ -208,32 +213,30 @@ def split_scan_pallas(
     h3 = hist.transpose(2, 0, 1).reshape(3, f * bpad).astype(jnp.float32)
     fpad = max(8, -(-f // 8) * 8)
     tri = jnp.tril(jnp.ones((bpad, bpad), jnp.bfloat16)).T  # tri[j,i] = j<=i
-    par4 = jnp.concatenate(
-        [parent.astype(jnp.float32), jnp.zeros((1,), jnp.float32)]
-    )
+
+    def rows(x, n_rows, dtype):
+        """[n] per-row values -> lane-broadcast [n_rows, bpad] VMEM table."""
+        x = x.astype(dtype)
+        x = jnp.pad(x, (0, n_rows - x.shape[0]))
+        return jnp.broadcast_to(x[:, None], (n_rows, bpad))
+
     kernel = functools.partial(
         _split_scan_kernel, f=f, bpad=bpad, l1=float(l1), l2=float(l2),
         min_data=int(min_data), min_hess=float(min_hess),
     )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     out = pl.pallas_call(
         kernel,
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        in_specs=[vmem] * 6,
+        out_specs=vmem,
         out_shape=jax.ShapeDtypeStruct((fpad, 128), jnp.float32),
         interpret=interpret,
     )(
-        par4,
-        num_bins.astype(jnp.int32),
-        nan_bins.astype(jnp.int32),
-        feature_mask.astype(jnp.float32),
+        rows(parent, 8, jnp.float32),
+        rows(num_bins, fpad, jnp.int32),
+        rows(nan_bins, fpad, jnp.int32),
+        rows(feature_mask, fpad, jnp.float32),
         h3,
         tri,
     )

@@ -219,8 +219,7 @@ def sort_partition(
     if use_gl:
         args = args + (gl_vec,)
     if jax.default_backend() != "tpu":
-        # no TPU registered: older jax lowers every platform_dependent
-        # branch and the Pallas one cannot lower for CPU
+        # no TPU in this process: don't trace the Pallas branch
         return _xla(*args)
     return jax.lax.platform_dependent(*args, tpu=_pallas, default=_xla)
 

@@ -92,45 +92,37 @@ def psum_bytes_per_iteration(
 
 
 def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level alias (check_vma)
-    landed after 0.4.x, where the API lives in jax.experimental.shard_map
-    with the equivalent knob spelled check_rep."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as sm_old
-
-    return sm_old(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with the varying-manual-axes check off — the one
+    spelling every call site in the package uses."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )
 
 
 def choose_devices(min_devices: int = 2):
-    """Devices for distributed training: the default backend's devices, or —
-    when it has a single chip (e.g. tests on a 1-chip host with a virtual CPU
-    mesh) — the CPU backend's. Returns None when no multi-device backend
-    exists, signalling serial training (the reference likewise degrades
+    """Devices for distributed training: the default backend's devices, or
+    None (with a log line) when it has fewer than ``min_devices``,
+    signalling serial training (the reference likewise degrades
     ``tree_learner=data`` to serial when num_machines==1, config.cpp).
+    Never another backend's devices: a mesh on CPU devices in a process
+    whose default backend is the TPU would silently take every
+    ``lax.platform_dependent`` ``default=`` branch.
     ``LGBM_TPU_FORCE_NDEV`` caps the mesh width (scaling experiments)."""
     import os
 
     cap = int(os.environ.get("LGBM_TPU_FORCE_NDEV", "0"))
-
-    def _cap(devs):
-        return devs[:cap] if cap > 0 else devs
-
-    devices = _cap(jax.devices())
+    devices = jax.devices()
+    if cap > 0:
+        devices = devices[:cap]
     if len(devices) >= min_devices:
         return devices
-    try:
-        cpu = _cap(jax.devices("cpu"))
-    except RuntimeError:
-        cpu = []
-    if len(cpu) >= min_devices:
-        return cpu
+    from ..utils.log import log_warning
+
+    log_warning(
+        f"distributed tree_learner requested but the {jax.default_backend()} "
+        f"backend has {len(devices)} usable device(s) (< {min_devices}); "
+        "training serially on one device"
+    )
     return None
 
 

@@ -1,6 +1,6 @@
 """Named-mesh SPMD layouts: one sharding spec for data/feature/hybrid.
 
-The t5x-style architecture (SNIPPETS [1]-[3]): a 2-D device mesh
+The t5x-style architecture: a 2-D device mesh
 ``Mesh(('data', 'feature'))`` plus a small logical-axis-rule table mapping
 array ROLES (bin planes, per-row gradient state, score state, tree arrays)
 to mesh axes via ``PartitionSpec``.  Every layout is then a mesh SHAPE, not
@@ -21,7 +21,7 @@ a code path:
 One ``shard_map``-wrapped ``grow_tree`` (``make_mesh_grow``) consumes the
 spec; ``boosting/gbdt.py`` holds no per-layout forks.  On a trivial mesh
 (1 device, or no mesh at all) the wrapper falls back to a plain ``jax.jit``
-— the SNIPPETS [1] pjit-or-jit pattern — so the whole path stays testable
+— the pjit-or-jit pattern — so the whole path stays testable
 on the CI virtual CPU mesh.
 """
 
@@ -175,7 +175,7 @@ def make_mesh_grow(mesh: Optional[Mesh], params: GrowerParams,
     All three layouts flow through THIS function — the spec (mesh shape +
     derived GrowerParams axis fields) is the only thing that changes.
     With no mesh (or a 1-device one) the same grower jits directly
-    (SNIPPETS [1] fallback), which is what CI exercises off the virtual
+    (the pjit-or-jit fallback), which is what CI exercises off the virtual
     mesh.  The jit label is kept at ``parallel/sharded_grow`` so the perf
     contract's retrace keys cover the mesh path unchanged.
     """

@@ -412,9 +412,10 @@ def test_position_debias_golden_parity():
     # Train under a PRIVATE persistent-compilation-cache dir.  The 3/8
     # "flake" this test had was never model nondeterminism: with the cache
     # off, the trained model dump is bit-identical across PYTHONHASHSEED
-    # values and device counts.  The machine-wide /tmp/lgbm_jax_cache the
-    # suite shares (conftest.py) is also written by non-suite processes
-    # (bench, smokes, debug shells) under other XLA topologies, and certain
+    # values and device counts.  The cache directory the suite shares
+    # (conftest.py: JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache) may
+    # also be written by non-suite processes (smokes, debug shells) under
+    # other XLA topologies, and certain
     # cache states serve this test's lambdarank programs an executable
     # whose scores go NON-FINITE (observed: booster._score NaN, trees stop
     # growing, ndcg frozen ~0.63-0.84).  Which entry gets hit varies with

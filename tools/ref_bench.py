@@ -5,8 +5,8 @@
 BASELINE.md's 3.8 iters/s was measured on a 16-core Xeon; this sandbox
 has ONE core, so cross-machine comparison is meaningless.  This script
 runs the REFERENCE on the identical synthetic workload bench.py uses
-(same rng seed, shapes, params), on THIS machine, so the driver's
-cpu-fallback number finally has a denominator measured under the same
+(same rng seed, shapes, params), on THIS machine, so a CPU-backend
+number of this system has a denominator measured under the same
 conditions.  Marginal-rep: wall(num_trees=N2) - wall(num_trees=N1)
 over N2-N1 iterations cancels data loading/binning.
 """
