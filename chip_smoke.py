@@ -17,9 +17,12 @@ Contract (see README "Running"):
   ``jax.devices()[0].platform == "tpu"``;
 * a leg that fails raises — no leg is caught and continued past — and the
   exit code is non-zero;
-* the last line of stdout is one JSON object
-  ``{"ok": true, "device": {"platform", "kind", "count"}, ...}``;
-  everything else (library logs, progress) goes to stderr.
+* stdout carries exactly two lines, both JSON: first the report (versions,
+  compile-cache directory, per-leg ``ok`` / ``wall_s`` / ``compile_s`` and
+  facts), then, LAST, the verdict with exactly these keys and no others:
+  ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}``
+  — the device as JAX reports it.  Everything else (library logs,
+  progress) goes to stderr.
 
 The legs are plain functions of their sizes so tests/test_chip_smoke.py can
 run them at toy size on CPU; nothing in this file reads a flag or an
@@ -202,7 +205,7 @@ def _launch_events() -> List[Dict[str, Any]]:
 
 # --------------------------------------------------------------------- legs
 # Each leg fills ``res`` as it goes (so a failure leaves its partial facts on
-# the result line) and sets res["ok"] only at its end.
+# the report line) and sets res["ok"] only at its end.
 
 
 def leg_train(
@@ -454,6 +457,23 @@ def _versions() -> Dict[str, str]:
     return out
 
 
+def emit(report: Dict[str, Any], device: Dict[str, Any]) -> None:
+    """The script's whole stdout: the report line, then the verdict line.
+    The verdict's key set is a contract with whoever runs the script —
+    exactly ``ok`` and ``device`` {``platform``, ``kind``, ``count``}; new
+    facts go into the report, never into the verdict."""
+    print(json.dumps(report))
+    verdict = {
+        "ok": bool(report["ok"]),
+        "device": {
+            "platform": str(device["platform"]),
+            "kind": str(device["kind"]),
+            "count": int(device["count"]),
+        },
+    }
+    print(json.dumps(verdict), flush=True)
+
+
 def main() -> int:
     import jax
 
@@ -475,9 +495,9 @@ def main() -> int:
     lgb.register_logger(logs)
     clock = _CompileClock()
     n_dev = len(jax.devices())
+    device = {"platform": dev.platform, "kind": dev.device_kind, "count": n_dev}
     out: Dict[str, Any] = {
         "ok": False,
-        "device": {"platform": dev.platform, "kind": dev.device_kind, "count": n_dev},
         "platform": dev.platform,
         "device_kind": dev.device_kind,
         "n_devices": n_dev,
@@ -512,7 +532,7 @@ def main() -> int:
             legs["multichip"] = {"ran": False, "reason": f"{n_dev} device(s)"}
         out["ok"] = all(leg["ok"] for leg in legs.values() if leg.get("ran", True))
     except BaseException as e:
-        # not a catch-and-continue: say what failed on the result line (the
+        # not a catch-and-continue: say what failed on the report line (the
         # caller may only see the tail of the output), then fail
         out["error"] = f"{type(e).__name__}: {e}"[:2000]
         raise
@@ -520,7 +540,7 @@ def main() -> int:
         out["warnings"] = logs.warnings[-20:]
         out["wall_s"] = round(time.perf_counter() - t_start, 3)
         out["compile_s"] = round(clock.seconds, 3)
-        print(json.dumps(out), flush=True)
+        emit(out, device)
     return 0 if out["ok"] else 1
 
 

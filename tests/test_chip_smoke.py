@@ -7,6 +7,7 @@ degradation fails the train leg.
 """
 
 import importlib.util
+import json
 import os
 import sys
 
@@ -83,6 +84,22 @@ def test_main_refuses_without_tpu(capsys):
     out, err = capsys.readouterr()
     assert out == ""  # no result line from a CPU run
     assert "'cpu'" in err
+
+
+def test_verdict_is_last_line_with_exact_keys(capsys):
+    """The last stdout line is the verdict whose key set the caller checks
+    exactly; everything else the run learned rides on the line before."""
+    report = {"ok": True, "legs": {"train": {"ok": True}}, "wall_s": 1.0}
+    chip_smoke.emit(report, {"platform": "tpu", "kind": "TPU v5 lite", "count": 1})
+    lines = capsys.readouterr().out.splitlines()
+    assert len(lines) == 2
+    assert json.loads(lines[0]) == report
+    verdict = json.loads(lines[1])
+    assert verdict == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    assert type(verdict["ok"]) is bool and type(verdict["device"]["count"]) is int
 
 
 def test_degradation_fails_train_leg(toy, logs, monkeypatch):
