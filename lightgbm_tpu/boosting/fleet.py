@@ -34,9 +34,9 @@ import jax
 import jax.numpy as jnp
 
 from ..obs.registry import get_session
+from ..obs.trace import get_tracer
 from ..obs.flight import get_flight
 from ..obs.device import sample_device_memory
-from ..utils.timer import global_timer
 from .gbdt import Booster
 
 
@@ -313,7 +313,7 @@ class FleetTrainer:
                 fm_rows.append(o["feature_mask"])
                 r = o["tree_rngs"][kk]
                 keys.append(self._zero_key if r is None else r)
-        with global_timer.timed("tree/grow"), get_session().phase("grow"):
+        with get_tracer().span("train/grow", phase="grow", timer="tree/grow"):
             fta, fleaf = self._grow(
                 b0._bins,
                 jnp.stack(grad_rows),
@@ -337,8 +337,7 @@ class FleetTrainer:
             sample_device_memory("grow")
         from ..ops.grower import fetch_fleet_tree_arrays
 
-        with get_session().phase("host_materialize"):
-            ta_hosts = fetch_fleet_tree_arrays(fta)
+        ta_hosts = fetch_fleet_tree_arrays(fta)  # wait/fetch_tree inside
         grown = {}
         for i in ops:
             b = boosters[i]

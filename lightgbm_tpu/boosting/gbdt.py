@@ -222,19 +222,29 @@ class Booster:
         if pend is None:
             return
         self._pending = None
-        with get_session().phase("host_materialize"):
-            self._process_pending(pend)
+        self._process_pending(pend)
 
     def _process_pending(self, pend: dict) -> None:
+        tracer = get_tracer()
+        # the one place the pipelined loop blocks on the device: the packed
+        # arrays of the tree dispatched an iteration ago
+        with tracer.span("wait/fetch_tree", phase="host_materialize"):
+            fetched = [
+                None if ints_d is None
+                else (np.asarray(ints_d), np.asarray(floats_d))
+                for _kk, ints_d, floats_d, _nn, _L in pend["classes"]
+            ]
+        with tracer.span("train/host_tree", phase="host_materialize"):
+            self._materialize_pending(pend, fetched)
+
+    def _materialize_pending(self, pend: dict, fetched: list) -> None:
         decoded = []
         should_continue = False
-        for kk, ints_d, floats_d, nn, L in pend["classes"]:
-            if ints_d is None:
+        for (kk, _i, _f, nn, L), host in zip(pend["classes"], fetched):
+            if host is None:
                 decoded.append((kk, None))
                 continue
-            ta_host = unpack_tree_arrays(
-                np.asarray(ints_d), np.asarray(floats_d), nn, L
-            )
+            ta_host = unpack_tree_arrays(host[0], host[1], nn, L)
             if self.config.check_numerics:
                 self._guard_tree(ta_host, pend.get("iter", self._iter - 1))
             if int(ta_host.num_leaves) > 1:
@@ -411,7 +421,7 @@ class Booster:
                     self._tree_rng(),
                 )
                 ta = self._quant_renew(ta, leaf_id, grad[kk], hess[kk], mask)
-                with get_session().phase("score_update"):
+                with get_tracer().span("train/score_update", phase="score_update"):
                     shrunk = ta.leaf_value * self._shrinkage_rate
                     self._score = self._score.at[kk].add(shrunk[leaf_id])
                     for entry in self._valid:
@@ -455,8 +465,7 @@ class Booster:
         }
         self._iter += 1
         if prev is not None:
-            with get_session().phase("host_materialize"):
-                self._process_pending(prev)
+            self._process_pending(prev)
             if self._finished:
                 # the previous iteration found no split: training stopped
                 # THERE, so the iteration just dispatched must leave no trace
@@ -1081,10 +1090,8 @@ class Booster:
         """Grow one tree: serial grow_tree or the mesh-sharded shard_map path
         (reference: SerialTreeLearner vs DataParallelTreeLearner dispatch,
         src/boosting/gbdt.cpp:59 tree_learner selection)."""
-        from ..utils.timer import global_timer
-
         ses = get_session()
-        with global_timer.timed("tree/grow"), ses.phase("grow"):
+        with get_tracer().span("train/grow", phase="grow", timer="tree/grow"):
             fused = self._mesh is None and bool(self._grower_params.grow_fused)
             try:
                 if fused:
@@ -1795,7 +1802,8 @@ class Booster:
         Catches poisoned labels/init_score/learning-rate blowups at the
         iteration that produced them instead of training NaN into the
         model silently."""
-        ok = bool(jnp.isfinite(grad).all() & jnp.isfinite(hess).all())
+        with get_tracer().span("wait/check_numerics"):
+            ok = bool(jnp.isfinite(grad).all() & jnp.isfinite(hess).all())
         if not ok:
             self._fault_dump("numerics_gradients")
             raise NumericsError(
@@ -1898,19 +1906,16 @@ class Booster:
             it = self._iter
             tracer = get_tracer()
             t0 = time.perf_counter()
-            sp = tracer.begin(
-                "train/iteration",
-                "train",
-                args={"iter": it},
-                attach=True,
-                ambient=True,
-            )
             finished = False
-            try:
-                finished = self._update_impl(train_set, fobj)
-            finally:
-                if sp is not None:
-                    tracer.end(sp, extra={"finished": bool(finished)})
+            with tracer.span(
+                "train/iteration", timer="boosting/update",
+                args={"iter": it}, ambient=True,
+            ) as sp:
+                try:
+                    finished = self._update_impl(train_set, fobj)
+                finally:
+                    if sp is not None:
+                        sp.args["finished"] = bool(finished)
             if flight.active or wd is not None:
                 event = {
                     "event": "iteration",
@@ -1927,31 +1932,25 @@ class Booster:
         compiles_before = _obs_compile_count()
         tracer = get_tracer()
         t0 = time.perf_counter()
-        # iteration span opens BEFORE begin_iteration so phase timers
-        # (registry._PhaseTimer -> note_phase) attach as children; ambient
-        # parents the collective io_callback spans fired off-thread
-        sp = tracer.begin(
-            "train/iteration",
-            "train",
-            args={"iter": it},
-            attach=True,
-            ambient=True,
-        )
-        ses.begin_iteration()
         finished = False
-        try:
+        # ambient parents the collective io_callback spans fired off-thread
+        with tracer.span(
+            "train/iteration", timer="boosting/update",
+            args={"iter": it}, ambient=True,
+        ) as sp:
+            ses.begin_iteration()
             try:
-                finished = self._update_impl(train_set, fobj)
+                try:
+                    finished = self._update_impl(train_set, fobj)
+                finally:
+                    phases = ses.end_iteration()
+                # under obs_sync_timing wall_ms is the fully synchronized
+                # iteration time; otherwise it is dispatch time (async
+                # runtime)
+                ses.sync(self._score)
             finally:
-                phases = ses.end_iteration()
-            # under obs_sync_timing wall_ms is the fully synchronized
-            # iteration time; otherwise it is dispatch time (async runtime)
-            ses.sync(self._score)
-        finally:
-            # the finally keeps the tls span stack balanced when
-            # _update_impl raises (NumericsError -> _fault_dump)
-            if sp is not None:
-                tracer.end(sp, extra={"finished": bool(finished)})
+                if sp is not None:
+                    sp.args["finished"] = bool(finished)
         wall_ms = (time.perf_counter() - t0) * 1e3
         # host bookkeeping (and hence these records) lags one iteration on
         # the pipelined path — splits here count trees MATERIALIZED this call
@@ -2061,6 +2060,7 @@ class Booster:
         if train_set is not None and train_set is not self.train_set:
             self._init_train(train_set)
         ses = get_session()
+        tracer = get_tracer()
         cfg = self.config
         k = self.num_tree_per_iteration
         n = self.train_set.num_data
@@ -2080,13 +2080,13 @@ class Booster:
             and type(self) is Booster
             and eff_len >= k  # init/boost-from-avg settled
         ):
-            with ses.phase("gradients"):
+            with tracer.span("train/gradients", phase="gradients"):
                 grad, hess = self._get_gradients()
                 ses.sync(grad)
             grad, hess = chaos.maybe_poison_gradients(grad, hess, self._iter)
             if cfg.check_numerics:
                 self._guard_gradients(grad, hess)
-            with ses.phase("sample"):
+            with tracer.span("train/sample", phase="sample"):
                 mask, grad, hess = self._sample(grad, hess)
                 ses.sync(mask)
             feature_mask = self._feature_mask_for_iter()
@@ -2111,7 +2111,7 @@ class Booster:
                         self._score = self._score.at[kk].add(s)
                         for entry in self._valid:
                             entry.score = entry.score.at[kk].add(s)
-            with ses.phase("gradients"):
+            with tracer.span("train/gradients", phase="gradients"):
                 grad, hess = self._get_gradients()
                 ses.sync(grad)
         else:
@@ -2140,7 +2140,7 @@ class Booster:
             self._guard_gradients(grad, hess)
 
         # bagging / GOSS (reference: SampleStrategy::Bagging gbdt.cpp:384)
-        with ses.phase("sample"):
+        with tracer.span("train/sample", phase="sample"):
             mask, grad, hess = self._sample(grad, hess)
             ses.sync(mask)
         feature_mask = self._feature_mask_for_iter()
@@ -2168,9 +2168,8 @@ class Booster:
         ta, leaf_id = self._grow_one(qg, qh, mask, feature_mask, rng)
         ta = self._quant_renew(ta, leaf_id, grad[kk], hess[kk], mask)
         # two bulk transfers instead of ~14 small ones (remote TPU
-        # round-trips dominate otherwise)
-        with get_session().phase("host_materialize"):
-            ta_host = fetch_tree_arrays(ta)
+        # round-trips dominate otherwise); wait/fetch_tree opens inside
+        ta_host = fetch_tree_arrays(ta)
         if cfg.check_numerics:
             self._guard_tree(ta_host, self._iter)
         self._note_refine_rate(ta_host)
@@ -2205,12 +2204,13 @@ class Booster:
                 leaf_value = jnp.asarray(lv, dtype=jnp.float32)
                 ta = ta._replace(leaf_value=leaf_value)
                 ta_host = ta_host._replace(leaf_value=lv)
-            tree = Tree.from_device_arrays(
-                ta_host,
-                self.train_set.bin_mappers,
-                self.train_set.used_features,
-                bundle_layout=self._bundle,
-            )
+            with get_tracer().span("train/host_tree"):
+                tree = Tree.from_device_arrays(
+                    ta_host,
+                    self.train_set.bin_mappers,
+                    self.train_set.used_features,
+                    bundle_layout=self._bundle,
+                )
             if cfg.verbosity >= 2:
                 tree.validate()  # debug CHECK paths (tree.py)
             is_linear = bool(cfg.linear_tree)
@@ -2238,29 +2238,30 @@ class Booster:
                         self._pad_delta(vdelta, entry.pad)
                     )
             else:
-                shrunk = leaf_value * self._shrinkage_rate
-                # train score update: one gather (reference UpdateScore
-                # :501); the donated entry retires the old score cache
-                if not skip_train_score:
-                    self._score = _apply_tree_score(
-                        self._score, shrunk, leaf_id, jnp.int32(kk)
-                    )
-                # valid score updates: bin-space walk of the new tree
-                for entry in self._valid:
-                    entry.score = _apply_tree_valid_score(
-                        entry.score,
-                        entry.bins,
-                        self._nan_bins,
-                        ta.split_feature,
-                        ta.split_bin,
-                        ta.default_left,
-                        ta.left_child,
-                        ta.right_child,
-                        shrunk,
-                        ta.split_is_cat,
-                        ta.cat_mask,
-                        jnp.int32(kk),
-                    )
+                with get_tracer().span("train/score_update"):
+                    shrunk = leaf_value * self._shrinkage_rate
+                    # train score update: one gather (reference UpdateScore
+                    # :501); the donated entry retires the old score cache
+                    if not skip_train_score:
+                        self._score = _apply_tree_score(
+                            self._score, shrunk, leaf_id, jnp.int32(kk)
+                        )
+                    # valid score updates: bin-space walk of the new tree
+                    for entry in self._valid:
+                        entry.score = _apply_tree_valid_score(
+                            entry.score,
+                            entry.bins,
+                            self._nan_bins,
+                            ta.split_feature,
+                            ta.split_bin,
+                            ta.default_left,
+                            ta.left_child,
+                            ta.right_child,
+                            shrunk,
+                            ta.split_is_cat,
+                            ta.cat_mask,
+                            jnp.int32(kk),
+                        )
             if abs(init_scores[kk]) > _EPS:
                 tree.add_bias(init_scores[kk])
             nn = n_leaves - 1
@@ -2337,6 +2338,7 @@ class Booster:
         Returns the iteration operands the fleet trainer stacks across
         members before the single batched grow."""
         ses = get_session()
+        tracer = get_tracer()
         cfg = self.config
         k = self.num_tree_per_iteration
         init_scores = [0.0] * k
@@ -2353,13 +2355,13 @@ class Booster:
                     self._score = self._score.at[kk].add(s)
                     for entry in self._valid:
                         entry.score = entry.score.at[kk].add(s)
-        with ses.phase("gradients"):
+        with tracer.span("train/gradients", phase="gradients"):
             grad, hess = self._get_gradients()
             ses.sync(grad)
         grad, hess = chaos.maybe_poison_gradients(grad, hess, self._iter)
         if cfg.check_numerics:
             self._guard_gradients(grad, hess)
-        with ses.phase("sample"):
+        with tracer.span("train/sample", phase="sample"):
             mask, grad, hess = self._sample(grad, hess)
             ses.sync(mask)
         feature_mask = self._feature_mask_for_iter()
@@ -2486,6 +2488,7 @@ class Booster:
     def _eval_entry(self, entry: _EvalEntry, feval=None) -> List[Tuple[str, str, float, bool]]:
         dev_score = self._score if entry is self._train_entry else entry.score
         n_real = entry.dataset.num_data
+        tracer = get_tracer()
         out = []
         score = None  # host copy, pulled only if some metric needs it
         dev_sliced = None
@@ -2494,17 +2497,22 @@ class Booster:
             if feval is None and hasattr(m, "eval_device"):
                 # device-side metric: only the result scalar crosses to host
                 # (the [K, N] score pull dominates eval at 10M+ rows)
-                if dev_sliced is None:
-                    dev_sliced = dev_score[:, :n_real]
-                res = m.eval_device(dev_sliced, self.objective)
+                # the metric's dispatches; its one blocking read of the
+                # result is wait/eval_metric inside (metrics.host_scalar)
+                with tracer.span("train/eval_score"):
+                    if dev_sliced is None:
+                        dev_sliced = dev_score[:, :n_real]
+                    res = m.eval_device(dev_sliced, self.objective)
             if res is None:
                 if score is None:
-                    score = np.asarray(dev_score, dtype=np.float64)[:, :n_real]
+                    with tracer.span("wait/eval_metric"):
+                        score = np.asarray(dev_score, dtype=np.float64)[:, :n_real]
                 res = m.eval(score, self.objective)
             for name, val in res:
                 out.append((entry.name, name, val, m.is_higher_better))
         if score is None and feval is not None:
-            score = np.asarray(dev_score, dtype=np.float64)[:, :n_real]
+            with tracer.span("wait/eval_metric"):
+                score = np.asarray(dev_score, dtype=np.float64)[:, :n_real]
         if feval is not None:
             fevals = feval if isinstance(feval, (list, tuple)) else [feval]
             # feval receives transformed predictions, matching the reference
@@ -2574,7 +2582,7 @@ class Booster:
         """Write the span recorder's ring as a Chrome trace-event JSON file
         (atomic tmp+rename).  Load the file in Perfetto
         (https://ui.perfetto.dev) or ``chrome://tracing`` to see the
-        train-launch / iteration / phase / collective span timeline.  The
+        train-launch / iteration / wait / collective span timeline.  The
         same document is served live at ``GET /trace`` when
         ``obs_export_port`` is set, and dumped automatically next to every
         flight-recorder fault dump.  Returns the path written."""

@@ -334,7 +334,8 @@ class LaunchRunner:
             # 1) gradient key + gradients (serial: _get_gradients)
             pair = jax.random.split(rng)
             rng_g, gkey = pair[0], pair[1]
-            grad, hess = objective.get_gradients(score, gkey)
+            with jax.named_scope("gradients"):
+                grad, hess = objective.get_gradients(score, gkey)
             # 2) device-side numerics latch (serial: _guard_gradients pulls
             # one host bool per iteration; here the verdict rides the carry)
             if check:
@@ -355,9 +356,10 @@ class LaunchRunner:
             rng_b, bkey = pair[0], pair[1]
             if fold_bag:
                 bkey = jax.random.fold_in(bkey, cfg.bagging_seed)
-            mask, grad, hess, bag_new = sampler.scan_sample(
-                it, grad, hess, bkey, bag
-            )
+            with jax.named_scope("sample"):
+                mask, grad, hess, bag_new = sampler.scan_sample(
+                    it, grad, hess, bkey, bag
+                )
             if any_pad:
                 mask = mask * ones_mask
             if has_fixed:
@@ -383,11 +385,13 @@ class LaunchRunner:
                 )
                 has_split = ta.num_leaves > 1
                 upd = jnp.logical_and(live_step, has_split)
-                shrunk = ta.leaf_value * shrink
-                # whole-array select (NOT add-of-masked-delta): a skipped
-                # step must keep the old score bit patterns, -0.0 included
-                cand = new_score.at[kk].add(shrunk[leaf_id])
-                new_score = jnp.where(upd, cand, new_score)
+                with jax.named_scope("score_update"):
+                    shrunk = ta.leaf_value * shrink
+                    # whole-array select (NOT add-of-masked-delta): a
+                    # skipped step must keep the old score bit patterns,
+                    # -0.0 included
+                    cand = new_score.at[kk].add(shrunk[leaf_id])
+                    new_score = jnp.where(upd, cand, new_score)
                 any_split = jnp.logical_or(any_split, has_split)
                 ii, ff = _pack_tree_arrays_impl(ta)
                 ints_rows[kk] = ii
@@ -464,8 +468,6 @@ class LaunchRunner:
         b = self._b
         cfg = b.config
         k = self._k
-        from .sampling import BaggingStrategy
-
         b._drain_pending()
         if b._finished:
             return 0, True
@@ -497,10 +499,28 @@ class LaunchRunner:
             # iteration runs serially and launches start from iteration 1
             return 1, b.update()
 
+        it0 = int(b._iter)
+        # dispatch, fetch and replay attach under the launch span via the
+        # tls stack; _note_launch puts the per-iteration counters on it
+        with get_tracer().span(
+            "train/launch",
+            timer="boosting/update",
+            args={"launch_begin": it0, "steps_per_launch": self._n},
+            ambient=True,
+        ) as lsp:
+            return self._run_window(lsp, it0, init_scores)
+
+    def _run_window(self, lsp, it0: int, init_scores) -> Tuple[int, bool]:
+        """``run()`` inside its ``train/launch`` span: one dispatch, one
+        blocking fetch, the host replay."""
+        from .sampling import BaggingStrategy
+
+        b = self._b
+        cfg = b.config
+        k = self._k
         ses = get_session()
         flight = get_flight()
         wd = getattr(b, "_watchdog", None)
-        it0 = int(b._iter)
         S = self._n
         args, fm_rows = self._operands(it0)
         for m in fm_rows:
@@ -511,22 +531,12 @@ class LaunchRunner:
 
         compiles_before = _compile_count()
         tracer = get_tracer()
-        # launch span: the phase("launch") child attaches under it via the
-        # tls stack; synthetic per-iteration children are reconstructed from
-        # the device counter records in _note_launch, which also ends it
-        lsp = tracer.begin(
-            "train/launch",
-            "train",
-            args={"launch_begin": it0, "steps_per_launch": S},
-            attach=True,
-            ambient=True,
-        )
         t0 = time.perf_counter()
         if ses.enabled:
             ses.begin_iteration()
         try:
             try:
-                with ses.phase("launch"):
+                with tracer.span("train/launch_dispatch", phase="launch"):
                     carry, ys = self._fn(*args)
                     score, rng, bag, finished_dev, bad_dev = carry
                     # donated score: rebind before anything can raise
@@ -536,15 +546,14 @@ class LaunchRunner:
                         b._sampler._mask = bag
             finally:
                 phases = ses.end_iteration() if ses.enabled else {}
-            ints = np.asarray(ys["ints"])  # [S, k, ints_len] — blocks = synced
-            floats = np.asarray(ys["floats"])
-            bad = int(bad_dev)
+            with tracer.span("wait/launch_fetch"):
+                # [S, k, ints_len] — blocks = synced
+                ints = np.asarray(ys["ints"])
+                floats = np.asarray(ys["floats"])
+                bad = int(bad_dev)
         except BaseException:
-            # scan failure skips _note_launch — end the span here to keep
-            # the tls span stack balanced for the fault path
-            if lsp is not None:
-                tracer.end(lsp, extra={"error": True})
-                lsp = None
+            if lsp is not None:  # scan failure skips _note_launch
+                lsp.args["error"] = True
             raise
         wall_ms = (time.perf_counter() - t0) * 1e3
 
@@ -552,6 +561,7 @@ class LaunchRunner:
         steps_done = 0
         records = []
         is_finished = False
+        replay = tracer.begin("train/launch_replay", attach=True)
         try:
             for s in range(S):
                 it = it0 + s
@@ -604,6 +614,7 @@ class LaunchRunner:
                     is_finished = True
                     break
         finally:
+            tracer.end(replay)
             self._note_launch(
                 ses, flight, wd, it0, steps_done, wall_ms, phases,
                 _compile_count() - compiles_before, records, is_finished,
@@ -670,42 +681,16 @@ class LaunchRunner:
                 "collective_ring_bytes_per_device",
                 coll["ring_bytes_per_device"],
             )
-        tracer = get_tracer()
+        # the exact device counters of each iteration (they rode the packed
+        # scan carry out) go on the launch span; the iterations ran inside
+        # ONE scan, so the host has no time of theirs to record
         if span is not None:
-            # synthetic per-iteration children: the device ran the S
-            # iterations inside ONE scan, so the host reconstructs S
-            # equal-width child spans under the launch span.  Boundaries
-            # are estimated (device-uniform division of the launch wall);
-            # the per-iteration counters (splits, grow_steps, refine_count)
-            # are exact device values that rode the packed scan carry out.
-            slice_us = (wall_ms * 1000.0) / steps
-            for s, rec in enumerate(records):
-                tracer.add_span(
-                    "train/iteration",
-                    "train",
-                    int(span.t0_us + s * slice_us),
-                    max(1, int(slice_us)),
-                    trace_id=span.trace_id,
-                    parent_id=span.span_id,
-                    args={
-                        "iter": rec["iter"],
-                        "trees_materialized": rec["trees_materialized"],
-                        "splits": rec["splits"],
-                        "grow_steps": rec["grow_steps"],
-                        "refine_count": rec["refine_count"],
-                        "from_launch": True,
-                    },
-                    synthetic=True,
-                    tid=span.tid,
-                )
-            tracer.end(
-                span,
-                extra={
-                    "steps": steps_done,
-                    "launch_wall_ms": wall_ms,
-                    "compiles_delta": compiles_delta,
-                    "finished": bool(is_finished),
-                },
+            span.args.update(
+                steps=steps_done,
+                launch_wall_ms=wall_ms,
+                compiles_delta=compiles_delta,
+                finished=bool(is_finished),
+                per_iteration=[dict(r) for r in records],
             )
         if ses.enabled:
             ses.inc("iterations", steps_done)
@@ -1045,9 +1030,10 @@ class FleetLaunchRunner:
                 b._rng = rngs[i]
                 if isinstance(b._sampler, BaggingStrategy):
                     b._sampler._mask = bags[i]
-        ints = np.asarray(ys["ints"])  # [S, n_trained, M, ints_len]
-        floats = np.asarray(ys["floats"])
-        bad = [int(x) for x in bad_dev]
+        with get_tracer().span("wait/launch_fetch"):
+            ints = np.asarray(ys["ints"])  # [S, n_trained, M, ints_len]
+            floats = np.asarray(ys["floats"])
+            bad = [int(x) for x in bad_dev]
         wall_ms = (time.perf_counter() - t0) * 1e3
 
         trained_idx = [kk for kk in range(k) if self._trains[kk]]

@@ -678,13 +678,13 @@ class Dataset:
     def construct(self) -> "Dataset":
         if self._constructed:
             return self
-        from .utils.timer import global_timer
+        from .obs.trace import get_tracer
 
-        with global_timer.timed("dataset/construct"):
+        with get_tracer().span("dataset/construct", "setup", timer=True):
             return self._construct_inner()
 
     def _construct_inner(self) -> "Dataset":
-        from .utils.timer import global_timer
+        from .obs.trace import get_tracer
 
         data = self._raw_data
         label = self._label
@@ -824,10 +824,10 @@ class Dataset:
                 sparse_csc = sparse_csc.copy()
                 sparse_csc.resize(n, self.num_total_features)
         elif sparse_csc is not None:
-            with global_timer.timed("dataset/bin_fit"):
+            with get_tracer().span("dataset/bin_fit", "setup", timer=True):
                 self._build_bin_mappers_sparse(sparse_csc, cat_idx)
         else:
-            with global_timer.timed("dataset/bin_fit"):
+            with get_tracer().span("dataset/bin_fit", "setup", timer=True):
                 self._build_bin_mappers(data, cat_idx)
         self._sync_mappers_across_processes()
 
@@ -838,7 +838,7 @@ class Dataset:
         # above so planes bin identically.
         if self.reference is None and self.config.enable_bundle \
                 and self._bundling_allowed():
-            with global_timer.timed("dataset/bundle"):
+            with get_tracer().span("dataset/bundle", "setup", timer=True):
                 self.bundle_layout = self._find_bundle_layout(
                     data, sparse_csc, n
                 )
@@ -883,7 +883,7 @@ class Dataset:
             # cv()'s fold slicing works; the dense float is still never built
             self.raw = None if self.free_raw_data else sparse_csc.tocsr()
         else:
-            with global_timer.timed("dataset/pack"):
+            with get_tracer().span("dataset/pack", "setup", timer=True):
                 if layout is not None:
                     binned = layout.pack_columns(
                         n,
@@ -981,7 +981,7 @@ class Dataset:
         row shard alone."""
         from .ingest.pipeline import stream_pack
         from .ingest.sources import ArrowChunkSource, PandasChunkSource
-        from .utils.timer import global_timer
+        from .obs.trace import get_tracer
 
         cfg = self.config
         n = source.n_rows
@@ -1020,7 +1020,7 @@ class Dataset:
             self.feature_names = ref.feature_names
             self.num_total_features = ref.num_total_features
         else:
-            with global_timer.timed("dataset/ingest/sample"):
+            with get_tracer().span("dataset/ingest/sample", "setup", timer=True):
                 if sharded:
                     from .ingest.sharded import exchange_global_sample
 
@@ -1039,13 +1039,13 @@ class Dataset:
                     else:
                         rows = np.arange(n, dtype=np.int64)
                     sample = source.sample_rows(rows)
-            with global_timer.timed("dataset/ingest/bin_fit"):
+            with get_tracer().span("dataset/ingest/bin_fit", "setup", timer=True):
                 self.bin_mappers = []
                 self.used_features = []
                 for j in range(num_features):
                     self._add_mapper(j, sample[:, j], cat_idx)
             if cfg.enable_bundle and self._bundling_allowed():
-                with global_timer.timed("dataset/ingest/bundle"):
+                with get_tracer().span("dataset/ingest/bundle", "setup", timer=True):
                     from .bundling import build_layout
 
                     # nonzero scan over the SAMPLE matrix with the sample
@@ -1074,7 +1074,7 @@ class Dataset:
             n_cols = len(self.used_features)
         dtype = np.uint8 if max_bins <= 256 else np.uint16
         self._check_binned_footprint(n, n_cols, np.dtype(dtype).itemsize)
-        with global_timer.timed("dataset/ingest/pack"):
+        with get_tracer().span("dataset/ingest/pack", "setup", timer=True):
             self.bins = stream_pack(
                 source, self.bin_mappers, self.used_features, layout,
                 dtype, cfg,

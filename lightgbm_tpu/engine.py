@@ -27,6 +27,7 @@ from .obs.flight import (
 )
 from .obs.profiler import TraceWindow
 from .obs.registry import get_session
+from .obs.trace import get_tracer
 from .utils.log import log_info
 from .utils.timer import global_timer
 
@@ -69,8 +70,6 @@ def train(
     # distributed tracing: always-on span recorder (independent of the
     # telemetry session) — iteration/launch spans land under one train/run
     # root span, dumped via Booster.dump_trace / GET /trace / on fault
-    from .obs.trace import get_tracer
-
     tracer = get_tracer()
     tracer.configure(
         active=cfg.trace_spans,
@@ -96,6 +95,9 @@ def train(
     valid_sets = list(valid_sets or [])
     valid_names = list(valid_names or [])
 
+    # create_booster -> the first iteration's start; unattached, so a raise
+    # on the way leaves no stale parent on the span stack
+    init_span = tracer.begin("setup/booster_init", "setup")
     booster = create_booster(params, train_set)
     if init_model is not None:
         init_booster = (
@@ -197,6 +199,7 @@ def train(
     prev_dispatch_end: Optional[float] = None
     # root span for the whole training run: iteration/launch spans created
     # by Booster.update / LaunchRunner.run attach as children (tls stack)
+    tracer.end(init_span)
     run_span = tracer.begin(
         "train/run",
         "train",
@@ -245,14 +248,15 @@ def train(
                 booster._host_overhead_n += 1
                 if ses.enabled:
                     ses.set_gauge("train/host_overhead_ms", host_ms)
-            with global_timer.timed("boosting/update"):
-                if use_launch:
-                    steps, is_finished = booster.update_launch(launch_n)
-                else:
-                    is_finished = booster.update(fobj=fobj)
-                    steps = 1
-                    if ses.enabled and launch_n > 1:
-                        ses.set_gauge("train/steps_per_launch_effective", 1.0)
+            # train/iteration and train/launch open inside these calls and
+            # feed global_timer's boosting/update
+            if use_launch:
+                steps, is_finished = booster.update_launch(launch_n)
+            else:
+                is_finished = booster.update(fobj=fobj)
+                steps = 1
+                if ses.enabled and launch_n > 1:
+                    ses.set_gauge("train/steps_per_launch_effective", 1.0)
             prev_dispatch_end = time.perf_counter()
             it_last = it + max(1, steps) - 1
             if trace is not None:
@@ -273,7 +277,7 @@ def train(
             if ck_dir and ck_int > 0 and (it_last + 1) % ck_int == 0:
                 from .resilience.checkpoint import save_checkpoint
 
-                with global_timer.timed("boosting/checkpoint"):
+                with tracer.span("train/checkpoint", timer="boosting/checkpoint"):
                     save_checkpoint(booster, ck_dir)
 
             evaluation_result_list = []
@@ -281,7 +285,7 @@ def train(
                 (it_last + 1) % max(1, booster.config.metric_freq) == 0
                 or it_last + 1 == end_iteration
             ):
-                with global_timer.timed("boosting/eval"):
+                with tracer.span("train/eval", timer="boosting/eval"):
                     if is_valid_contain_train:
                         res = booster.eval_train(feval)
                         evaluation_result_list.extend(
@@ -296,17 +300,18 @@ def train(
                             for (d, n, v, _hib) in evaluation_result_list
                         }
                     })
-            for cb in callbacks_after:
-                cb(
-                    CallbackEnv(
-                        model=booster,
-                        params=params,
-                        iteration=it_last,
-                        begin_iteration=begin_iteration,
-                        end_iteration=end_iteration,
-                        evaluation_result_list=evaluation_result_list,
+            with tracer.span("train/callbacks"):
+                for cb in callbacks_after:
+                    cb(
+                        CallbackEnv(
+                            model=booster,
+                            params=params,
+                            iteration=it_last,
+                            begin_iteration=begin_iteration,
+                            end_iteration=end_iteration,
+                            evaluation_result_list=evaluation_result_list,
+                        )
                     )
-                )
             if is_finished:
                 break
             it += max(1, steps)
@@ -482,7 +487,7 @@ def train_fleet(
             if (it_last + 1) % max(1, b.config.metric_freq) == 0 or (
                 it_last + 1 == num_boost_round
             ):
-                with global_timer.timed("boosting/eval"):
+                with get_tracer().span("train/eval", timer="boosting/eval"):
                     evals = b.eval_valid(feval)
                 if evals:
                     last_eval[i] = evals

@@ -19,8 +19,16 @@ import numpy as np
 
 from ..config import Config
 from ..obs.jit import instrumented_jit
+from ..obs.trace import get_tracer
 
 _EPS = 1e-15
+
+
+def host_scalar(x) -> float:
+    """A device metric's one blocking read, as the ``wait/eval_metric``
+    span: everything before it in ``eval_device`` is dispatch."""
+    with get_tracer().span("wait/eval_metric"):
+        return float(x)
 
 
 def _to_np(x) -> np.ndarray:
@@ -117,7 +125,7 @@ class _PointwiseMetric(Metric):
             return None
         if self._weight_dev is not None:
             pt = pt * self._weight_dev
-        return [(self.name, self.average(float(pt.sum()), self.sum_weights))]
+        return [(self.name, self.average(host_scalar(pt.sum()), self.sum_weights))]
 
 
 class L2Metric(_PointwiseMetric):
@@ -315,7 +323,7 @@ class AUCMetric(Metric):
         sum_all = ww.sum()
         denom = sum_pos * (sum_all - sum_pos)
         auc = jnp.where(denom > 0, accum / jnp.maximum(denom, 1e-30), 1.0)
-        return [(self.name, float(auc))]
+        return [(self.name, host_scalar(auc))]
 
 
 class AveragePrecisionMetric(Metric):
@@ -399,7 +407,7 @@ class MultiLoglossMetric(Metric):
         total = _mlogloss_device_jit(
             score_dev, self._label_dev, self._weight_dev
         )
-        return [(self.name, float(total) / self.sum_weights)]
+        return [(self.name, host_scalar(total) / self.sum_weights)]
 
 
 class MultiErrorMetric(Metric):

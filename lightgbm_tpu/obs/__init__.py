@@ -18,6 +18,9 @@ into:
 * per-host aggregation — GlobalSyncUp-style counter/gauge merge plus
   straggler gauges for multi-host runs — ``aggregate``;
 * ``jax.profiler`` trace capture over an iteration window — ``profiler``;
+  the program's names in any such trace: every ``trace`` span is a profiler
+  annotation in ``/host:CPU``, and ``op_scopes()`` maps a device event's
+  HLO instruction to the ``jax.named_scope`` path that emitted it — ``jit``;
 * the LIVE ops plane — ``flight`` (always-on bounded ring buffer with
   atomic dump-on-fault: NumericsError, degradation latch, SIGTERM),
   ``health`` (per-iteration host-side watchdog emitting severity-tagged
@@ -72,6 +75,8 @@ from .jit import (  # noqa: F401
     instrumented_jit,
     note_compile,
     note_executable,
+    op_scope_maps,
+    op_scopes,
     record_executable,
 )
 from .profiler import TraceWindow  # noqa: F401
@@ -108,6 +113,8 @@ __all__ = [
     "record_executable",
     "compile_count",
     "compile_counts_by_label",
+    "op_scopes",
+    "op_scope_maps",
     "collectives_snapshot",
     "measured_summary",
     "timed_psum",

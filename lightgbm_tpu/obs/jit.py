@@ -24,23 +24,42 @@ the memoized gauge values into the current session, so a session started
 after the traces were made still sees the full cost/memory families.
 Backends whose executables expose neither analysis degrade to a silent
 no-op (absent gauge keys, never an error).
+
+Operation scopes (:func:`op_scopes`): a profiler trace names a device event
+by its HLO instruction and carries no ``jax.named_scope``; the compiled
+artifact's text carries both (``metadata={op_name="jit(f)/while/body/
+split_scan/reduce"}``).  So on a call that traced, when the persistent
+compilation cache keeps the program, the wrapper lowers and compiles it once
+more (a hit on the entry just written), parses instruction -> scope and
+writes the map beside the cache (``<cache dir>/op_scopes/``).  A later call,
+a later process, or an operator joining a ``profile_trace_dir`` trace reads
+the files; a warm run pays one existence check per traced label.
 """
 
 from __future__ import annotations
 
 import functools
+import glob
+import hashlib
+import json
+import os
+import re
 import threading
-from typing import Any, Dict, Optional
+import time
+import weakref
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
+from .flight import _atomic_write_text
 from .registry import get_session
 
 _lock = threading.Lock()
 _count = 0
 _by_label: Dict[str, int] = {}
 # bumped on every counted trace: __call__ compares before/after to detect
-# "this call traced" without touching jax internals
+# "this call traced" without touching jax internals (a plain read: a trace
+# on another thread at worst costs one more existence check)
 _epoch = 0
 _tls = threading.local()  # .suppress set during the accounting re-lower
 
@@ -71,11 +90,6 @@ def compile_counts_by_label() -> Dict[str, int]:
     """Per-call-site breakdown of :func:`compile_count`."""
     with _lock:
         return dict(_by_label)
-
-
-def _trace_epoch() -> int:
-    with _lock:
-        return _epoch
 
 
 # --------------------------------------------------- executable accounting
@@ -179,12 +193,16 @@ class _InstrumentedJit:
         functools.update_wrapper(self, fun)
 
     def __call__(self, *args: Any, **kwargs: Any):
+        before = _epoch
+        t0 = time.perf_counter()
+        out = self._jit(*args, **kwargs)
+        traced = _epoch != before  # bumped by every counted trace
+        if traced:
+            _note_traced(self, args, kwargs, time.perf_counter() - t0)
         ses = get_session()
         if not (ses.enabled and ses.device_accounting):
-            return self._jit(*args, **kwargs)
-        before = _trace_epoch()
-        out = self._jit(*args, **kwargs)
-        if _trace_epoch() != before:
+            return out
+        if traced:
             self._capture(args, kwargs)
         else:
             cached = _label_analyses.get(self._label)
@@ -254,6 +272,278 @@ class _InstrumentedJit:
     def __getattr__(self, name: str):
         # delegate everything else (clear_cache, eval_shape, ...) to the jit
         return getattr(self._jit, name)
+
+
+# ------------------------------------------------------------ op scopes
+SCOPES_SCHEMA = "lgbtpu.op_scopes.v1"
+AMBIGUOUS = "ambiguous"
+# op_name segments that are structure, not scopes: control flow, call
+# wrappers, and the qualified name of a loop body's function
+_STRUCTURAL = re.compile(
+    r"^(?:while|body|cond|branch_\d+_fun|closed_call|checkpoint|remat\d*|"
+    r"custom_[jv][vj]p_call\w*|core_call|pjit|shard_map|named_call|"
+    r".*<locals>.*)$"
+)
+# "jit(f)" names an inner jitted function; "vmap(split_scan)" is a scope
+# seen through a transform
+_WRAPPED = re.compile(r"^([\w.\-]+)\((.*)\)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)")
+# instructions the device never runs as events of their own
+_NO_EVENT = re.compile(r"[\]})] (?:parameter|constant|get-tuple-element|tuple)\(")
+
+# (predicted module, signature) -> (weak instance, abstract args, kwargs):
+# what a later op_scopes() needs to rebuild a map that is not on disk
+_traced: Dict[Tuple[str, str], Tuple[Any, Any, Any]] = {}
+_built: Dict[Tuple[str, str], Dict[str, Any]] = {}  # maps built on request
+_code_hash: Optional[str] = None
+
+
+def _scopes_dir() -> Optional[str]:
+    cache = jax.config.jax_compilation_cache_dir
+    return os.path.join(cache, "op_scopes") if cache else None
+
+
+def _code_fingerprint() -> str:
+    """Hash of the package's sources: a map is of the code that wrote it, so
+    a cache directory shared by two checkouts never joins one's trace with
+    the other's instruction names."""
+    global _code_hash
+    if _code_hash is None:
+        h = hashlib.sha1(jax.__version__.encode())
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        for d, _dirs, files in sorted(os.walk(root)):
+            for f in sorted(files):
+                if f.endswith(".py"):
+                    with open(os.path.join(d, f), "rb") as fh:
+                        h.update(fh.read())
+        _code_hash = h.hexdigest()[:16]
+    return _code_hash
+
+
+def _abstract(x: Any) -> Any:
+    """An argument as ``lower()`` takes it without holding its buffer."""
+    if isinstance(x, jax.Array):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=x.sharding if x.committed else None,
+            weak_type=x.weak_type,
+        )
+    if hasattr(x, "shape") and hasattr(x, "dtype"):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+    return x
+
+
+def _signature(abstract: Any) -> str:
+    """Hash of what selects an executable among those of one function:
+    argument shapes, dtypes, shardings and static values, the backend, and
+    the code."""
+    leaves, treedef = jax.tree_util.tree_flatten(abstract)
+    dev = jax.devices()[0]
+    h = hashlib.sha1(
+        f"{_code_fingerprint()}|{dev.platform}|{dev.device_kind}|{treedef}".encode()
+    )
+    for leaf in leaves:
+        h.update(repr(leaf).encode())
+    return h.hexdigest()[:16]
+
+
+def _note_traced(inst: "_InstrumentedJit", args, kwargs, seconds: float) -> None:
+    """After a call that traced: remember how to rebuild the label's map,
+    and write it now if the persistent cache keeps this program (it took at
+    least ``jax_persistent_cache_min_compile_time_secs``, so the second
+    ``compile()`` is a hit) and the file is not there yet.  Never raises."""
+    try:
+        if _has_tracer(jax.tree_util.tree_leaves((args, kwargs))):
+            return  # traced inside another program: that program's map has it
+        abstract = jax.tree_util.tree_map(_abstract, (args, kwargs))
+        module = re.sub(r"[^\w.\-]", "_", "jit_" + inst.__name__)
+        key = (module, _signature(abstract))
+        _traced[key] = (weakref.ref(inst), abstract[0], abstract[1])
+        directory = _scopes_dir()
+        if directory is None or seconds < float(
+            jax.config.jax_persistent_cache_min_compile_time_secs
+        ):
+            return
+        path = os.path.join(directory, f"{key[0]}-{key[1]}.json")
+        if not os.path.exists(path):
+            doc = _build_map(inst, key, args, kwargs)
+            os.makedirs(directory, exist_ok=True)
+            _atomic_write_text(path, json.dumps(doc))
+    except Exception:
+        pass
+
+
+def _build_map(inst, key, args, kwargs) -> Dict[str, Any]:
+    t0 = time.perf_counter()
+    _tls.suppress = True  # not a new logical trace
+    try:
+        text = inst._jit.lower(*args, **kwargs).compile().as_text()
+    finally:
+        _tls.suppress = False
+    module, scopes = parse_op_scopes(text)
+    return {
+        "schema": SCOPES_SCHEMA, "module": module, "label": inst._label,
+        "signature": key[1], "code": _code_fingerprint(),
+        "capture_s": time.perf_counter() - t0, "scopes": scopes,
+    }
+
+
+def _common_path(paths: List[List[str]]) -> List[str]:
+    """The leading segments every path shares."""
+    common = paths[0]
+    for path in paths[1:]:
+        k = 0
+        while k < min(len(common), len(path)) and common[k] == path[k]:
+            k += 1
+        common = common[:k]
+    return common
+
+
+def _segment(seg: str) -> str:
+    """The scope a name-stack segment holds, or ``""``."""
+    m = _WRAPPED.match(seg)
+    while m is not None:
+        if m.group(1) in ("jit", "pjit"):
+            return ""
+        seg = m.group(2)
+        m = _WRAPPED.match(seg)
+    return "" if _STRUCTURAL.match(seg) else seg
+
+
+def scope_path(op_name: str, scope_names=()) -> str:
+    """``jit(step)/while/body/leaf_loop/vmap(bookkeeping)/scatter`` ->
+    ``leaf_loop/bookkeeping``: what ``jax.named_scope`` put there.  The last
+    segment is the primitive, unless it is one of ``scope_names`` (a
+    compiler-made instruction can carry its scope alone); of names the
+    compiler merged (``a;b``) the common leading part is kept."""
+    paths = []
+    for one in op_name.split(";"):
+        parts = one.split("/")
+        if parts and _segment(parts[-1]) not in scope_names:
+            parts = parts[:-1]
+        paths.append([seg for seg in map(_segment, parts) if seg])
+    return "/".join(_common_path(paths))
+
+
+def parse_op_scopes(text: str) -> Tuple[str, Dict[str, str]]:
+    """(module name, {instruction name: scope path}) of a compiled
+    executable's text.  An instruction without metadata that calls a
+    computation (a ``fusion``) takes the deepest common scope of that
+    computation's instructions; one without either maps to ``""``."""
+    module = ""
+    op_names: Dict[str, Optional[str]] = {}
+    calls: Dict[str, str] = {}
+    members: Dict[str, List[str]] = {}
+    skip = set()  # never events of their own: parameters, constants, ...
+    fused = set()  # ... and what a fusion runs as one event
+    comp = ""
+    for line in text.splitlines():
+        if not module:
+            m = _MODULE.match(line)
+            if m:
+                module = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            c = _COMPUTATION.match(line)
+            if c:
+                comp = c.group(1)
+            continue
+        name = m.group(1)
+        members.setdefault(comp, []).append(name)
+        o = _OP_NAME.search(line)
+        op_names[name] = o.group(1) if o else None
+        c = _CALLS.search(line)
+        if c:
+            calls[name] = c.group(1)
+            if " fusion(" in line:
+                fused.add(c.group(1))
+        if _NO_EVENT.search(line):
+            skip.add(name)
+    scope_names = {""}
+    for op_name in op_names.values():
+        for one in (op_name or "").split(";"):
+            scope_names.update(map(_segment, one.split("/")[:-1]))
+    scope_names.discard("")
+    resolved: Dict[str, Optional[str]] = {}
+
+    def resolve(name: str) -> Optional[str]:
+        """The instruction's scope, or None when nothing says."""
+        if name in resolved:
+            return resolved[name]
+        resolved[name] = None  # cycle guard
+        op_name = op_names.get(name)
+        if op_name is not None:
+            scope: Optional[str] = scope_path(op_name, scope_names)
+        elif name in calls:
+            inner = [resolve(n) for n in members.get(calls[name], ())]
+            paths = [s.split("/") if s else [] for s in inner if s is not None]
+            scope = "/".join(_common_path(paths)) if paths else None
+        else:
+            scope = None
+        resolved[name] = scope
+        return scope
+
+    for c in fused:
+        skip.update(members.get(c, ()))
+    return module, {
+        name: resolve(name) or "" for name in op_names if name not in skip
+    }
+
+
+def op_scope_maps() -> List[Dict[str, Any]]:
+    """One document per executable of this code on this backend: the files
+    under ``<compilation cache dir>/op_scopes/`` plus, for labels traced in
+    this process whose file is absent (no cache directory, or a program the
+    cache does not keep), a map built now from the live ``instrumented_jit``
+    object.  A dead object's map is simply absent."""
+    docs: Dict[Tuple[str, str], Dict[str, Any]] = {}
+    directory = _scopes_dir()
+    code = _code_fingerprint()
+    if directory is not None:
+        for path in sorted(glob.glob(os.path.join(directory, "*.json"))):
+            try:
+                with open(path, "r", encoding="utf-8") as fh:
+                    doc = json.load(fh)
+            except (OSError, ValueError):
+                continue
+            if doc.get("schema") == SCOPES_SCHEMA and doc.get("code") == code:
+                stem = os.path.basename(path)[: -len(".json")]
+                docs[tuple(stem.rsplit("-", 1))] = doc
+    for key, (ref, args, kwargs) in list(_traced.items()):
+        if key in docs:
+            continue
+        if key not in _built:
+            inst = ref()
+            if inst is None:
+                del _traced[key]
+                continue
+            try:
+                _built[key] = _build_map(inst, key, args, kwargs)
+            except Exception:
+                continue
+        docs[key] = _built[key]
+    return list(docs.values())
+
+
+def op_scopes() -> Dict[str, Dict[str, str]]:
+    """``{HLO module name: {instruction name: scope path}}`` for every
+    program compiled through :func:`instrumented_jit`: the join a profiler
+    trace needs to put the program's ``jax.named_scope`` names on its device
+    events (an ``XLA Ops`` event is named by its instruction, inside an
+    ``XLA Modules`` event named by its module).  Two executables that share
+    a module name are merged; an instruction they map to different scopes
+    reads ``"ambiguous"``.  See :func:`op_scope_maps` for what is found."""
+    out: Dict[str, Dict[str, str]] = {}
+    for doc in op_scope_maps():
+        merged = out.setdefault(doc["module"], {})
+        for name, scope in doc["scopes"].items():
+            if merged.setdefault(name, scope) != scope:
+                merged[name] = AMBIGUOUS
+    return out
 
 
 def instrumented_jit(fun=None, *, label: Optional[str] = None, **jit_kwargs):

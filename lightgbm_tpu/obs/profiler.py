@@ -3,12 +3,13 @@
 ``Config.profile_trace_dir`` plus ``profile_iter_start``/``profile_iter_end``
 drive ``jax.profiler.start_trace``/``stop_trace`` from the training loop —
 the standard way to get a TensorBoard-loadable device trace of exactly the
-steady-state iterations (skipping compile/warmup noise).  The grower's
-``jax.named_scope`` labels (partition / histogram / split_scan /
-candidate_refresh / bookkeeping — or ``fused_grow_step`` replacing the
-partition/histogram pair when the fused Pallas grow step is engaged, see
-ops/pallas/grow_step.py) and the predictor's ``TraceAnnotation`` phases
-appear inside the captured trace.
+steady-state iterations (skipping compile/warmup noise).  While the window
+is open every ``TraceRecorder`` span (obs/trace.py) and the predictor's
+``TraceAnnotation`` phases are events of the ``/host:CPU`` plane.  The
+grower's ``jax.named_scope`` labels (partition / histogram / split_scan /
+candidate_refresh / bookkeeping / fused_grow_step ...) do NOT appear on a
+TPU's device events, which are named by their HLO instruction alone; join
+them through ``obs.op_scopes()`` (obs/jit.py).
 """
 
 from __future__ import annotations

@@ -25,25 +25,7 @@ from __future__ import annotations
 import contextlib
 import json
 import threading
-import time
 from typing import Any, Dict, List, Optional
-
-from .trace import note_phase as _note_phase
-
-
-class _NullPhase:
-    """Shared no-op context manager handed out when telemetry is off."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_PHASE = _NullPhase()
 
 
 def _jsonable(obj: Any) -> Any:
@@ -239,7 +221,7 @@ class TelemetrySession:
 
     # -------------------------------------------------------- phase timing
     def begin_iteration(self) -> None:
-        """Open a per-iteration phase accumulator (see :meth:`phase`)."""
+        """Open a per-iteration phase accumulator (see :meth:`add_phase`)."""
         if self.enabled:
             self._phases = {}
 
@@ -248,14 +230,14 @@ class TelemetrySession:
         phases, self._phases = self._phases, None
         return phases or {}
 
-    def phase(self, name: str):
-        """Context manager accumulating host wall time for ``name`` into the
-        open iteration accumulator.  A shared no-op when telemetry is off
-        (or no iteration is open), so hot paths can call it unconditionally.
-        """
-        if not self.enabled or self._phases is None:
-            return _NULL_PHASE
-        return _PhaseTimer(self._phases, name)
+    def add_phase(self, name: str, seconds: float) -> None:
+        """Add host wall time to ``name`` in the open iteration accumulator
+        (fed by the trace spans of the layer boundaries, obs/trace.py
+        ``TraceRecorder.span(phase=...)``).  A no-op when telemetry is off
+        or no iteration is open, so hot paths call it unconditionally."""
+        phases = self._phases
+        if self.enabled and phases is not None:
+            phases[name] = phases.get(name, 0.0) + seconds
 
     def sync(self, value: Any) -> None:
         """Block on device values inside a phase when ``obs_sync_timing`` is
@@ -264,26 +246,6 @@ class TelemetrySession:
             import jax
 
             jax.block_until_ready(value)
-
-
-class _PhaseTimer:
-    __slots__ = ("_acc", "_name", "_t0")
-
-    def __init__(self, acc: Dict[str, float], name: str) -> None:
-        self._acc = acc
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        self._acc[self._name] = self._acc.get(self._name, 0.0) + dt
-        # phase walls double as trace spans under the open iteration/launch
-        # span (obs/trace.py); no-op when tracing is off or no span is open
-        _note_phase(self._name, self._t0, dt)
-        return False
 
 
 _SESSION = TelemetrySession()

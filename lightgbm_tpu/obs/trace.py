@@ -15,14 +15,28 @@ sized: 16-byte / 8-byte hex), monotonic-clock timestamps
 recorder is GL003-clean by construction), and a category used by the
 per-category sampling knobs.
 
-Span taxonomy (see README "Distributed tracing"):
+Every span opened through ``begin``/``span`` is also a
+``jax.profiler.TraceAnnotation``: while any profiler session is live
+(``profile_trace_dir``, a TensorBoard capture, the benchmark's traced
+window) the span is an event of the ``/host:CPU`` plane beside the
+``PjitFunction(...)`` dispatches, on the clock the device planes use, with
+its scalar args as stats.  With no session the annotation is a flag check.
+``add_span`` builds a span after the fact and stays ring-only.
 
-* ``train``      — ``train/run`` > ``train/launch`` > ``train/iteration``
-                   (launch-window per-iteration children are reconstructed
-                   from device-side counters and flagged ``synthetic: true``
-                   — device-uniform time division, not measurement)
-* ``phase``      — ``registry.phase`` timers as children of the open
-                   iteration/launch span
+Span taxonomy (see README "Distributed tracing"; ``wait/*`` is every place
+the training loop blocks on the device):
+
+* ``train``      — ``train/run`` > ``train/iteration`` > {``train/gradients``,
+                   ``train/sample``, ``train/grow``, ``wait/fetch_tree``,
+                   ``train/host_tree``, ``train/score_update``};
+                   ``train/launch`` > {``train/launch_dispatch``,
+                   ``wait/launch_fetch``, ``train/launch_replay``} (the
+                   exact per-iteration device counters ride in its
+                   ``per_iteration`` arg); ``train/eval`` >
+                   ``train/eval_score`` > ``wait/eval_metric``;
+                   ``train/callbacks``; ``train/checkpoint``
+* ``setup``      — ``setup/booster_init``; ``dataset/construct`` >
+                   {``dataset/bin_fit``, ``dataset/bundle``, ``dataset/pack``}
 * ``collective`` — ``timed_psum``/``timed_pmax`` sites with payload bytes
 * ``serve``      — ``serve/batch`` > {``serve/request`` >
                    ``serve/queue_wait``, ``serve/batch_assembly``,
@@ -47,7 +61,11 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple, Union
 
+from jax.profiler import TraceAnnotation
+
+from ..utils.timer import global_timer
 from .flight import _atomic_write_text
+from .registry import get_session
 
 TRACE_SCHEMA = "lgbtpu.trace.v1"
 
@@ -85,7 +103,7 @@ class SpanHandle:
 
     __slots__ = (
         "name", "cat", "trace_id", "span_id", "parent_id",
-        "t0_us", "args", "tid", "_attached", "_ambient",
+        "t0_us", "args", "tid", "_attached", "_ambient", "_annotation",
     )
 
     def __init__(
@@ -102,6 +120,16 @@ class SpanHandle:
         self.tid = tid
         self._attached = False
         self._ambient = False
+        # the same span in the profiler's trace; records there only while a
+        # profiler session is live, with the scalar args as the event's stats
+        self._annotation = TraceAnnotation(
+            name,
+            **{
+                k: v for k, v in args.items()
+                if isinstance(v, (bool, int, float, str))
+            },
+        )
+        self._annotation.__enter__()
 
 
 class TraceRecorder:
@@ -283,6 +311,7 @@ class TraceRecorder:
             handle._ambient = False
         if extra:
             handle.args.update(extra)
+        handle._annotation.__exit__(None, None, None)
         t1 = self.now_us() if end_us is None else int(end_us)
         self._append(
             {
@@ -299,13 +328,34 @@ class TraceRecorder:
         )
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "train", **kwargs):
-        """Context-managed span, attached as the current parent."""
+    def span(
+        self,
+        name: str,
+        cat: str = "train",
+        *,
+        phase: Optional[str] = None,
+        timer: Union[bool, str, None] = None,
+        **kwargs,
+    ):
+        """Context-managed span, attached as the current parent.
+
+        One call site per layer boundary: the span's wall also feeds
+        ``phase`` of the telemetry session's open iteration accumulator
+        (``Booster.telemetry()`` / JSONL ``phases``) and the ``timer`` label
+        of ``global_timer`` (``True``: the span's own name).  Both are fed
+        whether or not the span itself was recorded (tracing off, sampled
+        out)."""
         h = self.begin(name, cat, attach=True, **kwargs)
+        t0 = time.perf_counter()
         try:
             yield h
         finally:
             self.end(h)
+            dt = time.perf_counter() - t0
+            if timer:
+                global_timer.add(name if timer is True else timer, dt)
+            if phase is not None:
+                get_session().add_phase(phase, dt)
 
     def instant(
         self,
@@ -352,13 +402,13 @@ class TraceRecorder:
         span_id: Optional[str] = None,
         parent_id: Optional[str] = None,
         args: Optional[Dict[str, Any]] = None,
-        synthetic: bool = False,
         tid: Optional[int] = None,
     ) -> Optional[str]:
-        """Record a finished span with explicit timestamps (the launch
-        replay's synthetic per-iteration children and the batcher's stage
-        decomposition both build spans after the fact).  Bypasses sampling
-        — the enclosing span already made the sampling decision."""
+        """Record a finished span with explicit timestamps (the batcher's
+        stage decomposition and the measured collectives build spans after
+        the fact, from clock readings they took themselves).  Ring-only: a
+        finished span cannot be a profiler annotation.  Bypasses sampling —
+        the enclosing span already made the sampling decision."""
         if not self.active:
             return None
         sid = span_id or self.new_span_id()
@@ -373,8 +423,6 @@ class TraceRecorder:
             "tid": self._tid() if tid is None else int(tid),
             "args": dict(args or {}),
         }
-        if synthetic:
-            rec["synthetic"] = True
         self._append(rec)
         return sid
 
@@ -426,8 +474,6 @@ class TraceRecorder:
             args["span_id"] = rec["span_id"]
             if rec.get("parent_id"):
                 args["parent_id"] = rec["parent_id"]
-            if rec.get("synthetic"):
-                args["synthetic"] = True
             ev: Dict[str, Any] = {
                 "name": rec["name"],
                 "cat": rec["cat"],
@@ -484,24 +530,6 @@ def get_tracer() -> TraceRecorder:
 
 
 # --------------------------------------------------------------- hot hooks
-def note_phase(name: str, t0_s: float, dur_s: float) -> None:
-    """Record a ``registry.phase`` timer as a child span of the open
-    iteration/launch span.  ``t0_s`` is a ``time.perf_counter`` reading —
-    the same clock as span timestamps, so no epoch conversion is needed.
-    No-op (one attribute check + one current() lookup) when tracing is off
-    or no span is open, so the phase hot path stays cheap."""
-    tr = _TRACER
-    if not tr.active:
-        return
-    parent = tr.current()
-    if parent is None or not tr._sampled("phase"):
-        return
-    tr.add_span(
-        f"phase/{name}", "phase", int(t0_s * 1e6), int(dur_s * 1e6),
-        trace_id=parent.trace_id, parent_id=parent.span_id, tid=parent.tid,
-    )
-
-
 def note_collective(site: str, t0_ns: int, t1_ns: int, nbytes: int) -> None:
     """Record one measured-collective site call as a span with payload-byte
     args, parented under the ambient training span when one is open.  Host

@@ -34,6 +34,7 @@ from jax import lax
 
 from ..obs.collectives import timed_pmax, timed_pmin, timed_psum
 from ..obs.jit import instrumented_jit
+from ..obs.trace import get_tracer
 from .histogram import leaf_histogram
 from .split import CatParams, SplitCandidate, best_split, leaf_gain, leaf_output
 
@@ -612,32 +613,33 @@ def _pack_tree_arrays_impl(ta: "TreeArrays"):
     """Pack a TreeArrays into (ints, floats) flat vectors so the host can
     fetch a whole tree in two transfers instead of ~14 (each transfer is a
     full round-trip on remote-attached TPUs)."""
-    ints = jnp.concatenate(
-        [
-            ta.split_feature,
-            ta.split_bin,
-            ta.left_child,
-            ta.right_child,
-            ta.default_left.astype(jnp.int32),
-            ta.leaf_depth,
-            ta.num_leaves[None],
-            ta.grow_steps[None],
-            ta.refine_count[None],
-            ta.split_is_cat.astype(jnp.int32),
-            ta.cat_mask.astype(jnp.int32).reshape(-1),
-        ]
-    )
-    floats = jnp.concatenate(
-        [
-            ta.split_gain,
-            ta.internal_value,
-            ta.internal_weight,
-            ta.internal_count,
-            ta.leaf_value,
-            ta.leaf_weight,
-            ta.leaf_count,
-        ]
-    )
+    with jax.named_scope("pack_tree"):
+        ints = jnp.concatenate(
+            [
+                ta.split_feature,
+                ta.split_bin,
+                ta.left_child,
+                ta.right_child,
+                ta.default_left.astype(jnp.int32),
+                ta.leaf_depth,
+                ta.num_leaves[None],
+                ta.grow_steps[None],
+                ta.refine_count[None],
+                ta.split_is_cat.astype(jnp.int32),
+                ta.cat_mask.astype(jnp.int32).reshape(-1),
+            ]
+        )
+        floats = jnp.concatenate(
+            [
+                ta.split_gain,
+                ta.internal_value,
+                ta.internal_weight,
+                ta.internal_count,
+                ta.leaf_value,
+                ta.leaf_weight,
+                ta.leaf_count,
+            ]
+        )
     return ints, floats
 
 
@@ -703,7 +705,9 @@ def fetch_tree_arrays(ta: "TreeArrays") -> "TreeArrays":
     ints_d, floats_d = pack_tree_arrays(ta)
     nn = ta.split_feature.shape[0]  # L - 1
     L = ta.leaf_value.shape[0]
-    return unpack_tree_arrays(np.asarray(ints_d), np.asarray(floats_d), nn, L)
+    with get_tracer().span("wait/fetch_tree", phase="host_materialize"):
+        ints, floats = np.asarray(ints_d), np.asarray(floats_d)
+    return unpack_tree_arrays(ints, floats, nn, L)
 
 
 # fleet variant: one vmapped pack of the whole [M, ...] stacked TreeArrays,
@@ -724,8 +728,9 @@ def fetch_fleet_tree_arrays(ta: "TreeArrays"):
     m = ta.split_feature.shape[0]
     nn = ta.split_feature.shape[1]  # L - 1
     L = ta.leaf_value.shape[1]
-    ints = np.asarray(ints_d)
-    floats = np.asarray(floats_d)
+    with get_tracer().span("wait/fetch_tree", phase="host_materialize"):
+        ints = np.asarray(ints_d)
+        floats = np.asarray(floats_d)
     return [unpack_tree_arrays(ints[i], floats[i], nn, L) for i in range(m)]
 
 
@@ -1073,9 +1078,10 @@ def grow_tree(
                     "use hist_mode='ordered' or a smaller max_bin"
                 )
         n_pad_seg = padded_rows(n)
-        seg0 = pack_rows(
-            bins_loc, grad, hess, count_mask, n_pad_seg, wide=seg_wide
-        )
+        with jax.named_scope("pack_rows"):
+            seg0 = pack_rows(
+                bins_loc, grad, hess, count_mask, n_pad_seg, wide=seg_wide
+            )
 
         # explicit int8 opt-in (hist_method='pallas_int8' + quantized
         # gradients): integer grid accumulation, exact and ~2x throughput
@@ -1099,7 +1105,8 @@ def grow_tree(
         if use_int8_acc:
             from .quantize import hist_acc_scales
 
-            seg_qs = hist_acc_scales(grad, hess, count_mask)
+            with jax.named_scope("pack_rows"):
+                seg_qs = hist_acc_scales(grad, hess, count_mask)
 
         # live-plane skip: feature-plane groups with no usable feature under
         # the TREE-level deterministic mask (feature_fraction bytree / EFB
@@ -1391,91 +1398,92 @@ def grow_tree(
             **_root_kwargs,
         )
 
-    neg_inf = jnp.full((L,), -jnp.inf, dtype=jnp.float32)
-    cand = SplitCandidate(
-        gain=neg_inf,
-        feature=jnp.zeros((L,), jnp.int32),
-        bin=jnp.zeros((L,), jnp.int32),
-        default_left=jnp.zeros((L,), bool),
-        left_g=jnp.zeros((L,), jnp.float32),
-        left_h=jnp.zeros((L,), jnp.float32),
-        left_cnt=jnp.zeros((L,), jnp.float32),
-        right_g=jnp.zeros((L,), jnp.float32),
-        right_h=jnp.zeros((L,), jnp.float32),
-        right_cnt=jnp.zeros((L,), jnp.float32),
-        is_cat=jnp.zeros((L,), bool),
-        cat_mask=jnp.zeros((L, Bm), bool),
-    )
-    cand = _set_cand(cand, 0, cand0)
-
-    if use_ordered:
-        order0 = jnp.concatenate(
-            [
-                jnp.arange(n, dtype=jnp.int32),
-                jnp.full((order_len - n,), n, jnp.int32),
-            ]
+    with jax.named_scope("init_state"):
+        neg_inf = jnp.full((L,), -jnp.inf, dtype=jnp.float32)
+        cand = SplitCandidate(
+            gain=neg_inf,
+            feature=jnp.zeros((L,), jnp.int32),
+            bin=jnp.zeros((L,), jnp.int32),
+            default_left=jnp.zeros((L,), bool),
+            left_g=jnp.zeros((L,), jnp.float32),
+            left_h=jnp.zeros((L,), jnp.float32),
+            left_cnt=jnp.zeros((L,), jnp.float32),
+            right_g=jnp.zeros((L,), jnp.float32),
+            right_h=jnp.zeros((L,), jnp.float32),
+            right_cnt=jnp.zeros((L,), jnp.float32),
+            is_cat=jnp.zeros((L,), bool),
+            cat_mask=jnp.zeros((L, Bm), bool),
         )
-        leaf_begin0 = jnp.zeros((L,), jnp.int32)
-        leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n)
-        leaf_id0 = jnp.zeros((0,), jnp.int32)
-    elif use_seg:
-        # the order slot carries the packed segment matrix in seg mode
-        order0 = seg0
-        leaf_begin0 = jnp.zeros((L,), jnp.int32)
-        leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n)
-        leaf_id0 = jnp.zeros((0,), jnp.int32)
-    else:
-        order0 = jnp.zeros((0,), jnp.int32)
-        leaf_begin0 = jnp.zeros((0,), jnp.int32)
-        leaf_nrows0 = jnp.zeros((0,), jnp.int32)
-        leaf_id0 = jnp.zeros((n,), jnp.int32)
+        cand = _set_cand(cand, 0, cand0)
 
-    state = _State(
-        leaf_id=leaf_id0,
-        order=order0,
-        leaf_begin=leaf_begin0,
-        leaf_nrows=leaf_nrows0,
-        hist_buf=jnp.zeros((L, f_loc, B, 3), jnp.float32).at[0].set(hist0),
-        leaf_g=jnp.zeros((L,), jnp.float32).at[0].set(totals[0]),
-        leaf_h=jnp.zeros((L,), jnp.float32).at[0].set(totals[1]),
-        leaf_cnt=jnp.zeros((L,), jnp.float32).at[0].set(totals[2]),
-        leaf_depth=jnp.zeros((L,), jnp.int32),
-        leaf_parent=jnp.full((L,), -1, jnp.int32),
-        leaf_is_right=jnp.zeros((L,), bool),
-        leaf_lb=jnp.full((L,), -jnp.inf, jnp.float32),
-        leaf_ub=jnp.full((L,), jnp.inf, jnp.float32),
-        # root box spans the whole bin space of every feature
-        leaf_box=(
-            jnp.zeros((L, f, 2), jnp.int32).at[:, :, 1].set(B - 1)
-            if use_inter_mono
-            else jnp.zeros((L, 0, 2), jnp.int32)
-        ),
-        leaf_allowed=jnp.zeros((L, f), bool),  # stores USED features per path
-        cand=cand,
-        split_feature=jnp.zeros((L - 1,), jnp.int32),
-        split_bin=jnp.zeros((L - 1,), jnp.int32),
-        split_gain=jnp.zeros((L - 1,), jnp.float32),
-        default_left=jnp.zeros((L - 1,), bool),
-        split_is_cat=jnp.zeros((L - 1,), bool),
-        node_cat_mask=jnp.zeros((L - 1, Bm), bool),
-        # unused nodes point at leaf 0 (~0 = -1) so walking a trivial tree
-        # (no splits recorded) terminates instead of spinning on node 0
-        left_child=jnp.full((L - 1,), -1, jnp.int32),
-        right_child=jnp.full((L - 1,), -1, jnp.int32),
-        internal_value=jnp.zeros((L - 1,), jnp.float32),
-        internal_weight=jnp.zeros((L - 1,), jnp.float32),
-        internal_count=jnp.zeros((L - 1,), jnp.float32),
-        num_leaves=jnp.asarray(1, jnp.int32),
-        done=jnp.asarray(False),
-        forced_ok=jnp.asarray(p.n_forced > 0),
-        cegb_used=cegb_used0,
-        steps=jnp.asarray(0, jnp.int32),
-        refines=(
-            near0.astype(jnp.int32)
-            if use_int8_acc
-            else jnp.asarray(0, jnp.int32)
-        ),
-    )
+        if use_ordered:
+            order0 = jnp.concatenate(
+                [
+                    jnp.arange(n, dtype=jnp.int32),
+                    jnp.full((order_len - n,), n, jnp.int32),
+                ]
+            )
+            leaf_begin0 = jnp.zeros((L,), jnp.int32)
+            leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n)
+            leaf_id0 = jnp.zeros((0,), jnp.int32)
+        elif use_seg:
+            # the order slot carries the packed segment matrix in seg mode
+            order0 = seg0
+            leaf_begin0 = jnp.zeros((L,), jnp.int32)
+            leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n)
+            leaf_id0 = jnp.zeros((0,), jnp.int32)
+        else:
+            order0 = jnp.zeros((0,), jnp.int32)
+            leaf_begin0 = jnp.zeros((0,), jnp.int32)
+            leaf_nrows0 = jnp.zeros((0,), jnp.int32)
+            leaf_id0 = jnp.zeros((n,), jnp.int32)
+
+        state = _State(
+            leaf_id=leaf_id0,
+            order=order0,
+            leaf_begin=leaf_begin0,
+            leaf_nrows=leaf_nrows0,
+            hist_buf=jnp.zeros((L, f_loc, B, 3), jnp.float32).at[0].set(hist0),
+            leaf_g=jnp.zeros((L,), jnp.float32).at[0].set(totals[0]),
+            leaf_h=jnp.zeros((L,), jnp.float32).at[0].set(totals[1]),
+            leaf_cnt=jnp.zeros((L,), jnp.float32).at[0].set(totals[2]),
+            leaf_depth=jnp.zeros((L,), jnp.int32),
+            leaf_parent=jnp.full((L,), -1, jnp.int32),
+            leaf_is_right=jnp.zeros((L,), bool),
+            leaf_lb=jnp.full((L,), -jnp.inf, jnp.float32),
+            leaf_ub=jnp.full((L,), jnp.inf, jnp.float32),
+            # root box spans the whole bin space of every feature
+            leaf_box=(
+                jnp.zeros((L, f, 2), jnp.int32).at[:, :, 1].set(B - 1)
+                if use_inter_mono
+                else jnp.zeros((L, 0, 2), jnp.int32)
+            ),
+            leaf_allowed=jnp.zeros((L, f), bool),  # stores USED features per path
+            cand=cand,
+            split_feature=jnp.zeros((L - 1,), jnp.int32),
+            split_bin=jnp.zeros((L - 1,), jnp.int32),
+            split_gain=jnp.zeros((L - 1,), jnp.float32),
+            default_left=jnp.zeros((L - 1,), bool),
+            split_is_cat=jnp.zeros((L - 1,), bool),
+            node_cat_mask=jnp.zeros((L - 1, Bm), bool),
+            # unused nodes point at leaf 0 (~0 = -1) so walking a trivial tree
+            # (no splits recorded) terminates instead of spinning on node 0
+            left_child=jnp.full((L - 1,), -1, jnp.int32),
+            right_child=jnp.full((L - 1,), -1, jnp.int32),
+            internal_value=jnp.zeros((L - 1,), jnp.float32),
+            internal_weight=jnp.zeros((L - 1,), jnp.float32),
+            internal_count=jnp.zeros((L - 1,), jnp.float32),
+            num_leaves=jnp.asarray(1, jnp.int32),
+            done=jnp.asarray(False),
+            forced_ok=jnp.asarray(p.n_forced > 0),
+            cegb_used=cegb_used0,
+            steps=jnp.asarray(0, jnp.int32),
+            refines=(
+                near0.astype(jnp.int32)
+                if use_int8_acc
+                else jnp.asarray(0, jnp.int32)
+            ),
+        )
 
     node_ids = jnp.arange(L - 1, dtype=jnp.int32)
     use_forced_splits = p.n_forced > 0 and forced is not None
@@ -1493,105 +1501,106 @@ def grow_tree(
         dynamic-update-slice on the loop carry with NO conditional in sight.
         A no-split step degenerates to zero-count partition/histogram work
         plus O(L·F·B) bookkeeping."""
-        norm_leaf = jnp.argmax(st.cand.gain).astype(jnp.int32)
+        with jax.named_scope("bookkeeping"):
+            norm_leaf = jnp.argmax(st.cand.gain).astype(jnp.int32)
 
-        # ---- local candidate for this step: the per-leaf best, or — for the
-        # first n_forced steps — the host-provided forced split evaluated on
-        # the leaf's histogram (reference ForceSplits,
-        # serial_tree_learner.cpp:627 + GatherInfoForThreshold,
-        # feature_histogram.hpp:475-595)
-        if use_forced_splits:
-            f_leaf_a, f_feat_a, f_bin_a, f_iscat_a = forced
-            tf = jnp.minimum(t, p.n_forced - 1)
-            is_f_step = (t < p.n_forced) & st.forced_ok
-            f_leaf = f_leaf_a[tf]
-            f_feat = f_feat_a[tf]
-            f_bin = f_bin_a[tf]
-            f_iscat = f_iscat_a[tf]
-            hrow = st.hist_buf[f_leaf, f_feat]  # [B, 3]
-            if use_voting:
-                # voting keeps hist_buf LOCAL; a forced split needs the
-                # global row for this one feature (tiny psum)
-                hrow = timed_psum(
-                    hrow, p.axis_name, site="hist",
-                    measure=p.measure_collectives,
+            # ---- local candidate for this step: the per-leaf best, or — for the
+            # first n_forced steps — the host-provided forced split evaluated on
+            # the leaf's histogram (reference ForceSplits,
+            # serial_tree_learner.cpp:627 + GatherInfoForThreshold,
+            # feature_histogram.hpp:475-595)
+            if use_forced_splits:
+                f_leaf_a, f_feat_a, f_bin_a, f_iscat_a = forced
+                tf = jnp.minimum(t, p.n_forced - 1)
+                is_f_step = (t < p.n_forced) & st.forced_ok
+                f_leaf = f_leaf_a[tf]
+                f_feat = f_feat_a[tf]
+                f_bin = f_bin_a[tf]
+                f_iscat = f_iscat_a[tf]
+                hrow = st.hist_buf[f_leaf, f_feat]  # [B, 3]
+                if use_voting:
+                    # voting keeps hist_buf LOCAL; a forced split needs the
+                    # global row for this one feature (tiny psum)
+                    hrow = timed_psum(
+                        hrow, p.axis_name, site="hist",
+                        measure=p.measure_collectives,
+                    )
+                nbv = nan_bins[f_feat]
+                has_nb = nbv >= 0
+                nan_s = jnp.where(has_nb, hrow[jnp.maximum(nbv, 0)], 0.0)
+                brow_ids = jnp.arange(B, dtype=jnp.int32)
+                hrow_o = jnp.where(
+                    ((brow_ids == nbv) & has_nb)[:, None], 0.0, hrow
                 )
-            nbv = nan_bins[f_feat]
-            has_nb = nbv >= 0
-            nan_s = jnp.where(has_nb, hrow[jnp.maximum(nbv, 0)], 0.0)
-            brow_ids = jnp.arange(B, dtype=jnp.int32)
-            hrow_o = jnp.where(
-                ((brow_ids == nbv) & has_nb)[:, None], 0.0, hrow
-            )
-            cumr = jnp.cumsum(hrow_o, axis=0)
-            fpg, fph, fpc = (
-                st.leaf_g[f_leaf],
-                st.leaf_h[f_leaf],
-                st.leaf_cnt[f_leaf],
-            )
-            # numeric: missing goes LEFT (GatherInfoForThresholdNumerical
-            # sets default_left=true); categorical: one-hot on the bin
-            f_left = jnp.where(f_iscat, hrow[f_bin], cumr[f_bin] + nan_s)
-            f_lg, f_lh, f_lc = f_left[0], f_left[1], f_left[2]
-            f_rg, f_rh, f_rc = fpg - f_lg, fph - f_lh, fpc - f_lc
-            f_raw = leaf_gain(f_lg, f_lh, p.lambda_l1, p.lambda_l2) + leaf_gain(
-                f_rg, f_rh, p.lambda_l1, p.lambda_l2
-            )
-            f_gain = (
-                f_raw
-                - leaf_gain(fpg, fph, p.lambda_l1, p.lambda_l2)
-                - p.min_gain_to_split
-            )
-            use_forced = is_f_step & (f_gain > 0)
-            # a failed forced split aborts the REMAINING forced steps
-            # (abort_last_forced_split) and normal growth resumes
-            forced_ok_next = st.forced_ok & (~is_f_step | use_forced)
-            best_leaf = jnp.where(use_forced, f_leaf, norm_leaf)
-        else:
-            use_forced = None
-            forced_ok_next = st.forced_ok
-            best_leaf = norm_leaf
+                cumr = jnp.cumsum(hrow_o, axis=0)
+                fpg, fph, fpc = (
+                    st.leaf_g[f_leaf],
+                    st.leaf_h[f_leaf],
+                    st.leaf_cnt[f_leaf],
+                )
+                # numeric: missing goes LEFT (GatherInfoForThresholdNumerical
+                # sets default_left=true); categorical: one-hot on the bin
+                f_left = jnp.where(f_iscat, hrow[f_bin], cumr[f_bin] + nan_s)
+                f_lg, f_lh, f_lc = f_left[0], f_left[1], f_left[2]
+                f_rg, f_rh, f_rc = fpg - f_lg, fph - f_lh, fpc - f_lc
+                f_raw = leaf_gain(f_lg, f_lh, p.lambda_l1, p.lambda_l2) + leaf_gain(
+                    f_rg, f_rh, p.lambda_l1, p.lambda_l2
+                )
+                f_gain = (
+                    f_raw
+                    - leaf_gain(fpg, fph, p.lambda_l1, p.lambda_l2)
+                    - p.min_gain_to_split
+                )
+                use_forced = is_f_step & (f_gain > 0)
+                # a failed forced split aborts the REMAINING forced steps
+                # (abort_last_forced_split) and normal growth resumes
+                forced_ok_next = st.forced_ok & (~is_f_step | use_forced)
+                best_leaf = jnp.where(use_forced, f_leaf, norm_leaf)
+            else:
+                use_forced = None
+                forced_ok_next = st.forced_ok
+                best_leaf = norm_leaf
 
-        l = best_leaf
-        c_gain = st.cand.gain[l]
-        c_feat = st.cand.feature[l]
-        c_bin = st.cand.bin[l]
-        c_dl = st.cand.default_left[l]
-        c_cis = st.cand.is_cat[l]
-        c_cmask = st.cand.cat_mask[l]
-        c_lg, c_lh, c_lc = (
-            st.cand.left_g[l],
-            st.cand.left_h[l],
-            st.cand.left_cnt[l],
-        )
-        c_rg, c_rh, c_rc = (
-            st.cand.right_g[l],
-            st.cand.right_h[l],
-            st.cand.right_cnt[l],
-        )
-        if use_forced_splits:
-            c_gain = jnp.where(use_forced, f_gain, c_gain)
-            c_feat = jnp.where(use_forced, f_feat, c_feat)
-            c_bin = jnp.where(use_forced, f_bin, c_bin)
-            c_dl = jnp.where(use_forced, ~f_iscat, c_dl)
-            c_cis = jnp.where(use_forced, f_iscat, c_cis)
-            if use_cat:
-                oh = jnp.arange(Bm, dtype=jnp.int32) == f_bin
-                c_cmask = jnp.where(use_forced, oh, c_cmask)
-            c_lg = jnp.where(use_forced, f_lg, c_lg)
-            c_lh = jnp.where(use_forced, f_lh, c_lh)
-            c_lc = jnp.where(use_forced, f_lc, c_lc)
-            c_rg = jnp.where(use_forced, f_rg, c_rg)
-            c_rh = jnp.where(use_forced, f_rh, c_rh)
-            c_rc = jnp.where(use_forced, f_rc, c_rc)
+            l = best_leaf
+            c_gain = st.cand.gain[l]
+            c_feat = st.cand.feature[l]
+            c_bin = st.cand.bin[l]
+            c_dl = st.cand.default_left[l]
+            c_cis = st.cand.is_cat[l]
+            c_cmask = st.cand.cat_mask[l]
+            c_lg, c_lh, c_lc = (
+                st.cand.left_g[l],
+                st.cand.left_h[l],
+                st.cand.left_cnt[l],
+            )
+            c_rg, c_rh, c_rc = (
+                st.cand.right_g[l],
+                st.cand.right_h[l],
+                st.cand.right_cnt[l],
+            )
+            if use_forced_splits:
+                c_gain = jnp.where(use_forced, f_gain, c_gain)
+                c_feat = jnp.where(use_forced, f_feat, c_feat)
+                c_bin = jnp.where(use_forced, f_bin, c_bin)
+                c_dl = jnp.where(use_forced, ~f_iscat, c_dl)
+                c_cis = jnp.where(use_forced, f_iscat, c_cis)
+                if use_cat:
+                    oh = jnp.arange(Bm, dtype=jnp.int32) == f_bin
+                    c_cmask = jnp.where(use_forced, oh, c_cmask)
+                c_lg = jnp.where(use_forced, f_lg, c_lg)
+                c_lh = jnp.where(use_forced, f_lh, c_lh)
+                c_lc = jnp.where(use_forced, f_lc, c_lc)
+                c_rg = jnp.where(use_forced, f_rg, c_rg)
+                c_rh = jnp.where(use_forced, f_rh, c_rh)
+                c_rc = jnp.where(use_forced, f_rc, c_rc)
 
-        raw_can = c_gain > 0.0
-        done = st.done | ~raw_can
-        # once any step's best gain is <= 0 it stays <= 0 (cand is frozen),
-        # but gate on st.done anyway so no stale candidate can ever re-split
-        can_split = raw_can & ~st.done
-        nl = (t + 1).astype(jnp.int32)
-        feat, tbin, dl, cis, cmask = c_feat, c_bin, c_dl, c_cis, c_cmask
+            raw_can = c_gain > 0.0
+            done = st.done | ~raw_can
+            # once any step's best gain is <= 0 it stays <= 0 (cand is frozen),
+            # but gate on st.done anyway so no stale candidate can ever re-split
+            can_split = raw_can & ~st.done
+            nl = (t + 1).astype(jnp.int32)
+            feat, tbin, dl, cis, cmask = c_feat, c_bin, c_dl, c_cis, c_cmask
 
         # ---- partition rows of leaf l (reference DataPartition::Split) and
         # histogram the smaller child (serial_tree_learner.cpp:558-583), all
@@ -2148,31 +2157,32 @@ def grow_tree(
                 )
                 hist2 = jnp.where(near2[:, None, None, None], hist_rf, hist2)
             cand2 = jax.vmap(_child_cand)(hist2, g2, h2, c2, fm2, po2, *opt2)
-        cand_l = SplitCandidate(*[a[0] for a in cand2])
-        cand_r = SplitCandidate(*[a[1] for a in cand2])
-        depth_ok = (p.max_depth <= 0) | (d_new < p.max_depth)
-        cand = _set_cand(
-            st.cand, l, cand_l,
-            jnp.where(depth_ok, cand_l.gain, -jnp.inf), pred=can_split,
-        )
-        cand = _set_cand(
-            cand, nl, cand_r,
-            jnp.where(depth_ok, cand_r.gain, -jnp.inf), pred=can_split,
-        )
-        if use_inter_mono:
-            # write back the refreshed candidates of bound-tightened leaves
-            for kk in range(inter_idxs.shape[0]):
-                row = SplitCandidate(*[a[2 + kk] for a in cand2])
-                cand = _set_cand(
-                    cand, inter_idxs[kk], row,
-                    pred=can_split & inter_valid[kk],
-                )
+        with jax.named_scope("candidate_refresh"):
+            cand_l = SplitCandidate(*[a[0] for a in cand2])
+            cand_r = SplitCandidate(*[a[1] for a in cand2])
+            depth_ok = (p.max_depth <= 0) | (d_new < p.max_depth)
+            cand = _set_cand(
+                st.cand, l, cand_l,
+                jnp.where(depth_ok, cand_l.gain, -jnp.inf), pred=can_split,
+            )
+            cand = _set_cand(
+                cand, nl, cand_r,
+                jnp.where(depth_ok, cand_r.gain, -jnp.inf), pred=can_split,
+            )
+            if use_inter_mono:
+                # write back the refreshed candidates of bound-tightened leaves
+                for kk in range(inter_idxs.shape[0]):
+                    row = SplitCandidate(*[a[2 + kk] for a in cand2])
+                    cand = _set_cand(
+                        cand, inter_idxs[kk], row,
+                        pred=can_split & inter_valid[kk],
+                    )
 
-        if use_ordered or use_seg:
-            leaf_begin = _set1(st.leaf_begin, nl, begin_l + nleft)
-            leaf_nrows = _set1(_set1(st.leaf_nrows, l, nleft), nl, nright)
-        else:
-            leaf_begin, leaf_nrows = st.leaf_begin, st.leaf_nrows
+            if use_ordered or use_seg:
+                leaf_begin = _set1(st.leaf_begin, nl, begin_l + nleft)
+                leaf_nrows = _set1(_set1(st.leaf_nrows, l, nleft), nl, nright)
+            else:
+                leaf_begin, leaf_nrows = st.leaf_begin, st.leaf_nrows
 
         return _State(
             leaf_id=leaf_id,
@@ -2900,25 +2910,26 @@ def grow_tree(
         else:
             state = lax.fori_loop(0, L - 1, body, state)
 
-    leaf_idx = jnp.arange(L, dtype=jnp.int32)
-    active = leaf_idx < state.num_leaves
-    out = leaf_output(
-        state.leaf_g, state.leaf_h, p.lambda_l1, p.lambda_l2, p.max_delta_step
-    )
-    if p.path_smooth > 0.0:
-        parent_out = jnp.where(
-            state.leaf_parent >= 0,
-            state.internal_value[jnp.maximum(state.leaf_parent, 0)],
-            0.0,
+    with jax.named_scope("leaf_values"):
+        leaf_idx = jnp.arange(L, dtype=jnp.int32)
+        active = leaf_idx < state.num_leaves
+        out = leaf_output(
+            state.leaf_g, state.leaf_h, p.lambda_l1, p.lambda_l2, p.max_delta_step
         )
-        ratio = state.leaf_cnt / p.path_smooth
-        out = out * ratio / (ratio + 1.0) + parent_out / (ratio + 1.0)
-    if use_mono:
-        out = jnp.clip(out, state.leaf_lb, state.leaf_ub)
-    # a tree with no splits contributes NOTHING (reference outputs a const-0
-    # tree and stops, gbdt.cpp:428) — zeroing here lets the booster dispatch
-    # the score update before knowing num_leaves on host (async pipeline)
-    leaf_value = jnp.where(active & (state.num_leaves > 1), out, 0.0)
+        if p.path_smooth > 0.0:
+            parent_out = jnp.where(
+                state.leaf_parent >= 0,
+                state.internal_value[jnp.maximum(state.leaf_parent, 0)],
+                0.0,
+            )
+            ratio = state.leaf_cnt / p.path_smooth
+            out = out * ratio / (ratio + 1.0) + parent_out / (ratio + 1.0)
+        if use_mono:
+            out = jnp.clip(out, state.leaf_lb, state.leaf_ub)
+        # a tree with no splits contributes NOTHING (reference outputs a const-0
+        # tree and stops, gbdt.cpp:428) — zeroing here lets the booster dispatch
+        # the score update before knowing num_leaves on host (async pipeline)
+        leaf_value = jnp.where(active & (state.num_leaves > 1), out, 0.0)
 
     tree = TreeArrays(
         split_feature=state.split_feature,
@@ -2944,14 +2955,15 @@ def grow_tree(
     if use_seg:
         # leaf per segment position (marker-cumsum) -> row order via ONE sort
         # (the scatter alternative serializes on TPU)
-        lp = leaf_of_positions(
-            state.leaf_begin, state.leaf_nrows, state.num_leaves, n
-        )
-        GLO = stat_lanes(f_seg, seg_wide)[0]
-        ridx = (state.order[GLO + 5, :n].astype(jnp.int32) & 0xFFFF) | (
-            (state.order[GLO + 6, :n].astype(jnp.int32) & 0xFFFF) << 16
-        )
-        return tree, leaf_id_from_seg(ridx, lp)
+        with jax.named_scope("leaf_ids"):
+            lp = leaf_of_positions(
+                state.leaf_begin, state.leaf_nrows, state.num_leaves, n
+            )
+            GLO = stat_lanes(f_seg, seg_wide)[0]
+            ridx = (state.order[GLO + 5, :n].astype(jnp.int32) & 0xFFFF) | (
+                (state.order[GLO + 6, :n].astype(jnp.int32) & 0xFFFF) << 16
+            )
+            return tree, leaf_id_from_seg(ridx, lp)
     if use_ordered:
         # reconstruct the per-row leaf-id vector from the segment layout in
         # ONE O(N) pass: mark each active leaf's segment start, turn starts

@@ -312,26 +312,27 @@ def _add_tree_to_score_impl(
     n = bins.shape[0]
     use_cat = cat_mask is not None and cat_mask.shape[-1] > 1
 
-    def cond(nodes):
-        return jnp.any(nodes >= 0)
+    with jax.named_scope("score_update"):
+        def cond(nodes):
+            return jnp.any(nodes >= 0)
 
-    def body(nodes):
-        cur = jnp.maximum(nodes, 0)
-        feat = split_feature[cur]
-        tbin = split_bin[cur]
-        dl = default_left[cur]
-        fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]
-        nb = nan_bins[feat]
-        go_left = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
-        if use_cat:
-            bm = cat_mask.shape[-1]
-            gl_cat = cat_mask[cur, jnp.minimum(fval, bm - 1)] & (fval < bm)
-            go_left = jnp.where(split_is_cat[cur], gl_cat, go_left)
-        nxt = jnp.where(go_left, left_child[cur], right_child[cur])
-        return jnp.where(nodes >= 0, nxt, nodes)
+        def body(nodes):
+            cur = jnp.maximum(nodes, 0)
+            feat = split_feature[cur]
+            tbin = split_bin[cur]
+            dl = default_left[cur]
+            fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]
+            nb = nan_bins[feat]
+            go_left = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
+            if use_cat:
+                bm = cat_mask.shape[-1]
+                gl_cat = cat_mask[cur, jnp.minimum(fval, bm - 1)] & (fval < bm)
+                go_left = jnp.where(split_is_cat[cur], gl_cat, go_left)
+            nxt = jnp.where(go_left, left_child[cur], right_child[cur])
+            return jnp.where(nodes >= 0, nxt, nodes)
 
-    nodes = lax.while_loop(cond, body, jnp.zeros((n,), jnp.int32))
-    return score_k + leaf_value[~nodes]
+        nodes = lax.while_loop(cond, body, jnp.zeros((n,), jnp.int32))
+        return score_k + leaf_value[~nodes]
 
 
 # standalone entry (valid-score updates call it once per tree with a dead

@@ -4,8 +4,8 @@ summary printed at shutdown when verbosity allows).
 
 On an async accelerator runtime, phase walls measure HOST time: dispatch cost
 for jitted phases, full device time for phases that synchronize (eval pulls
-scores to host).  ``jax.named_scope`` annotations inside the grower mark the
-same phases for ``jax.profiler`` traces.
+scores to host).  The phases are fed by the trace spans of the layer
+boundaries (obs/trace.py), which are also ``jax.profiler`` annotations.
 """
 
 from __future__ import annotations
@@ -25,16 +25,20 @@ class GlobalTimer:
         # workers, threaded predict) would drop increments without a lock
         self._lock = threading.Lock()
 
+    def add(self, name: str, seconds: float) -> None:
+        """One finished phase (the trace spans of the layer boundaries feed
+        their wall here: obs/trace.py ``TraceRecorder.span(timer=...)``)."""
+        with self._lock:
+            self.totals[name] += seconds
+            self.counts[name] += 1
+
     @contextlib.contextmanager
     def timed(self, name: str) -> Iterator[None]:
         t0 = time.perf_counter()
         try:
             yield
         finally:
-            dt = time.perf_counter() - t0
-            with self._lock:
-                self.totals[name] += dt
-                self.counts[name] += 1
+            self.add(name, time.perf_counter() - t0)
 
     def reset(self) -> None:
         with self._lock:

@@ -41,3 +41,37 @@ def topo():
 def test_kernel_mosaic_compiles(topo, name):
     compiled = aot_check.CHECKS[name](topo)
     assert compiled is not None
+
+
+def test_named_scopes_survive_the_tpu_compiler(topo):
+    """``obs.op_scopes()`` rests on this: the optimized HLO of a v5e
+    executable still carries ``jax.named_scope`` paths in its instructions'
+    ``op_name``, through a ``while`` body, and a fusion's scope can be read
+    from the computation it calls."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.obs.jit import parse_op_scopes
+
+    def step(x):
+        with jax.named_scope("split_scan"):
+            best = jnp.argmax(jnp.cumsum(x, axis=0) * x, axis=1)
+
+        def body(c, t):
+            with jax.named_scope("leaf_loop"):
+                with jax.named_scope("bookkeeping"):
+                    c = c.at[t].set(c[t] * 2 + 1)
+                with jax.named_scope("candidate_refresh"):
+                    c = jnp.sort(c) + jnp.where(c > 0, c, -c)
+            return c, c.sum()
+
+        c, s = jax.lax.scan(body, x[:, 0], jnp.arange(8))
+        return best, c, s
+
+    compiled = aot_check.compile_on_topo(topo, step, aot_check.s((256, 128), jnp.float32))
+    module, scopes = parse_op_scopes(compiled.as_text())
+    assert module.startswith("jit_")
+    paths = set(scopes.values())
+    assert {"split_scan", "leaf_loop/bookkeeping", "leaf_loop/candidate_refresh"} <= paths
+    assert any(name.startswith("fusion") or name.endswith("fusion") or "fusion." in name
+               for name, path in scopes.items() if path)

@@ -139,6 +139,12 @@ def test_batcher_deadline_vs_full_flush_and_carry():
         # lone small request: nothing else arrives -> deadline flush
         r = b.submit(np.ones((8, 3))).result(timeout=30.0)
         assert r.values.shape == (8,)
+        # the worker publishes its counters after it has answered: the
+        # answer can arrive a moment before they do
+        for _ in range(200):
+            if b.counters["deadline_flush"]:
+                break
+            time.sleep(0.01)
         assert b.counters["deadline_flush"] == 1
         # 200 + 100 rows: the second overflows 256, so the first batch
         # flushes FULL and the overflow is carried (FIFO) to the next
@@ -146,7 +152,11 @@ def test_batcher_deadline_vs_full_flush_and_carry():
         f2 = b.submit(np.full((100, 3), 3.0))
         assert np.array_equal(f1.result(timeout=30.0).values, np.full(200, 6.0))
         assert np.array_equal(f2.result(timeout=30.0).values, np.full(100, 9.0))
-        stats = b.stats()
+        for _ in range(200):  # as above: counters follow the answers
+            stats = b.stats()
+            if stats["requests"] == 3:
+                break
+            time.sleep(0.01)
         assert stats["full_flush"] >= 1
         assert stats["requests"] == 3
     finally:

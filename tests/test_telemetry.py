@@ -102,7 +102,7 @@ def test_telemetry_callback_collects_history():
 
 
 # ------------------------------------------------------------ disabled = noop
-def test_disabled_records_nothing_and_phase_is_shared_noop():
+def test_disabled_records_nothing_and_phase_is_noop():
     ses = get_session()
     X, y = _data()
     lgb.train(
@@ -113,11 +113,11 @@ def test_disabled_records_nothing_and_phase_is_shared_noop():
     assert ses.events == []
     assert ses.counters == {}
     assert ses.gauges == {}
-    # structural overhead guard: disabled phase() hands back one shared
-    # no-op object (no allocation, no timing) — the <2% bench budget
-    p1 = ses.phase("grow")
-    p2 = ses.phase("gradients")
-    assert p1 is p2
+    # structural overhead guard: with telemetry off a layer-boundary span
+    # feeds no phase accumulator (add_phase is a flag check)
+    ses.begin_iteration()
+    ses.add_phase("grow", 1.0)
+    assert ses.end_iteration() == {}
     ses.record({"event": "x"})
     assert ses.events == []
     ses.inc("n")

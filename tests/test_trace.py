@@ -3,9 +3,10 @@
 Covers the always-on span recorder (tree integrity, bounded-ring
 eviction accounting, deterministic sampling), the Chrome trace-event
 JSON export (Perfetto-loadable schema), the training instrumentation
-(iteration spans, launch spans with synthetic per-iteration children
-reconstructed from device counters, per-iteration ``from_launch`` JSONL
-events), the serving decomposition (request/queue_wait/batch stages,
+(the ``train/*`` / ``wait/*`` tree of the layer boundaries, in the ring and
+as profiler annotations in ``/host:CPU``; launch spans carrying the exact
+per-iteration device counters; per-iteration ``from_launch`` JSONL events;
+the phases and ``global_timer`` the spans feed), the serving decomposition (request/queue_wait/batch stages,
 W3C traceparent round-trip over HTTP), dump-on-fault pairing with the
 flight recorder, the iteration-denominated watchdog cadence at
 ``train_steps_per_launch`` N=1 vs N=8, and the zero-retrace contract.
@@ -156,23 +157,148 @@ def test_chrome_trace_schema(tmp_path):
 
 
 # -------------------------------------------------------------- train spans
+_ITER_CHILDREN = {
+    "train/gradients", "train/sample", "train/grow", "train/score_update",
+    "wait/fetch_tree", "train/host_tree",
+}
+
+
 def test_train_iteration_spans_and_phase_children():
     X, y = _data()
     lgb.train(dict(_PARAMS, telemetry=True), lgb.Dataset(X, y), 3)
     spans = get_tracer().spans()
     runs = [s for s in spans if s["name"] == "train/run"]
     iters = [s for s in spans if s["name"] == "train/iteration"]
-    phases = [s for s in spans if s["name"].startswith("phase/")]
     assert len(runs) == 1
     assert len(iters) == 3
     assert all(s["parent_id"] == runs[0]["span_id"] for s in iters)
     assert all(s["trace_id"] == runs[0]["trace_id"] for s in iters)
     iter_ids = {s["span_id"] for s in iters}
-    assert phases and all(s["parent_id"] in iter_ids for s in phases)
-    assert not any(s.get("synthetic") for s in spans)
+    kids = [s for s in spans if s["parent_id"] in iter_ids]
+    # the layer boundaries are the iteration's children, under fixed names;
+    # the pipelined path fetches a tree one iteration late, so the first
+    # iteration has no wait/fetch_tree and the last tree is fetched by the
+    # model's first reader, outside any iteration
+    assert {s["name"] for s in kids} == _ITER_CHILDREN
+    per_iter = {i: {s["name"] for s in kids if s["parent_id"] == i} for i in iter_ids}
+    assert all({"train/gradients", "train/sample", "train/grow",
+                "train/score_update"} <= names for names in per_iter.values())
+    assert sum("wait/fetch_tree" in names for names in per_iter.values()) == 2
+    assert not any(s["name"].startswith("phase/") or s["cat"] == "phase" for s in spans)
+    assert not any("synthetic" in s for s in spans)
 
 
-def test_launch_synthetic_children_match_serial(tmp_path):
+def _host_events(trace_dir):
+    """(name, start_ns, end_ns) of every event of the profile's /host:CPU
+    plane, whichever thread line it is on."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*", "*.xplane.pb"))
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            {k: v for k, v in e.stats} if e.name == "train/iteration" else {})
+                           for e in line.events]
+    return events
+
+
+def test_span_tree_is_in_the_profilers_host_plane(tmp_path):
+    """With telemetry OFF (the default, what every benchmark cell runs) and
+    any profiler session live, the layer boundaries are events of
+    /host:CPU under exactly the ring's names, nested in time."""
+    X, y = _data()
+    Xv, yv = _data(n=120, seed=3)
+    train, valid = lgb.Dataset(X, y), lgb.Dataset(Xv, yv)
+    train.construct()
+    lgb.global_timer.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        lgb.train(dict(_PARAMS, metric="l2"), train, 2, valid_sets=[valid],
+                  callbacks=[lambda env: None])
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    ours = [e for e in events if e[0].split("/")[0] in ("train", "wait", "setup", "dataset")]
+    names = {e[0] for e in ours}
+    assert names == {
+        "setup/booster_init", "dataset/construct", "dataset/bin_fit",
+        "dataset/bundle", "dataset/pack",
+        "train/run", "train/iteration", "train/gradients", "train/sample",
+        "train/grow", "train/score_update", "wait/fetch_tree", "train/host_tree",
+        "train/eval", "train/eval_score", "wait/eval_metric", "train/callbacks",
+    }, names
+
+    def inside(child, parent):
+        return [c for c in ours if c[0] == child and any(
+            p[0] == parent and p[1] <= c[1] and c[2] <= p[2] for p in ours)]
+
+    (run,) = [e for e in ours if e[0] == "train/run"]
+    iters = [e for e in ours if e[0] == "train/iteration"]
+    assert len(iters) == 2 and len(inside("train/iteration", "train/run")) == 2
+    assert [e[3].get("iter") for e in sorted(iters, key=lambda e: e[1])] == [0, 1]
+    assert len(inside("wait/fetch_tree", "train/iteration")) == 1
+    for child in ("train/gradients", "train/sample", "train/grow", "train/score_update"):
+        assert len(inside(child, "train/iteration")) == 2, child
+    assert len(inside("train/eval", "train/run")) == 2
+    assert len(inside("train/eval_score", "train/eval")) == 2
+    assert len(inside("wait/eval_metric", "train/eval_score")) == 2
+    assert len(inside("train/callbacks", "train/run")) == 2
+    assert len(inside("dataset/construct", "setup/booster_init")) == 1  # the valid set's
+    assert not inside("train/iteration", "setup/booster_init")
+
+    # the same names are in the ring, with parent links
+    ring = get_tracer().spans()
+    by_id = {s["span_id"]: s for s in ring}
+    assert names <= {s["name"] for s in ring}
+    parents = {(s["name"], by_id[s["parent_id"]]["name"]) for s in ring
+               if s["parent_id"] in by_id}
+    assert {("train/iteration", "train/run"), ("wait/fetch_tree", "train/iteration"),
+            ("train/grow", "train/iteration"), ("train/eval", "train/run"),
+            ("train/eval_score", "train/eval"), ("wait/eval_metric", "train/eval_score"),
+            ("train/callbacks", "train/run")} <= parents
+    # and the spans fed GlobalTimer with telemetry off
+    t = lgb.global_timer
+    assert t.counts["boosting/update"] == 2 and t.counts["tree/grow"] == 2
+    assert t.counts["boosting/eval"] == 2
+
+
+def test_spans_feed_the_phases_and_the_timer_they_fed_before():
+    """With telemetry on, ``Booster.telemetry()`` phases and ``global_timer``
+    hold what the registry's phase timers and the doubled ``timed`` sites
+    put there before they folded into the spans."""
+    X, y = _data()
+    lgb.global_timer.reset()
+    b = lgb.train(dict(_PARAMS, telemetry=True), lgb.Dataset(X, y), 4)
+    events = [e for e in b.telemetry()["events"] if e.get("event") == "iteration"]
+    assert len(events) == 4
+    for e in events:
+        assert {"gradients", "sample", "grow"} <= set(e["phases"])
+        assert set(e["phases"]) <= {"gradients", "sample", "grow", "score_update",
+                                    "host_materialize"}
+        assert sum(e["phases"].values()) <= e["wall_ms"] + 1.0
+    # the first iteration commits its tree on the spot; from the second on
+    # the pipelined path updates the score at dispatch and fetches a tree late
+    assert ["score_update" in e["phases"] for e in events] == [False, True, True, True]
+    assert sum("host_materialize" in e["phases"] for e in events) >= 3
+    t = lgb.global_timer
+    assert t.counts["boosting/update"] == 4 and t.counts["tree/grow"] == 4
+    assert t.counts["dataset/construct"] == 1
+    assert t.totals["tree/grow"] <= t.totals["boosting/update"]
+    # the launch path: its dispatch is the "launch" phase, one update per launch
+    lgb.global_timer.reset()
+    get_session().reset()
+    b = lgb.train(dict(_PARAMS, telemetry=True, train_steps_per_launch=2),
+                  lgb.Dataset(X, y), 4)
+    launches = [e for e in b.telemetry()["events"] if e.get("event") == "launch"]
+    assert len(launches) == 2 and all(set(e["phases"]) == {"launch"} for e in launches)
+    assert lgb.global_timer.counts["boosting/update"] == 2
+
+
+def test_launch_per_iteration_counters_match_serial(tmp_path):
     X, y = _data()
     serial = lgb.train(
         dict(_PARAMS, telemetry=True), lgb.Dataset(X, y), 6
@@ -207,25 +333,29 @@ def test_launch_synthetic_children_match_serial(tmp_path):
     ]
     assert drop(serial.model_to_string()) == drop(launched.model_to_string())
     spans = tracer.spans()
-    launches = [s for s in spans if s["name"] == "train/launch"]
-    synth = [s for s in spans if s.get("synthetic")]
+    launches = sorted((s for s in spans if s["name"] == "train/launch"),
+                      key=lambda s: s["ts"])
     assert len(launches) == 2
-    assert len(synth) == 6
+    # no time that nobody measured is in the ring: a launch has no
+    # train/iteration children, only what the host did
+    assert not any("synthetic" in s for s in spans)
+    assert not any(s["name"] == "train/iteration" for s in spans)
     launch_ids = {s["span_id"] for s in launches}
-    for s in synth:
-        assert s["name"] == "train/iteration"
-        assert s["parent_id"] in launch_ids
-        assert s["args"]["from_launch"] is True
-        # device counters on the synthetic span match the serial run
-        assert s["args"]["splits"] == serial_splits[s["args"]["iter"]]
-    # synthetic children tile their launch window in iteration order
-    for launch in launches:
-        kids = sorted(
-            (s for s in synth if s["parent_id"] == launch["span_id"]),
-            key=lambda s: s["args"]["iter"],
-        )
-        assert [s["ts"] for s in kids] == sorted(s["ts"] for s in kids)
-        assert all(s["ts"] >= launch["ts"] for s in kids)
+    kids = {}
+    for s in spans:
+        if s["parent_id"] in launch_ids:
+            kids.setdefault(s["parent_id"], []).append(s["name"])
+    assert all(sorted(v) == ["train/launch_dispatch", "train/launch_replay",
+                             "wait/launch_fetch"] for v in kids.values()), kids
+    # the exact device counters of every iteration ride on the launch span
+    records = [r for s in launches for r in s["args"]["per_iteration"]]
+    assert [r["iter"] for r in records] == list(range(6))
+    assert [s["args"]["launch_begin"] for s in launches] == [0, 3]
+    for r in records:
+        assert set(r) == {"iter", "trees_materialized", "splits", "grow_steps",
+                          "refine_count"}
+        assert r["splits"] == serial_splits[r["iter"]]
+        assert r["trees_materialized"] == 1 and r["grow_steps"] >= r["splits"] > 0
 
     # satellite: per-iteration JSONL events replayed with from_launch=true
     launched_events = [
