@@ -62,7 +62,13 @@ from ...obs.jit import instrumented_jit
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .partition import T, W, _partition_window, read_aliased_tile
+from .partition import (
+    T,
+    _partition_window,
+    partition_scratch,
+    partition_sub,
+    read_aliased_tile,
+)
 from .seg import (
     COL_ALIGN,
     TILE,
@@ -72,7 +78,6 @@ from .seg import (
     hist_group,
     hist_ngroups,
     hist_sub,
-    used_lanes,
 )
 
 # Test hook: route the fused step through the Pallas interpret-mode kernel
@@ -95,21 +100,9 @@ def _fused_grow_kernel(
     scratch_out,  # ANY [SUB_P, n_pad] i16 — partition right-stream spill
     dec_ref,  # SMEM [K, 4] i32: nl, nr, child_start, child_cnt per member
     hist_ref,  # VMEM [1, 1, 8, group * bpad] f32 | i32 block (raw planes)
-    in_stage,  # VMEM [SUB_P, T] i16 — partition staging
-    out_stage,  # VMEM [SUB_P, T] i16
-    stage_lo,  # VMEM [SUB_P, W] f32
-    stage_hi,  # VMEM [SUB_P, W] f32
-    rstage_lo,  # VMEM [SUB_P, W] f32
-    rstage_hi,  # VMEM [SUB_P, W] f32
-    gl_stage,  # VMEM [1, T] f32 (unused: use_gl is always False here)
-    hist_stage,  # VMEM [SUB_H, TILE] i16 — histogram staging
-    acc,  # VMEM [8, group * bpad] f32 | i32
-    onehot,  # VMEM [TILE, group * bpad] bf16 | i8
-    sem_in,
-    sem_out,
-    sem_gl,
-    sem_hist,
-    *,
+    *scratch,  # partition_scratch(sub_p, False), then the histogram's:
+    #            hist_stage VMEM [SUB_H, TILE] i16, acc VMEM [8, group * bpad]
+    #            f32 | i32, onehot VMEM [TILE, group * bpad] bf16 | i8, sem_hist
     f: int,
     n_pad: int,
     use_cat: bool,
@@ -122,6 +115,8 @@ def _fused_grow_kernel(
     quantized: bool,
     read_via_input: bool = False,
 ):
+    part_scratch = scratch[:-4]
+    hist_stage, acc, onehot, sem_hist = scratch[-4:]
     i = pl.program_id(0)
     pt = pl.program_id(1)
     sbegin = scal_ref[i, 0]
@@ -145,16 +140,7 @@ def _fused_grow_kernel(
             cat_ref,
             tri_ref,
             gl_any,
-            in_stage,
-            out_stage,
-            stage_lo,
-            stage_hi,
-            rstage_lo,
-            rstage_hi,
-            gl_stage,
-            sem_in,
-            sem_out,
-            sem_gl,
+            part_scratch,
             use_cat=use_cat,
             sub=sub_p,
             wide=wide,
@@ -238,9 +224,8 @@ def fused_grow_step_pallas(
     k = scal.shape[0]
     lanes = seg.shape[0]
     bmt = catmask.shape[1]
-    # partition DMAs need second-minor 8-sublane multiples; hist tiles DMA
-    # only the used planes padded to an i16 sublane multiple
-    sub_p = -(-used_lanes(f, wide) // 8) * 8
+    # hist tiles DMA only the used planes padded to an i16 sublane multiple
+    sub_p = partition_sub(f, wide)
     sub_h = hist_sub(f, wide)
     bpad = hist_bpad(num_bins)
     group = hist_group(f, bpad)
@@ -286,22 +271,12 @@ def fused_grow_step_pallas(
             jax.ShapeDtypeStruct((k, 4), jnp.int32),
             jax.ShapeDtypeStruct((k, ngroups, 8, group * bpad), acc_dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((sub_p, T), jnp.int16),
-            pltpu.VMEM((sub_p, T), jnp.int16),
-            pltpu.VMEM((sub_p, W), jnp.float32),
-            pltpu.VMEM((sub_p, W), jnp.float32),
-            pltpu.VMEM((sub_p, W), jnp.float32),
-            pltpu.VMEM((sub_p, W), jnp.float32),
-            pltpu.VMEM((1, T), jnp.float32),
+        scratch_shapes=partition_scratch(sub_p, False) + [
             pltpu.VMEM((sub_h, TILE), jnp.int16),
             pltpu.VMEM((8, group * bpad), acc_dtype),
             pltpu.VMEM(
                 (TILE, group * bpad), jnp.int8 if quantized else jnp.bfloat16
             ),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
             pltpu.SemaphoreType.DMA,
         ],
         input_output_aliases={3: 0},

@@ -6,39 +6,68 @@ sums -> ``SplitInnerKernel``, src/treelearner/cuda/cuda_data_partition.cu).
 
 Why this kernel exists: the round-2 design partitioned a leaf's contiguous
 window with ``lax.sort`` over pow-2 capacity buckets (ops/segpart.py).  That
-was already the fastest pure-XLA formulation (~6 ns/row for the 44-byte
-packed row), but it pays (a) a multi-pass comparison sort for what is a
-1-bit-key partition, (b) up to 2x window overshoot from the pow-2 ladder,
-and (c) a defensive full-array copy per ``lax.switch`` branch (~0.45 ms per
-1M rows, measured).  This kernel streams the EXACT window once, tile by
-tile, and compacts rows with ONE-HOT MATMULS — the MXU as a crossbar.  TPUs
+was already the fastest pure-XLA formulation, but it pays (a) a multi-pass
+comparison sort for what is a 1-bit-key partition, (b) up to 2x window
+overshoot from the pow-2 ladder, and (c) a defensive full-array copy per
+``lax.switch`` branch.  This kernel streams the EXACT window once, tile by
+tile, and compacts rows with a ONE-HOT MATMUL — the MXU as a crossbar.  TPUs
 have no vector scatter/compaction primitive; a permutation applied as a
-``[T, W]`` 0/1 matrix multiply is exact (i16 planes split into two 0..255
-byte planes, each exact in bf16) and runs at MXU rate, far above the
-serialized per-element path XLA lowers gathers/scatters to.
+``[T, T]`` 0/1 matrix multiply is exact (i16 planes split into two 0..255
+byte planes, each exact in bf16).
 
-Algorithm (stable, in place, ~2.5 HBM passes over the window):
-  pass 1: stream aligned ``[SUB, T]`` tiles of the window left to right.
-    Per tile: evaluate the split predicate on the packed bin byte, then
-    matmul-compact the tile's LEFT rows (plus the sub-tile alignment
-    prefix) into a VMEM staging buffer and its RIGHT rows (plus the
-    alignment suffix) into a second staging buffer.  Full staged blocks
-    flush with aligned DMA writes: the left stream writes IN PLACE (flush
-    position provably trails the read cursor), the right stream writes to
-    an HBM scratch buffer.
-  pass 2: stream the right scratch back through the same staging machinery,
-    appending after the left stream — every block write is 128-aligned, and
-    the two passes together rewrite exactly the tiles pass 1 read.
+Measured on a v5e (PR 28, one 10.5M-row window of the 28-feature layout,
+``sub`` = 24 planes; 8M rows at 67 features, ``sub`` = 48, in brackets):
+the tile loop of PRs 3-27 took 8.48 [9.25] ns a row — four ``[sub, T] x
+[2T, T]`` compactions, three cumulative sums and three blocking DMAs a tile,
+DMA and compute strictly in series (40 + 48 of 89 ms); this one takes 1.57
+[1.72] ns a row (16.5 [13.8] ms a window), of which the DMAs alone are
+0.54.  The memory bound — every plane read once and written once, the
+right rows once more each way — is 0.18 ns a row at 819 GB/s; what is left
+is the per-tile latency of three dependent matmuls and a vector-to-scalar
+count, not bytes.  The ``lax.sort`` formulation was quoted at ~6 ns a row
+when it was replaced and was not measured again.
+
+Algorithm (stable, in place; the window is read once and written once, the
+right stream spilled and read back once more):
+  pass 1: stream aligned ``[SUB, T]`` tiles of the window left to right, G
+    at a loop iteration.  Per tile: evaluate the split predicate on the
+    packed bin byte, one cumulative count of the LEFT columns (left rows
+    plus the sub-tile alignment prefix), and ONE permutation matmul over the
+    lo and hi byte planes stacked on the M axis: lefts in order, then
+    rights (right rows plus the alignment suffix) in order, rotated so the
+    lefts land on the left staging's free lanes.  A lane rotation
+    (``pltpu.roll``, dynamic shift) and a lane mask put the rights on the
+    right staging's.  Stagings are one block of integers each; a block that
+    fills is flushed by an aligned DMA that is left in flight: the left
+    stream writes IN PLACE, the right stream to an HBM scratch buffer.
+  pass 2: the spilled right stream is a copy shifted by the left stream's
+    residual fill — rotate, merge under a mask, flush; no count, no
+    permutation.  Its last partial block never leaves VMEM.  Every block
+    write is 128-aligned, and the two passes together rewrite exactly the
+    tiles pass 1 read.
+
+Reads come in blocks of ``block_tiles(sub)`` tiles through two buffers:
+block b + 1 is started before block b is computed.  Why that is safe in
+place: flush k of the left stream needs k + 1 blocks' worth of left columns
+consumed, so it writes tile k only while tile t >= k is being computed — the
+write position trails the read cursor — and a block read ahead holds only
+tiles beyond t, columns no flush has reached.  The read-ahead never crosses
+the window's last tile (the last, partial block goes tile by tile), and
+every flush is waited for before ``_partition_window`` returns, so a later
+grid program — or the fused kernel's histogram phase — that re-reads a
+shared boundary block sees this window's writes.  The spill is waited for
+before pass 2 reads it.
 
 Stability: both children preserve original row order (streams keep tile
-order and the in-tile compaction keeps column order), so results are
-bit-identical to the stable-sort path this replaces.
+order and the in-tile permutation keeps column order within each side), so
+results are bit-identical to the stable-sort path this replaces.
 
 The per-window body is factored into ``_partition_window`` so the fused
 grow-step kernel (ops/pallas/grow_step.py) can run partition + smaller-child
-histogram in ONE launch; ``read_aliased_tile`` is the shared
-read-through-the-output-alias helper both kernels use (see its docstring
-for the interpret-mode aliasing pitfall it guards against).
+histogram in ONE launch; ``read_aliased_tile`` is the
+read-through-the-output-alias helper of that kernel's histogram phase (see
+its docstring for the interpret-mode aliasing pitfall it guards against),
+and the partition's block reads take the same source (``_aliased_cols``).
 """
 
 from __future__ import annotations
@@ -56,14 +85,65 @@ from jax.experimental.pallas import tpu as pltpu
 from .seg import COL_ALIGN, used_lanes
 
 T = 256  # streaming tile columns (rows of training data)
-W = 2 * T  # staging width: residual (< T) + one tile's append (<= T)
+G = 4  # tiles a loop iteration takes together (block_tiles is a multiple)
 
 
-def _bytes_bf16(xu):
-    """Split u16 values [SUB, T] into two exact-in-bf16 byte planes."""
-    lo = (xu & 0xFF).astype(jnp.bfloat16)
-    hi = ((xu >> 8) & 0xFF).astype(jnp.bfloat16)
-    return lo, hi
+def partition_sub(f: int, wide: bool = False) -> int:
+    """Planes the partition moves: the used ones, rounded up because Mosaic
+    wants second-minor DMA slice shapes in 8-sublane multiples."""
+    return -(-used_lanes(f, wide) // 8) * 8
+
+
+def block_tiles(sub: int) -> int:
+    """Tiles per read block, from the one shape the kernel can see: a block
+    of ``sub`` planes is 128-256 KB (64 KB at sub = 8, where 16 tiles cap
+    it), so two of them stay a small part of SEG_VMEM_BUDGET at sub = 128."""
+    return max(G, min(16, 1 << ((128 * 1024) // (sub * T)).bit_length() - 1))
+
+
+def window_block_tiles(sub: int, n_pad: int) -> int:
+    """``block_tiles`` clipped to the matrix: a block DMA's static extent
+    must fit its ``n_pad`` columns (toy sizes only)."""
+    return min(block_tiles(sub), n_pad // T) // G * G
+
+
+def partition_scratch(sub: int, use_gl: bool):
+    """Scratch of ``_partition_window`` in its argument order — the one list
+    the three ``pallas_call`` wrappers (and the fused grow step) allocate."""
+    bc = block_tiles(sub) * T
+    return [
+        pltpu.VMEM((2, sub, bc), jnp.int16),  # in_blk: two read blocks
+        pltpu.VMEM((2, 1, bc if use_gl else COL_ALIGN), jnp.float32),  # gl_blk
+        pltpu.VMEM((2, sub, T), jnp.int16),  # out_l: left-stream flushes
+        pltpu.VMEM((2, sub, T), jnp.int16),  # out_r: right-stream spills
+        pltpu.VMEM((sub, T), jnp.int32),  # stage_l: < T pending columns
+        pltpu.VMEM((sub, T), jnp.int32),  # stage_r
+        pltpu.SemaphoreType.DMA((2,)),  # sem_in, one per read block
+        pltpu.SemaphoreType.DMA((2,)),  # sem_gl
+        pltpu.SemaphoreType.DMA((2,)),  # sem_l, one per flush buffer
+        pltpu.SemaphoreType.DMA((2,)),  # sem_r
+    ]
+
+
+def partition_scratch_bytes(sub: int) -> int:
+    """VMEM bytes of ``partition_scratch`` (with the go-left-bits blocks,
+    each row of which pads to 8 sublanes) plus the tile loop's own
+    temporaries (tri, Q, the stacked byte planes and their product)."""
+    bc = block_tiles(sub) * T
+    blocks = 2 * sub * bc * 2 + 2 * 8 * bc * 4
+    stages = 2 * 2 * sub * T * 2 + 2 * sub * T * 4
+    temps = 2 * T * T * 2 + 2 * sub * T * (2 + 4)
+    return blocks + stages + temps
+
+
+def _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input):
+    """``[sub, cols]`` window of an IN-PLACE (input/output-aliased) packed
+    segment matrix, as a DMA source, through the OUTPUT alias (see
+    ``read_aliased_tile``)."""
+    src = seg_in if read_via_input else seg_out
+    # the input-ref read below is unreachable in production: it only
+    # engages under the test-only read_via_input knob of read_aliased_tile
+    return src.at[pl.ds(0, sub), pl.ds(pl.multiple_of(base_col, COL_ALIGN), cols)]  # graftlint: disable=GL002
 
 
 def read_aliased_tile(seg_in, seg_out, stage, sem, base_col, *,
@@ -76,19 +156,17 @@ def read_aliased_tile(seg_in, seg_out, stage, sem, base_col, *,
     earlier program (or an earlier phase of the SAME program, in the fused
     grow-step kernel) already rewrote — adjacent leaf windows share
     COL_ALIGN blocks — and Pallas interpret mode only makes those writes
-    visible on the output ref.  Shared by the seg partition kernel and the
-    fused grow-step kernel (ops/pallas/grow_step.py).
+    visible on the output ref.  Used by the fused grow-step kernel's
+    histogram phase (ops/pallas/grow_step.py); the partition's block reads
+    take the same source (``_aliased_cols``).
 
     ``read_via_input=True`` recreates the PR-3 aliasing bug by reading the
     input ref instead — a TEST-ONLY knob for the regression test in
     tests/test_partition_kernel.py; never set it from production code.
     """
     sub, cols = stage.shape
-    src = seg_in if read_via_input else seg_out
     dma = pltpu.make_async_copy(
-        # the input-ref read below is unreachable in production: it only
-        # engages under the test-only read_via_input knob documented above
-        src.at[pl.ds(0, sub), pl.ds(pl.multiple_of(base_col, COL_ALIGN), cols)],  # graftlint: disable=GL002
+        _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input),
         stage,
         sem,
     )
@@ -110,17 +188,8 @@ def _partition_window(
     scratch_out,  # ANY [SUB, n_pad] i16 — right-stream spill
     cat_ref,  # VMEM [1, bmt] f32 — bin -> goes-left (categorical)
     tri_ref,  # VMEM [T, T] bf16 — tri[i, j] = (i <= j), cumsum-by-matmul
-    gl_any,  # ANY [1, n_pad] f32 go-left bits, or None when not use_gl
-    in_stage,  # VMEM [SUB, T] i16
-    out_stage,  # VMEM [SUB, T] i16
-    stage_lo,  # VMEM [SUB, W] f32 — left/main stream staging (lo bytes)
-    stage_hi,  # VMEM [SUB, W] f32
-    rstage_lo,  # VMEM [SUB, W] f32 — right stream staging
-    rstage_hi,  # VMEM [SUB, W] f32
-    gl_stage,  # VMEM [1, T] f32 go-left tile, or None when not use_gl
-    sem_in,
-    sem_out,
-    sem_gl,
+    gl_any,  # ANY [1, n_pad] f32 go-left bits (a dummy when not use_gl)
+    scratch,  # the refs of partition_scratch(sub, use_gl), in its order
     *,
     use_cat: bool,
     sub: int,
@@ -132,132 +201,193 @@ def _partition_window(
     """Stable in-place partition of ONE leaf window (the per-program body of
     the seg partition kernel, factored out so the fused grow-step kernel can
     run it before its histogram phase).  Returns nl — rows going left."""
+    (in_blk, gl_blk, out_l, out_r, stage_l, stage_r,
+     sem_in, sem_gl, sem_l, sem_r) = scratch
+    nbt = window_block_tiles(sub, seg_out.shape[1])
+    bc = nbt * T
     abegin = (sbegin // COL_ALIGN) * COL_ALIGN
     off = sbegin - abegin
     nt = (off + cnt + T - 1) // T
 
     iota_j = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    iota_jf = iota_j.astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (sub, T), 1)
     # tpu.iota only produces integers; cast for the f32 dest compare.
-    # [W, T] orientation: dest stays a [1, T] row (Mosaic cannot legalize
-    # the [1, T] -> [T, 1] transpose) and the compact matmul contracts the
-    # shared T dim of lo/hi and Q ("NT" form).
-    iota_q = jax.lax.broadcasted_iota(jnp.int32, (W, T), 0).astype(jnp.float32)
+    # [T(dest), T(src)] orientation: dest stays a [1, T] row (Mosaic cannot
+    # legalize the [1, T] -> [T, 1] transpose) and the compact matmul
+    # contracts the shared source dim of the planes and Q ("NT" form).
+    iota_q = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0).astype(jnp.float32)
 
-    stage_lo[...] = jnp.zeros_like(stage_lo)
-    stage_hi[...] = jnp.zeros_like(stage_hi)
-    rstage_lo[...] = jnp.zeros_like(rstage_lo)
-    rstage_hi[...] = jnp.zeros_like(rstage_hi)
-
-    def _append(lo, hi, keep, fill, slo, shi):
-        """Matmul-compact `keep` columns of the tile into staging at `fill`.
-
-        P[j, w] = keep[j] & (dest[j] == w) with dest[j] = fill - 1 +
-        (#kept among cols <= j); built from iota compares plus one
-        cumsum-by-triangular-matmul — no scatter anywhere."""
-        keepf = keep.astype(jnp.bfloat16)  # [1, T]
-        csum = jax.lax.dot_general(
-            keepf, tri_ref[...],
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )  # [1, T] inclusive cumsum
-        nkeep = csum[0, T - 1].astype(jnp.int32)
-        # fold `keep` into dest arithmetically (dropped rows -> -1, matching
-        # no staging lane): kept rows have csum >= 1 so dest >= fill >= 0
-        keep32 = keep.astype(jnp.float32)
-        dest = (csum + (fill - 1).astype(jnp.float32)) * keep32 - (
-            1.0 - keep32
-        )  # [1, T]
-        Q = (iota_q == dest).astype(jnp.bfloat16)  # [W, T] one-hot rows
-        slo[...] += jax.lax.dot_general(
-            lo, Q, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    # Mosaic has no value-level dynamic_slice: the split feature's byte row
+    # comes out of the stacked [lo; hi] byte planes by a one-hot row matmul
+    # (the MXU as a dynamic row gather).  Row 0 picks the byte compared
+    # (narrow: the feature's half of its plane; wide: the low byte), row 1
+    # the high byte of a wide (u16) plane.
+    plane = feat if wide else feat >> 1
+    sel_r = jax.lax.broadcasted_iota(jnp.int32, (8, 2 * sub), 0)
+    sel_c = jax.lax.broadcasted_iota(jnp.int32, (8, 2 * sub), 1)
+    if wide:
+        sel = ((sel_r == 0) & (sel_c == plane)) | (
+            (sel_r == 1) & (sel_c == plane + sub)
         )
-        shi[...] += jax.lax.dot_general(
-            hi, Q, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return fill + nkeep
+    else:
+        sel = (sel_r == 0) & (sel_c == plane + (feat & 1) * sub)
+    sel = sel.astype(jnp.bfloat16)
 
-    def _combine_block(slo, shi):
-        lo32 = slo[:, :T].astype(jnp.int32)
-        hi32 = shi[:, :T].astype(jnp.int32)
-        u16 = (lo32 | (hi32 << 8)).astype(jnp.uint16)
-        out_stage[...] = jax.lax.bitcast_convert_type(u16, jnp.int16)
+    def _start(d):
+        d.start()
 
-    def _flush(fill, nblk, slo, shi, dst, dst_base):
-        """If a full block is staged, DMA it out and shift staging left."""
-        do = fill >= T
+    def _wait(d):
+        d.wait()
 
-        @pl.when(do)
+    def _read_block(op, src_cols, with_gl, b, ntiles, slot):
+        """``op`` (start | wait) the DMAs of read block ``b`` — tiles
+        [b*nbt, (b+1)*nbt) of a stream of ``ntiles`` — into buffer ``slot``.
+        A block wholly inside the stream is one DMA; the stream's last,
+        partial block goes tile by tile, so no read crosses the last tile
+        (nothing past it is this window's to touch, and n_pad ends soon
+        after the last window)."""
+
+        def copies(c0, cols):
+            out = [pltpu.make_async_copy(
+                src_cols(b * bc + c0, cols),
+                in_blk.at[slot, pl.ds(0, sub), pl.ds(c0, cols)],
+                sem_in.at[slot],
+            )]
+            if with_gl:
+                # precomputed go-left bits (feature-parallel seg: the
+                # winner's plane lives on the owning shard; the bits
+                # arrived by psum) ride beside their rows
+                out.append(pltpu.make_async_copy(
+                    gl_any.at[
+                        pl.ds(0, 1),
+                        pl.ds(pl.multiple_of(abegin + b * bc + c0, COL_ALIGN), cols),
+                    ],
+                    gl_blk.at[slot, pl.ds(0, 1), pl.ds(c0, cols)],
+                    sem_gl.at[slot],
+                ))
+            return out
+
+        whole = (b + 1) * nbt <= ntiles
+
+        @pl.when(whole)
         def _():
-            _combine_block(slo, shi)
-            dma = pltpu.make_async_copy(
-                out_stage,
-                dst.at[
-                    pl.ds(0, sub),
-                    pl.ds(pl.multiple_of(dst_base + nblk * T, COL_ALIGN), T),
-                ],
-                sem_out,
+            for d in copies(0, bc):
+                op(d)
+
+        @pl.when(jnp.logical_not(whole))
+        def _():
+            def one(k, c):
+                for d in copies(pl.multiple_of(k * T, T), T):
+                    op(d)
+                return c
+
+            lax.fori_loop(0, ntiles - b * nbt, one, 0)
+
+    def _tile_group(i, src_cols, with_gl, ntiles):
+        """Tiles G*i .. G*i + G - 1 of the stream as u16-in-i32 (and their
+        go-left bits); G divides nbt, so a group never straddles a block.
+        At a block's first group, block b + 1 is started BEFORE block b is
+        waited for, so its DMA overlaps the compute of block b.  Beyond the
+        stream's last tile a group holds stale buffer content, which its
+        caller appends none of."""
+        t = G * i
+        b = t // nbt
+        k = t - b * nbt
+        slot = b % 2
+
+        @pl.when(k == 0)
+        def _():
+            _read_block(_start, src_cols, with_gl, b + 1, ntiles, 1 - slot)
+            _read_block(_wait, src_cols, with_gl, b, ntiles, slot)
+
+        def tile(k):
+            c0 = pl.multiple_of(k * T, T)
+            xu = in_blk[slot, :, pl.ds(c0, T)].astype(jnp.int32) & 0xFFFF
+            return xu, gl_blk[slot, :, pl.ds(c0, T)] if with_gl else None
+
+        return [tile(k + j) for j in range(G)]
+
+    def _flush_dma(outb, sem, dst, dst_base, blk, slot):
+        return pltpu.make_async_copy(
+            outb.at[slot],
+            dst.at[
+                pl.ds(0, sub),
+                pl.ds(pl.multiple_of(dst_base + blk * T, COL_ALIGN), T),
+            ],
+            sem.at[slot],
+        )
+
+    def _append(vals, shift, fill, n, nblk, stage, outb, sem, dst, dst_base):
+        """Append ``n`` columns of ``vals`` — those a lane rotation by
+        ``shift`` (None: none needed) brings to lanes [fill, fill + n) mod T
+        — to a stream whose staging holds ``fill`` (< T) pending columns.  A
+        block that fills is flushed: flush ``nblk`` goes through buffer
+        nblk % 2, which is waited for only now, before it is written again;
+        the DMA is left in flight.  Columns rotated past lane T open the
+        next block."""
+        rl = vals if shift is None else pltpu.roll(vals, shift, 1)
+        nf = fill + n
+        cur = jnp.where((lane >= fill) & (lane < nf), rl, stage[...])
+        full = nf >= T
+
+        @pl.when(full)
+        def _():
+            slot = nblk % 2
+
+            @pl.when(nblk >= 2)
+            def _():
+                _flush_dma(outb, sem, dst, dst_base, 0, slot).wait()
+
+            outb[slot] = jax.lax.bitcast_convert_type(
+                cur.astype(jnp.uint16), jnp.int16
             )
-            dma.start()
-            dma.wait()
-            slo[:, :T] = slo[:, T:]
-            slo[:, T:] = jnp.zeros((sub, T), jnp.float32)
-            shi[:, :T] = shi[:, T:]
-            shi[:, T:] = jnp.zeros((sub, T), jnp.float32)
+            _flush_dma(outb, sem, dst, dst_base, nblk, slot).start()
 
-        doi = do.astype(jnp.int32)
-        return fill - doi * T, nblk + doi
+        stage[...] = jnp.where(lane < nf - T, rl, cur)
+        fulli = full.astype(jnp.int32)
+        return nf - fulli * T, nblk + fulli
 
-    def body1(t, carry):
-        fill_l, bl, fill_r, br, nl = carry
+    def _drain(nblk, outb, sem, dst):
+        """Wait for the (at most two) flushes of a stream still in flight."""
+        for back in (1, 2):
+            @pl.when(nblk >= back)
+            def _():
+                _flush_dma(outb, sem, dst, 0, 0, (nblk - back) % 2).wait()
+
+    def seg_cols(c0, cols):
         # boundary tiles must come through the OUTPUT alias — see
         # read_aliased_tile for the interpret-mode pitfall this guards
-        xu = read_aliased_tile(
-            seg_any, seg_out, in_stage, sem_in, abegin + t * T,
-            read_via_input=read_via_input,
+        return _aliased_cols(
+            seg_any, seg_out, sub, abegin + c0, cols, read_via_input
         )
+
+    def spill_cols(c0, cols):
+        return scratch_out.at[
+            pl.ds(0, sub), pl.ds(pl.multiple_of(c0, COL_ALIGN), cols)
+        ]
+
+    # ---- pass 1: split the window's tiles into the two streams
+    def _count(t, xu, gl):
+        """Which columns of tile ``t`` the left stream takes (its left rows
+        and the alignment prefix; the right stream takes the rest), their
+        inclusive running count and total nl_t — nothing here waits for the
+        tile before it."""
         rpos = iota_j + t * T
         in_seg = (rpos >= off) & (rpos < off + cnt)
+        # byte planes, lo over hi on the M axis: each exact in bf16
+        x2 = jnp.concatenate([xu & 0xFF, xu >> 8], axis=0).astype(jnp.bfloat16)
         if use_gl:
-            # precomputed go-left bits (feature-parallel seg: the winner's
-            # plane lives on the owning shard; the bits arrived by psum)
-            dma = pltpu.make_async_copy(
-                gl_any.at[
-                    pl.ds(0, 1),
-                    pl.ds(pl.multiple_of(abegin + t * T, COL_ALIGN), T),
-                ],
-                gl_stage,
-                sem_gl,
-            )
-            dma.start()
-            dma.wait()
-            go = gl_stage[...] > 0.5  # [1, T]
+            go = gl > 0.5  # [1, T]
         else:
-            # Mosaic has no value-level dynamic_slice: extract the feature's
-            # lane with a one-hot row matmul over the exact bf16 byte planes
-            # (0..255 each — the MXU as a dynamic row gather)
-            lane = feat if wide else feat >> 1
-            lane_oh = (
-                jax.lax.broadcasted_iota(jnp.int32, (1, sub), 1) == lane
-            ).astype(jnp.bfloat16)
-            xlo, xhi = _bytes_bf16(xu)
-            row_lo = jax.lax.dot_general(
-                lane_oh, xlo, dimension_numbers=(((1,), (0,)), ((), ())),
+            rows = jax.lax.dot_general(
+                sel, x2, dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ).astype(jnp.int32)  # [1, T]
-            row_hi = jax.lax.dot_general(
-                lane_oh, xhi, dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ).astype(jnp.int32)
+            ).astype(jnp.int32)  # [8, T]
+            colv = rows[0:1]
             if wide:
                 # one u16 plane per feature (max_bin > 256)
-                colv = row_lo | (row_hi << 8)  # [1, T]
-            else:
-                # scalar-cond select over a vector fails Mosaic
-                # legalization; broadcast the condition first
-                odd = jnp.broadcast_to((feat & 1) != 0, row_lo.shape)
-                colv = jnp.where(odd, row_hi, row_lo)
+                colv = colv | (rows[1:2] << 8)
             go = (colv <= tbin) | ((dl != 0) & (nanb >= 0) & (colv == nanb))
             if use_cat:
                 oh = (
@@ -276,54 +406,90 @@ def _partition_window(
                 )
                 go = gof > 0.5
         keep_l = (rpos < off) | (in_seg & go)
-        keep_r = jnp.logical_not(keep_l)
-        nl = nl + jnp.sum((in_seg & go).astype(jnp.int32))
-        lo, hi = _bytes_bf16(xu)
-        fill_l = _append(lo, hi, keep_l, fill_l, stage_lo, stage_hi)
-        fill_l, bl = _flush(fill_l, bl, stage_lo, stage_hi, seg_out, abegin)
-        fill_r = _append(lo, hi, keep_r, fill_r, rstage_lo, rstage_hi)
-        fill_r, br = _flush(fill_r, br, rstage_lo, rstage_hi, scratch_out, 0)
-        return fill_l, bl, fill_r, br, nl
+        keepf = keep_l.astype(jnp.float32)
+        csum = jax.lax.dot_general(
+            keepf.astype(jnp.bfloat16), tri_ref[...],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [1, T] inclusive count of left columns
+        return x2, keepf, csum, csum[0, T - 1].astype(jnp.int32)
 
-    fill_l, bl, fill_r, br, nl = lax.fori_loop(
-        0,
-        nt,
-        body1,
-        (jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0), jnp.int32(0)),
+    def _compact(x2, keepf, csum, nl_t, fill_l):
+        """ONE permutation a tile: a left column goes to its rank among the
+        lefts, a right column to nl_t + its rank among the rights (its
+        position less the lefts before it), all rotated by fill_l so that
+        the lefts sit where the left staging wants them — no scatter
+        anywhere."""
+        dest = keepf * (csum - 1.0) + (1.0 - keepf) * (
+            nl_t.astype(jnp.float32) + iota_jf - csum
+        ) + fill_l.astype(jnp.float32)  # [1, T]
+        dest = jnp.where(dest >= T, dest - T, dest)
+        q = (iota_q == dest).astype(jnp.bfloat16)  # [T, T] one-hot rows
+        comp = jax.lax.dot_general(
+            x2, q, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)  # [2 * sub, T]
+        return comp[:sub] | (comp[sub:] << 8)
+
+    def _split(cu, nl_t, nr_t, carry):
+        fill_l, bl, fill_r, br = carry
+        # the right columns begin at lane fill_l + nl_t of cu
+        shift_r = (fill_r - fill_l - nl_t) & (T - 1)
+        fill_l, bl = _append(
+            cu, None, fill_l, nl_t, bl, stage_l, out_l, sem_l, seg_out, abegin
+        )
+        fill_r, br = _append(
+            cu, shift_r, fill_r, nr_t, br, stage_r, out_r, sem_r,
+            scratch_out, 0,
+        )
+        return fill_l, bl, fill_r, br
+
+    def body1(i, carry):
+        # G tiles an iteration, all counted and compacted before any is
+        # moved: the tiles' matmul chains are independent but for the scalar
+        # fill_l each takes from the counts before it, and they sit ahead of
+        # the flushes' branches, where the scheduler can overlap them
+        tiles = _tile_group(i, seg_cols, use_gl, nt)
+        counts = [_count(G * i + j, *tiles[j]) for j in range(G)]
+        fill_l = carry[0]
+        cus = []
+        for c in counts:
+            cus.append(_compact(*c, fill_l))
+            fill_l = (fill_l + c[3]) & (T - 1)
+        for j, (c, cu) in enumerate(zip(counts, cus)):
+            # past the last tile every column is beyond the window: nl_t is
+            # 0 there and the right stream must take none either
+            nr_t = jnp.where(G * i + j < nt, T - c[3], 0)
+            carry = _split(cu, c[3], nr_t, carry)
+        return carry
+
+    _read_block(_start, seg_cols, use_gl, 0, nt, 0)
+    zero = jnp.int32(0)
+    fill_l, bl, fill_r, br = lax.fori_loop(
+        0, (nt + G - 1) // G, body1, (zero, zero, zero, zero)
     )
+    nl = bl * T + fill_l - off  # the left stream less its alignment prefix
 
-    # spill the partial right-stream block (cols beyond fill_r are garbage;
-    # pass 2 masks them out via the stream length)
-    @pl.when(fill_r > 0)
-    def _():
-        _combine_block(rstage_lo, rstage_hi)
-        dma = pltpu.make_async_copy(
-            out_stage,
-            scratch_out.at[
-                pl.ds(0, sub), pl.ds(pl.multiple_of(br * T, COL_ALIGN), T)
-            ],
-            sem_out,
-        )
-        dma.start()
-        dma.wait()
+    # ---- pass 2: the spilled right stream is a copy shifted by fill_l
+    # lanes; its last, partial block never left the staging
+    _drain(br, out_r, sem_r, scratch_out)  # the reads below need the spills
 
-    # ---- pass 2: append the right stream after the left stream
-    sr = nt * T - off - nl  # right-stream length (rights + alignment suffix)
-    nt2 = (sr + T - 1) // T
+    def body2(i, bl):
+        tiles = _tile_group(i, spill_cols, False, br)
+        for j, (xu, _) in enumerate(tiles):
+            _, bl = _append(
+                xu, fill_l, fill_l, jnp.where(G * i + j < br, T, 0), bl,
+                stage_l, out_l, sem_l, seg_out, abegin,
+            )
+        return bl
 
-    def body2(t2, carry):
-        fill_l, bl = carry
-        xu = read_aliased_tile(
-            scratch_out, scratch_out, in_stage, sem_in, t2 * T,
-        )
-        spos = iota_j + t2 * T
-        keep = spos < sr
-        lo, hi = _bytes_bf16(xu)
-        fill_l = _append(lo, hi, keep, fill_l, stage_lo, stage_hi)
-        fill_l, bl = _flush(fill_l, bl, stage_lo, stage_hi, seg_out, abegin)
-        return fill_l, bl
-
-    lax.fori_loop(0, nt2, body2, (fill_l, bl))
+    _read_block(_start, spill_cols, False, 0, br, 0)
+    bl = lax.fori_loop(0, (br + G - 1) // G, body2, bl)
+    _, bl = _append(
+        stage_r[...], fill_l, fill_l, fill_r, bl, stage_l, out_l, sem_l,
+        seg_out, abegin,
+    )
+    _drain(bl, out_l, sem_l, seg_out)
     return nl
 
 
@@ -339,17 +505,7 @@ def _seg_partition_kernel(
     seg_out,  # ANY [LANES, n_pad] i16 (aliased with seg_any)
     scratch_out,  # ANY [SUB, n_pad] i16 — right-stream spill
     nl_ref,  # SMEM [K, 1] i32 — rows of the segment going left, per program
-    in_stage,  # VMEM [SUB, T] i16
-    out_stage,  # VMEM [SUB, T] i16
-    stage_lo,  # VMEM [SUB, W] f32 — left/main stream staging (lo bytes)
-    stage_hi,  # VMEM [SUB, W] f32
-    rstage_lo,  # VMEM [SUB, W] f32 — right stream staging
-    rstage_hi,  # VMEM [SUB, W] f32
-    gl_stage,  # VMEM [1, T] f32 — go-left tile (use_gl)
-    sem_in,
-    sem_out,
-    sem_gl,
-    *,
+    *scratch,  # partition_scratch(sub, use_gl)
     f: int,
     n_pad: int,
     use_cat: bool,
@@ -360,7 +516,7 @@ def _seg_partition_kernel(
     read_via_input: bool = False,
 ):
     pid = pl.program_id(0)
-    nl = _partition_window(
+    nl_ref[pid, 0] = _partition_window(
         scal_ref[pid, 0],
         scal_ref[pid, 1],
         scal_ref[pid, 2],
@@ -374,16 +530,7 @@ def _seg_partition_kernel(
         cat_ref,
         tri_ref,
         gl_any,
-        in_stage,
-        out_stage,
-        stage_lo,
-        stage_hi,
-        rstage_lo,
-        rstage_hi,
-        gl_stage,
-        sem_in,
-        sem_out,
-        sem_gl,
+        scratch,
         use_cat=use_cat,
         sub=sub,
         wide=wide,
@@ -391,7 +538,6 @@ def _seg_partition_kernel(
         use_gl=use_gl,
         read_via_input=read_via_input,
     )
-    nl_ref[pid, 0] = nl
 
 
 @functools.partial(
@@ -425,8 +571,7 @@ def seg_partition_pallas(
     outside the window keeps its value.
     """
     use_gl = gl_vec is not None
-    # Mosaic requires second-minor DMA slice shapes in 8-sublane multiples
-    sub = -(-used_lanes(f, wide) // 8) * 8
+    sub = partition_sub(f, wide)
     lanes = seg.shape[0]
     tri = jnp.tril(jnp.ones((T, T), jnp.bfloat16)).T  # tri[i, j] = i <= j
     gl_arr = (
@@ -459,18 +604,7 @@ def seg_partition_pallas(
             jax.ShapeDtypeStruct((sub, n_pad), jnp.int16),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((sub, T), jnp.int16),
-            pltpu.VMEM((sub, T), jnp.int16),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((1, T), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=partition_scratch(sub, use_gl),
         input_output_aliases={1: 0},
         interpret=interpret,
     )(scal.reshape(1, 8), seg, catmask, tri, gl_arr)
@@ -509,7 +643,7 @@ def seg_partition_pallas_batch(
 
     Returns (seg', nl[K])."""
     k = scal.shape[0]
-    sub = -(-used_lanes(f, wide) // 8) * 8
+    sub = partition_sub(f, wide)
     lanes = seg.shape[0]
     bmt = catmask.shape[1]
     tri = jnp.tril(jnp.ones((T, T), jnp.bfloat16)).T  # tri[i, j] = i <= j
@@ -545,18 +679,7 @@ def seg_partition_pallas_batch(
             jax.ShapeDtypeStruct((sub, n_pad), jnp.int16),
             jax.ShapeDtypeStruct((k, 1), jnp.int32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((sub, T), jnp.int16),
-            pltpu.VMEM((sub, T), jnp.int16),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((sub, W), jnp.float32),
-            pltpu.VMEM((1, T), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=partition_scratch(sub, False),
         input_output_aliases={1: 0},
         interpret=interpret,
     )(scal.astype(jnp.int32), seg, catmask.reshape(k, 1, bmt), tri, gl_arr)

@@ -138,14 +138,21 @@ def seg_vmem_ok(f: int, num_bins: int, has_cat: bool = False) -> bool:
     The plane-tiled grid makes the histogram footprint O(group*bpad) per
     program — acc [8, group*bpad] + the matching out block + onehot
     [TILE, group*bpad] + the staging tile — independent of F.  The
-    categorical partition additionally builds a [bmt, 256] one-hot (bf16)
-    and is unchanged by the plane tiling, so it still binds wide-bin
-    categorical configs."""
+    partition's read blocks, flush buffers and stagings
+    (partition.partition_scratch_bytes, set by the packed planes alone) sit
+    beside it in the fused grow step.  The categorical partition
+    additionally builds a [bmt, 256] one-hot (bf16), unchanged by the plane
+    tiling, so it still binds wide-bin categorical configs."""
+    from .partition import partition_scratch_bytes, partition_sub
+
     bpad = hist_bpad(num_bins)
     gb = hist_group(f, bpad) * bpad
     hist = 2 * 8 * gb * 4 + TILE * gb * 2 + 128 * TILE * 2
-    part = (max(256, bpad) * 256 * 2) if has_cat else 0
-    return max(hist, part) <= SEG_VMEM_BUDGET
+    # (a width past the plane cap is refused elsewhere: count the cap's)
+    sub = min(partition_sub(f, num_bins > MAX_SEG_BIN), LANES)
+    part = partition_scratch_bytes(sub)
+    cat = (max(256, bpad) * 256 * 2) if has_cat else 0
+    return max(hist + part, part + cat) <= SEG_VMEM_BUDGET
 
 
 def padded_rows(n: int) -> int:

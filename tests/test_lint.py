@@ -831,9 +831,10 @@ def _partition_copy(tmp_path, mutate):
 def test_mutation_seeded_aliased_read_is_caught(tmp_path):
     """Re-introducing the aliasing bug into a copy of the REAL partition
     kernel fires GL002 through the _seg_partition_kernel ->
-    _partition_window -> read_aliased_tile chain."""
+    _partition_window -> (its block reader) -> _aliased_cols chain, the one
+    place the partition and read_aliased_tile pick their DMA source."""
     res = run_lint(_partition_copy(tmp_path, mutate=True))
-    assert "_seg_partition_kernel:read_aliased_tile:src" in idents(
+    assert "_seg_partition_kernel:_aliased_cols:src" in idents(
         res, "GL002"
     )
     assert not res.ok

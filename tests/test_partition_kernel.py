@@ -10,25 +10,49 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from lightgbm_tpu.ops.pallas.partition import seg_partition_pallas
+from lightgbm_tpu.ops.pallas.partition import (
+    T,
+    partition_sub,
+    seg_partition_pallas,
+    window_block_tiles,
+)
 from lightgbm_tpu.ops.pallas.seg import pack_rows, padded_rows
 from lightgbm_tpu.ops.segpart import sort_partition_xla
 
 
-@pytest.fixture(scope="module", params=[11, 28])
+# (features, rows, wide): sub = 16 and 24 at the original 5000 rows, then the
+# widths that set the DMA block (partition.block_tiles): sub = 8, 48, 128
+# with at least two blocks of rows, and u16 (wide) planes
+@pytest.fixture(
+    scope="module",
+    params=[(11, 5000, False), (28, 5000, False), (2, 9500, False),
+            (67, 5500, False), (242, 3500, False), (5, 5000, True)],
+    ids=lambda p: f"f{p[0]}{'w' if p[2] else ''}",
+)
 def packed(request):
     rng = np.random.default_rng(7)
-    f, n = request.param, 5000
+    f, n, wide = request.param
+    nb = 1024 if wide else 256
     n_pad = padded_rows(n)
-    bins = rng.integers(0, 256, size=(n, f)).astype(np.int32)
+    bins = rng.integers(0, nb, size=(n, f)).astype(np.int32)
     g = rng.normal(size=n).astype(np.float32)
     h = rng.random(n).astype(np.float32) + 0.5
     m = (rng.random(n) < 0.8).astype(np.float32)
     seg = pack_rows(
-        jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m), n_pad
+        jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
+        n_pad, wide=wide,
     )
-    catmask = (rng.random(256) < 0.5).astype(np.float32)
-    return dict(f=f, n=n, n_pad=n_pad, seg=seg, catmask=catmask)
+    catmask = (rng.random(nb) < 0.5).astype(np.float32)
+    return dict(f=f, n=n, n_pad=n_pad, seg=seg, catmask=catmask, wide=wide,
+                nb=nb, B=window_block_tiles(partition_sub(f, wide), n_pad) * T)
+
+
+def _cols(p, expr):
+    """A window coordinate: an int, or an expression in the fixture's DMA
+    block columns B, the tile T and the rows N (clamped by the caller)."""
+    if isinstance(expr, str):
+        return int(eval(expr, {"B": p["B"], "T": T, "N": p["n"]}))
+    return expr
 
 
 @pytest.mark.parametrize(
@@ -44,22 +68,40 @@ def packed(request):
         (130, 255, 4, 100, 0, -1, 0),  # offset > 128 alignment fold
         (333, 0, 0, 10, 0, -1, 0),  # empty window (done step)
         (256, 512, 6, 100, 0, -1, 0),  # exactly tile-aligned window
+        # --- the block-prefetching tile loop (PR 28) ---
+        (0, "N", 1, 120, 0, -1, 0),  # every block of the array
+        (77, 1, 0, 100, 0, -1, 0),  # one row
+        (77, "T - 1", 0, 100, 0, -1, 0),
+        (77, "T", 0, 100, 0, -1, 0),  # T rows over two tiles
+        (128, "T", 1, 100, 0, -1, 0),  # window base on a COL_ALIGN, off a T
+        (0, "B - 1", 1, 90, 0, -1, 0),  # one block less a row
+        (0, "B", 1, 90, 0, -1, 0),  # exactly one block
+        (0, "B + 1", 1, 90, 0, -1, 0),  # one row into the second block
+        (130, "B - 1", 0, 140, 0, -1, 0),  # the same, begin inside a tile
+        (130, "B", 0, 140, 0, -1, 0),
+        (130, "B + 1", 0, 140, 0, -1, 0),
+        ("B - 3", "B + 7", 1, 128, 0, -1, 0),  # begins at a block's end
+        ("B + 128", "B", 0, 60, 0, -1, 1),  # blocks off the array's T grid
+        (9, "2 * B", 1, 100000, 0, -1, 0),  # all-left: no right stream
+        (9, "2 * B", 1, -1, 0, -1, 0),  # all-right: no left stream
+        (1, "2 * B + T", 0, 128, 1, 7, 0),  # ends inside a third block
     ],
 )
 def test_partition_kernel_matches_sort(packed, sb, cnt, feat, tbin, dl, nanb, iscat):
     p = packed
-    if feat >= p["f"]:
-        feat = feat % p["f"]
-    catm = jnp.asarray(p["catmask"]).reshape(1, 256)
+    sb = min(_cols(p, sb), p["n"])
+    cnt = min(_cols(p, cnt), p["n"] - sb)
+    feat = feat % p["f"]
+    catm = jnp.asarray(p["catmask"]).reshape(1, p["nb"])
     scal = jnp.asarray([sb, cnt, feat, tbin, dl, nanb, iscat, 0], jnp.int32)
     got, nl_k = seg_partition_pallas(
         p["seg"], scal, catm, f=p["f"], n_pad=p["n_pad"],
-        use_cat=True, interpret=True,
+        use_cat=True, wide=p["wide"], interpret=True,
     )
     want, nl_s, _ = sort_partition_xla(
         p["seg"], jnp.int32(sb), jnp.int32(cnt), jnp.int32(feat),
         jnp.int32(tbin), jnp.int32(dl), jnp.int32(nanb), jnp.int32(iscat),
-        jnp.asarray(p["catmask"]), f=p["f"], n_pad=p["n_pad"],
+        jnp.asarray(p["catmask"]), f=p["f"], n_pad=p["n_pad"], wide=p["wide"],
     )
     assert int(nl_k) == int(nl_s)
     assert np.array_equal(np.asarray(got), np.asarray(want))
@@ -126,7 +168,12 @@ def test_partition_kernel_gl_vec_matches_sort():
         jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
         n_pad,
     )
-    for sb, cnt, feat, tbin in ((0, n, 3, 120), (137, 7000, 5, 40)):
+    # sub = 16 here: a DMA block is 4096 columns, and the bits ride in
+    # blocks of their own beside the rows
+    for sb, cnt, feat, tbin in (
+        (0, n, 3, 120), (137, 7000, 5, 40), (4095, 4098, 1, 99),
+        (130, 4096, 2, 255), (77, 1, 0, 10),
+    ):
         colv = np.zeros(n_pad, np.int64)
         colv[:n] = bins[:, feat]
         glv = jnp.asarray((colv <= tbin).astype(np.float32))
@@ -145,7 +192,30 @@ def test_partition_kernel_gl_vec_matches_sort():
         assert np.array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_partition_kernel_batch_matches_serial_loop():
+@pytest.mark.parametrize(
+    "rows",
+    [
+        # disjoint windows incl. a zero-cnt member and a categorical member
+        [
+            (0, 1200, 3, 120, 0, -1, 0, 0),
+            (1200, 800, 5, 80, 1, 200, 0, 0),
+            (2000, 0, 0, 10, 0, -1, 0, 0),  # no-op
+            (2500, 1500, 7, 30, 0, -1, 1, 0),  # categorical
+        ],
+        # adjacent windows that share COL_ALIGN blocks, the first longer
+        # than a DMA block (4096 columns at this width): every program
+        # re-reads a boundary block the one before it rewrote, and the
+        # second's first block starts inside the first's last tile
+        [
+            (0, 4100, 3, 120, 0, -1, 0, 0),
+            (4100, 1, 5, 80, 0, -1, 0, 0),
+            (4101, 255, 2, 128, 0, -1, 1, 0),
+            (4356, 644, 7, 30, 0, -1, 0, 0),
+        ],
+    ],
+    ids=["disjoint", "adjacent-over-a-block"],
+)
+def test_partition_kernel_batch_matches_serial_loop(rows):
     """K-program batched launch over DISJOINT windows == K serial kernel
     calls (bit-equal state), including zero-cnt no-op members."""
     from lightgbm_tpu.ops.pallas.partition import seg_partition_pallas_batch
@@ -161,13 +231,6 @@ def test_partition_kernel_batch_matches_serial_loop():
         jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m), n_pad
     )
     catmask = (rng.random(256) < 0.5).astype(np.float32)
-    # disjoint windows incl. a zero-cnt member and a categorical member
-    rows = [
-        (0, 1200, 3, 120, 0, -1, 0, 0),
-        (1200, 800, 5, 80, 1, 200, 0, 0),
-        (2000, 0, 0, 10, 0, -1, 0, 0),  # no-op
-        (2500, 1500, 7, 30, 0, -1, 1, 0),  # categorical
-    ]
     scal = jnp.asarray(rows, jnp.int32)
     catm = jnp.broadcast_to(jnp.asarray(catmask), (4, 256))
     got, nl_b = seg_partition_pallas_batch(

@@ -359,7 +359,7 @@ def test_wide_grow_tree_matches_ordered():
     np.testing.assert_array_equal(trees["seg"][1], trees["ordered"][1])
 
 
-def test_seg_vmem_gate():
+def test_seg_vmem_gate(monkeypatch):
     from lightgbm_tpu.ops.pallas.seg import seg_vmem_ok
 
     assert seg_vmem_ok(28, 256)  # the bench config always fits
@@ -370,6 +370,26 @@ def test_seg_vmem_gate():
     assert seg_vmem_ok(100, 4096)
     assert not seg_vmem_ok(121, 65536)
     assert not seg_vmem_ok(4, 65536, has_cat=True)  # cat one-hot blows up
+    # the partition's block-prefetch scratch is counted beside the
+    # histogram's (both live in the fused grow step): the largest admitted
+    # shape — every one of the 128 planes, the widest histogram group that
+    # fits — still passes with it, and a budget one byte under their sum
+    # refuses it
+    from lightgbm_tpu.ops.pallas.partition import (
+        T, block_tiles, partition_scratch_bytes,
+    )
+    from lightgbm_tpu.ops.pallas import seg
+
+    assert seg_vmem_ok(121, 8192, has_cat=True)
+    assert seg_vmem_ok(242, 256, has_cat=True)
+    part = partition_scratch_bytes(128)
+    assert 2 * 128 * block_tiles(128) * T * 2 < part < seg.SEG_VMEM_BUDGET // 4
+    hist_alone = 2 * 8 * 8192 * 4 + seg.TILE * 8192 * 2 + 128 * seg.TILE * 2
+    assert hist_alone + part <= seg.SEG_VMEM_BUDGET
+    monkeypatch.setattr(seg, "SEG_VMEM_BUDGET", hist_alone + part)
+    assert seg_vmem_ok(121, 8192)
+    monkeypatch.setattr(seg, "SEG_VMEM_BUDGET", hist_alone + part - 1)
+    assert not seg_vmem_ok(121, 8192)
 
 
 def test_wide_seg_hist_int8_quantized(packed_wide):

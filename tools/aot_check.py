@@ -131,37 +131,64 @@ def _c5(topo):
     )
 
 
-@check("seg_partition_pallas column-read f=28")
+# The partition kernel's block-prefetching tile loop at the shapes that run
+# it: both benchmark cells' (their rows set n_pad, so the DMAs' extents are
+# the real ones), every plane there is (f=242: sub = 128, where the scratch
+# is largest), u16 planes with the widest categorical table seg_vmem_ok
+# admits, and the go-left-bits stream of feature-parallel seg.
+_HIGGS_ROWS = 10_500_000
+_CRITEO_ROWS = 8_000_000
+
+
+def _partition(topo, f, rows, *, use_cat=False, wide=False, bmt=256, gl=False):
+    n_pad = padded_rows(rows)
+    args = [
+        s((storage_lanes(f, wide), n_pad), jnp.int16), s((8,), jnp.int32),
+        s((1, bmt), jnp.float32),
+    ]
+    if gl:
+        args.append(s((n_pad,), jnp.float32))
+    return compile_on_topo(
+        topo, seg_partition_pallas, *args,
+        f=f, n_pad=n_pad, use_cat=use_cat, wide=wide,
+    )
+
+
+@check("seg_partition_pallas column-read f=28 (toy rows: the clipped block)")
 def _c6(topo):
-    n_pad = padded_rows(5000)
-    return compile_on_topo(
-        topo, seg_partition_pallas,
-        s((storage_lanes(28), n_pad), jnp.int16), s((8,), jnp.int32),
-        s((1, 256), jnp.float32),
-        f=28, n_pad=n_pad, use_cat=True,
-    )
-
-
-@check("seg_partition_pallas bits-fed (gl_vec) f=28")
-def _c7(topo):
-    n_pad = padded_rows(5000)
-    return compile_on_topo(
-        topo, seg_partition_pallas,
-        s((storage_lanes(28), n_pad), jnp.int16), s((8,), jnp.int32),
-        s((1, 256), jnp.float32), s((n_pad,), jnp.float32),
-        f=28, n_pad=n_pad, use_cat=False,
-    )
+    return _partition(topo, 28, 5000, use_cat=True)
 
 
 @check("seg_partition_pallas u16 wide f=4")
 def _c8(topo):
-    n_pad = padded_rows(5000)
-    return compile_on_topo(
-        topo, seg_partition_pallas,
-        s((storage_lanes(4, wide=True), n_pad), jnp.int16),
-        s((8,), jnp.int32), s((1, 1024), jnp.float32),
-        f=4, n_pad=n_pad, use_cat=True, wide=True,
+    return _partition(topo, 4, 5000, use_cat=True, wide=True, bmt=1024)
+
+
+@check("seg_partition_pallas higgs.fit shape f=28 10.5M rows")
+def _c17(topo):
+    return _partition(topo, 28, _HIGGS_ROWS)
+
+
+@check("seg_partition_pallas criteo67 shape f=67 8M rows")
+def _c18(topo):
+    return _partition(topo, 67, _CRITEO_ROWS)
+
+
+@check("seg_partition_pallas sub=128 f=242 categorical")
+def _c19(topo):
+    return _partition(topo, 242, 1_000_000, use_cat=True)
+
+
+@check("seg_partition_pallas u16 wide f=121 b=8192 categorical (largest admitted)")
+def _c20(topo):
+    return _partition(
+        topo, 121, 1_000_000, use_cat=True, wide=True, bmt=8192
     )
+
+
+@check("seg_partition_pallas bits-fed (gl_vec) f=28 10.5M rows")
+def _c7(topo):
+    return _partition(topo, 28, _HIGGS_ROWS, gl=True)
 
 
 @check("split_scan fused best-split (F=28, B=256)")
@@ -224,17 +251,17 @@ def _c13(topo):
     )
 
 
-def _fused_step(topo, k, quantized):
+def _fused_step(topo, k, quantized, f=28, rows=5000):
     from lightgbm_tpu.ops.pallas.grow_step import fused_grow_step_pallas
     from lightgbm_tpu.ops.pallas.seg import hist_bpad, hist_ngroups
 
-    n_pad = padded_rows(5000)
+    n_pad = padded_rows(rows)
     return compile_on_topo(
         topo, fused_grow_step_pallas,
-        s((storage_lanes(28), n_pad), jnp.int16), s((k, 8), jnp.int32),
+        s((storage_lanes(f), n_pad), jnp.int16), s((k, 8), jnp.int32),
         s((k, 256), jnp.float32), s((2,), jnp.float32),
-        s((hist_ngroups(28, hist_bpad(256)),), jnp.int32),
-        f=28, num_bins=256, n_pad=n_pad, use_cat=False, quantized=quantized,
+        s((hist_ngroups(f, hist_bpad(256)),), jnp.int32),
+        f=f, num_bins=256, n_pad=n_pad, use_cat=False, quantized=quantized,
     )
 
 
@@ -251,6 +278,16 @@ def _c15(topo):
 @check("fused_grow_step_pallas K=4 bf16 f=28 b=256")
 def _c16(topo):
     return _fused_step(topo, 4, False)
+
+
+@check("fused_grow_step_pallas criteo67.fit-eval shape K=1 bf16 f=67 8M rows")
+def _c22(topo):
+    return _fused_step(topo, 1, False, f=67, rows=_CRITEO_ROWS)
+
+
+@check("fused_grow_step_pallas K=1 bf16 sub=128 f=242")
+def _c23(topo):
+    return _fused_step(topo, 1, False, f=242, rows=1_000_000)
 
 
 @check("forest_walk predictor (T=64 trees, F=28, cat)")
