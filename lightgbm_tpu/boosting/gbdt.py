@@ -1318,6 +1318,21 @@ class Booster:
             jnp.asarray(arr[:, 3].astype(bool)),
         )
 
+    def _seg_span_args(self) -> Dict[str, int]:
+        """Plane groups of the packed row and the planes a group, for the
+        ``train/iteration`` and ``train/launch`` spans (none off the
+        segment path)."""
+        p = getattr(self, "_grower_params", None)
+        if p is None or p.hist_mode != "seg" or self.train_set is None:
+            return {}
+        from ..ops.pallas.seg import group_shape
+
+        f = int(self._bins.shape[1]) // max(self._featpar or 1, 1)
+        if f <= 0:
+            return {}
+        g, sub = group_shape(f, p.max_bin > 256)
+        return {"seg_groups": g, "seg_group_planes": sub}
+
     def _make_grower_params(self) -> GrowerParams:
         from ..ops.split import CatParams
 
@@ -1340,23 +1355,45 @@ class Booster:
                 "(use_quantized_grad=True provides the scales)"
             )
 
-        # feature budget: bins byte-pack two per i16 plane up to max_bin 256
-        # (242 features), one u16 plane per feature beyond (121 features —
-        # the reference's DenseBin<uint16_t> analog, dense_bin.hpp:18); wide
-        # configs must also fit the histogram kernel's VMEM scratch
-        from ..ops.pallas.seg import seg_vmem_ok
+        # bins byte-pack two per i16 plane up to max_bin 256, one u16 plane
+        # per feature beyond (the reference's DenseBin<uint16_t> analog,
+        # dense_bin.hpp:18).  Width is no limit: a row of more than 128
+        # planes is packed as plane groups (ops/pallas/seg.py).  What bounds
+        # a table on the seg path is the kernels' VMEM scratch at very wide
+        # bins and the device's memory: the packed matrix (2 B a plane a
+        # row), the partition's one-group spill and hist_buf [L, F, B, 3].
+        from ..ops.pallas.seg import (
+            group_shape,
+            padded_rows,
+            plane_groups,
+            seg_vmem_ok,
+        )
 
         # feature-parallel seg: each shard packs only its feature slice, so
-        # the lane/VMEM budgets apply to the PER-SHARD feature count
+        # the VMEM and memory budgets apply to the PER-SHARD feature count
         n_eff = n_used // self._featpar if self._featpar else n_used
-        seg_fcap = 242 if self._max_bin_padded <= 256 else 121
-        seg_fits = seg_vmem_ok(
+        bins_ok = self._max_bin_padded <= 65536
+        seg_fits = bins_ok and seg_vmem_ok(
             max(n_eff, 1), self._max_bin_padded, getattr(self, "_has_cat", False)
         )
+        seg_bytes = 0
+        if bins_ok and n_eff > 0:
+            g, sub = group_shape(n_eff, self._max_bin_padded > 256)
+            shards = (
+                self._mesh.size
+                if self._mesh is not None and not self._featpar else 1
+            )
+            rows = padded_rows(-(-int(self._bins.shape[0]) // shards))
+            seg_bytes = (
+                (g + 1) * sub * rows * 2
+                + cfg.num_leaves * n_eff * self._max_bin_padded * 3 * 4
+            )
+        mem_limit = (_jax.devices()[0].memory_stats() or {}).get("bytes_limit")
+        mem_fits = mem_limit is None or seg_bytes <= mem_limit
         seg_ok = (
-            self._max_bin_padded <= 65536
-            and seg_fits
-            and 0 < n_eff <= seg_fcap
+            seg_fits
+            and mem_fits
+            and n_eff > 0
             # the seg path has its own kernels: the default bf16 three-term
             # one and (r3) an int8 grid variant for quantized training;
             # other explicit kernel choices keep the ordered path
@@ -1378,24 +1415,29 @@ class Booster:
             # 1.4-10x slower than seg mode at scale (BENCH_NOTES.md)
             from ..utils.log import log_warning
 
-            if self._max_bin_padded > 65536:
+            if not bins_ok:
                 why = f"max_bin padded to {self._max_bin_padded} > 65536"
+                cure = "Consider a smaller max_bin."
             elif not seg_fits:
                 why = (
                     f"histogram VMEM scratch at {n_used} features x "
                     f"max_bin {self._max_bin_padded} exceeds the budget"
                 )
+                cure = "Consider a smaller max_bin."
             else:
                 why = (
-                    f"{n_used} used features > {seg_fcap} (packed row "
-                    "exceeds 128 i16 lanes)"
+                    f"the packed rows and hist_buf [num_leaves, features, "
+                    f"max_bin, 3] need {seg_bytes / 2**30:.1f} GiB of the "
+                    f"device's {mem_limit / 2**30:.1f} GiB"
+                )
+                cure = (
+                    "Consider fewer num_leaves, a smaller max_bin, or "
+                    "tree_learner='data' over more chips."
                 )
             log_warning(
                 "segment-resident training is unavailable: " + why +
                 "; falling back to hist_mode='ordered' (1.4-10x slower at "
-                "scale). Consider feature selection"
-                + (" or a smaller max_bin" if seg_fcap == 121 or not seg_fits
-                   else "") + "."
+                "scale). " + cure
             )
         hist_mode = str(
             self.params.get(
@@ -1423,6 +1465,10 @@ class Booster:
                  "monotone_constraints_method='intermediate'/'advanced'"),
                 (self._interaction_sets is not None,
                  "interaction_constraints"),
+                (hist_mode == "seg" and bins_ok and n_eff > 0 and plane_groups(
+                    n_eff, self._max_bin_padded > 256) > 1,
+                 "a packed row of more than one plane group "
+                 f"({n_eff} columns)"),
             ]
             why = [what for bad, what in blockers if bad]
             if why:
@@ -1909,7 +1955,7 @@ class Booster:
             finished = False
             with tracer.span(
                 "train/iteration", timer="boosting/update",
-                args={"iter": it}, ambient=True,
+                args={"iter": it, **self._seg_span_args()}, ambient=True,
             ) as sp:
                 try:
                     finished = self._update_impl(train_set, fobj)
@@ -1936,7 +1982,7 @@ class Booster:
         # ambient parents the collective io_callback spans fired off-thread
         with tracer.span(
             "train/iteration", timer="boosting/update",
-            args={"iter": it}, ambient=True,
+            args={"iter": it, **self._seg_span_args()}, ambient=True,
         ) as sp:
             ses.begin_iteration()
             try:

@@ -505,7 +505,8 @@ class LaunchRunner:
         with get_tracer().span(
             "train/launch",
             timer="boosting/update",
-            args={"launch_begin": it0, "steps_per_launch": self._n},
+            args={"launch_begin": it0, "steps_per_launch": self._n,
+                  **b._seg_span_args()},
             ambient=True,
         ) as lsp:
             return self._run_window(lsp, it0, init_scores)

@@ -1041,6 +1041,8 @@ def grow_tree(
     if use_seg:
         from .pallas.seg import (
             MAX_WIDE_BIN,
+            flat_planes,
+            is_grouped,
             pack_rows,
             padded_rows,
             seg_hist,
@@ -1048,6 +1050,7 @@ def grow_tree(
             stat_lanes,
         )
         from .segpart import (
+            go_left_bits,
             leaf_id_from_seg,
             leaf_of_positions,
             sort_partition,
@@ -1081,6 +1084,18 @@ def grow_tree(
         with jax.named_scope("pack_rows"):
             seg0 = pack_rows(
                 bins_loc, grad, hess, count_mask, n_pad_seg, wide=seg_wide
+            )
+        # a row of more than 128 planes comes back as G plane groups that
+        # share one row order (seg.pack_rows): the partition then runs once
+        # a group on go-left bits computed from the split feature's own
+        # plane, and the kernels whose predicate reads that plane in place
+        # (the fused step, the batched partition) are not its to take
+        seg_grouped = is_grouped(seg0)
+        if seg_grouped and leaf_k > 1:
+            raise ValueError(
+                f"leaf_batch > 1 does not support a packed row of "
+                f"{seg0.shape[0]} plane groups ({f_seg} columns); set "
+                "leaf_batch=1"
             )
 
         # explicit int8 opt-in (hist_method='pallas_int8' + quantized
@@ -1157,9 +1172,11 @@ def grow_tree(
         # per-shard partition counts BETWEEN partition and histogram, which a
         # single kernel launch cannot host.  Feature-parallel likewise: the
         # winner feature's go-left bits come from the owning shard via a
-        # gl_vec psum at partition time.
+        # gl_vec psum at partition time.  A grouped row likewise: its
+        # partition runs on bits, once a plane group.
         use_fused_grow = (
             p.grow_fused and p.axis_name is None and not use_featpar
+            and not seg_grouped
         )
     else:
         use_fused_grow = False
@@ -1646,30 +1663,29 @@ def grow_tree(
                 # (reference feature-parallel keeps partitioning local
                 # because every machine holds all columns; here columns are
                 # sliced, so the bits travel instead — O(N) f32 on ICI)
-                from .segpart import _go_left as _seg_go_left
-
                 owner = jnp.clip(feat // f_loc, 0, p.feature_shard - 1)
                 lane = jnp.clip(feat - owner * f_loc, 0, max(f_loc - 1, 0))
-                if seg_wide:
-                    p16 = lax.dynamic_slice_in_dim(st.order, lane, 1, axis=0)[0]
-                    colv = p16.astype(jnp.int32) & 0xFFFF
-                else:
-                    p16 = lax.dynamic_slice_in_dim(
-                        st.order, lane >> 1, 1, axis=0
-                    )[0]
-                    colv = (
-                        (p16.astype(jnp.int32) & 0xFFFF) >> ((lane & 1) * 8)
-                    ) & 0xFF
-                glv = _seg_go_left(
-                    colv, tbin, dl.astype(jnp.int32), nan_bins[feat],
-                    cis.astype(jnp.int32), cmask.astype(jnp.float32),
+                glv = go_left_bits(
+                    st.order, lane, tbin, dl.astype(jnp.int32),
+                    nan_bins[feat], cis.astype(jnp.int32),
+                    cmask.astype(jnp.float32), wide=seg_wide,
                 )
                 mine = lax.axis_index(feat_axis) == owner
                 gl_vec = timed_psum(
-                    jnp.where(mine, glv.astype(jnp.float32), 0.0),
+                    jnp.where(mine, glv, 0.0),
                     feat_axis, site="partition",
                     measure=p.measure_collectives,
                 )
+            elif seg_grouped:
+                # the split feature's plane lies in ONE plane group: decide
+                # go-left once, from that plane, and let the partition move
+                # every group by the same bits
+                with jax.named_scope("go_left"):
+                    gl_vec = go_left_bits(
+                        st.order, feat, tbin, dl.astype(jnp.int32),
+                        nan_bins[feat], cis.astype(jnp.int32),
+                        cmask.astype(jnp.float32), wide=seg_wide,
+                    )
             with jax.named_scope("partition"):
                 order, nleft, nright = sort_partition(
                     st.order,
@@ -2959,9 +2975,10 @@ def grow_tree(
             lp = leaf_of_positions(
                 state.leaf_begin, state.leaf_nrows, state.num_leaves, n
             )
-            GLO = stat_lanes(f_seg, seg_wide)[0]
-            ridx = (state.order[GLO + 5, :n].astype(jnp.int32) & 0xFFFF) | (
-                (state.order[GLO + 6, :n].astype(jnp.int32) & 0xFFFF) << 16
+            GLO = stat_lanes(f_seg, seg_wide, seg_grouped)[0]
+            planes = flat_planes(state.order)
+            ridx = (planes[GLO + 5, :n].astype(jnp.int32) & 0xFFFF) | (
+                (planes[GLO + 6, :n].astype(jnp.int32) & 0xFFFF) << 16
             )
             return tree, leaf_id_from_seg(ridx, lp)
     if use_ordered:

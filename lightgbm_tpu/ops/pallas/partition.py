@@ -82,7 +82,11 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .seg import COL_ALIGN, used_lanes
+from .seg import COL_ALIGN, is_grouped, used_lanes
+
+# Test hook: route segpart.sort_partition through this kernel in interpret
+# mode off-TPU (read at TRACE time, like seg._INTERPRET).
+_INTERPRET = False
 
 T = 256  # streaming tile columns (rows of training data)
 G = 4  # tiles a loop iteration takes together (block_tiles is a multiple)
@@ -136,14 +140,24 @@ def partition_scratch_bytes(sub: int) -> int:
     return blocks + stages + temps
 
 
-def _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input):
+def _plane_index(grp, sub, base_col, cols):
+    """Index of a ``[sub, cols]`` window of a packed matrix at a 128-aligned
+    column: of plane group ``grp`` of a grouped ``[G, sub, n_pad]`` matrix,
+    or (``grp`` None) of the first ``sub`` planes of a ``[LANES, n_pad]``
+    one."""
+    at = (pl.ds(0, sub), pl.ds(pl.multiple_of(base_col, COL_ALIGN), cols))
+    return at if grp is None else (grp,) + at
+
+
+def _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input,
+                  grp=None):
     """``[sub, cols]`` window of an IN-PLACE (input/output-aliased) packed
     segment matrix, as a DMA source, through the OUTPUT alias (see
     ``read_aliased_tile``)."""
     src = seg_in if read_via_input else seg_out
     # the input-ref read below is unreachable in production: it only
     # engages under the test-only read_via_input knob of read_aliased_tile
-    return src.at[pl.ds(0, sub), pl.ds(pl.multiple_of(base_col, COL_ALIGN), cols)]  # graftlint: disable=GL002
+    return src.at[_plane_index(grp, sub, base_col, cols)]  # graftlint: disable=GL002
 
 
 def read_aliased_tile(seg_in, seg_out, stage, sem, base_col, *,
@@ -197,13 +211,19 @@ def _partition_window(
     bmt: int,
     use_gl: bool,
     read_via_input: bool = False,
+    grp=None,
 ):
     """Stable in-place partition of ONE leaf window (the per-program body of
     the seg partition kernel, factored out so the fused grow-step kernel can
-    run it before its histogram phase).  Returns nl — rows going left."""
+    run it before its histogram phase).  Returns nl — rows going left.
+
+    ``grp`` (scalar i32): the window is plane group ``grp`` of a grouped
+    ``[G, sub, n_pad]`` matrix.  The predicate then has to come as bits
+    (``use_gl``): the split feature's plane lies in one group only, and
+    every group is permuted by the same bits."""
     (in_blk, gl_blk, out_l, out_r, stage_l, stage_r,
      sem_in, sem_gl, sem_l, sem_r) = scratch
-    nbt = window_block_tiles(sub, seg_out.shape[1])
+    nbt = window_block_tiles(sub, seg_out.shape[-1])
     bc = nbt * T
     abegin = (sbegin // COL_ALIGN) * COL_ALIGN
     off = sbegin - abegin
@@ -311,10 +331,9 @@ def _partition_window(
     def _flush_dma(outb, sem, dst, dst_base, blk, slot):
         return pltpu.make_async_copy(
             outb.at[slot],
-            dst.at[
-                pl.ds(0, sub),
-                pl.ds(pl.multiple_of(dst_base + blk * T, COL_ALIGN), T),
-            ],
+            # the spill is one group's worth whatever the matrix's form
+            dst.at[_plane_index(grp if dst is seg_out else None, sub,
+                                dst_base + blk * T, T)],
             sem.at[slot],
         )
 
@@ -359,7 +378,7 @@ def _partition_window(
         # boundary tiles must come through the OUTPUT alias — see
         # read_aliased_tile for the interpret-mode pitfall this guards
         return _aliased_cols(
-            seg_any, seg_out, sub, abegin + c0, cols, read_via_input
+            seg_any, seg_out, sub, abegin + c0, cols, read_via_input, grp
         )
 
     def spill_cols(c0, cols):
@@ -514,8 +533,11 @@ def _seg_partition_kernel(
     bmt: int,
     use_gl: bool,
     read_via_input: bool = False,
+    grouped: bool = False,
 ):
-    pid = pl.program_id(0)
+    # a grouped matrix: one window, program g permutes plane group g by the
+    # same bits (every program finds, and writes, the same nl)
+    pid = 0 if grouped else pl.program_id(0)
     nl_ref[pid, 0] = _partition_window(
         scal_ref[pid, 0],
         scal_ref[pid, 1],
@@ -537,6 +559,7 @@ def _seg_partition_kernel(
         bmt=bmt,
         use_gl=use_gl,
         read_via_input=read_via_input,
+        grp=pl.program_id(0) if grouped else None,
     )
 
 
@@ -566,13 +589,22 @@ def seg_partition_pallas(
 
     ``read_via_input``: test-only knob (see read_aliased_tile).
 
+    A grouped matrix (``[G, sub, n_pad]``, seg.pack_rows) needs ``gl_vec``
+    and takes a G-program grid: one launch, the loop's code once, program g
+    moving group g through the same VMEM scratch and HBM spill.
+
     Returns (seg', nl).  Left child lands at [sbegin, sbegin+nl), right at
     [sbegin+nl, sbegin+cnt), both in stable (original) order; every column
     outside the window keeps its value.
     """
     use_gl = gl_vec is not None
-    sub = partition_sub(f, wide)
-    lanes = seg.shape[0]
+    grouped = is_grouped(seg)
+    if grouped and not use_gl:
+        raise ValueError(
+            "a grouped segment matrix is partitioned by precomputed go-left "
+            "bits (segpart.go_left_bits): pass gl_vec"
+        )
+    sub = seg.shape[1] if grouped else partition_sub(f, wide)
     tri = jnp.tril(jnp.ones((T, T), jnp.bfloat16)).T  # tri[i, j] = i <= j
     gl_arr = (
         gl_vec.reshape(1, n_pad).astype(jnp.float32)
@@ -582,11 +614,11 @@ def seg_partition_pallas(
     kernel = functools.partial(
         _seg_partition_kernel, f=f, n_pad=n_pad, use_cat=use_cat, sub=sub,
         wide=wide, bmt=catmask.shape[1], use_gl=use_gl,
-        read_via_input=read_via_input,
+        read_via_input=read_via_input, grouped=grouped,
     )
     seg_new, _, nl = pl.pallas_call(
         kernel,
-        grid=(1,),
+        grid=(seg.shape[0] if grouped else 1,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -600,7 +632,7 @@ def seg_partition_pallas(
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((lanes, n_pad), jnp.int16),
+            jax.ShapeDtypeStruct(seg.shape, jnp.int16),
             jax.ShapeDtypeStruct((sub, n_pad), jnp.int16),
             jax.ShapeDtypeStruct((1, 1), jnp.int32),
         ],
@@ -642,6 +674,11 @@ def seg_partition_pallas_batch(
     ``read_via_input``: test-only knob (see read_aliased_tile).
 
     Returns (seg', nl[K])."""
+    if is_grouped(seg):
+        raise ValueError(
+            "the batched partition takes a one-group segment matrix; a "
+            "grouped one is partitioned window by window (leaf_batch=1)"
+        )
     k = scal.shape[0]
     sub = partition_sub(f, wide)
     lanes = seg.shape[0]

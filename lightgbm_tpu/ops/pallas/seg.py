@@ -18,13 +18,28 @@ keep the training rows PHYSICALLY in leaf-segment order, so that:
     rows — the kernel below — with zero gathers.
 
 Storage layout: one PLANE-MAJOR i16 matrix ``[storage_lanes(F), n_pad]``
-(used planes rounded to a 32-sublane tile; 128 is the hard cap) — plane p,
-data-row r.  Planes [0, ceil(F/2)) hold bins byte-packed two features per
-plane (feature j lives in byte j&1 of plane j>>1); then 7 stat planes:
-g_lo16, g_hi16, h_lo16, h_hi16 (the EXACT f32 bit patterns of grad/hess
-split into 16-bit halves — no precision loss), mask (0/1), ridx_lo, ridx_hi
-(original row index, for the final segment-order -> row-order inverse
-permutation).
+(used planes rounded to a 32-sublane tile) — plane p, data-row r.  Planes
+[0, ceil(F/2)) hold bins byte-packed two features per plane (feature j
+lives in byte j&1 of plane j>>1); then 7 stat planes: g_lo16, g_hi16,
+h_lo16, h_hi16 (the EXACT f32 bit patterns of grad/hess split into 16-bit
+halves — no precision loss), mask (0/1), ridx_lo, ridx_hi (original row
+index, for the final segment-order -> row-order inverse permutation).
+
+Rows wider than ``LANES`` planes (more than 242 byte-binned or 121 u16
+features) are stored as G PLANE GROUPS, ``[G, sub, n_pad]`` with ``sub`` <=
+128 a multiple of 16 (``group_shape``): plane p of the row is
+``[p // sub, p % sub]``, the groups share one row order, and the array's
+rank is how every consumer tells the two forms apart — G comes from the
+column count and the bin width alone.  A group is what ONE pass of the
+partition kernel moves (its VMEM blocks hold at most 128 planes); the
+kernel runs once a group, every group permuted by the same go-left bits
+(``segpart.go_left_bits``).  The 7 stat planes live ONCE, in a 16-plane
+block that starts at the first multiple of 16 past the bin planes
+(``stat_lanes(..., grouped=True)``): the partition moves every plane alike,
+so it needs them nowhere in particular; the histogram reads them beside
+each feature group's bin planes as one aligned 16-plane DMA, whichever
+group that block falls in; a copy a group would cost 7 planes x G of HBM
+and of every partition pass and buy nothing.
 
 Plane-major is the layout XLA itself assigns this loop-carried matrix (the
 sort-partition reads whole planes); storing it that way keeps every consumer
@@ -85,7 +100,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128  # hard cap on packed planes (128 i16 sublane budget)
+LANES = 128  # planes one partition pass moves: a plane group's cap
+STAT_BLOCK = 16  # grouped rows: the stat planes' aligned block (an i16 tile)
 TILE = 512  # rows per DMA tile in seg_hist
 N_STAT_LANES = 7
 MAX_SEG_BIN = 256  # byte-packed bins: values must fit u8 (narrow layout)
@@ -111,21 +127,64 @@ def bin_lanes(f: int, wide: bool = False) -> int:
     return f if wide else (f + 1) // 2
 
 
-def stat_lanes(f: int, wide: bool = False) -> Tuple[int, int, int, int, int, int, int]:
-    """Lane indices of (g_lo, g_hi, h_lo, h_hi, mask, ridx_lo, ridx_hi)."""
+def plane_groups(f: int, wide: bool = False) -> int:
+    """G, the plane groups of a packed row — from the column count and the
+    bin width alone.  1 while bins and stats fit ``LANES`` planes."""
+    if bin_lanes(f, wide) + N_STAT_LANES <= LANES:
+        return 1
+    return -(-used_lanes(f, wide, grouped=True) // LANES)
+
+
+def is_grouped(seg) -> bool:
+    """Whether a packed matrix is in the grouped ``[G, sub, n_pad]`` form."""
+    return seg.ndim == 3
+
+
+def flat_planes(seg: jnp.ndarray) -> jnp.ndarray:
+    """The ``[planes, n_pad]`` view of either form (a bitcast: ``sub`` is a
+    multiple of the i16 sublane tile)."""
+    return seg.reshape(-1, seg.shape[-1]) if is_grouped(seg) else seg
+
+
+def stat_lanes(f: int, wide: bool = False, grouped: bool = False
+               ) -> Tuple[int, int, int, int, int, int, int]:
+    """Plane indices of (g_lo, g_hi, h_lo, h_hi, mask, ridx_lo, ridx_hi) —
+    right after the bin planes; in a grouped row at the next multiple of
+    ``STAT_BLOCK`` (indices into ``flat_planes``)."""
     s = bin_lanes(f, wide)
+    if grouped:
+        s = -(-s // STAT_BLOCK) * STAT_BLOCK
     return s, s + 1, s + 2, s + 3, s + 4, s + 5, s + 6
 
 
-def used_lanes(f: int, wide: bool = False) -> int:
+def used_lanes(f: int, wide: bool = False, grouped: bool = False) -> int:
+    if grouped:
+        return stat_lanes(f, wide, True)[0] + STAT_BLOCK
     return bin_lanes(f, wide) + N_STAT_LANES
 
 
 def storage_lanes(f: int, wide: bool = False) -> int:
-    """Allocated planes: used planes rounded to an i16 sublane-tile multiple
-    (32).  Storing only these — not the full 128 cap — cuts the segment
-    matrix HBM footprint 4x at F=28 (2.7 GB -> 0.7 GB at 10.5M rows)."""
+    """Allocated planes of a one-group row: used planes rounded to an i16
+    sublane-tile multiple (32).  Storing only these — not the full 128 cap
+    — cuts the segment matrix HBM footprint 4x at F=28 (2.7 GB -> 0.7 GB at
+    10.5M rows)."""
     return min(LANES, -(-used_lanes(f, wide) // 32) * 32)
+
+
+def group_shape(f: int, wide: bool = False, groups: int = None
+                ) -> Tuple[int, int]:
+    """(G, sub) of a packed row.  One group: (1, ``storage_lanes``).  More:
+    the used planes dealt evenly over G groups of ``sub`` planes, ``sub`` a
+    multiple of 16 (243 features: 2 x 80, 2,000: 8 x 128).  ``groups``
+    overrides G (tests: two groups at a width that needs one)."""
+    g = plane_groups(f, wide) if groups is None else groups
+    if g == 1:
+        return 1, storage_lanes(f, wide)
+    used = used_lanes(f, wide, grouped=True)
+    sub = -(-(-(-used // g)) // STAT_BLOCK) * STAT_BLOCK
+    if sub > LANES:
+        raise ValueError(f"{g} plane groups cannot hold {used} planes")
+    return g, sub
 
 
 COL_ALIGN = 128  # minor-dim DMA starts must be 128-lane aligned
@@ -139,8 +198,9 @@ def seg_vmem_ok(f: int, num_bins: int, has_cat: bool = False) -> bool:
     program — acc [8, group*bpad] + the matching out block + onehot
     [TILE, group*bpad] + the staging tile — independent of F.  The
     partition's read blocks, flush buffers and stagings
-    (partition.partition_scratch_bytes, set by the packed planes alone) sit
-    beside it in the fused grow step.  The categorical partition
+    (partition.partition_scratch_bytes, set by the planes one pass moves:
+    the used planes, or one plane group's) sit beside it in the fused grow
+    step.  The categorical partition
     additionally builds a [bmt, 256] one-hot (bf16), unchanged by the plane
     tiling, so it still binds wide-bin categorical configs."""
     from .partition import partition_scratch_bytes, partition_sub
@@ -148,8 +208,12 @@ def seg_vmem_ok(f: int, num_bins: int, has_cat: bool = False) -> bool:
     bpad = hist_bpad(num_bins)
     gb = hist_group(f, bpad) * bpad
     hist = 2 * 8 * gb * 4 + TILE * gb * 2 + 128 * TILE * 2
-    # (a width past the plane cap is refused elsewhere: count the cap's)
-    sub = min(partition_sub(f, num_bins > MAX_SEG_BIN), LANES)
+    wide = num_bins > MAX_SEG_BIN
+    # a grouped row's partition pass moves one group, and so counts one
+    sub = (
+        group_shape(f, wide)[1] if plane_groups(f, wide) > 1
+        else partition_sub(f, wide)  # the used planes, to 8 not to 32
+    )
     part = partition_scratch_bytes(sub)
     cat = (max(256, bpad) * 256 * 2) if has_cat else 0
     return max(hist + part, part + cat) <= SEG_VMEM_BUDGET
@@ -178,15 +242,15 @@ def pack_rows(
     mask: jnp.ndarray,  # [N] f32 in {0, 1}
     n_pad: int,
     wide: bool = False,
+    groups: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Pack rows into the PLANE-MAJOR [LANES, n_pad] i16 layout (ridx = iota)."""
+    """Pack rows into the PLANE-MAJOR i16 layout (ridx = iota):
+    ``[storage_lanes, n_pad]``, or ``[G, sub, n_pad]`` when the row needs
+    G > 1 plane groups.  ``groups`` is for tests alone: it deals a row that
+    needs one group over several, to tie the grouped form to the plain one."""
     n, f = bins.shape
-    if used_lanes(f, wide) > LANES:
-        cap = (LANES - N_STAT_LANES) if wide else 2 * (LANES - N_STAT_LANES)
-        raise ValueError(
-            f"seg layout supports at most {cap} features"
-            f"{' at max_bin > 256' if wide else ''}, got {f}"
-        )
+    g = plane_groups(f, wide) if groups is None else groups
+    grouped = g > 1
     bt = bins.T.astype(jnp.int32)  # [F, N]
     if wide:
         # one u16 plane per feature (DenseBin<uint16_t>, dense_bin.hpp:18)
@@ -202,6 +266,10 @@ def pack_rows(
     ridx = jnp.arange(n, dtype=jnp.int32)
     planes = [
         bin16,
+        # a grouped row's stat planes start their own aligned block
+        jnp.zeros(
+            (stat_lanes(f, wide, grouped)[0] - bin16.shape[0], n), jnp.int16
+        ),
         _u16(gbits)[None, :],
         _u16(gbits >> 16)[None, :],
         _u16(hbits)[None, :],
@@ -211,10 +279,9 @@ def pack_rows(
         _u16(ridx >> 16)[None, :],
     ]
     packed = jnp.concatenate(planes, axis=0)
-    packed = jnp.pad(
-        packed, ((0, storage_lanes(f, wide) - packed.shape[0]), (0, n_pad - n))
-    )
-    return packed
+    g, sub = group_shape(f, wide, g)
+    packed = jnp.pad(packed, ((0, g * sub - packed.shape[0]), (0, n_pad - n)))
+    return packed.reshape(g, sub, n_pad) if grouped else packed
 
 
 def _plane_u16(seg: jnp.ndarray, plane) -> jnp.ndarray:
@@ -224,8 +291,10 @@ def _plane_u16(seg: jnp.ndarray, plane) -> jnp.ndarray:
 def unpack_stats(seg: jnp.ndarray, f: int, n: Optional[int] = None,
                  wide: bool = False):
     """Recover (bins[N,F] i32, g f32, h f32, mask f32, ridx i32) from the
-    plane-major matrix (optionally only the first n data rows)."""
-    GLO, GHI, HLO, HHI, M, RLO, RHI = stat_lanes(f, wide)
+    plane-major matrix of either form (optionally only the first n data
+    rows)."""
+    GLO, GHI, HLO, HHI, M, RLO, RHI = stat_lanes(f, wide, is_grouped(seg))
+    seg = flat_planes(seg)
     if n is None:
         n = seg.shape[1]
     seg = seg[:, :n]
@@ -272,11 +341,28 @@ def hist_ngroups(f: int, bpad: int) -> int:
     return -(-f // hist_group(f, bpad))
 
 
-def hist_sub(f: int, wide: bool) -> int:
+def hist_sub(f: int, wide: bool, grouped: bool = False) -> int:
     """DMA sublanes: only the used planes (bins + stats), padded to an i16
     sublane multiple — 32 planes at F=28, 4x less tile traffic than the
-    128-plane cap."""
+    128-plane cap.  A grouped row: the program's own 16-plane bin block and
+    the stat block."""
+    if grouped:
+        return 2 * STAT_BLOCK
     return min(storage_lanes(f, wide), (used_lanes(f, wide) + 15) // 16 * 16)
+
+
+def hist_variants(group: int, wide: bool) -> Tuple[int, int]:
+    """Grouped rows: (bin planes a histogram program owns, programs that
+    share one aligned 16-plane block).  A program's one-hot build selects
+    its planes from the block at one of these few static offsets, so the
+    kernel's code does not grow with the number of feature groups."""
+    ppp = group if wide else group // 2
+    if ppp < 1 or STAT_BLOCK % ppp or (not wide and group % 2):
+        raise ValueError(
+            f"grouped seg histogram: {group} features a program do not tile "
+            f"a {STAT_BLOCK}-plane block"
+        )
+    return ppp, STAT_BLOCK // ppp
 
 
 def _hist_window(
@@ -294,6 +380,7 @@ def _hist_window(
     group: int,
     quantized: bool,
     wide: bool,
+    grouped: bool = False,
 ):
     """Histogram accumulation over ONE packed-row window (the per-program
     body of the seg hist kernel, factored out so the fused grow-step kernel
@@ -304,7 +391,11 @@ def _hist_window(
     for plane group ``pt``; the caller copies it to the output and the
     digit recombine runs outside the kernel (``combine_hist_raw``).  Row
     convention (both dtypes): 0 g_hi, 1 h_hi, 2 count, 3 g_lo, 4 h_lo,
-    5 zero, 6 g_lo2, 7 h_lo2 (int8 leaves 5-7 zero)."""
+    5 zero, 6 g_lo2, 7 h_lo2 (int8 leaves 5-7 zero).
+
+    ``grouped``: ``read_fn`` stages the program's own aligned 16-plane bin
+    block over the stat block (``hist_sub``); the program's planes sit in
+    the block at offset (pt mod nvar) * ppp (``hist_variants``)."""
     abegin = (start // COL_ALIGN) * COL_ALIGN
     off = start - abegin
     nt = (off + cnt + TILE - 1) // TILE
@@ -317,7 +408,11 @@ def _hist_window(
     # rounding difference cannot change the result)
     inv_g = 1.0 / scales_ref[0]
     inv_h = 1.0 / scales_ref[1]
-    GLO, GHI, HLO, HHI, M, _, _ = stat_lanes(f, wide)
+    if grouped:
+        GLO, GHI, HLO, HHI, M = range(STAT_BLOCK, STAT_BLOCK + 5)
+        ppp, nvar = hist_variants(group, wide)
+    else:
+        GLO, GHI, HLO, HHI, M, _, _ = stat_lanes(f, wide)
     iota_rows = jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)[:, 0]
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (TILE, bpad), 1)
     ngroups = hist_ngroups(f, bpad)
@@ -417,7 +512,24 @@ def _hist_window(
                     (TILE, (group - nf) * bpad), oh_dtype
                 )
 
-        if ngroups == 1:
+        def build_onehot_at(v):
+            """One-hot block of a grouped row's program whose planes start
+            at STATIC offset v * ppp of its bin block.  The last program's
+            features past F read planes of the padding; ``combine_hist_raw``
+            drops their columns."""
+            for j in range(group):
+                if wide:
+                    col = xu[:, v * ppp + j]
+                else:
+                    col = (xu[:, v * ppp + (j >> 1)] >> (8 * (j & 1))) & 0xFF
+                onehot[:, j * bpad : (j + 1) * bpad] = (
+                    col[:, None] == iota_b
+                ).astype(oh_dtype)
+
+        if grouped:
+            for v in range(nvar):
+                pl.when(pt % nvar == v)(functools.partial(build_onehot_at, v))
+        elif ngroups == 1:
             build_onehot(0)
         else:
             for gi in range(ngroups):
@@ -473,7 +585,7 @@ def _seg_hist_kernel(
     scal_ref,  # SMEM [K, 2] i32: (start, cnt) per batch member
     scales_ref,  # SMEM [2] f32: g_scale, h_scale (quantized mode; else 1s)
     live_ref,  # SMEM [G] i32: per-plane-group live mask
-    seg_any,  # ANY [LANES, n_pad] i16 (plane-major)
+    seg_any,  # ANY [LANES, n_pad] | [G, sub, n_pad] i16 (plane-major)
     out_ref,  # VMEM [1, 1, 8, group * bpad] f32 | i32 block (raw planes)
     in_stage,  # VMEM [SUB, TILE] i16 — only the used planes are DMA'd
     acc,  # VMEM [8, group * bpad] f32 | i32
@@ -486,21 +598,39 @@ def _seg_hist_kernel(
     sub: int,
     quantized: bool,
     wide: bool,
+    grouped: bool = False,
 ):
     i = pl.program_id(0)
     pt = pl.program_id(1)
 
     def read_fn(base_col):
-        dma = pltpu.make_async_copy(
-            seg_any.at[
-                pl.ds(0, sub),
-                pl.ds(pl.multiple_of(base_col, COL_ALIGN), TILE),
-            ],
-            in_stage,
-            sem_in,
-        )
-        dma.start()
-        dma.wait()
+        cols = pl.ds(pl.multiple_of(base_col, COL_ALIGN), TILE)
+        if grouped:
+            # the program's aligned bin block, then the stat block: flat
+            # plane p of the row lives at [p // gsub, p % gsub]
+            gsub = seg_any.shape[1]
+            row0 = (pt // hist_variants(group, wide)[1]) * STAT_BLOCK
+            stat0 = stat_lanes(f, wide, True)[0]
+            dmas = [
+                pltpu.make_async_copy(
+                    seg_any.at[
+                        r // gsub,
+                        pl.ds(pl.multiple_of(r % gsub, STAT_BLOCK), STAT_BLOCK),
+                        cols,
+                    ],
+                    in_stage.at[pl.ds(k * STAT_BLOCK, STAT_BLOCK)],
+                    sem_in.at[k],
+                )
+                for k, r in enumerate((row0, stat0))
+            ]
+        else:
+            dmas = [pltpu.make_async_copy(
+                seg_any.at[pl.ds(0, sub), cols], in_stage, sem_in,
+            )]
+        for dma in dmas:
+            dma.start()
+        for dma in dmas:
+            dma.wait()
         return in_stage[...].astype(jnp.int32) & 0xFFFF
 
     _hist_window(
@@ -517,6 +647,7 @@ def _seg_hist_kernel(
         group=group,
         quantized=quantized,
         wide=wide,
+        grouped=grouped,
     )
     out_ref[0, 0] = acc[...]
 
@@ -572,16 +703,19 @@ def seg_hist_pallas_batch(
     program-to-program).  Frontier-batched growth (ops/grower.py
     leaf_batch) uses this to build all K smaller-child histograms per step
     with one launch's fixed cost; ``live`` (default all-ones) skips dead
-    plane groups under feature_fraction / EFB bundling."""
+    plane groups under feature_fraction / EFB bundling.  A grouped row
+    (``[G, sub, n_pad]``) runs the same grid: a program DMAs its own aligned
+    bin block and the stat block instead of every used plane."""
     k = scal.shape[0]
     bpad = hist_bpad(num_bins)
     group = hist_group(f, bpad)
     ngroups = hist_ngroups(f, bpad)
-    sub = hist_sub(f, wide)
+    grouped = is_grouped(seg)
+    sub = hist_sub(f, wide, grouped)
     acc_dtype = jnp.int32 if quantized else jnp.float32
     kernel = functools.partial(
         _seg_hist_kernel, f=f, bpad=bpad, group=group, sub=sub,
-        quantized=quantized, wide=wide,
+        quantized=quantized, wide=wide, grouped=grouped,
     )
     if scales is None:
         scales = jnp.ones((2,), jnp.float32)
@@ -607,7 +741,8 @@ def seg_hist_pallas_batch(
             pltpu.VMEM(
                 (TILE, group * bpad), jnp.int8 if quantized else jnp.bfloat16
             ),
-            pltpu.SemaphoreType.DMA,
+            pltpu.SemaphoreType.DMA((2,)) if grouped
+            else pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
     )(
@@ -628,7 +763,7 @@ def seg_hist_ref(seg: jnp.ndarray, scal: jnp.ndarray, *, f: int, num_bins: int,
 
     start, cnt = scal[0], scal[1]
     bins, g, h, m, _ = unpack_stats(seg, f, wide=wide)
-    idx = jnp.arange(seg.shape[1], dtype=jnp.int32)
+    idx = jnp.arange(seg.shape[-1], dtype=jnp.int32)
     window = (idx >= start) & (idx < start + cnt)
     return leaf_histogram_segment(bins, g, h, m * window.astype(jnp.float32), num_bins)
 
@@ -668,7 +803,7 @@ def _seg_hist_windowed(seg, scal, *, f: int, num_bins: int, n_pad: int,
     def _branch(cap):
         def _b(seg, start, cnt):
             s0 = jnp.clip((start // TILE) * TILE, 0, n_pad - cap)
-            win = lax.dynamic_slice_in_dim(seg, s0, cap, axis=1)
+            win = lax.dynamic_slice_in_dim(seg, s0, cap, axis=seg.ndim - 1)
             return seg_hist_ref(
                 win, jnp.stack([start - s0, cnt]), f=f, num_bins=num_bins,
                 n_pad=cap, wide=wide,

@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from ..obs.jit import instrumented_jit
 from jax import lax
 
-from .pallas.seg import _u16, used_lanes
+from .pallas.seg import _u16, flat_planes, is_grouped, used_lanes
 
 
 def window_caps(n_pad: int, floor: int = 8192) -> list:
@@ -56,6 +56,70 @@ def _go_left(colv, tbin, dl, nanb, iscat, catmask):
     bm = catmask.shape[0]
     cat = (catmask[jnp.clip(colv, 0, bm - 1)] > 0.5) & (colv < bm)
     return jnp.where(iscat != 0, cat, num)
+
+
+def go_left_bits(seg, feat, tbin, dl, nanb, iscat, catmask, *, wide=False):
+    """[n_pad] f32 go-left bits of every packed row, in segment order, from
+    the split feature's own plane: feature ``feat`` of the packed columns
+    lives in plane feat >> 1 (byte feat & 1), or plane feat of a ``wide``
+    row.  One pass over ONE plane of the matrix, whatever its form.
+
+    Who needs the predicate as bits: a grouped matrix (the plane lies in one
+    plane group; every group is permuted by the same bits) and
+    feature-parallel shards (the plane lies on one shard)."""
+    planes = flat_planes(seg)
+    if wide:
+        p16 = lax.dynamic_slice_in_dim(planes, feat, 1, axis=0)[0]
+        colv = p16.astype(jnp.int32) & 0xFFFF
+    else:
+        p16 = lax.dynamic_slice_in_dim(planes, feat >> 1, 1, axis=0)[0]
+        colv = ((p16.astype(jnp.int32) & 0xFFFF) >> ((feat & 1) * 8)) & 0xFF
+    if catmask.shape[0] <= 1:
+        # no categorical column in the table (the grower's Bm = 1): skip the
+        # membership lookup, an O(rows) gather that serializes on a TPU
+        go = (colv <= tbin) | ((dl != 0) & (nanb >= 0) & (colv == nanb))
+    else:
+        go = _go_left(colv, tbin, dl, nanb, iscat, catmask)
+    return go.astype(jnp.float32)
+
+
+def _sort_partition_grouped_xla(seg, sbegin, cnt, gl_vec, cnt_cap, *, n_pad):
+    """The stable-sort partition of a grouped matrix: the key and the
+    position are sorted, every plane of every group is gathered through the
+    one permutation (a 1,000-plane row as sort payloads would be 500
+    operands)."""
+    shape = seg.shape
+    flat = flat_planes(seg)
+    caps = window_caps(n_pad)
+
+    def make_branch(P: int):
+        def branch(op):
+            flat, sbegin, cnt, glv = op
+            start = jnp.minimum(sbegin, n_pad - P)
+            off = sbegin - start
+            win = lax.dynamic_slice_in_dim(flat, start, P, axis=1)
+            pos = jnp.arange(P, dtype=jnp.int32)
+            in_seg = (pos >= off) & (pos < off + cnt)
+            gl = (lax.dynamic_slice(glv, (start,), (P,)) > 0.5) & in_seg
+            key = jnp.where(
+                pos < off, 0, jnp.where(gl, 1, jnp.where(in_seg, 2, 3))
+            ).astype(jnp.int32)
+            _, perm = lax.sort((key, pos), num_keys=1, is_stable=True)
+            flat = lax.dynamic_update_slice(
+                flat, jnp.take(win, perm, axis=1), (0, start)
+            )
+            return flat, jnp.sum(gl).astype(jnp.int32)
+
+        return branch
+
+    bucket = jnp.clip(
+        jnp.searchsorted(jnp.asarray(caps, jnp.int32), cnt_cap, side="left"),
+        0, len(caps) - 1,
+    ).astype(jnp.int32)
+    flat, nl = lax.switch(
+        bucket, [make_branch(P) for P in caps], (flat, sbegin, cnt, gl_vec)
+    )
+    return flat.reshape(shape), nl, cnt - nl
 
 
 @functools.partial(
@@ -93,6 +157,11 @@ def sort_partition_xla(
     Returns (seg', nl, nr): left child at [sbegin, sbegin+nl), right child at
     [sbegin+nl, sbegin+cnt), both in stable order; rows outside untouched.
     """
+    if is_grouped(seg):
+        return _sort_partition_grouped_xla(
+            seg, sbegin, cnt, gl_vec, cnt if cnt_cap is None else cnt_cap,
+            n_pad=n_pad,
+        )
     n_ops = (used_lanes(f, wide) + 1) // 2  # i32 lanes that carry real data
     caps = window_caps(n_pad)
     if gl_vec is None:
@@ -174,13 +243,20 @@ def sort_partition(
     defensive copies), the stable-sort formulation elsewhere.  Both are
     stable partitions with bit-identical results.
 
-    ``gl_vec`` (feature-parallel seg): the go-left decision comes from a
-    precomputed [n_pad] bit vector; the Pallas kernel DMAs a bits tile per
-    row tile instead of reading the feature column."""
+    ``gl_vec`` (feature-parallel seg, grouped matrices): the go-left
+    decision comes from a precomputed [n_pad] bit vector (``go_left_bits``);
+    the Pallas kernel DMAs a bits tile per row tile instead of reading the
+    feature column.  A grouped matrix cannot do without it."""
+    from .pallas import partition as _part
     from .pallas.partition import seg_partition_pallas
     from ..obs.collectives import timed_pmax
 
     use_gl = gl_vec is not None
+    if is_grouped(seg) and not use_gl:
+        raise ValueError(
+            "a grouped segment matrix is partitioned by precomputed go-left "
+            "bits (go_left_bits): pass gl_vec"
+        )
     # fleet-vmapped growth: reduce cnt over the model axis HERE, outside
     # the platform branches, so both lower the same collective sequence
     # (none) and the XLA window ladder sizes one shared branch
@@ -192,7 +268,7 @@ def sort_partition(
         cnt_cap = cnt
 
     def _pallas(seg, sbegin, cnt, cnt_cap, feat, tbin, dl, nanb, iscat,
-                catmask, *maybe_gl):
+                catmask, *maybe_gl, interpret=False):
         bm = catmask.shape[0]
         bmt = max(256, -(-bm // 128) * 128)  # cat-table width (wide bins)
         catm = jnp.zeros((1, bmt), jnp.float32)
@@ -203,6 +279,7 @@ def sort_partition(
         seg_new, nl = seg_partition_pallas(
             seg, scal, catm, maybe_gl[0] if maybe_gl else None,
             f=f, n_pad=n_pad, use_cat=bm > 1, wide=wide,
+            interpret=interpret,
         )
         return seg_new, nl, cnt - nl
 
@@ -219,7 +296,10 @@ def sort_partition(
     if use_gl:
         args = args + (gl_vec,)
     if jax.default_backend() != "tpu":
-        # no TPU in this process: don't trace the Pallas branch
+        # no TPU in this process: don't trace the Pallas branch (but for
+        # the interpret-mode kernel under the test hook)
+        if _part._INTERPRET:
+            return _pallas(*args, interpret=True)
         return _xla(*args)
     return jax.lax.platform_dependent(*args, tpu=_pallas, default=_xla)
 
@@ -246,6 +326,11 @@ def sort_partition_batch(
     calls).  Returns (seg', nl[K], nr[K])."""
     from .pallas.partition import seg_partition_pallas_batch
 
+    if is_grouped(seg):
+        raise ValueError(
+            "the batched partition takes a one-group segment matrix; a "
+            "grouped one is partitioned window by window (leaf_batch=1)"
+        )
     k = sbegins.shape[0]
 
     def _pallas(seg, sbegins, cnts, feats, tbins, dls, nanbs, iscats,

@@ -117,7 +117,8 @@ def test_scope_paths_and_a_fusion_without_metadata():
 
 
 # ------------------------------------------------------------------- coverage
-def test_every_scope_of_the_grower_and_the_launch_scan_is_published(scopes_cache):
+def test_every_scope_of_the_grower_and_the_launch_scan_is_published(scopes_cache,
+                                                                     monkeypatch):
     """Each ``jax.named_scope`` name in ops/grower.py and boosting/launch.py
     shows up in ``op_scopes()`` of tiny CPU trains of the job shapes that
     reach it."""
@@ -129,6 +130,14 @@ def test_every_scope_of_the_grower_and_the_launch_scan_is_published(scopes_cache
         {"train_steps_per_launch": 2, "bagging_fraction": 0.6, "bagging_freq": 1},
     ):
         lgb.train(dict(_PARAMS, **extra), lgb.Dataset(X, y), 2)
+    # a packed row of two plane groups: the go-left pass ahead of the
+    # partition KERNEL (interpret mode: XLA's own partition would fuse the
+    # pass into its sort keys, and a fusion has one scope)
+    from lightgbm_tpu.ops.pallas import partition
+
+    monkeypatch.setattr(partition, "_INTERPRET", True)
+    Xw, yw = _data(n=600, f=243)
+    lgb.train(dict(_PARAMS, hist_mode="seg", num_leaves=4), lgb.Dataset(Xw, yw), 1)
     published = set()
     for scopes in op_scopes().values():
         for path in scopes.values():

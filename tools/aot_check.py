@@ -35,6 +35,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from lightgbm_tpu.ops.pallas.seg import (  # noqa: E402
+    group_shape,
     pack_rows,  # noqa: F401  (layout doc)
     padded_rows,
     seg_hist_pallas,
@@ -183,6 +184,66 @@ def _c19(topo):
 def _c20(topo):
     return _partition(
         topo, 121, 1_000_000, use_cat=True, wide=True, bmt=8192
+    )
+
+
+_EPSILON_ROWS, _EPSILON_F = 400_000, 2_000
+
+
+def _grouped_seg(f, rows, wide=False):
+    g, sub = group_shape(f, wide)
+    return s((g, sub, padded_rows(rows)), jnp.int16)
+
+
+@check("seg_partition_pallas grouped (8 x 128 planes) epsilon shape f=2000 400k rows")
+def _c24(topo):
+    n_pad = padded_rows(_EPSILON_ROWS)
+    return compile_on_topo(
+        topo, seg_partition_pallas,
+        _grouped_seg(_EPSILON_F, _EPSILON_ROWS), s((8,), jnp.int32),
+        s((1, 256), jnp.float32), s((n_pad,), jnp.float32),
+        f=_EPSILON_F, n_pad=n_pad, use_cat=False,
+    )
+
+
+@check("seg_partition_pallas grouped (2 x 80 planes) f=243")
+def _c25(topo):
+    n_pad = padded_rows(100_000)
+    return compile_on_topo(
+        topo, seg_partition_pallas,
+        _grouped_seg(243, 100_000), s((8,), jnp.int32),
+        s((1, 256), jnp.float32), s((n_pad,), jnp.float32),
+        f=243, n_pad=n_pad, use_cat=False,
+    )
+
+
+@check("seg_hist_pallas grouped epsilon shape f=2000 b=256 (250 feature groups)")
+def _c26(topo):
+    n_pad = padded_rows(_EPSILON_ROWS)
+    return compile_on_topo(
+        topo, seg_hist_pallas,
+        _grouped_seg(_EPSILON_F, _EPSILON_ROWS), s((2,), jnp.int32),
+        f=_EPSILON_F, num_bins=256, n_pad=n_pad,
+    )
+
+
+@check("seg_hist_pallas grouped int8 f=500 b=256")
+def _c27(topo):
+    n_pad = padded_rows(100_000)
+    return compile_on_topo(
+        topo, seg_hist_pallas,
+        _grouped_seg(500, 100_000), s((2,), jnp.int32), s((2,), jnp.float32),
+        f=500, num_bins=256, n_pad=n_pad, quantized=True,
+    )
+
+
+@check("seg_hist_pallas grouped u16 wide f=200 b=512")
+def _c28(topo):
+    n_pad = padded_rows(100_000)
+    return compile_on_topo(
+        topo, seg_hist_pallas,
+        _grouped_seg(200, 100_000, wide=True), s((2,), jnp.int32),
+        f=200, num_bins=512, n_pad=n_pad, wide=True,
     )
 
 
@@ -335,17 +396,18 @@ def _as_tpu_process():
         jax.default_backend = orig
 
 
-def _grow_program(topo, n_devices=1, **extra):
-    """AOT-compile ``make_mesh_grow``'s program at Higgs width: GrowerParams
-    come from a real Booster (built on a small sample, resolved as on TPU),
-    row-shaped operands are abstract at ``_ROWS`` rows."""
+def _grow_program(topo, n_devices=1, features=28, rows=_ROWS, **extra):
+    """AOT-compile ``make_mesh_grow``'s program at ``features`` columns
+    (Higgs width unless given): GrowerParams come from a real Booster (built
+    on a small sample, resolved as on TPU), row-shaped operands are abstract
+    at ``rows`` rows."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu.parallel.mesh import (
         MeshSpec, build_mesh, make_mesh_grow, role_sharding,
     )
 
     rng = np.random.default_rng(0)
-    x = rng.normal(size=(4000, 28)).astype(np.float32)
+    x = rng.normal(size=(4000, features)).astype(np.float32)
     y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
     with _as_tpu_process():
         booster = lgb.Booster(
@@ -365,10 +427,10 @@ def _grow_program(topo, n_devices=1, **extra):
             )
 
         rows = jax.ShapeDtypeStruct(
-            (_ROWS,), jnp.float32, sharding=role_sharding(mesh, "rows")
+            (rows,), jnp.float32, sharding=role_sharding(mesh, "rows")
         )
         bins = jax.ShapeDtypeStruct(
-            (_ROWS, 28), booster._bins.dtype,
+            (rows.shape[0], features), booster._bins.dtype,
             sharding=role_sharding(mesh, "bins"),
         )
         replicated = jax.tree.map(
@@ -411,6 +473,23 @@ for _name, _kw in _PROGRAMS.items():
     )
 
 
+# the Epsilon shape (400,000 x 2,000: a packed row of 8 plane groups).  Both
+# of lgb.train's grow programs there are the two-launch one: Booster.update
+# (grow_fused resolves on, the grower sees the groups and takes the
+# two-launch kernels) and the body of the eight-step launch scan (the scan's
+# own wrapper needs a live Booster's operands and is not compiled here).
+for _name, _kw in {
+    "Booster.update (valid_sets)": dict(),
+    "launch-scan body (grow_fused off)": dict(grow_fused="off"),
+}.items():
+    CHECKS[f"grow program 400k x 2000 (8 plane groups), 255 leaves: {_name}"] = (
+        functools.partial(
+            _checked_grow_program, features=_EPSILON_F, rows=_EPSILON_ROWS,
+            hist_acc="bf16", **_kw,
+        )
+    )
+
+
 def main(selected=None):
     topo = _topo()
     failures = []
@@ -426,7 +505,12 @@ def main(selected=None):
                 flops = ca.get("flops") if hasattr(ca, "get") else None
             except Exception:
                 pass
-            print(f"OK   {name}" + (f"  (flops={flops:.3g})" if flops else ""))
+            mem = ""
+            if name.startswith("grow program"):
+                ma = compiled.memory_analysis()
+                mem = (f"  (arguments {ma.argument_size_in_bytes / 1e9:.2f} GB"
+                       f" + temporaries {ma.temp_size_in_bytes / 1e9:.2f} GB)")
+            print(f"OK   {name}" + (f"  (flops={flops:.3g})" if flops else "") + mem)
         except Exception as e:
             failures.append(name)
             print(f"FAIL {name}: {type(e).__name__}")
