@@ -1319,19 +1319,29 @@ class Booster:
         )
 
     def _seg_span_args(self) -> Dict[str, int]:
-        """Plane groups of the packed row and the planes a group, for the
-        ``train/iteration`` and ``train/launch`` spans (none off the
+        """Plane groups of the packed row and the planes a group, and the
+        histogram kernel's two-digit one-hot ("HxL", "1x<bpad>" where it
+        resolves to the full one-hot) with the features a matmul takes, for
+        the ``train/iteration`` and ``train/launch`` spans (none off the
         segment path)."""
         p = getattr(self, "_grower_params", None)
         if p is None or p.hist_mode != "seg" or self.train_set is None:
             return {}
-        from ..ops.pallas.seg import group_shape
+        from ..ops.pallas.seg import (
+            group_shape, hist_bpad, hist_digits, hist_feature_block,
+        )
 
         f = int(self._bins.shape[1]) // max(self._featpar or 1, 1)
         if f <= 0:
             return {}
         g, sub = group_shape(f, p.max_bin > 256)
-        return {"seg_groups": g, "seg_group_planes": sub}
+        bpad = hist_bpad(p.max_bin)
+        high, low = hist_digits(bpad)
+        return {
+            "seg_groups": g, "seg_group_planes": sub,
+            "hist_digits": f"{high}x{low}",
+            "hist_feature_block": hist_feature_block(f, bpad),
+        }
 
     def _make_grower_params(self) -> GrowerParams:
         from ..ops.split import CatParams

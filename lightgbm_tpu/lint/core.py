@@ -43,7 +43,7 @@ RULES: Dict[str, Tuple[str, str]] = {
         "Pallas kernel reads a ref that is the input side of "
         "input_output_aliases",
         "read through the output-aliased ref instead (see "
-        "ops/pallas/partition.read_aliased_tile) — input-ref reads miss "
+        "ops/pallas/partition.aliased_tile_dma) — input-ref reads miss "
         "earlier writes in interpret mode and on re-read boundary tiles",
     ),
     "GL003": (

@@ -23,7 +23,7 @@ and then EVERY plane program (i, pt) — grid programs run sequentially, so
 (i, 0)'s writes are visible — reads the decision back and histograms its
 plane group over the freshly-partitioned rows (seg._hist_window), reading
 tiles through the OUTPUT alias so the histogram observes the partition's
-writes (partition.read_aliased_tile — the same idiom that fixes
+writes (partition.aliased_tile_dma — the same idiom that fixes
 cross-program boundary reads, and the reason the fused kernel works at
 all: the partition happened in an EARLIER program of the same sequential
 grid).  Dead plane groups (feature_fraction / EFB) skip their tile loop
@@ -39,8 +39,8 @@ dispatch boundaries.
 
 Plane-tiling trade (same as seg.py): per-program VMEM scratch shrinks to
 O(group*bpad) — independent of F — at the cost of each plane program
-re-streaming the window's stat planes (G-fold redundant DMA, hidden under
-the one-hot matmul for every shape seg_vmem_ok admits).
+re-streaming the window's stat planes (G-fold redundant DMA, read one tile
+ahead of the two-digit one-hot's matmuls and hidden under them).
 
 The XLA composition (`sort_partition_xla` chain + local election + masked
 reference histogram) is the always-available fallback AND the correctness
@@ -65,18 +65,18 @@ from jax.experimental.pallas import tpu as pltpu
 from .partition import (
     T,
     _partition_window,
+    aliased_tile_dma,
     partition_scratch,
     partition_sub,
-    read_aliased_tile,
 )
 from .seg import (
     COL_ALIGN,
-    TILE,
     _hist_window,
     combine_hist_raw,
     hist_bpad,
     hist_group,
     hist_ngroups,
+    hist_scratch,
     hist_sub,
 )
 
@@ -100,9 +100,9 @@ def _fused_grow_kernel(
     scratch_out,  # ANY [SUB_P, n_pad] i16 — partition right-stream spill
     dec_ref,  # SMEM [K, 4] i32: nl, nr, child_start, child_cnt per member
     hist_ref,  # VMEM [1, 1, 8, group * bpad] f32 | i32 block (raw planes)
-    *scratch,  # partition_scratch(sub_p, False), then the histogram's:
-    #            hist_stage VMEM [SUB_H, TILE] i16, acc VMEM [8, group * bpad]
-    #            f32 | i32, onehot VMEM [TILE, group * bpad] bf16 | i8, sem_hist
+    *scratch,  # partition_scratch(sub_p, False), then the histogram's
+    #            (seg.hist_scratch: two staging slots, the two-digit one-hot's
+    #            operands and accumulators, the slots' semaphores)
     f: int,
     n_pad: int,
     use_cat: bool,
@@ -113,10 +113,11 @@ def _fused_grow_kernel(
     bpad: int,
     group: int,
     quantized: bool,
+    n_hist: int,  # refs of seg.hist_scratch at the end of ``scratch``
     read_via_input: bool = False,
 ):
-    part_scratch = scratch[:-4]
-    hist_stage, acc, onehot, sem_hist = scratch[-4:]
+    part_scratch, hist_scratch_refs = scratch[:-n_hist], scratch[-n_hist:]
+    hist_stage, sem_hist = hist_scratch_refs[0], hist_scratch_refs[-1]
     i = pl.program_id(0)
     pt = pl.program_id(1)
     sbegin = scal_ref[i, 0]
@@ -166,28 +167,27 @@ def _fused_grow_kernel(
     child_start = dec_ref[i, 2]
     child_cnt = dec_ref[i, 3]
 
-    def read_fn(base_col):
-        return read_aliased_tile(
-            seg_any, seg_out, hist_stage, sem_hist, base_col,
-            read_via_input=read_via_input,
-        )
+    def tile_dmas(slot, base_col):
+        return [aliased_tile_dma(
+            seg_any, seg_out, hist_stage.at[slot], sem_hist.at[slot],
+            base_col, read_via_input=read_via_input,
+        )]
 
     _hist_window(
         child_start,
         child_cnt,
         pt,
         live_ref[pt],
-        read_fn,
+        tile_dmas,
         scales_ref,
-        acc,
-        onehot,
+        hist_ref.at[0, 0],
+        *hist_scratch_refs[:-1],
         f=f,
         bpad=bpad,
         group=group,
         quantized=quantized,
         wide=wide,
     )
-    hist_ref[0, 0] = acc[...]
 
 
 @functools.partial(
@@ -233,10 +233,12 @@ def fused_grow_step_pallas(
     acc_dtype = jnp.int32 if quantized else jnp.float32
     tri = jnp.tril(jnp.ones((T, T), jnp.bfloat16)).T  # tri[i, j] = i <= j
     gl_arr = jnp.zeros((1, COL_ALIGN), jnp.float32)
+    hist_refs = hist_scratch(f, bpad, sub_h, quantized)
     kernel = functools.partial(
         _fused_grow_kernel, f=f, n_pad=n_pad, use_cat=use_cat, sub_p=sub_p,
         sub_h=sub_h, wide=wide, bmt=bmt, bpad=bpad, group=group,
-        quantized=quantized, read_via_input=read_via_input,
+        quantized=quantized, n_hist=len(hist_refs),
+        read_via_input=read_via_input,
     )
     seg_new, _, dec, raw = pl.pallas_call(
         kernel,
@@ -271,14 +273,7 @@ def fused_grow_step_pallas(
             jax.ShapeDtypeStruct((k, 4), jnp.int32),
             jax.ShapeDtypeStruct((k, ngroups, 8, group * bpad), acc_dtype),
         ],
-        scratch_shapes=partition_scratch(sub_p, False) + [
-            pltpu.VMEM((sub_h, TILE), jnp.int16),
-            pltpu.VMEM((8, group * bpad), acc_dtype),
-            pltpu.VMEM(
-                (TILE, group * bpad), jnp.int8 if quantized else jnp.bfloat16
-            ),
-            pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=partition_scratch(sub_p, False) + hist_refs,
         input_output_aliases={3: 0},
         interpret=interpret,
     )(scal.astype(jnp.int32), scales.astype(jnp.float32),

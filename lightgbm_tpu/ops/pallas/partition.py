@@ -64,7 +64,7 @@ results are bit-identical to the stable-sort path this replaces.
 
 The per-window body is factored into ``_partition_window`` so the fused
 grow-step kernel (ops/pallas/grow_step.py) can run partition + smaller-child
-histogram in ONE launch; ``read_aliased_tile`` is the
+histogram in ONE launch; ``aliased_tile_dma`` is the
 read-through-the-output-alias helper of that kernel's histogram phase (see
 its docstring for the interpret-mode aliasing pitfall it guards against),
 and the partition's block reads take the same source (``_aliased_cols``).
@@ -153,17 +153,18 @@ def _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input,
                   grp=None):
     """``[sub, cols]`` window of an IN-PLACE (input/output-aliased) packed
     segment matrix, as a DMA source, through the OUTPUT alias (see
-    ``read_aliased_tile``)."""
+    ``aliased_tile_dma``)."""
     src = seg_in if read_via_input else seg_out
     # the input-ref read below is unreachable in production: it only
-    # engages under the test-only read_via_input knob of read_aliased_tile
+    # engages under the test-only read_via_input knob of aliased_tile_dma
     return src.at[_plane_index(grp, sub, base_col, cols)]  # graftlint: disable=GL002
 
 
-def read_aliased_tile(seg_in, seg_out, stage, sem, base_col, *,
-                      read_via_input: bool = False):
-    """DMA one aligned ``[sub, cols]`` tile of an IN-PLACE (input/output-
-    aliased) packed segment matrix into VMEM ``stage``; return u16-in-i32.
+def aliased_tile_dma(seg_in, seg_out, stage, sem, base_col, *,
+                     read_via_input: bool = False):
+    """The DMA of one aligned ``[sub, cols]`` tile of an IN-PLACE (input/
+    output-aliased) packed segment matrix into VMEM ``stage``, for the
+    caller to start and wait for.
 
     Reads go through the OUTPUT alias, not the input ref: on TPU they are
     the same HBM buffer, but batched grids re-read boundary tiles an
@@ -171,22 +172,21 @@ def read_aliased_tile(seg_in, seg_out, stage, sem, base_col, *,
     grow-step kernel) already rewrote — adjacent leaf windows share
     COL_ALIGN blocks — and Pallas interpret mode only makes those writes
     visible on the output ref.  Used by the fused grow-step kernel's
-    histogram phase (ops/pallas/grow_step.py); the partition's block reads
-    take the same source (``_aliased_cols``).
+    histogram phase (ops/pallas/grow_step.py), which starts a tile's read
+    while it works on the one before: phase 1 of the member has finished by
+    then, so reading ahead is as safe as reading.  The partition's block
+    reads take the same source (``_aliased_cols``).
 
     ``read_via_input=True`` recreates the PR-3 aliasing bug by reading the
     input ref instead — a TEST-ONLY knob for the regression test in
     tests/test_partition_kernel.py; never set it from production code.
     """
     sub, cols = stage.shape
-    dma = pltpu.make_async_copy(
+    return pltpu.make_async_copy(
         _aliased_cols(seg_in, seg_out, sub, base_col, cols, read_via_input),
         stage,
         sem,
     )
-    dma.start()
-    dma.wait()
-    return stage[...].astype(jnp.int32) & 0xFFFF
 
 
 def _partition_window(
@@ -376,7 +376,7 @@ def _partition_window(
 
     def seg_cols(c0, cols):
         # boundary tiles must come through the OUTPUT alias — see
-        # read_aliased_tile for the interpret-mode pitfall this guards
+        # aliased_tile_dma for the interpret-mode pitfall this guards
         return _aliased_cols(
             seg_any, seg_out, sub, abegin + c0, cols, read_via_input, grp
         )
@@ -587,7 +587,7 @@ def seg_partition_pallas(
     the feature column (feature-parallel seg — only the owning shard holds
     the winner's bin plane).
 
-    ``read_via_input``: test-only knob (see read_aliased_tile).
+    ``read_via_input``: test-only knob (see aliased_tile_dma).
 
     A grouped matrix (``[G, sub, n_pad]``, seg.pack_rows) needs ``gl_vec``
     and takes a G-program grid: one launch, the loop's code once, program g
@@ -671,7 +671,7 @@ def seg_partition_pallas_batch(
     Frontier-batched growth (ops/grower.py leaf_batch) pays ONE program's
     fixed cost for K splits.
 
-    ``read_via_input``: test-only knob (see read_aliased_tile).
+    ``read_via_input``: test-only knob (see aliased_tile_dma).
 
     Returns (seg', nl[K])."""
     if is_grouped(seg):

@@ -47,7 +47,7 @@ layout-native — the row-major alternative made XLA insert TWO full-array
 relayout copies per split (~0.3 ms each at 1M rows, measured).  The
 histogram kernel DMAs [sub, T] column tiles covering only the used planes
 (minor-dim starts 128-aligned, misalignment folded into the validity mask)
-and transposes each tile in VMEM.
+and works on them as they arrive, rows on the lanes: nothing is transposed.
 
 Histogram engine v2 (this file's kernel contract):
 
@@ -57,13 +57,40 @@ Histogram engine v2 (this file's kernel contract):
     per-program VMEM scratch is O(group*bpad) instead of O(F*bpad) — wide
     (F, max_bin) shapes that previously failed ``seg_vmem_ok`` now fit.
     The trade: every program re-streams the window's stat planes (G-fold
-    redundant DMA traffic); the one-hot matmul dominates per tile, so the
-    extra DMA hides under compute for all shapes the gate admits.
-  * Kernels emit RAW 8-sublane accumulator planes (f32 for the bf16 path,
-    i32 for the int8 paths); the digit-recombine/dequantize runs OUTSIDE
-    the kernel in plain XLA.  8 is exactly the f32/i32 VMEM tile height,
-    which retires the three GL005 sublane-3 layouts the previous
-    ``[3, F*bpad]`` outputs needed baselined.
+    redundant DMA traffic); the read of tile t+1 runs under the compute of
+    tile t (two staging slots), so it costs nothing while a tile's compute
+    outlasts its DMA (0.22 us against 0.61 on the v5e, PERF.md section 5).
+  * The one-hot is TWO-DIGIT.  With (H, L) = ``hist_digits(bpad)`` — from
+    ``bpad`` alone, (8, 32) at the usual bpad 256 — a bin is
+    ``b = hi * L + lo`` and
+
+        hist[s, j, b] = sum_r stats[s, r] * [bin_j(r) = b]
+                      = sum_r (stats[s, r] * [hi_j(r) = hi]) * [lo_j(r) = lo]
+
+    so a tile builds, for a block of nf = ``hist_feature_block`` features,
+    ``A[(j, hi, s), r]`` (the 8 stat rows masked by each high digit: 128
+    rows) and ``B[(j', lo), r]`` (the low digits' one-hots: nf * L rows) and
+    issues ONE ``A . B^T`` over the tile's rows.  The MXU sees 128
+    accumulator rows instead of 8 and the VPU builds ``L + 8 H`` elements a
+    feature and row instead of ``bpad``.  The result's diagonal blocks
+    j = j' are the nf histograms, laid out [(hi, s), lo]; the off-diagonal
+    blocks are joint counts of two DIFFERENT features, accumulated with the
+    rest and never read.  The whole result accumulates in VMEM across the
+    window's tiles; the diagonal blocks are copied into the raw output
+    block once a program.  The sums are the same addends as a full
+    one-hot's — the same bf16 | int8 terms times exact 0/1 masks, added in
+    f32 | i32 over the same rows in the same order, zeros elsewhere — and
+    the raw planes came out bit-identical to the full one-hot kernel's on
+    the chip, both dtypes (PERF.md section 6, PR 30).  ``H = 1`` (bpad past
+    2048, where 8 H would pass the MXU's 128 rows) is the full one-hot
+    itself through the same code: ``A`` is the 8 stat rows, every result
+    block diagonal.
+  * Kernels emit RAW 8-sublane accumulator planes, ``[K, G, 8, group *
+    bpad]`` (f32 for the bf16 path, i32 for the int8 paths; column
+    ``j * bpad + b`` of feature j of the group); the digit-recombine/
+    dequantize runs OUTSIDE the kernel in plain XLA.  8 is exactly the
+    f32/i32 VMEM tile height, which retires the three GL005 sublane-3
+    layouts the previous ``[3, F*bpad]`` outputs needed baselined.
   * int8 accumulation is 2-DIGIT: q = round(stat/scale) clipped to
     ±QMAX (127*128), split as q = hi*128 + lo with |hi| <= 127 and
     |lo| <= 64 — both int8-safe — accumulated as int8 x int8 -> i32 on the
@@ -81,15 +108,16 @@ Histogram engine v2 (this file's kernel contract):
 
 Precision contract (ADVICE r2, tightened r3): the bf16 path accumulates
 grad/hess as a THREE-TERM bf16 split (~26 mantissa bits per addend — i.e.
-f32-accurate for all practical gradients, the extra rows ride the matmul's
-6->8 sublane padding for free) with f32 accumulators, vs double histograms
-in the reference.  Near-tie split decisions can still flip vs the f64
+f32-accurate for all practical gradients, the extra rows ride the stat
+rows' 6->8 sublane padding for free) with f32 accumulators, vs double
+histograms in the reference.  Near-tie split decisions can still flip vs the f64
 reference within f32 epsilon, which golden-model parity tests tolerate.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -194,24 +222,24 @@ SEG_VMEM_BUDGET = 12 * 1024 * 1024  # scratch ceiling for the seg kernels
 def seg_vmem_ok(f: int, num_bins: int, has_cat: bool = False) -> bool:
     """Whether the seg kernels' VMEM scratch fits at this (F, max_bin).
 
-    The plane-tiled grid makes the histogram footprint O(group*bpad) per
-    program — acc [8, group*bpad] + the matching out block + onehot
-    [TILE, group*bpad] + the staging tile — independent of F.  The
-    partition's read blocks, flush buffers and stagings
-    (partition.partition_scratch_bytes, set by the planes one pass moves:
-    the used planes, or one plane group's) sit beside it in the fused grow
-    step.  The categorical partition
-    additionally builds a [bmt, 256] one-hot (bf16), unchanged by the plane
-    tiling, so it still binds wide-bin categorical configs."""
+    The plane-tiled grid makes the histogram footprint per program
+    (``hist_scratch_bytes``: two staging slots, the two-digit one-hot's
+    operands and accumulators of one feature group, the raw output block)
+    independent of F.  The partition's read blocks, flush buffers and
+    stagings (partition.partition_scratch_bytes, set by the planes one pass
+    moves: the used planes, or one plane group's) sit beside it in the fused
+    grow step.  The categorical partition additionally builds a [bmt, 256]
+    one-hot (bf16), unchanged by the plane tiling, so it still binds
+    wide-bin categorical configs."""
     from .partition import partition_scratch_bytes, partition_sub
 
     bpad = hist_bpad(num_bins)
-    gb = hist_group(f, bpad) * bpad
-    hist = 2 * 8 * gb * 4 + TILE * gb * 2 + 128 * TILE * 2
     wide = num_bins > MAX_SEG_BIN
+    grouped = plane_groups(f, wide) > 1
+    hist = hist_scratch_bytes(f, bpad, hist_sub(f, wide, grouped))
     # a grouped row's partition pass moves one group, and so counts one
     sub = (
-        group_shape(f, wide)[1] if plane_groups(f, wide) > 1
+        group_shape(f, wide)[1] if grouped
         else partition_sub(f, wide)  # the used planes, to 8 not to 32
     )
     part = partition_scratch_bytes(sub)
@@ -341,6 +369,55 @@ def hist_ngroups(f: int, bpad: int) -> int:
     return -(-f // hist_group(f, bpad))
 
 
+MXU_ROWS = 128  # accumulator rows one matmul can give the MXU
+
+
+def hist_digits(bpad: int) -> Tuple[int, int]:
+    """(H, L) of the two-digit one-hot, from ``bpad`` alone: a bin is
+    ``b = hi * L + lo`` with ``lo`` in [0, L) and ``hi`` in [0, H).  L is the
+    smallest power of two (32..128) with ``L * L >= 4 * bpad``, i.e. a block
+    of ``16 / H`` features gives the MXU all its 128 rows and at least 64
+    result columns: (4, 32) at bpad 128, (8, 32) at 256, (8, 64) at 512,
+    (16, 64) at 1024, (16, 128) at 2048.  Swept on the v5e at bpad 256
+    (PERF.md section 6, PR 30): (8, 32) 6 % ahead of (4, 64), (2, 128) far
+    behind.  Past 2048 ``8 * H`` would pass the MXU's 128 rows and the form
+    resolves to (1, bpad): the stats against the whole one-hot."""
+    low = 32
+    while low < 128 and low * low < 4 * bpad:
+        low *= 2
+    high = bpad // low  # exact: bpad is a multiple of 128
+    if 8 * high > MXU_ROWS:
+        return 1, bpad
+    return high, low
+
+
+def _digit_rows(bpad: int) -> int:
+    """Rows of ``A`` a feature owns: its H masked copies of the 8 stat rows,
+    padded to the int8 sublane tile (32) so no block straddles a tile."""
+    return -(-8 * hist_digits(bpad)[0] // 32) * 32
+
+
+def hist_feature_block(f: int, bpad: int) -> int:
+    """nf, the features one matmul of the seg histogram takes: as many as
+    fit the MXU's 128 accumulator rows at ``_digit_rows`` each (4 at bpad
+    128, 2 at 256); all of the program's where H = 1 (they share the stat
+    rows)."""
+    group = hist_group(f, bpad)
+    if hist_digits(bpad)[0] == 1:
+        return group
+    return min(group, MXU_ROWS // _digit_rows(bpad))
+
+
+def hist_operands(f: int, bpad: int) -> Tuple[int, int, int]:
+    """(blocks, A rows, B rows) of a program: its features in blocks of
+    ``hist_feature_block``, each one matmul ``A[rows, TILE] x B[rows, TILE]``
+    contracting the tile's rows."""
+    high, low = hist_digits(bpad)
+    nf = hist_feature_block(f, bpad)
+    nblk = -(-hist_group(f, bpad) // nf)
+    return nblk, (32 if high == 1 else MXU_ROWS), nf * low
+
+
 def hist_sub(f: int, wide: bool, grouped: bool = False) -> int:
     """DMA sublanes: only the used planes (bins + stats), padded to an i16
     sublane multiple — 32 planes at F=28, 4x less tile traffic than the
@@ -365,15 +442,64 @@ def hist_variants(group: int, wide: bool) -> Tuple[int, int]:
     return ppp, STAT_BLOCK // ppp
 
 
+def hist_scratch(f: int, bpad: int, sub: int, quantized: bool,
+                 grouped: bool = False):
+    """Scratch of ``_hist_window`` in its argument order, then the DMA
+    semaphores of the caller's ``tile_dmas`` (two slots x the DMAs a tile
+    takes) — the one list ``seg_hist_pallas_batch`` and the fused grow step
+    allocate."""
+    nblk, arows, brows = hist_operands(f, bpad)
+    high = hist_digits(bpad)[0]
+    op = jnp.int8 if quantized else jnp.bfloat16
+    return [
+        pltpu.VMEM((2, sub, TILE), jnp.int16),  # stage: two staging slots
+        pltpu.VMEM((_bin_rows(hist_group(f, bpad)), TILE), jnp.int32),  # bins
+        pltpu.VMEM((nblk, arows, TILE), op),  # a_op: masked stats, per block
+        pltpu.VMEM((nblk, brows, TILE), op),  # b_op: low-digit one-hots
+        pltpu.VMEM(  # acc: every block's whole matmul result
+            (nblk, 8 if high == 1 else arows, brows),
+            jnp.int32 if quantized else jnp.float32,
+        ),
+        pltpu.SemaphoreType.DMA((2 * (2 if grouped else 1),)),
+    ]
+
+
+def hist_scratch_bytes(f: int, bpad: int, sub: int) -> int:
+    """VMEM bytes of ``hist_scratch`` at the wider operand type (a last
+    dimension under 128 still takes whole lane tiles), the pipelined raw
+    output block and the tile loop's own temporaries (the unpacked tile,
+    one low-digit compare and one 32-row block of ``A``)."""
+    scratch = sum(
+        math.prod(ref.shape[:-1]) * max(ref.shape[-1], 128)
+        * jnp.dtype(ref.dtype).itemsize
+        for ref in hist_scratch(f, bpad, sub, quantized=False)[:-1]
+    )
+    out = 2 * 8 * hist_group(f, bpad) * bpad * 4
+    low = min(hist_digits(bpad)[1], 128)
+    temps = sub * TILE * 4 + 2 * low * TILE * 4 + 2 * 32 * TILE * 4
+    return scratch + out + temps
+
+
+def _bin_rows(group: int) -> int:
+    """Rows of the bins scratch: a program's features, even ones in the
+    first half and odd ones in the second (byte-packed planes unpack to two
+    aligned row blocks), each half a multiple of the i32 sublane tile."""
+    return 2 * (-(-group // 16) * 8)
+
+
 def _hist_window(
     start,  # scalar i32 — window begin (data-row index)
     cnt,  # scalar i32 — window rows (0 = all-zero histogram)
     pt,  # scalar i32 — this program's feature-plane group (grid dim 1)
     live,  # scalar i32 — 0 skips the tile loop entirely (dead plane group)
-    read_fn,  # (base_col: i32) -> [SUB, TILE] u16-in-i32 staged tile
+    tile_dmas,  # (slot, base_col) -> the DMAs that stage one [SUB, TILE] tile
     scales_ref,  # SMEM [2] f32: g_scale, h_scale (quantized mode; else 1s)
-    acc,  # VMEM [8, group * bpad] f32 | i32 — RAW accumulator planes
-    onehot,  # VMEM [TILE, group * bpad] bf16 | i8
+    out,  # VMEM [8, group * bpad] f32 | i32 — the program's RAW output block
+    stage,  # VMEM [2, SUB, TILE] i16 — tile t in slot t % 2
+    bins,  # VMEM [_bin_rows(group), TILE] i32 — the program's features' bins
+    a_op,  # VMEM [nblk, A rows, TILE] bf16 | i8
+    b_op,  # VMEM [nblk, B rows, TILE] bf16 | i8
+    acc,  # VMEM [nblk, A rows | 8, B rows] f32 | i32
     *,
     f: int,
     bpad: int,
@@ -384,18 +510,44 @@ def _hist_window(
 ):
     """Histogram accumulation over ONE packed-row window (the per-program
     body of the seg hist kernel, factored out so the fused grow-step kernel
-    can run it over just-partitioned data — its ``read_fn`` reads tiles
-    through the output alias; see partition.read_aliased_tile).
+    can run it over just-partitioned data — its ``tile_dmas`` read through
+    the output alias; see partition.aliased_tile_dma).
 
-    Fills ``acc`` with the program's RAW [8, group*bpad] accumulator block
-    for plane group ``pt``; the caller copies it to the output and the
-    digit recombine runs outside the kernel (``combine_hist_raw``).  Row
-    convention (both dtypes): 0 g_hi, 1 h_hi, 2 count, 3 g_lo, 4 h_lo,
-    5 zero, 6 g_lo2, 7 h_lo2 (int8 leaves 5-7 zero).
+    Fills ``out`` with the program's RAW [8, group*bpad] block for plane
+    group ``pt``; the digit recombine runs outside the kernel
+    (``combine_hist_raw``).  Row convention (both dtypes): 0 g_hi, 1 h_hi,
+    2 count, 3 g_lo, 4 h_lo, 5 zero, 6 g_lo2, 7 h_lo2 (int8 leaves 5-7 zero).
 
-    ``grouped``: ``read_fn`` stages the program's own aligned 16-plane bin
-    block over the stat block (``hist_sub``); the program's planes sit in
-    the block at offset (pt mod nvar) * ppp (``hist_variants``)."""
+    The two-digit one-hot.  With (H, L) = ``hist_digits(bpad)`` a bin is
+    ``b = hi * L + lo`` and
+
+        hist[s, j, b] = sum_r (stats[s, r] * [hi_j(r) = hi]) * [lo_j(r) = lo]
+
+    so for a block of nf = ``hist_feature_block`` features a tile builds,
+    rows of the tile on the lanes as they arrive (no transpose),
+    ``A[(j, hi, s), r]`` = the stat row s where feature j's high digit is
+    hi, else 0, and ``B[(j', lo), r]`` = [feature j' has low digit lo], and
+    issues ONE ``A . B^T`` contracting the rows.  The [A rows, nf * L] result's
+    diagonal blocks j = j' are the nf histograms as [(hi, s), lo]; the
+    off-diagonal blocks are joint counts of two different features and are
+    never read.  Every block's whole result accumulates in ``acc`` across
+    tiles and the diagonal blocks are copied to ``out`` once, after the
+    loop.  The sums are the same addends as a full one-hot's: the same
+    bf16 | int8 terms times exact 0/1, added in f32 | i32 over the same rows
+    in the same order, zeros elsewhere.  H = 1 is the full one-hot itself:
+    ``A`` is the 8 stat rows, shared by all the program's features, ``B``
+    their whole one-hots, every result block "diagonal".
+
+    The read of tile t+1 is started before tile t is waited for, into the
+    other of the two staging slots.
+
+    ``grouped``: a tile is the program's own aligned 16-plane bin block
+    over the stat block (``hist_sub``); the program's planes sit in the
+    block at offset (pt mod nvar) * ppp (``hist_variants``)."""
+    high, low = hist_digits(bpad)
+    nf = hist_feature_block(f, bpad)
+    nblk, arows, brows = hist_operands(f, bpad)
+    drows = 0 if high == 1 else _digit_rows(bpad)  # H = 1: shared stat rows
     abegin = (start // COL_ALIGN) * COL_ALIGN
     off = start - abegin
     nt = (off + cnt + TILE - 1) // TILE
@@ -413,24 +565,46 @@ def _hist_window(
         ppp, nvar = hist_variants(group, wide)
     else:
         GLO, GHI, HLO, HHI, M, _, _ = stat_lanes(f, wide)
-    iota_rows = jax.lax.broadcasted_iota(jnp.int32, (TILE, 1), 0)[:, 0]
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (TILE, bpad), 1)
     ngroups = hist_ngroups(f, bpad)
+    half = bins.shape[0] // 2
+    iota_pos = jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
+    row8 = jax.lax.broadcasted_iota(jnp.int32, (8, TILE), 0)
+    is_g = (row8 == 0) | (row8 == 3) | (row8 == 6)
+    hi32 = jax.lax.broadcasted_iota(jnp.int32, (32, TILE), 0) >> 3
+    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (min(low, 128), TILE), 0)
+    op_dtype = jnp.int8 if quantized else jnp.bfloat16
+    pref = jnp.int32 if quantized else jnp.float32
+
+    def bin_row(j):
+        """Row of ``bins`` that holds feature j of the program."""
+        return j if wide else (j >> 1) + (j & 1) * half
+
+    def start_tile(t):
+        for dma in tile_dmas(t % 2, abegin + t * TILE):
+            dma.start()
+
+    @pl.when(nt > 0)
+    def _first_read():
+        start_tile(0)
 
     def body(t, _):
-        # transpose the plane-major tile to row-major for the one-hot matmul
-        xu = read_fn(abegin + t * TILE).T  # [TILE, SUB]
-        pos = iota_rows + t * TILE
+        @pl.when(t + 1 < nt)
+        def _read_ahead():
+            start_tile(t + 1)
+
+        for dma in tile_dmas(t % 2, abegin + t * TILE):
+            dma.wait()
+        xu = stage[t % 2].astype(jnp.int32) & 0xFFFF  # [SUB, TILE]
+        pos = iota_pos + t * TILE
         valid = ((pos >= off) & (pos < off + cnt)).astype(jnp.float32)
-        g = lax.bitcast_convert_type(
-            (xu[:, GLO] | (xu[:, GHI] << 16)).astype(jnp.uint32), jnp.float32
-        )
-        h = lax.bitcast_convert_type(
-            (xu[:, HLO] | (xu[:, HHI] << 16)).astype(jnp.uint32), jnp.float32
-        )
-        m = xu[:, M].astype(jnp.float32) * valid
-        gm = g * m
-        hm = h * m
+        m = xu[M:M + 1].astype(jnp.float32) * valid  # [1, TILE]
+        # the 8 stat rows at once, g's on rows 0/3/6 and h's on 1/4/7 (one
+        # row costs a vreg a 128 lanes like eight do)
+        lo16 = jnp.where(is_g, xu[GLO:GLO + 1], xu[HLO:HLO + 1])
+        hi16 = jnp.where(is_g, xu[GHI:GHI + 1], xu[HHI:HHI + 1])
+        vm = lax.bitcast_convert_type(
+            (lo16 | (hi16 << 16)).astype(jnp.uint32), jnp.float32
+        ) * m  # [8, TILE]: g * m | h * m
         if quantized:
             # int8 MXU path (2x bf16 throughput), 2-DIGIT: q is clipped to
             # +-QMAX and split q = hi*128 + lo (|hi| <= 127, |lo| <= 64 —
@@ -443,109 +617,109 @@ def _hist_window(
             # 2^24.  As the default hist accumulator the grid carries ~14
             # bits per addend — near ties are re-accumulated in bf16/f32
             # by the grower before any structure decision.
-            qg = jnp.clip(jnp.round(gm * inv_g), -QMAX, QMAX).astype(jnp.int32)
-            qh = jnp.clip(jnp.round(hm * inv_h), -QMAX, QMAX).astype(jnp.int32)
-            g_hi = (qg + 64) >> 7
-            g_lo = qg - (g_hi << 7)
-            h_hi = (qh + 64) >> 7
-            h_lo = qh - (h_hi << 7)
-            # 5 live rows pad to the i32 output tile's 8 sublanes anyway,
-            # so the zero rows are free MXU work (same argument as the
-            # bf16 path's 6 -> 8 padding)
-            stats = jnp.concatenate(
-                [
-                    g_hi.astype(jnp.int8)[:, None],
-                    h_hi.astype(jnp.int8)[:, None],
-                    m.astype(jnp.int8)[:, None],
-                    g_lo.astype(jnp.int8)[:, None],
-                    h_lo.astype(jnp.int8)[:, None],
-                    jnp.zeros((TILE, 3), jnp.int8),
-                ],
-                axis=1,
-            )  # [TILE, 8]
-            oh_dtype, pref = jnp.int8, jnp.int32
+            q = jnp.clip(
+                jnp.round(vm * jnp.where(is_g, inv_g, inv_h)), -QMAX, QMAX
+            ).astype(jnp.int32)
+            q_hi = (q + 64) >> 7
+            q_lo = q - (q_hi << 7)
+            # 5 live rows pad to the i32 tile's 8 sublanes anyway
+            stats = jnp.where(
+                row8 < 2, q_hi,
+                jnp.where(row8 == 2, m.astype(jnp.int32),
+                          jnp.where(row8 < 5, q_lo, 0)),
+            )
         else:
             # THREE-term bf16 split of each f32 addend (~26 mantissa bits)
-            # — the matmul M-dim pads 6 -> 8 sublanes anyway, so the two
-            # extra residual rows are free MXU work (ADVICE r2: tighter
-            # precision contract at zero cost)
-            g_hi = gm.astype(jnp.bfloat16)
-            g_r1 = gm - g_hi.astype(jnp.float32)
-            g_lo = g_r1.astype(jnp.bfloat16)
-            g_lo2 = (g_r1 - g_lo.astype(jnp.float32)).astype(jnp.bfloat16)
-            h_hi = hm.astype(jnp.bfloat16)
-            h_r1 = hm - h_hi.astype(jnp.float32)
-            h_lo = h_r1.astype(jnp.bfloat16)
-            h_lo2 = (h_r1 - h_lo.astype(jnp.float32)).astype(jnp.bfloat16)
-            stats = jnp.concatenate(
-                [
-                    g_hi[:, None],
-                    h_hi[:, None],
-                    m.astype(jnp.bfloat16)[:, None],
-                    g_lo[:, None],
-                    h_lo[:, None],
-                    jnp.zeros((TILE, 1), jnp.bfloat16),
-                    g_lo2[:, None],
-                    h_lo2[:, None],
-                ],
-                axis=1,
-            )  # [TILE, 8]
-            oh_dtype, pref = jnp.bfloat16, jnp.float32
+            # — the stat rows pad 6 -> 8 sublanes anyway, so the two extra
+            # residual rows are free (ADVICE r2: tighter precision contract
+            # at zero cost).  Kept in f32 registers: every value is a bf16.
+            v_hi = vm.astype(jnp.bfloat16).astype(jnp.float32)
+            r1 = vm - v_hi
+            v_lo = r1.astype(jnp.bfloat16).astype(jnp.float32)
+            v_lo2 = (r1 - v_lo).astype(jnp.bfloat16).astype(jnp.float32)
+            stats = jnp.where(
+                row8 < 2, v_hi,
+                jnp.where(row8 == 2, m,
+                          jnp.where(row8 < 5, v_lo,
+                                    jnp.where(row8 == 5, 0.0, v_lo2))),
+            )
+        stats32 = jnp.concatenate([stats] * 4, axis=0)  # [32, TILE]
 
-        def build_onehot(gi):
-            """One-hot block for STATIC plane group gi (feature columns are
-            compile-time plane/byte selects, hence the unrolled dispatch on
-            the dynamic program id below)."""
-            basef = gi * group
-            nf = min(group, f - basef)
-            for j in range(nf):
-                fj = basef + j
-                if wide:
-                    col = xu[:, fj]  # u16 plane per feature
-                else:
-                    col = (xu[:, fj >> 1] >> (8 * (fj & 1))) & 0xFF
-                onehot[:, j * bpad : (j + 1) * bpad] = (
-                    col[:, None] == iota_b
-                ).astype(oh_dtype)
-            if nf < group:
-                onehot[:, nf * bpad :] = jnp.zeros(
-                    (TILE, (group - nf) * bpad), oh_dtype
-                )
-
-        def build_onehot_at(v):
-            """One-hot block of a grouped row's program whose planes start
-            at STATIC offset v * ppp of its bin block.  The last program's
-            features past F read planes of the padding; ``combine_hist_raw``
-            drops their columns."""
-            for j in range(group):
-                if wide:
-                    col = xu[:, v * ppp + j]
-                else:
-                    col = (xu[:, v * ppp + (j >> 1)] >> (8 * (j & 1))) & 0xFF
-                onehot[:, j * bpad : (j + 1) * bpad] = (
-                    col[:, None] == iota_b
-                ).astype(oh_dtype)
+        def take_planes(p0, nfl):
+            """The program's bins from planes [p0, ...) of the tile, at
+            STATIC offsets (hence the unrolled dispatch on the dynamic
+            program id below); -1, which matches no digit, for the
+            features a last group lacks."""
+            if nfl < group:
+                bins[...] = jnp.full(bins.shape, -1, jnp.int32)
+            if wide:
+                bins[0:nfl] = xu[p0:p0 + nfl]  # a u16 plane a feature
+                return
+            planes = xu[p0:p0 + (nfl + 1) // 2]
+            bins[0:(nfl + 1) // 2] = planes & 0xFF
+            if nfl > 1:
+                bins[half:half + nfl // 2] = (planes[:nfl // 2] >> 8) & 0xFF
 
         if grouped:
+            # the last program's features past F read planes of the
+            # padding; ``combine_hist_raw`` drops their columns
             for v in range(nvar):
-                pl.when(pt % nvar == v)(functools.partial(build_onehot_at, v))
+                pl.when(pt % nvar == v)(
+                    functools.partial(take_planes, v * ppp, group))
         elif ngroups == 1:
-            build_onehot(0)
+            take_planes(0, f)
         else:
             for gi in range(ngroups):
-                pl.when(pt == gi)(functools.partial(build_onehot, gi))
-        # ONE matmul per tile per program — the plane-tiled grid moves the
-        # old per-program group loop onto grid dim 1
-        part = jax.lax.dot_general(
-            stats,
-            onehot[...],
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=pref,
-        )
-        acc[...] += part
+                basef = gi * group
+                pl.when(pt == gi)(functools.partial(
+                    take_planes, basef if wide else basef >> 1,
+                    min(group, f - basef)))
+        b = bins[...]
+        lo_dig = b if high == 1 else b & (low - 1)
+        hi_dig = jnp.zeros_like(b) if high == 1 else b >> (low.bit_length() - 1)
+        for fb in range(nblk):
+            for c in range(arows // 32):
+                # 32 rows of A: feature j's stat rows under four high digits
+                j, h0 = (0, 0) if high == 1 else (
+                    (32 * c) // drows, (32 * c) % drows // 8)
+                jg = fb * nf + j
+                if j >= nf or jg >= group:
+                    blk = jnp.zeros((32, TILE), op_dtype)
+                else:
+                    r = bin_row(jg)
+                    blk = jnp.where(
+                        hi_dig[r:r + 1] == hi32 + h0, stats32, 0
+                    ).astype(op_dtype)
+                a_op[fb, 32 * c:32 * (c + 1)] = blk
+            for j in range(nf):
+                jg = fb * nf + j
+                for c0 in range(0, low, iota_lo.shape[0]):  # H = 1: by 128s
+                    rows = pl.ds(j * low + c0, iota_lo.shape[0])
+                    if jg >= group:
+                        b_op[fb, rows] = jnp.zeros(iota_lo.shape, op_dtype)
+                    else:
+                        r = bin_row(jg)
+                        b_op[fb, rows] = (
+                            lo_dig[r:r + 1] == iota_lo + c0
+                        ).astype(op_dtype)
+            # ONE matmul a feature block a tile
+            part = jax.lax.dot_general(
+                a_op[fb], b_op[fb],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=pref,
+            )
+            acc[fb] += part[:acc.shape[1]]
         return 0
 
     lax.fori_loop(0, nt, body, 0)
+    # the diagonal blocks, once a program: out[s, (jg, hi, lo)]
+    for jg in range(group):
+        fb, j = divmod(jg, nf)
+        for hi in range(high):
+            out[:, jg * bpad + hi * low:jg * bpad + (hi + 1) * low] = acc[
+                fb, j * drows + 8 * hi:j * drows + 8 * hi + 8,
+                j * low:(j + 1) * low,
+            ]
 
 
 def combine_hist_raw(
@@ -587,11 +761,7 @@ def _seg_hist_kernel(
     live_ref,  # SMEM [G] i32: per-plane-group live mask
     seg_any,  # ANY [LANES, n_pad] | [G, sub, n_pad] i16 (plane-major)
     out_ref,  # VMEM [1, 1, 8, group * bpad] f32 | i32 block (raw planes)
-    in_stage,  # VMEM [SUB, TILE] i16 — only the used planes are DMA'd
-    acc,  # VMEM [8, group * bpad] f32 | i32
-    onehot,  # VMEM [TILE, group * bpad] bf16 | i8
-    sem_in,
-    *,
+    *scratch,  # hist_scratch(...): only the used planes are DMA'd
     f: int,
     bpad: int,
     group: int,
@@ -600,48 +770,44 @@ def _seg_hist_kernel(
     wide: bool,
     grouped: bool = False,
 ):
+    stage, sem_in = scratch[0], scratch[-1]
     i = pl.program_id(0)
     pt = pl.program_id(1)
 
-    def read_fn(base_col):
+    def tile_dmas(slot, base_col):
         cols = pl.ds(pl.multiple_of(base_col, COL_ALIGN), TILE)
-        if grouped:
-            # the program's aligned bin block, then the stat block: flat
-            # plane p of the row lives at [p // gsub, p % gsub]
-            gsub = seg_any.shape[1]
-            row0 = (pt // hist_variants(group, wide)[1]) * STAT_BLOCK
-            stat0 = stat_lanes(f, wide, True)[0]
-            dmas = [
-                pltpu.make_async_copy(
-                    seg_any.at[
-                        r // gsub,
-                        pl.ds(pl.multiple_of(r % gsub, STAT_BLOCK), STAT_BLOCK),
-                        cols,
-                    ],
-                    in_stage.at[pl.ds(k * STAT_BLOCK, STAT_BLOCK)],
-                    sem_in.at[k],
-                )
-                for k, r in enumerate((row0, stat0))
-            ]
-        else:
-            dmas = [pltpu.make_async_copy(
-                seg_any.at[pl.ds(0, sub), cols], in_stage, sem_in,
+        if not grouped:
+            return [pltpu.make_async_copy(
+                seg_any.at[pl.ds(0, sub), cols], stage.at[slot],
+                sem_in.at[slot],
             )]
-        for dma in dmas:
-            dma.start()
-        for dma in dmas:
-            dma.wait()
-        return in_stage[...].astype(jnp.int32) & 0xFFFF
+        # the program's aligned bin block, then the stat block: flat
+        # plane p of the row lives at [p // gsub, p % gsub]
+        gsub = seg_any.shape[1]
+        row0 = (pt // hist_variants(group, wide)[1]) * STAT_BLOCK
+        stat0 = stat_lanes(f, wide, True)[0]
+        return [
+            pltpu.make_async_copy(
+                seg_any.at[
+                    r // gsub,
+                    pl.ds(pl.multiple_of(r % gsub, STAT_BLOCK), STAT_BLOCK),
+                    cols,
+                ],
+                stage.at[slot, pl.ds(k * STAT_BLOCK, STAT_BLOCK)],
+                sem_in.at[2 * slot + k],
+            )
+            for k, r in enumerate((row0, stat0))
+        ]
 
     _hist_window(
         scal_ref[i, 0],
         scal_ref[i, 1],
         pt,
         live_ref[pt],
-        read_fn,
+        tile_dmas,
         scales_ref,
-        acc,
-        onehot,
+        out_ref.at[0, 0],
+        *scratch[:-1],
         f=f,
         bpad=bpad,
         group=group,
@@ -649,7 +815,6 @@ def _seg_hist_kernel(
         wide=wide,
         grouped=grouped,
     )
-    out_ref[0, 0] = acc[...]
 
 
 def seg_hist_pallas(
@@ -735,15 +900,7 @@ def seg_hist_pallas_batch(
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((k, ngroups, 8, group * bpad), acc_dtype),
-        scratch_shapes=[
-            pltpu.VMEM((sub, TILE), jnp.int16),
-            pltpu.VMEM((8, group * bpad), acc_dtype),
-            pltpu.VMEM(
-                (TILE, group * bpad), jnp.int8 if quantized else jnp.bfloat16
-            ),
-            pltpu.SemaphoreType.DMA((2,)) if grouped
-            else pltpu.SemaphoreType.DMA,
-        ],
+        scratch_shapes=hist_scratch(f, bpad, sub, quantized, grouped),
         interpret=interpret,
     )(
         scal.astype(jnp.int32), scales.astype(jnp.float32),

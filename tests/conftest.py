@@ -198,6 +198,31 @@ def _free_compiled_programs():
     gc.collect()
 
 
+@pytest.fixture
+def full_onehot():
+    """A context manager inside which the seg histogram kernel resolves to
+    its H = 1 form (the stats against the whole one-hot) at every bin
+    width, so that the two-digit form can be held against it.  The digits
+    are no part of a jitted function's cache key: both edges clear JAX's
+    caches."""
+    import contextlib
+
+    from lightgbm_tpu.ops.pallas import seg
+
+    @contextlib.contextmanager
+    def ctx():
+        orig = seg.hist_digits
+        seg.hist_digits = lambda bpad: (1, bpad)
+        jax.clear_caches()
+        try:
+            yield
+        finally:
+            seg.hist_digits = orig
+            jax.clear_caches()
+
+    return ctx
+
+
 @pytest.fixture(scope="session")
 def cpu_mesh_devices():
     import jax

@@ -215,6 +215,65 @@ def test_fused_kernel_interpret_matches_oracle():
     )
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("f,num_bins,k", [
+    (11, 256, 2), (28, 256, 1), (28, 128, 4), (67, 256, 1), (7, 100, 2),
+])
+def test_fused_histogram_is_the_two_launch_histogram(f, num_bins, k, quantized,
+                                                     full_onehot):
+    """The fused kernel's phase 3 is ``seg._hist_window`` reading through
+    the output alias, two staging slots deep: its histogram of the elected
+    child equals, bit for bit, the two-launch kernel's over the partitioned
+    matrix, and the H = 1 form's (int8 exactly; bf16 to rounding)."""
+    from lightgbm_tpu.ops.pallas.seg import (
+        QMAX, hist_bpad, hist_ngroups, seg_hist_pallas_batch,
+    )
+
+    rng = np.random.default_rng(11 + f)
+    n = 2600
+    n_pad = padded_rows(n)
+    bins = rng.integers(0, num_bins, size=(n, f)).astype(np.int32)
+    seg = pack_rows(
+        jnp.asarray(bins), jnp.asarray(rng.normal(size=n).astype(np.float32)),
+        jnp.asarray(rng.random(n).astype(np.float32) * 0.24 + 0.01),
+        jnp.asarray((rng.random(n) < 0.9).astype(np.float32)), n_pad,
+    )
+    # K adjacent windows, none on a 128-column boundary, the last one empty
+    # when K > 1
+    cuts = np.linspace(37, n - 50, k + 1).astype(int)
+    rows = [
+        (cuts[i], 0 if (k > 1 and i == k - 1) else cuts[i + 1] - cuts[i],
+         i % f, num_bins // 3 + 7 * i, 0, -1, 0, 0)
+        for i in range(k)
+    ]
+    scal = jnp.asarray(rows, jnp.int32)
+    catm = jnp.zeros((k, 256), jnp.float32)
+    scales = jnp.asarray([5.0 / QMAX, 0.25 / QMAX], jnp.float32)
+    live = jnp.ones((hist_ngroups(f, hist_bpad(num_bins)),), jnp.int32)
+    kw = dict(f=f, num_bins=num_bins, n_pad=n_pad, quantized=quantized,
+              interpret=True)
+
+    def run():
+        seg2, dec, hist = grow_step.fused_grow_step_pallas(
+            seg, scal, catm, scales, live, use_cat=False, **kw)
+        return seg2, dec, np.asarray(hist)
+
+    seg2, dec, hist = run()
+    two_launch = np.asarray(seg_hist_pallas_batch(
+        seg2, dec[:, 2:4], scales, live, **kw))
+    np.testing.assert_array_equal(hist, two_launch)
+    assert hist[0].any() and (k == 1 or not hist[k - 1].any())
+    with full_onehot():
+        seg1, dec1, hist1 = run()
+    np.testing.assert_array_equal(np.asarray(seg1), np.asarray(seg2))
+    np.testing.assert_array_equal(np.asarray(dec1), np.asarray(dec))
+    if quantized:
+        np.testing.assert_array_equal(hist, hist1)
+    else:
+        np.testing.assert_allclose(
+            hist, hist1, rtol=0, atol=1e-6 * np.abs(hist1).max())
+
+
 def test_fused_booster_interpret_structure():
     """End-to-end through the booster with the real kernel (interpret):
     distinctive shapes/params guarantee a fresh trace (see module note);

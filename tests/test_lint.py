@@ -832,7 +832,7 @@ def test_mutation_seeded_aliased_read_is_caught(tmp_path):
     """Re-introducing the aliasing bug into a copy of the REAL partition
     kernel fires GL002 through the _seg_partition_kernel ->
     _partition_window -> (its block reader) -> _aliased_cols chain, the one
-    place the partition and read_aliased_tile pick their DMA source."""
+    place the partition and aliased_tile_dma pick their DMA source."""
     res = run_lint(_partition_copy(tmp_path, mutate=True))
     assert "_seg_partition_kernel:_aliased_cols:src" in idents(
         res, "GL002"

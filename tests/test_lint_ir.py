@@ -194,13 +194,14 @@ def test_mutation_dropped_donation_is_caught_by_gl013_only(tmp_path):
 
 
 _SEG_TILE = "TILE = 512  # rows per DMA tile in seg_hist"
-_SEG_TILE_BLOWN = "TILE = 8192  # rows per DMA tile in seg_hist"
+_SEG_TILE_BLOWN = "TILE = 16384  # rows per DMA tile in seg_hist"
 
 
 def test_mutation_vmem_blowout_is_caught_by_gl014_only(tmp_path):
-    """A 16x DMA-tile inflation keeps the kernel self-consistent (TILE
+    """A 32x DMA-tile inflation keeps the kernel self-consistent (TILE
     is used symbolically throughout) but pushes the static working set
-    (~21 MB of onehot/staging scratch) past the 16 MiB v5e arena — and
+    (~27 MB of one-hot operand and staging scratch) past the 16 MiB v5e
+    arena — and
     the caller-side seg_vmem_ok guard never sees a direct kernel call,
     which is exactly why GL014 audits the traced pallas_call itself."""
     root = _tree_copy(tmp_path)

@@ -299,7 +299,7 @@ def test_batch_aliased_boundary_reads_are_correct():
 
 
 def test_read_via_input_recreates_aliasing_bug():
-    """Regression guard for read_aliased_tile: reading boundary tiles
+    """Regression guard for aliased_tile_dma: reading boundary tiles
     through the INPUT ref of the input/output-aliased seg matrix (the
     PR-3 bug) makes interpret mode serve stale pre-partition data to the
     second program — this test FAILS (i.e. the outputs differ) if someone

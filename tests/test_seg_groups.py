@@ -170,6 +170,37 @@ def test_grouped_wide_rows_equal_numpy():
         got, _numpy_hist(bins, grad, hess, order[50:50 + nl], num_bins=nb))
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("f,groups,wide,num_bins", [
+    (500, None, False, 256), (28, 2, False, 256), (300, None, False, 128),
+    (130, None, True, 512), (125, None, True, 1000),
+])
+def test_grouped_two_digit_onehot_equals_numpy_and_full_onehot(
+        f, groups, wide, num_bins, quantized, full_onehot):
+    """The grouped row's histogram programs (an aligned 16-plane bin block
+    over the stat block, the planes picked at one of a few static offsets)
+    through the two-digit one-hot: exactly NumPy's ``bincount`` and exactly
+    the H = 1 form, K = 2 windows (one off a 128-column boundary, one of
+    cnt = 0), both dtypes (grad and hess are multiples of 1/8, so every
+    order of summation is exact and the int8 grid holds them whole)."""
+    n = 1200
+    bins, grad, hess = _table(f, n, f + 1, num_bins=num_bins)
+    mat, n_pad = _pack(bins, grad, hess, groups=groups, wide=wide)
+    assert seg.is_grouped(mat)
+    scal = jnp.asarray([(133, 900), (40, 0)], jnp.int32)
+    kw = dict(f=f, num_bins=num_bins, n_pad=n_pad, wide=wide,
+              quantized=quantized, interpret=True)
+    scales = jnp.full((2,), 1 / 8, jnp.float32)
+    got = np.asarray(seg.seg_hist_pallas_batch(mat, scal, scales, **kw))
+    with full_onehot():
+        want = np.asarray(seg.seg_hist_pallas_batch(mat, scal, scales, **kw))
+    assert np.array_equal(got, want)
+    assert np.array_equal(
+        got[0], _numpy_hist(bins, grad, hess, np.arange(133, 1033),
+                            num_bins=num_bins))
+    assert not got[1].any()
+
+
 @pytest.mark.parametrize("f", (28, 100, 242))
 def test_two_forced_groups_are_bit_identical_to_one(f):
     n, sb, cnt = 1400, 77, 1200
@@ -309,12 +340,16 @@ class _Log:
         self.warnings.append(str(msg))
 
 
-@pytest.mark.parametrize("f,max_bin,groups,planes", [
-    (2000, 255, 8, 128), (243, 255, 2, 80), (242, 255, 1, 128), (130, 511, 2, 80),
+@pytest.mark.parametrize("f,max_bin,groups,planes,digits,block", [
+    (2000, 255, 8, 128, "8x32", 2), (243, 255, 2, 80, "8x32", 2),
+    (242, 255, 1, 128, "8x32", 2), (130, 511, 2, 80, "8x64", 2),
+    (28, 127, 1, 32, "4x32", 4), (3, 4000, 1, 32, "16x64", 1),
 ])
-def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, monkeypatch):
+def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, digits,
+                                            block, monkeypatch):
     """On a TPU the Booster takes the segment path whatever the width, with
-    no warning; the spans carry G and the planes a group."""
+    no warning; the spans carry G and the planes a group, and the digits of
+    the histogram kernel's one-hot with the features a matmul takes."""
     import jax
     import lightgbm_tpu as lgb
     from lightgbm_tpu.utils import log as log_mod
@@ -337,7 +372,8 @@ def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, monkeypa
     assert p.hist_mode == "seg" and p.grow_fused
     assert not [w for w in logs.warnings if "segment-resident" in w], logs.warnings
     assert booster._seg_span_args() == {
-        "seg_groups": groups, "seg_group_planes": planes}
+        "seg_groups": groups, "seg_group_planes": planes,
+        "hist_digits": digits, "hist_feature_block": block}
 
 
 def test_the_gate_still_says_what_cannot_run(monkeypatch):

@@ -384,7 +384,10 @@ def test_seg_vmem_gate(monkeypatch):
     assert seg_vmem_ok(242, 256, has_cat=True)
     part = partition_scratch_bytes(128)
     assert 2 * 128 * block_tiles(128) * T * 2 < part < seg.SEG_VMEM_BUDGET // 4
-    hist_alone = 2 * 8 * 8192 * 4 + seg.TILE * 8192 * 2 + 128 * seg.TILE * 2
+    # two staging slots, the H = 1 operands ([32 | 8192, TILE]), the
+    # accumulators, the raw output block and the loop's temporaries
+    hist_alone = seg.hist_scratch_bytes(121, 8192, seg.hist_sub(121, True))
+    assert hist_alone > 2 * 128 * seg.TILE * 2 + 8192 * seg.TILE * 2
     assert hist_alone + part <= seg.SEG_VMEM_BUDGET
     monkeypatch.setattr(seg, "SEG_VMEM_BUDGET", hist_alone + part)
     assert seg_vmem_ok(121, 8192)
@@ -578,3 +581,140 @@ def test_seg_hist_batch_cpu_windowed(packed_big):
             f=p["f"], num_bins=256, n_pad=p["n_pad"],
         )
         np.testing.assert_array_equal(np.asarray(got[i]), np.asarray(want))
+
+
+# ---------------------------------------------------------------------------
+# the two-digit one-hot (bin = hi * L + lo) against the full one-hot (H = 1)
+# and the masked reference
+# ---------------------------------------------------------------------------
+
+# whole table, off a 128-column boundary, empty, short
+_WINDOWS = [(0, 1500), (133, 513), (700, 0), (1000, 37)]
+
+
+def _hist_case(f, num_bins, wide=False, n=1500, seed=3):
+    rng = np.random.default_rng(seed + f)
+    n_pad = padded_rows(n)
+    bins = rng.integers(0, num_bins, size=(n, f)).astype(np.int32)
+    g = rng.normal(size=n).astype(np.float32)
+    h = rng.random(n).astype(np.float32) * 0.24 + 0.01
+    m = (rng.random(n) < 0.8).astype(np.float32)
+    return pack_rows(
+        jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
+        n_pad, wide=wide,
+    ), n_pad
+
+
+def _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized, wide=False):
+    """(two-digit, H = 1) results of a K=4 grid, and of a K=1 grid over the
+    window off a 128-column boundary with the last plane group dead."""
+    from lightgbm_tpu.ops.pallas.seg import (
+        QMAX, hist_bpad, hist_ngroups, seg_hist_pallas_batch,
+    )
+
+    scal = jnp.asarray(_WINDOWS, jnp.int32)
+    scales = jnp.asarray([5.0 / QMAX, 0.25 / QMAX], jnp.float32)
+    ng = hist_ngroups(f, hist_bpad(num_bins))
+    live = jnp.ones((ng,), jnp.int32).at[ng - 1].set(int(ng == 1))
+    kw = dict(f=f, num_bins=num_bins, n_pad=n_pad, quantized=quantized,
+              wide=wide, interpret=True)
+
+    def run():
+        return [np.asarray(a) for a in (
+            seg_hist_pallas_batch(seg, scal, scales, **kw),
+            seg_hist_pallas_batch(seg, scal[1:2], scales, live, **kw),
+        )]
+
+    got = run()
+    with full_onehot():
+        want = run()
+    return got, want
+
+
+def _assert_forms_agree(got, want, quantized):
+    for a, b in zip(got, want):
+        if quantized:
+            np.testing.assert_array_equal(a, b)  # integer sums
+        else:
+            np.testing.assert_allclose(a, b, rtol=0, atol=1e-6 * np.abs(b).max())
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("f,num_bins", [
+    (1, 256), (7, 256), (8, 256), (28, 256), (67, 256),
+    (1, 128), (7, 100), (8, 128), (28, 128), (67, 127),
+])
+def test_two_digit_onehot_equals_full_onehot_and_reference(
+        f, num_bins, quantized, full_onehot):
+    """bpad 256 -> (8, 32) and bpad 128 -> (4, 32): F = 1, 7 (a feature block
+    that is not full), 8, 28 (a last program of 4 features), 67 (of 3);
+    K = 4 and K = 1; a window off a 128-column boundary, one of cnt = 0; a
+    dead plane group.  int8 sums are integers and equal exactly."""
+    from lightgbm_tpu.ops.pallas.seg import (
+        hist_bpad, hist_digits, hist_group, hist_ngroups, seg_hist_ref,
+    )
+
+    bpad = hist_bpad(num_bins)
+    assert hist_digits(bpad) == {256: (8, 32), 128: (4, 32)}[bpad]
+    seg, n_pad = _hist_case(f, num_bins)
+    got, want = _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized)
+    _assert_forms_agree(got, want, quantized)
+    for i, (st, cnt) in enumerate(_WINDOWS):
+        ref = np.asarray(seg_hist_ref(
+            seg, jnp.asarray([st, cnt], jnp.int32), f=f, num_bins=num_bins,
+            n_pad=n_pad))
+        np.testing.assert_array_equal(got[0][i][..., 2], ref[..., 2])
+        if not quantized:  # three-term bf16 split: ~26-bit addends
+            assert np.abs(got[0][i] - ref).max() <= 5e-6 * max(
+                1e-9, np.abs(ref).max())
+    # K = 1 is K = 4's member: the dead group's features zero, the live whole
+    ng, gb = hist_ngroups(f, bpad), hist_group(f, bpad)
+    live_f = gb * (ng - 1) if ng > 1 else f
+    np.testing.assert_array_equal(got[1][0][:live_f], got[0][1][:live_f])
+    assert (got[1][0][live_f:] == 0).all()
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("f,num_bins,digits", [
+    (9, 500, (8, 64)), (4, 700, (12, 64)), (5, 1000, (16, 64)),
+    (3, 1100, (9, 128)), (3, 2000, (16, 128)), (3, 4000, (1, 4096)),
+])
+def test_wide_two_digit_onehot_equals_full_onehot(f, num_bins, digits,
+                                                   quantized, full_onehot):
+    """u16 bin planes: the widths the factoring takes (a high digit of up
+    to 16 values) and the one past them, which IS the full one-hot."""
+    from lightgbm_tpu.ops.pallas.seg import hist_bpad, hist_digits, seg_hist_ref
+
+    assert hist_digits(hist_bpad(num_bins)) == digits
+    seg, n_pad = _hist_case(f, num_bins, wide=True)
+    got, want = _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized,
+                            wide=True)
+    _assert_forms_agree(got, want, quantized)
+    ref = np.asarray(seg_hist_ref(
+        seg, jnp.asarray(_WINDOWS[1], jnp.int32), f=f, num_bins=num_bins,
+        n_pad=n_pad, wide=True))
+    np.testing.assert_array_equal(got[0][1][..., 2], ref[..., 2])
+    if not quantized:
+        assert np.abs(got[0][1] - ref).max() <= 5e-6 * np.abs(ref).max()
+
+
+@pytest.mark.parametrize("bpad,digits,block", [
+    (128, (4, 32), 4), (256, (8, 32), 2), (384, (6, 64), 2), (512, (8, 64), 2),
+    (640, (10, 64), 1), (1024, (16, 64), 1), (2048, (16, 128), 1),
+    (2176, (1, 2176), 1), (8192, (1, 8192), 1),
+])
+def test_hist_digits_come_from_bpad_alone(bpad, digits, block):
+    """(8, 32), the swept winner, at the cells' bpad 256; H = 1 (the full one-hot) where the
+    high digit's 8 * H rows would pass the MXU's 128; every width factors
+    exactly and a feature's rows never straddle an int8 tile."""
+    from lightgbm_tpu.ops.pallas import seg
+
+    assert seg.hist_digits(bpad) == digits
+    assert seg.hist_feature_block(100, bpad) == block
+    high, low = digits
+    assert high * low == bpad and 8 * high <= seg.MXU_ROWS
+    nblk, arows, brows = seg.hist_operands(100, bpad)
+    assert nblk * block >= seg.hist_group(100, bpad) > (nblk - 1) * block
+    assert brows == block * low
+    assert arows == (32 if high == 1 else seg.MXU_ROWS)
+    assert high == 1 or block * seg._digit_rows(bpad) <= seg.MXU_ROWS

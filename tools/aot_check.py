@@ -247,6 +247,47 @@ def _c28(topo):
     )
 
 
+def _hist_batch(topo, f, rows, num_bins=256, k=1, quantized=False, wide=False):
+    from lightgbm_tpu.ops.pallas.seg import seg_hist_pallas_batch
+
+    n_pad = padded_rows(rows)
+    return compile_on_topo(
+        topo, seg_hist_pallas_batch,
+        s((storage_lanes(f, wide), n_pad), jnp.int16), s((k, 2), jnp.int32),
+        s((2,), jnp.float32),
+        f=f, num_bins=num_bins, n_pad=n_pad, quantized=quantized, wide=wide,
+    )
+
+
+# The histogram kernel's two-digit one-hot at the cells' shapes through the
+# two-launch caller (the fused caller at criteo67's and the grouped row's
+# 250-program grid at epsilon's are above), and the digit widths the cells
+# do not run: bpad 128, a u16 width that factors, one past 2048 (H = 1).
+@check("seg_hist_pallas_batch higgs.fit shape bf16 f=28 10.5M rows")
+def _c29(topo):
+    return _hist_batch(topo, 28, _HIGGS_ROWS)
+
+
+@check("seg_hist_pallas_batch criteo67.fit shape bf16 f=67 8M rows")
+def _c30(topo):
+    return _hist_batch(topo, 67, _CRITEO_ROWS)
+
+
+@check("seg_hist_pallas_batch K=4 bf16 bpad=128 (4x32 digits) f=28")
+def _c31(topo):
+    return _hist_batch(topo, 28, 5000, num_bins=127, k=4)
+
+
+@check("seg_hist_pallas_batch u16 wide int8 f=9 b=1000 (16x64 digits)")
+def _c32(topo):
+    return _hist_batch(topo, 9, 5000, num_bins=1000, quantized=True, wide=True)
+
+
+@check("seg_hist_pallas_batch u16 wide f=3 b=4000 (H = 1: the full one-hot)")
+def _c33(topo):
+    return _hist_batch(topo, 3, 5000, num_bins=4000, wide=True)
+
+
 @check("seg_partition_pallas bits-fed (gl_vec) f=28 10.5M rows")
 def _c7(topo):
     return _partition(topo, 28, _HIGGS_ROWS, gl=True)
