@@ -1421,8 +1421,9 @@ class Booster:
             and hist_method == "auto"
             and n_used > 0
         ):
-            # loud fence (VERDICT r2 #10): the ordered fallback is measured
-            # 1.4-10x slower than seg mode at scale (BENCH_NOTES.md)
+            # loud fence: no benchmark cell runs the ordered fallback, so
+            # its speed on the chip is not measured (PERF.md section 7);
+            # benchmark/run.py counts this warning by its opening words
             from ..utils.log import log_warning
 
             if not bins_ok:
@@ -1446,8 +1447,7 @@ class Booster:
                 )
             log_warning(
                 "segment-resident training is unavailable: " + why +
-                "; falling back to hist_mode='ordered' (1.4-10x slower at "
-                "scale). " + cure
+                "; falling back to hist_mode='ordered'. " + cure
             )
         hist_mode = str(
             self.params.get(

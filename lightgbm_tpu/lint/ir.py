@@ -189,25 +189,34 @@ _WIDE_DTYPES = {"float64", "int64", "uint64", "complex128"}
 
 def _pkg_frames(eqn) -> Tuple[SrcFrame, ...]:
     """In-package source frames for an eqn, innermost first, lint/
-    excluded (the tracer itself must never be 'the source')."""
-    try:
-        from jax._src import source_info_util as siu
+    excluded (the tracer itself must never be 'the source').
 
-        frames = []
-        marker = os.sep + PKG_NAME + os.sep
-        for fr in siu.user_frames(eqn.source_info):
-            fname = fr.file_name or ""
-            if marker not in fname:
-                continue
-            rel = PKG_NAME + "/" + fname.split(marker, 1)[1].replace(os.sep, "/")
-            if rel.startswith(PKG_NAME + "/lint/"):
-                continue
-            frames.append(
-                SrcFrame(path=rel, line=int(fr.start_line), func=fr.function_name)
-            )
-        return tuple(frames)
-    except Exception:
-        return ()
+    Nothing is caught here: ``user_frames`` takes the eqn's
+    ``Traceback`` as of jax 0.9, and a jax that moves the API again
+    must fail the entry's trace (``trace_entry`` reports it as a
+    finding), not blind every rule that asks where an eqn came from.
+    Every entry of the matrix is package code, so an eqn with no
+    in-package frame is the same failure."""
+    from jax._src import source_info_util as siu
+
+    frames = []
+    marker = os.sep + PKG_NAME + os.sep
+    for fr in siu.user_frames(eqn.source_info.traceback):
+        fname = fr.file_name or ""
+        if marker not in fname:
+            continue
+        rel = PKG_NAME + "/" + fname.split(marker, 1)[1].replace(os.sep, "/")
+        if rel.startswith(PKG_NAME + "/lint/"):
+            continue
+        frames.append(
+            SrcFrame(path=rel, line=int(fr.start_line), func=fr.function_name)
+        )
+    if not frames:
+        raise LookupError(
+            f"'{eqn.primitive.name}' eqn carries no source frame inside "
+            f"{PKG_NAME}/: the lint cannot say where it came from"
+        )
+    return tuple(frames)
 
 
 def _aval_bytes(aval) -> int:
@@ -243,6 +252,7 @@ def _subjaxprs(params: dict):
 
 
 def _pallas_fact(eqn) -> Optional[PallasFact]:
+    frames = _pkg_frames(eqn)  # outside the try: see _pkg_frames
     try:
         gm = eqn.params.get("grid_mapping")
         nsi = eqn.params.get("name_and_src_info")
@@ -285,7 +295,7 @@ def _pallas_fact(eqn) -> Optional[PallasFact]:
             grid=grid,
             block_bytes=tuple(blocks),
             scratch_bytes=scratch,
-            frames=_pkg_frames(eqn),
+            frames=frames,
         )
     except Exception:
         return None
@@ -1071,31 +1081,27 @@ def _dump(entries: List[TracedEntry]) -> None:
             continue
         print(f"   donate={te.donate_argnums} arg_bytes={te.arg_bytes}")
         for c in te.facts.collectives:
-            src = c.frames[0] if c.frames else None
+            src = c.frames[0]
             print(
                 f"   {c.kind} axes={c.axes} payload={c.payload_bytes}B "
-                f"@ {src.path}:{src.line} ({src.func})" if src else
-                f"   {c.kind} axes={c.axes} payload={c.payload_bytes}B @ ?"
+                f"@ {src.path}:{src.line} ({src.func})"
             )
         for cb in te.facts.callbacks:
-            src = cb.frames[0] if cb.frames else None
-            where = f"{src.path}:{src.line} ({src.func})" if src else "?"
-            print(f"   callback {cb.kind} @ {where}")
+            src = cb.frames[0]
+            print(f"   callback {cb.kind} @ {src.path}:{src.line} ({src.func})")
         for p in te.facts.pallas:
             print(
                 f"   pallas {p.kernel} grid={p.grid} blocks={p.block_bytes} "
                 f"scratch={p.scratch_bytes} est={p.vmem_estimate()}"
             )
         for w in te.facts.wide:
-            src = w.frames[0] if w.frames else None
-            where = f"{src.path}:{src.line}" if src else "?"
-            print(f"   WIDE {w.dtype} in {w.prim} @ {where}")
+            src = w.frames[0]
+            print(f"   WIDE {w.dtype} in {w.prim} @ {src.path}:{src.line}")
         if te.facts.weak_outputs:
             print(f"   WEAK outputs: {te.facts.weak_outputs}")
         for w in te.x64_wide:
-            src = w.frames[0] if w.frames else None
-            where = f"{src.path}:{src.line}" if src else "?"
-            print(f"   X64-WIDE {w.dtype} in {w.prim} @ {where}")
+            src = w.frames[0]
+            print(f"   X64-WIDE {w.dtype} in {w.prim} @ {src.path}:{src.line}")
 
 
 if __name__ == "__main__":

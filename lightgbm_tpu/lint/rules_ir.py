@@ -90,14 +90,6 @@ def _ast_site_spans(
     return spans
 
 
-def _where(
-    fr: Optional[SrcFrame], spec
-) -> Tuple[str, int]:
-    if fr is not None:
-        return fr.path, fr.line
-    return spec.anchor
-
-
 # ------------------------------------------------------------------ GL011
 def check_collective_congruence(
     project: Project, entries: Sequence[TracedEntry]
@@ -119,16 +111,15 @@ def check_collective_congruence(
             continue
         model = spec.psum_model() if spec.psum_model is not None else {}
         for c in te.facts.collectives:
-            inner = c.frames[0] if c.frames else None
+            # frames are never empty: ir._pkg_frames fails the entry's
+            # trace on an eqn it cannot place
+            inner = c.frames[0]
             site = _user_site(c.frames)
             loc = site or inner
-            path, line = _where(loc, spec)
-            func = loc.func if loc is not None else "?"
+            path, line, func = loc.path, loc.line, loc.func
             # (a) provenance: must come out of the timed wrappers
-            if inner is None or inner.path != _SANCTIONED:
-                at = (
-                    f"{inner.path}:{inner.line}" if inner else "unknown"
-                )
+            if inner.path != _SANCTIONED:
+                at = f"{inner.path}:{inner.line}"
                 out.append(
                     Finding(
                         "GL011",
@@ -204,13 +195,13 @@ def check_collective_congruence(
 # ------------------------------------------------------------------ GL012
 def _wide_sites(
     facts: Sequence[WideDtypeFact],
-) -> List[Tuple[WideDtypeFact, Optional[SrcFrame]]]:
+) -> List[Tuple[WideDtypeFact, SrcFrame]]:
     seen = set()
     client, infra = [], []
     for w in facts:
         site = _user_site(w.frames)
-        fr = site or (w.frames[0] if w.frames else None)
-        key = (w.dtype, fr.path if fr else "?", fr.line if fr else 0)
+        fr = site or w.frames[0]
+        key = (w.dtype, fr.path, fr.line)
         if key in seen:
             continue
         seen.add(key)
@@ -245,11 +236,9 @@ def check_dtype_promotion(
             sites = _wide_sites(facts)
             if not sites:
                 continue
-            path, line = _where(sites[0][1], spec)
+            path, line = sites[0][1].path, sites[0][1].line
             detail = "; ".join(
                 f"{w.dtype} ({w.prim}) at {fr.path}:{fr.line}"
-                if fr
-                else f"{w.dtype} ({w.prim})"
                 for w, fr in sites[:3]
             )
             extra = (
@@ -314,13 +303,12 @@ def check_vmem_budget(
             est = p.vmem_estimate()
             if est <= limit:
                 continue
-            fr = p.frames[0] if p.frames else None
-            path, line = _where(fr, te.spec)
+            fr = p.frames[0]
             out.append(
                 Finding(
                     "GL014",
-                    path,
-                    line,
+                    fr.path,
+                    fr.line,
                     f"vmem:{p.kernel}",
                     f"pallas kernel '{p.kernel}' (entry "
                     f"'{te.spec.name}') wants ~{est} B of VMEM "
@@ -341,11 +329,10 @@ def check_host_transfers(
         if te.error or not te.spec.hot:
             continue
         for cb in te.facts.callbacks:
-            inner = cb.frames[0] if cb.frames else None
-            if inner is not None and inner.path == _SANCTIONED:
+            inner = cb.frames[0]
+            if inner.path == _SANCTIONED:
                 continue
-            path, line = _where(inner, te.spec)
-            func = inner.func if inner else "?"
+            path, line, func = inner.path, inner.line, inner.func
             out.append(
                 Finding(
                     "GL015",

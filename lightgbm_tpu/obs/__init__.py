@@ -83,7 +83,6 @@ from .profiler import TraceWindow  # noqa: F401
 from .registry import (  # noqa: F401
     TelemetrySession,
     get_session,
-    session_disabled,
 )
 from .trace import (  # noqa: F401
     TraceRecorder,
@@ -95,7 +94,6 @@ from .trace import (  # noqa: F401
 __all__ = [
     "TelemetrySession",
     "get_session",
-    "session_disabled",
     "FlightRecorder",
     "get_flight",
     "list_flight_dumps",

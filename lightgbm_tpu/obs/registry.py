@@ -22,7 +22,6 @@ eval metrics computed after the update — land inside the same line.
 
 from __future__ import annotations
 
-import contextlib
 import json
 import threading
 from typing import Any, Dict, List, Optional
@@ -256,12 +255,3 @@ def get_session() -> TelemetrySession:
     return _SESSION
 
 
-@contextlib.contextmanager
-def session_disabled():
-    """Temporarily disable telemetry (used by bench harness internals)."""
-    prev = _SESSION.enabled
-    _SESSION.enabled = False
-    try:
-        yield
-    finally:
-        _SESSION.enabled = prev

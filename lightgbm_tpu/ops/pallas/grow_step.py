@@ -3,8 +3,8 @@
 The frontier-batched grower (ops/grower.py, leaf_batch=K) already amortizes
 per-split fixed cost, but each compiled step still runs partition ->
 election -> histogram as separately-launched regions with full HBM
-round-trips and dispatch gaps between them (the 36% "bookkeeping" share in
-BENCH_NOTES round 8).  This kernel fuses the per-member pipeline over a
+round-trips and dispatch gaps between them.  This kernel fuses the
+per-member pipeline over a
 PLANE-TILED ``(K, G)`` grid (batch member x feature-plane group — the
 histogram-engine-v2 layout shared with seg.py): for each of the K disjoint
 frontier windows, the member's FIRST plane program

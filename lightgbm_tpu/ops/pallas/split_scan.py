@@ -1,15 +1,12 @@
-"""Per-leaf best-split scan as one Pallas kernel — the split-phase
-fixed-cost killer.
+"""Per-leaf best-split scan as one Pallas kernel.
 
 Reference analog: the CUDA per-(leaf, feature) scan kernel
 ``FindBestSplitsForLeafKernel`` (src/treelearner/cuda/
 cuda_best_split_finder.cu:776): take a leaf's histogram, produce each
 feature's best (gain, threshold, missing-direction, left stats) in one
 launch.  The XLA formulation (ops/split.py best_split) builds [C, F, B]
-gain tensors through several fused-but-separate HBM-bound ops; at small
-leaf counts the per-split FIXED cost (dispatch + launch chain) dominates
-the v5e-16 north-star arithmetic (BENCH_NOTES r4: 0.2 ms/split => ~10
-iters/s at 10.5M rows).  This kernel does the whole scan in VMEM:
+gain tensors through several fused-but-separate HBM-bound ops.  This
+kernel does the whole scan in VMEM:
 cumulative sums by triangular matmul (exact for counts, ~2^-26 relative
 for g/h via the three-digit bf16 split), gain evaluation, and per-feature
 argmax, emitting an [F, 8] result row per feature.

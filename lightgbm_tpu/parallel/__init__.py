@@ -29,7 +29,6 @@ documented cutover; reference voting_parallel_tree_learner.cpp:152).
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 from typing import Callable, Optional, Tuple
 
@@ -39,7 +38,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.jit import instrumented_jit
-from ..ops.grower import GrowerParams, TreeArrays, grow_tree
+from ..ops.grower import GrowerParams, grow_tree
 
 DATA_AXIS = "data"
 
@@ -56,7 +55,7 @@ def psum_bytes_per_iteration(
 
     The psums sit inside a jitted while_loop — traced once, executed per
     split step — so runtime interception can't count them; the payloads are
-    fully determined by shapes instead (tools/collective_model.py):
+    fully determined by shapes instead:
 
     * root: one ``[F, B, 3]`` f32 histogram psum per tree;
     * serial (``leaf_batch=1``): per split, one smaller-child ``[F, B, 3]``
@@ -151,62 +150,6 @@ def pad_rows_np(arr: np.ndarray, pad: int, fill=0):
         return arr
     widths = [(0, pad)] + [(0, 0)] * (arr.ndim - 1)
     return np.pad(arr, widths, constant_values=fill)
-
-
-def make_sharded_grow(
-    mesh: Mesh,
-    params: GrowerParams,
-    axis_name: str = DATA_AXIS,
-    feature_parallel: bool = False,
-):
-    """shard_map'd grow_tree over the mesh's data axis.
-
-    Data-parallel (default): every shard runs the identical leaf loop on its
-    local rows; histograms and root totals are psummed inside
-    (ops/grower.py) so all shards compute the IDENTICAL tree — the
-    reference's histogram ReduceScatter + SplitInfo Allreduce
-    (src/treelearner/data_parallel_tree_learner.cpp:225-302) as XLA
-    collectives. Inputs: row-sharded (bins, grad, hess, mask), replicated
-    (num_bins, nan_bins, feature_mask, monotone, interaction_sets, rng).
-    Returns (TreeArrays replicated, leaf_id row-sharded).
-
-    Feature-parallel (``feature_parallel=True``): every operand is
-    REPLICATED (each shard holds all rows) and the grower slices features by
-    axis_index internally; the only collective is the winner all-reduce
-    (reference feature_parallel_tree_learner.cpp:74).  leaf_id comes back
-    replicated (every shard partitions identically)."""
-    p = dataclasses.replace(params, axis_name=axis_name)
-
-    def local(bins, grad, hess, mask, num_bins, nan_bins, feature_mask,
-              monotone, interaction_sets, rng, is_cat, forced, cegb_penalty,
-              cegb_used, quant_scales, bundle_end, feature_contri):
-        return grow_tree(
-            bins, grad, hess, mask, num_bins, nan_bins, feature_mask, p,
-            monotone=monotone, interaction_sets=interaction_sets, rng=rng,
-            is_cat=is_cat, forced=forced, cegb_penalty=cegb_penalty,
-            cegb_used=cegb_used, quant_scales=quant_scales,
-            bundle_end=bundle_end, feature_contri=feature_contri,
-        )
-
-    rep = P()
-    if feature_parallel:
-        sh = sh2 = rep  # rows replicated; features sliced inside grow_tree
-        leaf_out = rep
-    else:
-        sh = P(axis_name)
-        sh2 = P(axis_name, None)
-        leaf_out = sh
-    fn = _shard_map(
-        local,
-        mesh=mesh,
-        in_specs=(sh2, sh, sh, sh, rep, rep, rep, rep, rep, rep, rep, rep,
-                  rep, rep, rep, rep, rep),
-        out_specs=(
-            jax.tree.map(lambda _: rep, TreeArrays(*([0] * len(TreeArrays._fields)))),
-            leaf_out,
-        ),
-    )
-    return instrumented_jit(fn, label="parallel/sharded_grow")
 
 
 def make_mesh(n_devices: Optional[int] = None, axis_name: str = DATA_AXIS) -> Mesh:

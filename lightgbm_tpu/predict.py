@@ -205,7 +205,9 @@ def _predict_bins_leaves_impl(batch: BinTreeBatch, bins: jnp.ndarray, nan_bins: 
         feat = batch.split_feature[tree_ids, cur]  # [N, T]
         tbin = batch.split_bin[tree_ids, cur]
         dl = batch.default_left[tree_ids, cur]
-        fval = jnp.take_along_axis(bins, feat, axis=1)
+        # GL012: jax 0.9's take_along_axis converts its indices through the
+        # default int dtype, int32 with x64 off, which is how every entry runs
+        fval = jnp.take_along_axis(bins, feat, axis=1)  # graftlint: disable=GL012
         nb = nan_bins[feat]
         gl = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
         bm = batch.cat_mask.shape[-1]
@@ -321,7 +323,11 @@ def _add_tree_to_score_impl(
             feat = split_feature[cur]
             tbin = split_bin[cur]
             dl = default_left[cur]
-            fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]
+            # GL012: as in _predict_bins_leaves_impl, the int64 is jax's own
+            # index conversion under enable_x64.  This is the validation walk
+            # (269 ms an iteration in criteo67.fit-eval, PERF.md section 5):
+            # suppressed, not rewritten, until that walk is the subject
+            fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]  # graftlint: disable=GL012
             nb = nan_bins[feat]
             go_left = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
             if use_cat:

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives.
 
 One policy for every entry point that compiles a lot (chip_smoke.py,
-bench.py, the CLI, tests/conftest.py): the machine decides, the program
+the CLI, tests/conftest.py): the machine decides, the program
 follows.  Nothing else in the package sets a cache directory.
 """
 

@@ -10,11 +10,9 @@ boundaries (obs/trace.py), which are also ``jax.profiler`` annotations.
 
 from __future__ import annotations
 
-import contextlib
 import threading
-import time
 from collections import defaultdict
-from typing import Dict, Iterator
+from typing import Dict
 
 
 class GlobalTimer:
@@ -31,14 +29,6 @@ class GlobalTimer:
         with self._lock:
             self.totals[name] += seconds
             self.counts[name] += 1
-
-    @contextlib.contextmanager
-    def timed(self, name: str) -> Iterator[None]:
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
 
     def reset(self) -> None:
         with self._lock:

@@ -18,7 +18,7 @@ Contracts under test:
     mismatch (GL008 on ops/grower.py), and a dropped static_argnames
     entry (GL009 on ops/quantize.py);
   * the real tree is CLEAN against the committed lint_baseline.json and a
-    full run fits the 6 s budget (it is a hard gate in tools/run_tests.sh).
+    full run fits its CPU budget (it is a hard gate in tools/run_tests.sh).
 """
 
 import json
@@ -931,14 +931,22 @@ def test_mutation_dropped_static_argname_is_caught_by_gl009_only(tmp_path):
 # ================================================================== the gate
 def test_real_tree_clean_against_committed_baseline():
     """THE gate: the shipped package has zero unbaselined findings and zero
-    stale baseline entries, within the 6 s budget (tightened from 10 s when
-    the SPMD rules landed — the shared SpmdIndex keeps GL007–GL010 to one
-    walk, so the full ten-rule run must stay inside a dev-loop budget).
+    stale baseline entries, within a CPU budget (the shared SpmdIndex
+    keeps GL007–GL010 to one walk, so the full ten-rule run must stay
+    inside a dev-loop budget; a rule that rebuilds the index costs 3-4x).
     The budget is the CLI's own CPU accounting in a FRESH interpreter —
     how the tool is actually invoked (run_tests.sh, the dev loop) — not a
     wall clock inside this long-lived pytest process, where hundreds of
     earlier tests leave the allocator fragmented enough to roughly double
-    the cost of the pointer-chasing ast walk."""
+    the cost of the pointer-chasing ast walk.
+
+    CPU seconds stretch when six xdist workers share the caches, which
+    is how the driver runs this file: alone the CLI reads 2.98 s; under
+    the driver's command (-n 6 --dist loadfile) three whole runs read
+    4.59, 3.98 and 3.65 s (PR 31), and one of PR 28 read 6.002 s against
+    the 6 s this test then allowed.  The budget is twice the worst of
+    the three, rounded up: it clears every reading seen by 1.6x and
+    still fails a lint that costs 3.4x what it does alone."""
     res = run_lint(PKG, baseline=REPO / "lint_baseline.json")
     assert res.ok, (
         "new findings:\n"
@@ -954,7 +962,7 @@ def test_real_tree_clean_against_committed_baseline():
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     cpu = json.loads(proc.stdout)["cpu_s"]
-    assert cpu < 6.0, f"lint took {cpu:.1f}s CPU (budget: 6s)"
+    assert cpu < 10.0, f"lint took {cpu:.1f}s CPU (budget: 10s)"
 
 
 def test_cli_exit_codes():

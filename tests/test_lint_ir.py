@@ -297,3 +297,26 @@ def test_donated_score_update_traces_once():
     assert after - before == 1  # donation does not perturb retrace count
     assert s2.shape == score.shape
     assert float(s2[0, 0]) == 0.0  # leaf 0 value added twice, still 0
+
+
+def test_moved_traceback_api_fails_the_entry_not_the_frames(monkeypatch):
+    """``_pkg_frames`` catches nothing: the day jax moves
+    ``source_info_util.user_frames`` again (0.9 moved its argument from
+    the SourceInfo to its Traceback, and a swallowed AttributeError left
+    every IR finding without frames since the seed), the entry reports a
+    trace error, which GL011 turns into a finding — and so does an eqn
+    whose frames all lie outside the package."""
+    from jax._src import source_info_util as siu
+
+    from lightgbm_tpu.lint import ir
+
+    (spec,) = [s for s in ir.build_entry_specs() if s.name == "pallas/histogram"]
+    te = ir.trace_entry(spec)
+    assert te.error is None and te.facts.pallas[0].frames
+
+    def moved(_traceback):
+        raise AttributeError("'Traceback' object has no attribute 'raw_frames'")
+
+    for fake, said in ((moved, "AttributeError"), (lambda _tb: iter(()), "LookupError")):
+        monkeypatch.setattr(siu, "user_frames", fake)
+        assert said in (ir.trace_entry(spec).error or "")

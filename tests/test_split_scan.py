@@ -136,7 +136,7 @@ def test_fused_grower_matches_default_end_to_end():
 # optimal — near-tie flips are benign, wrong-split flips are bugs.
 #
 # Measured on this construction (seeds 0..9, gap targets 1e-1..1e-6, CPU
-# f32, recorded in BENCH_NOTES.md): zero flips for relative gap >= 1e-5
+# f32): zero flips for relative gap >= 1e-5
 # (53 trials); at gap ~1e-6 each engine flips on 1 of 7 trials (~14%), and
 # a wider 150-trial sweep (25 seeds) showed 3-4 of 18 trials (~20%) at
 # gap <= 1e-6 — every flip landing on the f64 runner-up candidate.
@@ -283,7 +283,6 @@ def test_with_margin_matches_oracle_gap():
 # The property: the FINAL pick never flips away from the f64 oracle at
 # relative gain gaps >= 1e-4 (_NT_CANCEL_SCALE), and the f32 refine
 # actually triggers whenever the true gap is deep inside the tolerance.
-# Measured rates on this battery are recorded in BENCH_NOTES.md (round 10).
 
 _NT_TOL = 1e-3  # GrowerParams.near_tie_tol default
 
