@@ -1371,7 +1371,7 @@ class Booster:
         # planes is packed as plane groups (ops/pallas/seg.py).  What bounds
         # a table on the seg path is the kernels' VMEM scratch at very wide
         # bins and the device's memory: the packed matrix (2 B a plane a
-        # row), the partition's one-group spill and hist_buf [L, F, B, 3].
+        # row), the partition's one-group spill and hist_buf [L + 1, 3, F, B].
         from ..ops.pallas.seg import (
             group_shape,
             padded_rows,
@@ -1396,7 +1396,7 @@ class Booster:
             rows = padded_rows(-(-int(self._bins.shape[0]) // shards))
             seg_bytes = (
                 (g + 1) * sub * rows * 2
-                + cfg.num_leaves * n_eff * self._max_bin_padded * 3 * 4
+                + (cfg.num_leaves + 1) * 3 * n_eff * self._max_bin_padded * 4
             )
         mem_limit = (_jax.devices()[0].memory_stats() or {}).get("bytes_limit")
         mem_fits = mem_limit is None or seg_bytes <= mem_limit
@@ -1437,8 +1437,8 @@ class Booster:
                 cure = "Consider a smaller max_bin."
             else:
                 why = (
-                    f"the packed rows and hist_buf [num_leaves, features, "
-                    f"max_bin, 3] need {seg_bytes / 2**30:.1f} GiB of the "
+                    f"the packed rows and hist_buf [num_leaves + 1, 3, "
+                    f"features, max_bin] need {seg_bytes / 2**30:.1f} GiB of the "
                     f"device's {mem_limit / 2**30:.1f} GiB"
                 )
                 cure = (
@@ -2044,7 +2044,7 @@ class Booster:
             coll = mesh_psum_bytes_per_iteration(
                 per_tree,
                 int(self._bins.shape[1]),
-                # PADDED bin-axis size: the psum moves the [F, B, 3] padded
+                # PADDED bin-axis size: the psum moves the [3, F, B] padded
                 # histogram, so the measured cross-check only matches with
                 # the same B the trace actually uses
                 int(self._grower_params.max_bin),

@@ -400,8 +400,8 @@ def _grow_psum_model(spec, leaf_batch: int) -> Dict[str, FrozenSet[int]]:
     TOTALS; statically a jaxpr shows each loop-body site once, so the
     allowed set holds the per-site payloads the model is built from:
 
-    * 'data': the [K, F_loc, B, 3] frontier histogram psum (or its two
-      db0/db1 halves under overlap), the [F_loc, B, 3] root histogram,
+    * 'data': the [K, 3, F_loc, B] frontier histogram psum (or its two
+      db0/db1 halves under overlap), the [3, F_loc, B] root histogram,
       and the small per-step count payloads (2 x i32/f32 per member,
       plus the serial root [2]);
     * 'feature': the 11-value winner-election broadcast and the [3]
@@ -417,7 +417,7 @@ def _grow_psum_model(spec, leaf_batch: int) -> Dict[str, FrozenSet[int]]:
         allowed["data"] = frozenset(
             {
                 hist,  # root / per-step smaller-child histogram
-                k * hist,  # batched frontier histogram [K, F_loc, B, 3]
+                k * hist,  # batched frontier histogram [K, 3, F_loc, B]
                 k * hist // 2,  # overlap db0/db1 half-batch planes
                 4,  # scalar count / stat psum (f32 or i32)
                 8,  # [2] count pair

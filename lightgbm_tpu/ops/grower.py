@@ -171,7 +171,7 @@ class GrowerParams:
     # worst-case gain-domain amplification under gradient cancellation)
     near_tie_tol: float = 1e-3
     # double-buffered histogram collectives: under leaf_batch > 1 with a
-    # histogram psum axis, split the [K, F, B, 3] frontier stack into two
+    # histogram psum axis, split the [K, 3, F, B] frontier stack into two
     # half-window psums (sites "hist_db0"/"hist_db1") issued BETWEEN the
     # half-builds, so XLA's async all-reduce of buffer 0 overlaps the
     # histogram build of buffer 1.  Byte-identical to the single psum
@@ -266,7 +266,11 @@ class _State(NamedTuple):
     order: jnp.ndarray  # [N + maxcap] row permutation (ordered mode; else empty)
     leaf_begin: jnp.ndarray  # [L] segment start per leaf (ordered mode)
     leaf_nrows: jnp.ndarray  # [L] RAW row count per leaf (ordered mode)
-    hist_buf: jnp.ndarray  # [L, F, B, 3]
+    # one histogram per leaf as three [F, B] planes (g, h, count: stat axis
+    # first, so a leaf's row is one contiguous slab with features on the
+    # sublanes and bins on the lanes), plus a spare row L that takes the
+    # children's writes of a step that does not split (_write_children)
+    hist_buf: jnp.ndarray  # [L + 1, 3, F, B]
     leaf_g: jnp.ndarray
     leaf_h: jnp.ndarray
     leaf_cnt: jnp.ndarray
@@ -452,12 +456,13 @@ def _candidate_for_leaf(
     cegb_penalty=None, rand_bins=None, adv=None, bundle_end=None,
     depth=None, feature_contri=None, with_margin=False,
 ):
-    """Best split for one leaf.  ``hist`` is the GLOBAL (psummed) histogram
-    normally; under voting-parallel it is the LOCAL histogram and only the
+    """Best split for one leaf.  ``hist`` ([3, F, B] planes) is the GLOBAL
+    (psummed) histogram normally; under voting-parallel it is the LOCAL
+    histogram and only the
     globally-elected top-2k features' slices are psummed (PV-Tree,
     reference voting_parallel_tree_learner.cpp:152 GlobalVoting + :396
     elected-feature ReduceScatter)."""
-    f = hist.shape[0]
+    f = hist.shape[1]
     fused_ok = (
         # grow_fused implies the Pallas scan too: the fused grow step already
         # emits the stacked hist, so the scan is the only launch left to save
@@ -532,7 +537,7 @@ def _candidate_for_leaf(
     # ---- PV-Tree election.  1) local per-feature best gains from the LOCAL
     # histogram (local parent stats derive from it: feature 0's bins cover
     # every local row)
-    loc = hist[0].sum(axis=0)  # [3] local (g, h, cnt)
+    loc = hist[:, 0].sum(axis=-1)  # [3] local (g, h, cnt)
     _, gains_f = best_split(
         hist, loc[0], loc[1], loc[2], num_bins, nan_bins, feature_mask,
         monotone=monotone,
@@ -563,10 +568,10 @@ def _candidate_for_leaf(
     )
     # 3) elect top-2k features globally; every shard elects the SAME ids
     _, ids = lax.top_k(glob, min(2 * p.voting_top_k, f))
-    # 4) aggregate ONLY the elected slices ([2k, B, 3] over ICI instead of
-    # [F, B, 3]) and scan them with GLOBAL parent stats
+    # 4) aggregate ONLY the elected slices ([3, 2k, B] over ICI instead of
+    # [3, F, B]) and scan them with GLOBAL parent stats
     sub = timed_psum(
-        hist[ids], p.axis_name, site="hist", measure=p.measure_collectives
+        hist[:, ids], p.axis_name, site="hist", measure=p.measure_collectives
     )
     cand = best_split(
         sub, g, h, c, num_bins[ids], nan_bins[ids], feature_mask[ids],
@@ -1227,7 +1232,7 @@ def grow_tree(
             # same branch per member) — a shared max-over-members bucket was
             # measured 15% slower at the 1M-row bench shape because every
             # member paid the largest window's gather.  The inner histograms
-            # run with axis_name=None and the [K, F, B, 3] stack psums ONCE
+            # run with axis_name=None and the [K, 3, F, B] stack psums ONCE
             # outside.
             def _make_hist_branch_loc(cap: int):
                 def branch(member):  # [N] bool
@@ -1362,7 +1367,7 @@ def grow_tree(
                 axis_name=hist_axis, quant_scales=quant_scales,
                 measure=p.measure_collectives,
             )
-    totals = hist0[0].sum(axis=0)  # every row lands in exactly one bin of feature 0
+    totals = hist0[:, 0].sum(axis=-1)  # every row lands in exactly one bin of feature 0
     if use_voting:
         totals = timed_psum(  # global root stats
             totals, p.axis_name, site="counts",
@@ -1460,7 +1465,7 @@ def grow_tree(
             order=order0,
             leaf_begin=leaf_begin0,
             leaf_nrows=leaf_nrows0,
-            hist_buf=jnp.zeros((L, f_loc, B, 3), jnp.float32).at[0].set(hist0),
+            hist_buf=jnp.zeros((L + 1, 3, f_loc, B), jnp.float32).at[0].set(hist0),
             leaf_g=jnp.zeros((L,), jnp.float32).at[0].set(totals[0]),
             leaf_h=jnp.zeros((L,), jnp.float32).at[0].set(totals[1]),
             leaf_cnt=jnp.zeros((L,), jnp.float32).at[0].set(totals[2]),
@@ -1505,6 +1510,70 @@ def grow_tree(
     node_ids = jnp.arange(L - 1, dtype=jnp.int32)
     use_forced_splits = p.n_forced > 0 and forced is not None
 
+    def _forced_stats(st: _State, f_leaf, f_feat, f_bin, f_iscat):
+        """Child sums and gain of a host-forced split, read off the leaf's
+        histogram row of that one feature (reference
+        GatherInfoForThreshold, feature_histogram.hpp:475-595)."""
+        hrow = st.hist_buf[f_leaf, :, f_feat]  # [3, B]
+        if use_voting:
+            # voting keeps hist_buf LOCAL; a forced split needs the
+            # global row for this one feature (tiny psum)
+            hrow = timed_psum(
+                hrow, p.axis_name, site="hist",
+                measure=p.measure_collectives,
+            )
+        nbv = nan_bins[f_feat]
+        has_nb = nbv >= 0
+        nan_s = jnp.where(has_nb, hrow[:, jnp.maximum(nbv, 0)], 0.0)
+        brow_ids = jnp.arange(B, dtype=jnp.int32)
+        hrow_o = jnp.where((brow_ids == nbv) & has_nb, 0.0, hrow)
+        cumr = jnp.cumsum(hrow_o, axis=1)
+        fpg, fph, fpc = (
+            st.leaf_g[f_leaf],
+            st.leaf_h[f_leaf],
+            st.leaf_cnt[f_leaf],
+        )
+        # numeric: missing goes LEFT (GatherInfoForThresholdNumerical
+        # sets default_left=true); categorical: one-hot on the bin
+        f_left = jnp.where(f_iscat, hrow[:, f_bin], cumr[:, f_bin] + nan_s)
+        f_lg, f_lh, f_lc = f_left[0], f_left[1], f_left[2]
+        f_rg, f_rh, f_rc = fpg - f_lg, fph - f_lh, fpc - f_lc
+        f_raw = leaf_gain(f_lg, f_lh, p.lambda_l1, p.lambda_l2) + leaf_gain(
+            f_rg, f_rh, p.lambda_l1, p.lambda_l2
+        )
+        f_gain = (
+            f_raw
+            - leaf_gain(fpg, fph, p.lambda_l1, p.lambda_l2)
+            - p.min_gain_to_split
+        )
+        return f_lg, f_lh, f_lc, f_rg, f_rh, f_rc, f_gain
+
+    def _child_hists(parent_hist, sm, left_smaller):
+        """(left, right) histograms of a split [.., 3, F, B]: the smaller
+        child measured, its sibling by subtraction from the parent
+        (serial_tree_learner.cpp:558).  Both come out whole from behind one
+        barrier, so the parent's row is read off the carry once, before the
+        first row of the carry is written: the compiler then has no use for
+        the old buffer and updates it in place (a read of the old carry
+        after its first write costs two whole-buffer copies a split)."""
+        other = parent_hist - sm
+        return lax.optimization_barrier((
+            jnp.where(left_smaller, sm, other),
+            jnp.where(left_smaller, other, sm),
+        ))
+
+    def _write_children(hist_buf, l, nl, left_hist, right_hist, ok):
+        """Rows ``l`` and ``nl`` of the carry take the children's
+        histograms; a step that does not split writes both to the spare row
+        L instead, so no row is read back to be preserved."""
+        for idx, row in ((l, left_hist), (nl, right_hist)):
+            # a plain dynamic-update-slice: ``.at[].set`` is a scatter, whose
+            # out-of-bounds rule reads the old row back beside the new one
+            hist_buf = lax.dynamic_update_index_in_dim(
+                hist_buf, row, jnp.where(ok, idx, L), axis=0
+            )
+        return hist_buf
+
     def body(t, st: _State) -> _State:
         """One split step, fully UNCONDITIONAL.
 
@@ -1514,8 +1583,10 @@ def grow_tree(
         rows — hist_buf is 22 MB at L=255, the packed seg matrix 0.3 GB at
         1M).  So instead of an `apply` branch, every state write below is
         value-preserving under ``~can_split`` (write the old value back at
-        the same index), which keeps each update an in-place
-        dynamic-update-slice on the loop carry with NO conditional in sight.
+        the same index; ``hist_buf``, the one large table, reads nothing
+        back and sends the write to its spare row instead), which keeps
+        each update an in-place dynamic-update-slice on the loop carry with
+        NO conditional in sight.
         A no-split step degenerates to zero-count partition/histogram work
         plus O(L·F·B) bookkeeping."""
         with jax.named_scope("bookkeeping"):
@@ -1534,39 +1605,8 @@ def grow_tree(
                 f_feat = f_feat_a[tf]
                 f_bin = f_bin_a[tf]
                 f_iscat = f_iscat_a[tf]
-                hrow = st.hist_buf[f_leaf, f_feat]  # [B, 3]
-                if use_voting:
-                    # voting keeps hist_buf LOCAL; a forced split needs the
-                    # global row for this one feature (tiny psum)
-                    hrow = timed_psum(
-                        hrow, p.axis_name, site="hist",
-                        measure=p.measure_collectives,
-                    )
-                nbv = nan_bins[f_feat]
-                has_nb = nbv >= 0
-                nan_s = jnp.where(has_nb, hrow[jnp.maximum(nbv, 0)], 0.0)
-                brow_ids = jnp.arange(B, dtype=jnp.int32)
-                hrow_o = jnp.where(
-                    ((brow_ids == nbv) & has_nb)[:, None], 0.0, hrow
-                )
-                cumr = jnp.cumsum(hrow_o, axis=0)
-                fpg, fph, fpc = (
-                    st.leaf_g[f_leaf],
-                    st.leaf_h[f_leaf],
-                    st.leaf_cnt[f_leaf],
-                )
-                # numeric: missing goes LEFT (GatherInfoForThresholdNumerical
-                # sets default_left=true); categorical: one-hot on the bin
-                f_left = jnp.where(f_iscat, hrow[f_bin], cumr[f_bin] + nan_s)
-                f_lg, f_lh, f_lc = f_left[0], f_left[1], f_left[2]
-                f_rg, f_rh, f_rc = fpg - f_lg, fph - f_lh, fpc - f_lc
-                f_raw = leaf_gain(f_lg, f_lh, p.lambda_l1, p.lambda_l2) + leaf_gain(
-                    f_rg, f_rh, p.lambda_l1, p.lambda_l2
-                )
-                f_gain = (
-                    f_raw
-                    - leaf_gain(fpg, fph, p.lambda_l1, p.lambda_l2)
-                    - p.min_gain_to_split
+                f_lg, f_lh, f_lc, f_rg, f_rh, f_rc, f_gain = _forced_stats(
+                    st, f_leaf, f_feat, f_bin, f_iscat
                 )
                 use_forced = is_f_step & (f_gain > 0)
                 # a failed forced split aborts the REMAINING forced steps
@@ -1892,15 +1932,11 @@ def grow_tree(
             )
 
             # ---- histograms: smaller child measured, sibling by subtraction
-            parent_hist = st.hist_buf[l]
-            other = parent_hist - sm
-            left_hist = jnp.where(left_smaller, sm, other)
-            right_hist = jnp.where(left_smaller, other, sm)
-            hist_buf = st.hist_buf.at[l].set(
-                jnp.where(can_split, left_hist, parent_hist)
+            left_hist, right_hist = _child_hists(
+                st.hist_buf[l], sm, left_smaller
             )
-            hist_buf = hist_buf.at[nl].set(
-                jnp.where(can_split, right_hist, st.hist_buf[nl])
+            hist_buf = _write_children(
+                st.hist_buf, l, nl, left_hist, right_hist, can_split
             )
 
         # ---- monotone bounds for the children.
@@ -2247,7 +2283,7 @@ def grow_tree(
 
         The top-K frontier leaves by cached gain are partitioned over their
         DISJOINT row windows, the K smaller children are histogrammed in one
-        batched pass (one [K, 2] count psum + one [K, F, B, 3] histogram
+        batched pass (one [K, 2] count psum + one [K, 3, F, B] histogram
         psum under data-parallel), and all 2K child candidates refresh in
         one vmapped scan.  Exactness by the prefix-commit rule: member i
         commits iff every earlier member committed AND its gain strictly
@@ -2280,30 +2316,9 @@ def grow_tree(
             f_feat = f_feat_a[tf]
             f_bin = f_bin_a[tf]
             f_iscat = f_iscat_a[tf]
-            hrow = st.hist_buf[f_leaf, f_feat]  # [B, 3] (voting raises @K>1)
-            nbv = nan_bins[f_feat]
-            has_nb = nbv >= 0
-            nan_s = jnp.where(has_nb, hrow[jnp.maximum(nbv, 0)], 0.0)
-            brow_ids = jnp.arange(B, dtype=jnp.int32)
-            hrow_o = jnp.where(
-                ((brow_ids == nbv) & has_nb)[:, None], 0.0, hrow
-            )
-            cumr = jnp.cumsum(hrow_o, axis=0)
-            fpg, fph, fpc = (
-                st.leaf_g[f_leaf],
-                st.leaf_h[f_leaf],
-                st.leaf_cnt[f_leaf],
-            )
-            f_left = jnp.where(f_iscat, hrow[f_bin], cumr[f_bin] + nan_s)
-            f_lg, f_lh, f_lc = f_left[0], f_left[1], f_left[2]
-            f_rg, f_rh, f_rc = fpg - f_lg, fph - f_lh, fpc - f_lc
-            f_raw = leaf_gain(f_lg, f_lh, p.lambda_l1, p.lambda_l2) + leaf_gain(
-                f_rg, f_rh, p.lambda_l1, p.lambda_l2
-            )
-            f_gain = (
-                f_raw
-                - leaf_gain(fpg, fph, p.lambda_l1, p.lambda_l2)
-                - p.min_gain_to_split
+            # (voting raises at K > 1, so the row is always the global one)
+            f_lg, f_lh, f_lc, f_rg, f_rh, f_rc, f_gain = _forced_stats(
+                st, f_leaf, f_feat, f_bin, f_iscat
             )
             use_forced = is_f_step & (f_gain > 0)
             forced_ok_next = st.forced_ok & (~is_f_step | use_forced)
@@ -2656,11 +2671,10 @@ def grow_tree(
 
         with jax.named_scope("bookkeeping"):
             # ---- sibling histograms by subtraction, per pair
-            parent_hist_k = st.hist_buf[l_k]  # [K, f_loc, B, 3]
-            other_k = parent_hist_k - sm_k
-            ls4 = left_smaller_k[:, None, None, None]
-            left_hist_k = jnp.where(ls4, sm_k, other_k)
-            right_hist_k = jnp.where(ls4, other_k, sm_k)
+            left_hist_k, right_hist_k = _child_hists(
+                st.hist_buf[l_k],  # [K, 3, f_loc, B]
+                sm_k, left_smaller_k[:, None, None, None],
+            )
 
         lg_k, lh_k, lc_k = c_lg_k, c_lh_k, c_lc_k
         rg_k, rh_k, rc_k = c_rg_k, c_rh_k, c_rc_k
@@ -2833,11 +2847,8 @@ def grow_tree(
                 _setb(leaf_is_right, l_i, jnp.asarray(False), ok),
                 nl_i, jnp.asarray(True), ok,
             )
-            hist_buf = hist_buf.at[l_i].set(
-                jnp.where(ok, left_hist_k[i], hist_buf[l_i])
-            )
-            hist_buf = hist_buf.at[nl_i].set(
-                jnp.where(ok, right_hist_k[i], hist_buf[nl_i])
+            hist_buf = _write_children(
+                hist_buf, l_i, nl_i, left_hist_k[i], right_hist_k[i], ok
             )
             if use_mono:
                 leaf_lb = _setb(

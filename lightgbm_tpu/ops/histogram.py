@@ -37,8 +37,8 @@ def _segment_hist_fn(num_bins: int):
     the segment ids — ``id += member * (F * B)`` — so all M histograms
     accumulate in a single segment_sum launch over ``M * F * B`` segments.
     Float adds happen in the same per-(row, feature, bin) order as the
-    unbatched kernel, so each member's [F, B, 3] plane is byte-identical to
-    its solo run.  ``num_bins`` is closed over (lru_cached) because
+    unbatched kernel, so each member's [3, F, B] planes are byte-identical
+    to its solo run.  ``num_bins`` is closed over (lru_cached) because
     custom_vmap arguments must all be array operands.
     """
 
@@ -53,7 +53,7 @@ def _segment_hist_fn(num_bins: int):
             jnp.concatenate([g, h, c], axis=1)[:, None, :], (n, f, 3)
         ).reshape(-1, 3)
         hist = jax.ops.segment_sum(data, ids, num_segments=f * num_bins)
-        return hist.reshape(f, num_bins, 3)
+        return hist.T.reshape(3, f, num_bins)
 
     @impl.def_vmap
     def impl_vmap(axis_size, in_batched, bins, grad, hess, mask):
@@ -76,7 +76,8 @@ def _segment_hist_fn(num_bins: int):
         hist = jax.ops.segment_sum(
             data, ids.reshape(-1), num_segments=m * f * num_bins
         )
-        return hist.reshape(m, f, num_bins, 3), True
+        planes = hist.reshape(m, f * num_bins, 3).transpose(0, 2, 1)
+        return planes.reshape(m, 3, f, num_bins), True
 
     return impl
 
@@ -88,7 +89,8 @@ def leaf_histogram_segment(
     mask: jnp.ndarray,  # [N] f32 — 1 for rows of the target leaf (in-bag), else 0
     num_bins: int,
 ) -> jnp.ndarray:
-    """Masked histogram via segment_sum. Returns [F, B, 3] (g, h, count).
+    """Masked histogram via segment_sum. Returns [3, F, B] (g, h, count
+    planes, stat axis first).
 
     Vmapping over a leading member axis (fleet training) collapses into one
     flattened segment_sum launch — see ``_segment_hist_fn``."""
@@ -105,9 +107,9 @@ def leaf_histogram_onehot(
 ) -> jnp.ndarray:
     """Masked histogram as chunked one-hot matmuls (MXU-friendly).
 
-    hist[f, b, k] = sum_n [bins[n, f] == b] * ghc[n, k]
-    computed as a batched dot_general over feature with the row axis
-    contracted, scanning over fixed-size row chunks to bound memory.
+    hist[k, f, b] = sum_n ghc[n, k] * [bins[n, f] == b]
+    computed as a dot_general with the row axis contracted, scanning over
+    fixed-size row chunks to bound memory.
     """
     n, f = bins.shape
     ghc = jnp.stack([grad * mask, hess * mask, mask], axis=1)  # [N, 3]
@@ -122,17 +124,17 @@ def leaf_histogram_onehot(
     def body(acc, xs):
         b_c, v_c = xs
         onehot = jax.nn.one_hot(b_c, num_bins, dtype=jnp.float32)  # [chunk, F, B]
-        # contract over rows: [F, B, chunk] x [chunk, 3] -> [F, B, 3]
+        # contract over rows: [3, chunk] x [chunk, F, B] -> [3, F, B]
         part = jax.lax.dot_general(
-            onehot,
             v_c,
+            onehot,
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST,
         )
         return acc + part, None
 
-    init = jnp.zeros((f, num_bins, 3), dtype=jnp.float32)
+    init = jnp.zeros((3, f, num_bins), dtype=jnp.float32)
     hist, _ = jax.lax.scan(body, init, (bins_c, ghc_c))
     return hist
 

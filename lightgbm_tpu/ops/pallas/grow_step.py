@@ -216,7 +216,7 @@ def fused_grow_step_pallas(
 ):
     """K fused partition+election+histogram steps in ONE kernel launch.
 
-    Returns (seg', dec[K, 4], hist[K, F, B, 3]) with dec rows
+    Returns (seg', dec[K, 4], hist[K, 3, F, B]) with dec rows
     (nl, nr, child_start, child_cnt).  Grid programs run sequentially on
     the core, so the in-place aliasing, the shared scratch, and the
     dec-written-at-pt==0 handoff stay safe program-to-program (same
@@ -317,7 +317,7 @@ def fused_grow_step(
     exercises the kernel without a TPU.
 
     Returns (seg', nl[K], nr[K], child_start[K], child_cnt[K],
-    hist[K, F, B, 3])."""
+    hist[K, 3, F, B])."""
     # fault-injection consult (trace time — the moment a Mosaic compile
     # failure would surface); disarmed it costs one dict truthiness check
     from ...resilience import chaos

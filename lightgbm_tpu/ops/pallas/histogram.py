@@ -154,17 +154,18 @@ def histogram_pallas(
     num_bins: int,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Masked histogram [F, B, 3] = (sum_g, sum_h, count) per (feature, bin)."""
+    """Masked histogram [3, F, B] = (sum_g, sum_h, count) planes per
+    (feature, bin)."""
     n, f = bins.shape
     if f == 0:  # all-constant datasets: platform_dependent traces all branches
-        return jnp.zeros((0, num_bins, 3), jnp.float32)
+        return jnp.zeros((3, 0, num_bins), jnp.float32)
     from .seg import combine_hist_raw
 
     ghc = jnp.stack([grad * mask, hess * mask, mask], axis=1)  # [N, 3]
     out, bpad = tile_pallas_histogram(
         bins, ghc, num_bins, _hist_kernel, jnp.bfloat16, jnp.float32, interpret
     )
-    # raw [8, F*bpad] planes -> recombined [F, B, 3] outside the kernel
+    # raw [8, F*bpad] planes -> recombined [3, F, B] outside the kernel
     return combine_hist_raw(
         out[None, None],
         jnp.ones((2,), jnp.float32),

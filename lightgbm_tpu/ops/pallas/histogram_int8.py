@@ -13,7 +13,7 @@ contracts int8 x int8 -> int32 on the MXU — EXACT integer accumulation on
 the quantized grid (no bf16 hi/lo split needed) at twice the bf16 MXU
 rate.  The kernel emits the RAW [8, F*bpad] i32 accumulator planes (the
 i32 VMEM tile height — GL005-clean); the digit recombine/dequantize runs
-outside in seg.combine_hist_raw, so the [F, B, 3] f32 histogram drops into
+outside in seg.combine_hist_raw, so the [3, F, B] f32 histogram drops into
 the existing split search unchanged.
 
 Selected explicitly via ``hist_method='pallas_int8'`` (grower params); the
@@ -111,10 +111,11 @@ def histogram_pallas_int8(
     h_scale: jnp.ndarray,  # scalar f32
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """[F, B, 3] (sum_g, sum_h, count) from 2-digit int8 MXU accumulation."""
+    """[3, F, B] (sum_g, sum_h, count planes) from 2-digit int8 MXU
+    accumulation."""
     n, f = bins.shape
     if f == 0:
-        return jnp.zeros((0, num_bins, 3), jnp.float32)
+        return jnp.zeros((3, 0, num_bins), jnp.float32)
     ghc = int8_digit_rows(grad, hess, mask, g_scale, h_scale)
     out, bpad = tile_pallas_histogram(
         bins, ghc, num_bins, _hist_kernel_int8, jnp.int8, jnp.int32, interpret

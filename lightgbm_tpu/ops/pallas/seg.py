@@ -733,7 +733,8 @@ def combine_hist_raw(
     quantized: bool,
 ) -> jnp.ndarray:
     """Recombine the kernels' raw 8-sublane accumulator planes into the
-    [K, F, B, 3] (g, h, count) histogram — plain XLA, outside the kernel.
+    [K, 3, F, B] histogram — the three (g, h, count) planes, stat axis
+    first — in plain XLA, outside the kernel.
 
     int8: g = (S_hi*128 + S_lo)*g_scale (the *128 is a f32 exponent bump,
     exact; the digit sum is exact below 2^24 — same bound as the old
@@ -752,7 +753,7 @@ def combine_hist_raw(
         g = a[:, 0] + a[:, 3] + a[:, 6]
         h = a[:, 1] + a[:, 4] + a[:, 7]
         c = a[:, 2] + a[:, 5]
-    return jnp.stack([g, h, c], axis=-1)[:, :, :num_bins, :]
+    return jnp.stack([g, h, c], axis=1)[..., :num_bins]
 
 
 def _seg_hist_kernel(
@@ -830,7 +831,8 @@ def seg_hist_pallas(
     wide: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Histogram [F, B, 3] (g, h, count) of packed rows [start, start+cnt).
+    """Histogram [3, F, B] (g, h, count planes) of packed rows
+    [start, start+cnt).
 
     A thin K=1 wrapper over the batched plane-tiled kernel (one launch, G
     grid programs).  ``quantized=True`` (requires ``scales``): 2-digit
@@ -861,7 +863,7 @@ def seg_hist_pallas_batch(
     wide: bool = False,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """K histograms [K, F, B, 3] of K disjoint packed-row windows in ONE
+    """K histograms [K, 3, F, B] of K disjoint packed-row windows in ONE
     plane-tiled launch: a (K, G) grid — batch member x feature-plane group
     — over the shared kernel (TPU grid programs run sequentially on the
     core, so the shared staging/accumulator scratch is reused safely
@@ -1049,7 +1051,7 @@ def seg_hist(seg, scal, *, f: int, num_bins: int, n_pad: int,
 
 def seg_hist_batch(seg, scal_k, *, f: int, num_bins: int, n_pad: int,
                    quant_scales=None, wide: bool = False, live=None):
-    """K-window histogram dispatch ([K, 2] (start, cnt) -> [K, F, B, 3]):
+    """K-window histogram dispatch ([K, 2] (start, cnt) -> [K, 3, F, B]):
     one plane-tiled Pallas launch on TPU, the windowed/masked reference
     elsewhere."""
     quantized = quant_scales is not None

@@ -187,7 +187,7 @@ def _split_scan_kernel(
     ),
 )
 def split_scan_pallas(
-    hist: jnp.ndarray,  # [F, B, 3] f32 leaf histogram
+    hist: jnp.ndarray,  # [3, F, B] f32 leaf histogram (g, h, count planes)
     parent: jnp.ndarray,  # [3] f32 (g, h, cnt)
     num_bins: jnp.ndarray,  # [F] i32
     nan_bins: jnp.ndarray,  # [F] i32
@@ -204,10 +204,10 @@ def split_scan_pallas(
     """Per-feature best numeric split rows [F, 8]:
     (gain, bin, default_left, left_g, left_h, left_cnt, second_gain, 0)."""
     bpad = (max(num_bins_pad, 1) + 127) // 128 * 128
-    b = hist.shape[1]
+    b = hist.shape[2]
     if b < bpad:
-        hist = jnp.pad(hist, ((0, 0), (0, bpad - b), (0, 0)))
-    h3 = hist.transpose(2, 0, 1).reshape(3, f * bpad).astype(jnp.float32)
+        hist = jnp.pad(hist, ((0, 0), (0, 0), (0, bpad - b)))
+    h3 = hist.reshape(3, f * bpad).astype(jnp.float32)
     fpad = max(8, -(-f // 8) * 8)
     tri = jnp.tril(jnp.ones((bpad, bpad), jnp.bfloat16)).T  # tri[j,i] = j<=i
 
@@ -252,7 +252,8 @@ def fused_best_split(
     interpret: bool = False,
     with_margin: bool = False,
 ):
-    """best_split (basic numeric path) backed by the Pallas scan kernel.
+    """best_split (basic numeric path) backed by the Pallas scan kernel;
+    ``hist`` is best_split's [3, F, B] planes and feeds the kernel as is.
 
     Returns the same SplitCandidate best_split would for configurations
     fused_eligible() admits (tie order differs only on exact cross-feature
@@ -270,7 +271,7 @@ def fused_best_split(
     i.e. nothing to refine)."""
     from ..split import SplitCandidate, leaf_gain
 
-    f, b, _ = hist.shape
+    _, f, b = hist.shape
     rows = split_scan_pallas(
         hist,
         jnp.stack([

@@ -22,7 +22,7 @@ usually fastest, so it is accepted and mapped onto the same path (results
 are identical regardless).  ``tree_learner='voting'`` implements the real
 PV-Tree election (ops/grower._candidate_for_leaf): histograms stay LOCAL,
 each shard's top-``top_k`` weighted gains are pmax-merged, and only the
-elected 2k features' ``[2k, B, 3]`` slices are psummed — engaged only when
+elected 2k features' ``[3, 2k, B]`` slices are psummed — engaged only when
 ``F > 2 * top_k`` (below that the dense psum is exact and cheaper, the
 documented cutover; reference voting_parallel_tree_learner.cpp:152).
 """
@@ -57,10 +57,10 @@ def psum_bytes_per_iteration(
     split step — so runtime interception can't count them; the payloads are
     fully determined by shapes instead:
 
-    * root: one ``[F, B, 3]`` f32 histogram psum per tree;
-    * serial (``leaf_batch=1``): per split, one smaller-child ``[F, B, 3]``
+    * root: one ``[3, F, B]`` f32 histogram psum per tree;
+    * serial (``leaf_batch=1``): per split, one smaller-child ``[3, F, B]``
       f32 histogram psum plus a ``[2]`` i32 count psum;
-    * batched (``leaf_batch=K``): per loop step, ONE ``[K, F, B, 3]``
+    * batched (``leaf_batch=K``): per loop step, ONE ``[K, 3, F, B]``
       histogram psum plus ONE ``[K, 2]`` count psum.  The prefix-commit rule
       may commit fewer than K members per step, so ``ceil(splits / K)``
       steps is a lower bound — the model's documented approximation.
@@ -76,7 +76,7 @@ def psum_bytes_per_iteration(
     """
     f, b, k = int(n_features), int(num_bins), max(1, int(leaf_batch))
     splits = max(0, int(n_splits))
-    hist_payload = f * b * 3 * 4  # [F, B, 3] f32
+    hist_payload = f * b * 3 * 4  # [3, F, B] f32
     steps = -(-splits // k) if splits else 0
     hist_bytes = (steps * k + 1) * hist_payload  # + 1 root histogram
     count_bytes = steps * k * 2 * 4 + 8  # [K, 2] i32 + root totals

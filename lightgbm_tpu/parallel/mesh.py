@@ -230,7 +230,7 @@ def make_fleet_grow(mesh: Optional[Mesh], params: GrowerParams,
     feature_mask, rng.  Everything else — the [N, P] bin planes, bin
     metadata, constraint tables — is shared across members, so the batched
     histogram builds reuse ONE resident bin matrix and the data-mesh
-    histogram psum moves one stacked [M, K, F, B, 3] payload per step
+    histogram psum moves one stacked [M, K, 3, F, B] payload per step
     instead of M separate ones.  Outputs come back stacked: TreeArrays
     [M, ...] and leaf_id [M, N].
 
@@ -343,7 +343,7 @@ def mesh_psum_bytes_per_iteration(
     count_bytes = 0
     elect_bytes = 0
     if spec.data > 1:
-        hist_payload = f_loc * b * 3 * 4  # [F_loc, B, 3] f32
+        hist_payload = f_loc * b * 3 * 4  # [3, F_loc, B] f32
         hist_bytes = (steps * k + 1) * hist_payload  # + 1 root histogram
         count_bytes = steps * k * 2 * 4 + (0 if spec.feature > 1 else 8)
     if spec.feature > 1:

@@ -1,114 +1,26 @@
-"""Best-split search over a leaf histogram.
-
-Reference analogs: ``FeatureHistogram::FindBestThresholdSequentially``
-(src/treelearner/feature_histogram.hpp:832 — per-feature sequential scan with
-missing-direction handling) and the CUDA per-(leaf,feature) scan kernel
-(src/treelearner/cuda/cuda_best_split_finder.cu:776).
-
-TPU-native formulation: the histogram is three [F, B] planes (sum_grad,
-sum_hess, count — stat axis FIRST, so features ride the sublanes and bins the
-lanes of every tile); one vectorized cumulative-sum over the bin axis for ALL
-features at once, gains evaluated for every (feature, bin, missing-dir)
-candidate simultaneously, then a single argmax.  The reference's two-direction
-scan for missing values becomes two gain tensors (NaN bin counted left vs
-right).  Gain math (L1 thresholding, L2, max_delta_step, min_data/min_hess
-gates) follows feature_histogram.hpp:711-828.
+"""The split scan as it was before the histogram became three [F, B] planes:
+``best_split`` of the commit before PR 32, verbatim, on the record form
+[F, B, 3] (stat axis last).  tests/test_split_planes.py holds the plane-form
+scan to it bit for bit; nothing else may import it.  The helpers it calls are
+the package's own (the gain arithmetic is shared, elementwise and unchanged).
 """
 
-from __future__ import annotations
-
-from typing import NamedTuple, Optional
+from typing import Optional
 
 import jax.numpy as jnp
 
-_EPS = 1e-15
+from lightgbm_tpu.ops.split import (
+    CatParams,
+    SplitCandidate,
+    _EPS,
+    constrained_output,
+    gain_given_output,
+    leaf_gain,
+)
 
 
-def threshold_l1(g: jnp.ndarray, l1: float) -> jnp.ndarray:
-    return jnp.sign(g) * jnp.maximum(jnp.abs(g) - l1, 0.0)
-
-
-def leaf_gain(g, h, l1: float, l2: float):
-    t = threshold_l1(g, l1)
-    return (t * t) / (h + l2 + _EPS)
-
-
-def leaf_output(g, h, l1: float, l2: float, max_delta_step: float = 0.0):
-    """CalculateSplittedLeafOutput (feature_histogram.hpp:711)."""
-    out = -threshold_l1(g, l1) / (h + l2 + _EPS)
-    if max_delta_step > 0.0:
-        out = jnp.clip(out, -max_delta_step, max_delta_step)
-    return out
-
-
-class SplitCandidate(NamedTuple):
-    """Best split for one leaf (reference: SplitInfo, split_info.hpp:22).
-
-    For categorical splits ``is_cat`` is True and ``cat_mask`` is a bin-space
-    bitmask ([B] bool, True = bin goes LEFT) — the TPU formulation of the
-    reference's ``cat_threshold`` uint32 vector (bitset of categories); the
-    mapping back to category values happens at host Tree materialization.
-    ``cat_mask`` has width 1 when the grower runs without categorical
-    features (static no-op)."""
-
-    gain: jnp.ndarray  # improvement over parent minus min_gain; <=0 means no split
-    feature: jnp.ndarray  # used-feature index (int32)
-    bin: jnp.ndarray  # threshold bin: bin <= threshold goes left
-    default_left: jnp.ndarray  # bool: missing goes left
-    left_g: jnp.ndarray
-    left_h: jnp.ndarray
-    left_cnt: jnp.ndarray
-    right_g: jnp.ndarray
-    right_h: jnp.ndarray
-    right_cnt: jnp.ndarray
-    is_cat: jnp.ndarray  # bool
-    cat_mask: jnp.ndarray  # [B] bool (or [1] when categorical is disabled)
-
-
-def constrained_output(
-    g,
-    h,
-    l1: float,
-    l2: float,
-    max_delta_step: float,
-    path_smooth: float = 0.0,
-    num_data=None,
-    parent_output=0.0,
-    lb=None,
-    ub=None,
-):
-    """CalculateSplittedLeafOutput with smoothing + monotone bounds
-    (feature_histogram.hpp:717-755)."""
-    out = leaf_output(g, h, l1, l2, max_delta_step)
-    if path_smooth > 0.0 and num_data is not None:
-        ratio = num_data / path_smooth
-        out = out * ratio / (ratio + 1.0) + parent_output / (ratio + 1.0)
-    if lb is not None:
-        out = jnp.maximum(out, lb)
-    if ub is not None:
-        out = jnp.minimum(out, ub)
-    return out
-
-
-def gain_given_output(g, h, l1: float, l2: float, out):
-    """GetLeafGainGivenOutput (feature_histogram.hpp:739)."""
-    t = threshold_l1(g, l1)
-    return -(2.0 * t * out + (h + l2 + _EPS) * out * out)
-
-
-class CatParams(NamedTuple):
-    """Static categorical-split config (reference: Config fields consumed by
-    FindBestThresholdCategoricalInner, src/treelearner/feature_histogram.cpp:147)."""
-
-    max_cat_to_onehot: int = 4
-    max_cat_threshold: int = 32
-    cat_l2: float = 10.0
-    cat_smooth: float = 10.0
-    min_data_per_group: int = 100
-
-
-def best_split(
-    hist: jnp.ndarray,  # [3, F, B] planes (sum_grad, sum_hess, count)
+def best_split_records(
+    hist: jnp.ndarray,  # [F, B, 3] (sum_grad, sum_hess, count)
     parent_g: jnp.ndarray,
     parent_h: jnp.ndarray,
     parent_cnt: jnp.ndarray,
@@ -167,23 +79,21 @@ def best_split(
     cost_effective_gradient_boosting.hpp DeltaGain — gain is reduced by
     tradeoff*penalty_split*num_data plus a per-feature penalty, here the
     coupled penalty for features not yet used anywhere in the model)."""
-    _, f, b = hist.shape
+    f, b, _ = hist.shape
     use_full_gain = monotone is not None or path_smooth > 0.0
     use_cat = is_cat is not None
 
     has_nan = nan_bins >= 0
-    bin_ids = jnp.arange(b, dtype=jnp.int32)[None, :]
-    is_nan_bin = has_nan[:, None] & (bin_ids == nan_bins[:, None])
-    # single elements are picked out of a plane by a masked sum (one addend
-    # and zeros: exact), never by a gather: on TPU a gather dictates its
-    # operand's layout, and the compiler carries that back through the
-    # children's histograms into the grower's hist_buf carry
-    nan_stats = jnp.sum(jnp.where(is_nan_bin[None], hist, 0.0), axis=2)  # [3, F]
+    nan_idx = jnp.where(has_nan, nan_bins, 0)
+    nan_stats = jnp.take_along_axis(hist, nan_idx[:, None, None], axis=1)[:, 0, :]
+    nan_stats = nan_stats * has_nan[:, None]  # [F, 3]
 
     # zero out the NaN bin so the cumsum covers only ordered numeric bins
-    hist_o = jnp.where(is_nan_bin[None], 0.0, hist)
+    bin_ids = jnp.arange(b, dtype=jnp.int32)[None, :]
+    is_nan_bin = has_nan[:, None] & (bin_ids == nan_bins[:, None])
+    hist_o = jnp.where(is_nan_bin[:, :, None], 0.0, hist)
 
-    cum = jnp.cumsum(hist_o, axis=2)  # [3, F, B] left stats (missing right)
+    cum = jnp.cumsum(hist_o, axis=1)  # [F, B, 3] left stats (missing right)
     parent = jnp.stack(
         [parent_g.astype(jnp.float32), parent_h.astype(jnp.float32), parent_cnt.astype(jnp.float32)]
     )
@@ -201,11 +111,11 @@ def best_split(
         bundled_bin = bundle_end >= 0  # [F, B]
         plane_bundled = bundled_bin.any(axis=1)  # [F]
         cum_end = jnp.take_along_axis(
-            cum, jnp.clip(bundle_end, 0, b - 1)[None], axis=2
-        )  # [3, F, B]
+            cum, jnp.clip(bundle_end, 0, b - 1)[:, :, None], axis=1
+        )  # [F, B, 3]
         cum = jnp.where(
-            bundled_bin[None],
-            parent[:, None, None] - cum_end + cum - hist_o,
+            bundled_bin[:, :, None],
+            parent[None, None, :] - cum_end + cum - hist_o,
             cum,
         )
         valid_bin = jnp.where(plane_bundled[:, None], bundled_bin, valid_bin)
@@ -255,11 +165,11 @@ def best_split(
                 ok = ok & ~violated
         return jnp.where(ok, gain, -jnp.inf)
 
-    def eval_case(left):  # left: [3, F, B] — numeric cumsum candidates
+    def eval_case(left):  # left: [F, B, 3] — numeric cumsum candidates
         return eval_gain(
-            left[0],
-            left[1],
-            left[2],
+            left[..., 0],
+            left[..., 1],
+            left[..., 2],
             lambda_l2,
             valid_bin & num_feature_mask[:, None],
             bnds=adv_bounds,
@@ -267,7 +177,7 @@ def best_split(
 
     gain_right = eval_case(cum)  # missing -> right (default_left = False)
     gain_left = jnp.where(
-        has_nan[:, None], eval_case(cum + nan_stats[:, :, None]), -jnp.inf
+        has_nan[:, None], eval_case(cum + nan_stats[:, None, :]), -jnp.inf
     )  # missing -> left; only distinct when a NaN bin exists
 
     cases = [gain_right, gain_left]
@@ -279,7 +189,7 @@ def best_split(
         # candidates at once; the winning subset is reconstructed as a
         # bin-space bitmask from the sort ranks.
         cp = cat_params if cat_params is not None else CatParams()
-        g_, h_, c_ = hist[0], hist[1], hist[2]
+        g_, h_, c_ = hist[..., 0], hist[..., 1], hist[..., 2]
         # the NaN bin never moves LEFT: prediction sends categorical NaN to
         # the right child (reference CategoricalDecision, tree.h:346), so
         # keeping its rows right during training makes train == predict
@@ -435,14 +345,9 @@ def best_split(
     tbin = (rem % b).astype(jnp.int32)
     best_gain_raw = gains.reshape(-1)[flat]
 
-    win = (jnp.arange(f, dtype=jnp.int32)[:, None] == feat) & (bin_ids == tbin)
-
-    def at_win(planes):  # [3, F, B] -> [3]: the winner's (feat, tbin) element
-        return jnp.sum(jnp.where(win[None], planes, 0.0), axis=(1, 2))
-
-    left = at_win(cum) + jnp.where(dl == 1, nan_stats[:, feat], 0.0)
+    left = cum[feat, tbin] + jnp.where(dl == 1, nan_stats[feat], 0.0)
     if use_cat:
-        left_oh = at_win(hist)
+        left_oh = hist[feat, tbin]
         left_fwd = jnp.stack([pre_g[feat, tbin], pre_h[feat, tbin], pre_c[feat, tbin]])
         left_bwd = jnp.stack([bg[feat, tbin], bh[feat, tbin], bc[feat, tbin]])
         left = jnp.select(

@@ -16,6 +16,8 @@ import jax.numpy as jnp  # noqa: E402
 
 from lightgbm_tpu.ops.split import CatParams, best_split  # noqa: E402
 
+from .planes import planes  # noqa: E402
+
 
 def _np_leaf_gain(g, h, l1, l2):
     t = np.sign(g) * np.maximum(np.abs(g) - l1, 0.0)
@@ -106,7 +108,7 @@ def test_categorical_matches_oracle(num_bins, max_oh):
     oracle_improvement = oracle_gain - _np_leaf_gain(pg, ph, l1, l2)
 
     cand = best_split(
-        jnp.asarray(hist, jnp.float32),
+        jnp.asarray(planes(hist), jnp.float32),
         jnp.float32(pg),
         jnp.float32(ph),
         jnp.float32(pc),
@@ -263,7 +265,7 @@ def test_mixed_numeric_and_categorical():
     # parent; scale only feature 1's association, not its totals
     np.add.at(hist[1, :, 0], catv, grad * 0.99)  # totals now match feature 0
     cand = best_split(
-        jnp.asarray(hist, jnp.float32),
+        jnp.asarray(planes(hist), jnp.float32),
         jnp.float32(grad.sum()),
         jnp.float32(n),
         jnp.float32(n),

@@ -14,6 +14,8 @@ from lightgbm_tpu.ops.histogram import leaf_histogram_segment, leaf_histogram_on
 from lightgbm_tpu.ops.split import best_split, leaf_gain
 from lightgbm_tpu.ops.grower import GrowerParams, grow_tree
 
+from .planes import planes, records
+
 
 def _rand_problem(n=500, f=4, b=16, seed=0):
     rng = np.random.default_rng(seed)
@@ -38,8 +40,8 @@ def _np_histogram(bins, grad, hess, mask, b):
 def test_histogram_matches_numpy(impl):
     bins, grad, hess = _rand_problem()
     mask = (np.arange(len(grad)) % 3 == 0).astype(np.float32)
-    got = np.asarray(impl(jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
-                          jnp.asarray(mask), 16))
+    got = records(impl(jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+                       jnp.asarray(mask), 16))
     want = _np_histogram(bins, grad, hess, mask, 16)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
@@ -87,7 +89,7 @@ def test_best_split_matches_bruteforce():
         cand = jax.tree_util.tree_map(
             np.asarray,
             best_split(
-                jnp.asarray(hist), pg, ph, pc,
+                jnp.asarray(planes(hist)), pg, ph, pc,
                 jnp.asarray(num_bins), jnp.asarray(nan_bins), jnp.asarray(fm),
                 lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=1,
                 min_sum_hessian_in_leaf=0.0, min_gain_to_split=0.0,
@@ -107,10 +109,10 @@ def test_best_split_matches_bruteforce():
 def test_min_data_constraint_respected():
     bins, grad, hess = _rand_problem(n=100, f=2, b=8, seed=7)
     mask = np.ones(100, dtype=np.float32)
-    hist = jnp.asarray(_np_histogram(bins, grad, hess, mask, 8).astype(np.float32))
-    pg, ph, pc = np.asarray(hist[0].sum(axis=0))
+    hist = _np_histogram(bins, grad, hess, mask, 8).astype(np.float32)
+    pg, ph, pc = hist[0].sum(axis=0)
     cand = best_split(
-        hist, pg, ph, pc,
+        jnp.asarray(planes(hist)), pg, ph, pc,
         jnp.asarray([8, 8], dtype=jnp.int32), jnp.asarray([-1, -1], dtype=jnp.int32),
         jnp.ones(2, dtype=bool),
         lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=60,

@@ -81,5 +81,5 @@ def test_int8_kernel_matches_oracle(n, f, b):
     )
     # counts are exactly the masked row counts
     np.testing.assert_array_equal(
-        np.asarray(got)[..., 2].sum(axis=1), np.full(f, mask.sum())
+        np.asarray(got)[2].sum(axis=1), np.full(f, mask.sum())
     )

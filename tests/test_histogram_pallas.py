@@ -42,14 +42,14 @@ def test_pallas_interpret_matches_segment(n, f, b):
             jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess), jnp.asarray(mask), b, interpret=True
         )
     )
-    assert got.shape == (f, b, 3)
+    assert got.shape == (3, f, b)
     # the interpreter evaluates the dot at bf16 precision (the hi/lo residual
     # is lost), so interpret-mode accuracy is ~2^-9 relative; the native MXU
     # path keeps f32 accumulation and is tested at 5e-5 below
     scale = np.abs(ref).max() + 1e-9
     np.testing.assert_allclose(got / scale, ref / scale, atol=4e-3)
     # counts are integral sums of 0/1 — must be exact
-    np.testing.assert_allclose(got[..., 2], ref[..., 2], rtol=0, atol=1e-3)
+    np.testing.assert_allclose(got[2], ref[2], rtol=0, atol=1e-3)
 
 
 @pytest.mark.native_tpu
@@ -62,7 +62,7 @@ def test_pallas_native_matches_segment(n, f, b):
     # n rows stay within a few ulps of the f32 oracle
     scale = np.abs(ref).max() + 1e-9
     np.testing.assert_allclose(got / scale, ref / scale, atol=5e-5)
-    np.testing.assert_allclose(got[..., 2], ref[..., 2], rtol=0, atol=0.01)
+    np.testing.assert_allclose(got[2], ref[2], rtol=0, atol=0.01)
 
 
 @pytest.mark.native_tpu
@@ -93,4 +93,4 @@ def test_uint8_bins_accepted():
     )
     scale = np.abs(ref).max() + 1e-9
     np.testing.assert_allclose(got / scale, ref / scale, atol=4e-3)
-    np.testing.assert_allclose(got[..., 2], ref[..., 2], rtol=0, atol=1e-3)
+    np.testing.assert_allclose(got[2], ref[2], rtol=0, atol=1e-3)

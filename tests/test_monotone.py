@@ -14,6 +14,8 @@ import pytest
 
 import lightgbm_tpu as lgb
 
+from .planes import planes
+
 
 def _make_data(seed=3, n=4000):
     rng = np.random.default_rng(seed)
@@ -190,7 +192,7 @@ def _dup_feature_hist(seed=0, n=2000, b=32):
         np.add.at(hist[j, :, 2], bins, 1.0)
     parent = hist[0].sum(axis=0)
     return (
-        jnp.asarray(hist),
+        jnp.asarray(planes(hist)),
         parent,
         jnp.full((2,), b, np.int32),
         jnp.full((2,), -1, np.int32),

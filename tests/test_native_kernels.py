@@ -197,15 +197,15 @@ def test_seg_hist_int8_native():
     keep = m > 0
     kq = np.rint(g / gs).astype(np.int64) * keep
     hq = np.rint(h / hsc).astype(np.int64) * keep
-    exact = np.zeros((f, 256, 3), np.int64)
+    exact = np.zeros((3, f, 256), np.int64)  # (g, h, count) planes
     for j in range(f):
-        np.add.at(exact[j, :, 0], bins[:, j], kq)
-        np.add.at(exact[j, :, 1], bins[:, j], hq)
-        np.add.at(exact[j, :, 2], bins[:, j], keep.astype(np.int64))
-    assert np.array_equal(out_q[:, :, 2], exact[:, :, 2])
+        np.add.at(exact[0, j], bins[:, j], kq)
+        np.add.at(exact[1, j], bins[:, j], hq)
+        np.add.at(exact[2, j], bins[:, j], keep.astype(np.int64))
+    assert np.array_equal(out_q[2], exact[2])
     # one f32 rounding of (integer sum) * scale
-    np.testing.assert_allclose(out_q[:, :, 0], exact[:, :, 0] * float(gs), rtol=3e-7)
-    np.testing.assert_allclose(out_q[:, :, 1], exact[:, :, 1] * float(hsc), rtol=3e-7)
+    np.testing.assert_allclose(out_q[0], exact[0] * float(gs), rtol=3e-7)
+    np.testing.assert_allclose(out_q[1], exact[1] * float(hsc), rtol=3e-7)
     # and the f32 reference agrees at ITS accuracy
     ref_q = _window_hist_ref(seg_q, start, cnt, f, 256)
     np.testing.assert_allclose(out_q, ref_q, rtol=1e-5, atol=1e-5)
@@ -249,12 +249,12 @@ def test_fused_grow_step_native(k, int8):
     for i, name in enumerate(("seg", "nl", "nr", "child_start", "child_cnt")):
         assert np.array_equal(np.asarray(got[i]), want[i]), name
     hist, ref = np.asarray(got[5]), want[5]
-    assert np.array_equal(hist[..., 2], ref[..., 2]), "counts"
+    assert np.array_equal(hist[:, 2], ref[:, 2]), "counts"
     if int8:
         # per-bin error <= rows_in_bin * half a grid step
         step = np.asarray([6.0 / QMAX, 1.5 / QMAX], np.float32)
-        bound = 0.5 * step * ref[..., 2:3] + 1e-4
-        assert np.all(np.abs(hist[..., :2] - ref[..., :2]) <= bound)
+        bound = 0.5 * step[:, None, None] * ref[:, 2:3] + 1e-4
+        assert np.all(np.abs(hist[:, :2] - ref[:, :2]) <= bound)
     else:
         assert _rel_err(hist, ref) < 5e-6
 
@@ -274,16 +274,16 @@ def test_split_scan_vmapped_native():
               min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0)
     num_bins = rng.integers(b // 2, b + 1, size=f).astype(np.int32)
     nan_bins = np.where(rng.random(f) < 0.5, num_bins - 1, -1).astype(np.int32)
-    hist2 = np.zeros((2, f, b, 3), np.float32)
+    hist2 = np.zeros((2, 3, f, b), np.float32)  # (g, h, count) planes
     for c in range(2):
         g = rng.normal(size=n).astype(np.float32)
         h = rng.random(n).astype(np.float32) + 0.1
         for j in range(f):
             bins = rng.integers(0, num_bins[j], size=n)
-            np.add.at(hist2[c, j, :, 0], bins, g)
-            np.add.at(hist2[c, j, :, 1], bins, h)
-            np.add.at(hist2[c, j, :, 2], bins, 1.0)
-    par2 = hist2[:, 0].sum(axis=1)  # [2, 3] parent (g, h, cnt)
+            np.add.at(hist2[c, 0, j], bins, g)
+            np.add.at(hist2[c, 1, j], bins, h)
+            np.add.at(hist2[c, 2, j], bins, 1.0)
+    par2 = hist2[:, :, 0].sum(axis=-1)  # [2, 3] parent (g, h, cnt)
     fm2 = np.ones((2, f), bool)
     fm2[1, ::3] = False  # the two children see different feature masks
 

@@ -71,12 +71,12 @@ def _hist(mat, n_pad, f, start, cnt, num_bins=B, wide=False):
 
 
 def _numpy_hist(bins, grad, hess, rows, num_bins=B):
-    out = np.zeros((bins.shape[1], num_bins, 3))
+    out = np.zeros((3, bins.shape[1], num_bins))  # (g, h, count) planes
     for j in range(bins.shape[1]):
         col = bins[rows, j]
-        out[j, :, 0] = np.bincount(col, weights=grad[rows], minlength=num_bins)
-        out[j, :, 1] = np.bincount(col, weights=hess[rows], minlength=num_bins)
-        out[j, :, 2] = np.bincount(col, minlength=num_bins)
+        out[0, j] = np.bincount(col, weights=grad[rows], minlength=num_bins)
+        out[1, j] = np.bincount(col, weights=hess[rows], minlength=num_bins)
+        out[2, j] = np.bincount(col, minlength=num_bins)
     return out
 
 

@@ -175,7 +175,7 @@ def test_seg_hist_int8_quantized_exact(packed):
     ref = leaf_histogram_segment(bo, go, ho, mo, 256)
     got = np.asarray(hs_out)
     # counts exact; g/h equal to the integer sums times the scales
-    assert np.array_equal(got[:, :, 2], np.asarray(ref)[:, :, 2])
+    assert np.array_equal(got[2], np.asarray(ref)[2])
     assert np.allclose(got, np.asarray(ref), rtol=1e-6, atol=1e-6)
 
 
@@ -418,7 +418,7 @@ def test_wide_seg_hist_int8_quantized(packed_wide):
     bo, go, ho, mo, _ = unpack_stats(seg[:, 17:17 + 1500], p["f"], wide=True)
     ref = leaf_histogram_segment(bo, go, ho, mo, p["b"])
     got = np.asarray(out)
-    assert np.array_equal(got[:, :, 2], np.asarray(ref)[:, :, 2])
+    assert np.array_equal(got[2], np.asarray(ref)[2])
     assert np.allclose(got, np.asarray(ref), rtol=1e-6, atol=1e-6)
 
 
@@ -481,12 +481,10 @@ def test_seg_hist_int8_default_error_bound(packed):
     ))
     bo, go, ho, mo, _ = unpack_stats(p["seg"][:, 17:17 + 3000], p["f"])
     ref = np.asarray(leaf_histogram_segment(bo, go, ho, mo, 256))
-    cnt = ref[:, :, 2]
-    assert np.array_equal(got[:, :, 2], cnt)  # counts are exact
-    assert (np.abs(got[:, :, 0] - ref[:, :, 0])
-            <= 0.5 * float(gs) * cnt + 1e-6).all()
-    assert (np.abs(got[:, :, 1] - ref[:, :, 1])
-            <= 0.5 * float(hs) * cnt + 1e-6).all()
+    cnt = ref[2]
+    assert np.array_equal(got[2], cnt)  # counts are exact
+    assert (np.abs(got[0] - ref[0]) <= 0.5 * float(gs) * cnt + 1e-6).all()
+    assert (np.abs(got[1] - ref[1]) <= 0.5 * float(hs) * cnt + 1e-6).all()
 
 
 def test_seg_hist_live_plane_skip_interpret(packed):
@@ -511,8 +509,8 @@ def test_seg_hist_live_plane_skip_interpret(packed):
         p["seg"], jnp.asarray([17, 3000], jnp.int32), live=live,
         f=p["f"], num_bins=256, n_pad=p["n_pad"], interpret=True,
     ))
-    np.testing.assert_array_equal(got[:gb], full[:gb])  # live group intact
-    assert (got[gb:] == 0.0).all()  # dead group fully skipped
+    np.testing.assert_array_equal(got[:, :gb], full[:, :gb])  # live group intact
+    assert (got[:, gb:] == 0.0).all()  # dead group fully skipped
     all_live = np.asarray(seg_hist_pallas(
         p["seg"], jnp.asarray([17, 3000], jnp.int32),
         live=jnp.ones((ng,), jnp.int32),
@@ -560,7 +558,7 @@ def test_seg_hist_cpu_windowed_parity(packed_big, st, cnt):
     )
     # counts must be exact (integral sums of the same values)
     np.testing.assert_array_equal(
-        np.asarray(got)[:, :, 2], np.asarray(want)[:, :, 2]
+        np.asarray(got)[2], np.asarray(want)[2]
     )
 
 
@@ -663,15 +661,15 @@ def test_two_digit_onehot_equals_full_onehot_and_reference(
         ref = np.asarray(seg_hist_ref(
             seg, jnp.asarray([st, cnt], jnp.int32), f=f, num_bins=num_bins,
             n_pad=n_pad))
-        np.testing.assert_array_equal(got[0][i][..., 2], ref[..., 2])
+        np.testing.assert_array_equal(got[0][i][2], ref[2])
         if not quantized:  # three-term bf16 split: ~26-bit addends
             assert np.abs(got[0][i] - ref).max() <= 5e-6 * max(
                 1e-9, np.abs(ref).max())
     # K = 1 is K = 4's member: the dead group's features zero, the live whole
     ng, gb = hist_ngroups(f, bpad), hist_group(f, bpad)
     live_f = gb * (ng - 1) if ng > 1 else f
-    np.testing.assert_array_equal(got[1][0][:live_f], got[0][1][:live_f])
-    assert (got[1][0][live_f:] == 0).all()
+    np.testing.assert_array_equal(got[1][0][:, :live_f], got[0][1][:, :live_f])
+    assert (got[1][0][:, live_f:] == 0).all()
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
@@ -693,7 +691,7 @@ def test_wide_two_digit_onehot_equals_full_onehot(f, num_bins, digits,
     ref = np.asarray(seg_hist_ref(
         seg, jnp.asarray(_WINDOWS[1], jnp.int32), f=f, num_bins=num_bins,
         n_pad=n_pad, wide=True))
-    np.testing.assert_array_equal(got[0][1][..., 2], ref[..., 2])
+    np.testing.assert_array_equal(got[0][1][2], ref[2])
     if not quantized:
         assert np.abs(got[0][1] - ref).max() <= 5e-6 * np.abs(ref).max()
 

@@ -12,6 +12,8 @@ import jax.numpy as jnp  # noqa: E402
 from lightgbm_tpu.ops.pallas.split_scan import fused_best_split  # noqa: E402
 from lightgbm_tpu.ops.split import best_split  # noqa: E402
 
+from .planes import planes  # noqa: E402
+
 
 def _leaf_problem(n, f, b, seed=0, nan_frac=0.0):
     rng = np.random.default_rng(seed)
@@ -57,11 +59,11 @@ def test_fused_matches_best_split(hp, n, f, b, nan_frac):
     )
     mask = jnp.ones((f,), bool)
     want = best_split(
-        jnp.asarray(hist), parent[0], parent[1], parent[2],
+        jnp.asarray(planes(hist)), parent[0], parent[1], parent[2],
         jnp.asarray(num_bins), jnp.asarray(nan_bins), mask, **hp,
     )
     got = fused_best_split(
-        jnp.asarray(hist), parent[0], parent[1], parent[2],
+        jnp.asarray(planes(hist)), parent[0], parent[1], parent[2],
         jnp.asarray(num_bins), jnp.asarray(nan_bins), mask,
         interpret=True, **hp,
     )
@@ -85,7 +87,7 @@ def test_fused_matches_best_split(hp, n, f, b, nan_frac):
 def test_fused_no_valid_split_returns_neg_inf():
     hist, parent, num_bins, nan_bins = _leaf_problem(30, 4, 16, seed=2)
     got = fused_best_split(
-        jnp.asarray(hist), parent[0], parent[1], parent[2],
+        jnp.asarray(planes(hist)), parent[0], parent[1], parent[2],
         jnp.asarray(num_bins), jnp.asarray(nan_bins),
         jnp.ones((4,), bool),
         lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=10_000,
@@ -212,7 +214,7 @@ def test_near_tie_flip_rate_bounded():
             best, second = flat[0], flat[1]
             rel_gap = (best - second) / abs(best)
             fo, to = divmod(int(np.argmax(gain64.ravel())), B)
-            hist32 = jnp.asarray(hist64.astype(np.float32))
+            hist32 = jnp.asarray(planes(hist64.astype(np.float32)))
             picks = {}
             w = best_split(hist32, parent[0], parent[1], parent[2],
                            nb, nanb, mask, **hp)
@@ -259,7 +261,7 @@ def test_with_margin_matches_oracle_gap():
         gain64 = _oracle_gains64(hist64, parent)
         flat = np.sort(gain64.ravel())[::-1]
         rel_gap = (flat[0] - flat[1]) / abs(flat[0])
-        hist32 = jnp.asarray(hist64.astype(np.float32))
+        hist32 = jnp.asarray(planes(hist64.astype(np.float32)))
         _, mx = best_split(hist32, parent[0], parent[1], parent[2],
                            nb, nanb, mask, with_margin=True, **hp)
         _, mf = fused_best_split(hist32, parent[0], parent[1], parent[2],
@@ -361,8 +363,8 @@ def test_int8_default_near_tie_zero_flips():
             fo, to = divmod(int(np.argmax(gain64.ravel())), B)
             hq = _int8_hist(rows, B)
             pq = hq[0].sum(axis=0)  # grower totals come from the int8 hist
-            hq32 = jnp.asarray(hq.astype(np.float32))
-            h32 = jnp.asarray(hist64.astype(np.float32))
+            hq32 = jnp.asarray(planes(hq.astype(np.float32)))
+            h32 = jnp.asarray(planes(hist64.astype(np.float32)))
             for eng, scan in (
                 ("xla", lambda *a, **k: best_split(*a, **k)),
                 ("fused", lambda *a, **k: fused_best_split(
@@ -459,7 +461,7 @@ def _dup_hist(seed=0, n=2000, b=32):
         np.add.at(hist[j, :, 2], bins, 1.0)
     parent = hist[0].sum(axis=0)
     return (
-        jnp.asarray(hist), parent, jnp.full((2,), b, np.int32),
+        jnp.asarray(planes(hist)), parent, jnp.full((2,), b, np.int32),
         jnp.full((2,), -1, np.int32), jnp.ones((2,), bool),
     )
 

@@ -17,6 +17,7 @@ Usage: JAX_PLATFORMS=cpu python tools/aot_check.py [filter]
 import contextlib
 import functools
 import os
+import re
 import sys
 import traceback
 
@@ -299,7 +300,7 @@ def _c10(topo):
 
     return compile_on_topo(
         topo, split_scan_pallas,
-        s((28, 256, 3), jnp.float32), s((3,), jnp.float32),
+        s((3, 28, 256), jnp.float32), s((3,), jnp.float32),
         s((28,), jnp.int32), s((28,), jnp.int32), s((28,), jnp.float32),
         f=28, num_bins_pad=256, l1=0.1, l2=1.0, min_data=20, min_hess=1e-3,
     )
@@ -319,7 +320,7 @@ def _c11(topo):
 
     return compile_on_topo(
         topo, call,
-        s((2, 28, 256, 3), jnp.float32), s((2, 3), jnp.float32),
+        s((2, 3, 28, 256), jnp.float32), s((2, 3), jnp.float32),
         s((28,), jnp.int32), s((28,), jnp.int32), s((2, 28), jnp.float32),
     )
 
@@ -500,11 +501,39 @@ _PROGRAMS = {
 }
 
 
+_HLO_VALUE = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = f32\[([\d,]+)\](?:\{([\d,]*))?\S* ([\w-]+)\(", re.M
+)
+
+
+def hist_form_faults(text, features, leaves=255, bins=256):
+    """What the optimized HLO of a grow program says against the histogram's
+    form ([.., 3, F, B] planes; ``hist_buf`` [L + 1, 3, F, B] updated in
+    place).  Two things: a ``copy`` as large as ``hist_buf`` (a read of the
+    old loop carry after its first write costs one out and one back, every
+    split), and an f32 value as large as one histogram whose minor-most
+    dimension, as laid out, is the stat axis (length 3: whatever touches it
+    runs on 3 of 128 lanes)."""
+    one_hist = 3 * features * bins
+    faults = set()
+    for dims, layout, op in _HLO_VALUE.findall(text):
+        shape = [int(d) for d in dims.split(",")]
+        size = int(np.prod(shape))
+        if op == "copy" and size >= leaves * one_hist:
+            faults.add(f"a copy as large as hist_buf: f32[{dims}]")
+        minor = shape[int(layout.split(",")[0])] if layout else shape[-1]
+        if minor == 3 and size >= one_hist:
+            faults.add(f"stat axis on the lanes: f32[{dims}]{{{layout}}} {op}")
+    return sorted(faults)
+
+
 def _checked_grow_program(topo, **kw):
     compiled = _grow_program(topo, **kw)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert kw.get("n_devices", 1) == 1 or "all-reduce" in text
+    faults = hist_form_faults(text, kw.get("features", 28))
+    assert not faults, faults
     return compiled
 
 
