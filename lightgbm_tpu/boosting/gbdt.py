@@ -412,7 +412,7 @@ class Booster:
         pend = []
         for kk in range(k):
             if self._class_need_train[kk] and self._bins.shape[1] > 0:
-                qg, qh = self._quant_grow_inputs(grad[kk], hess[kk])
+                qg, qh = self._quant_grow_inputs(grad[kk], hess[kk], kk)
                 ta, leaf_id = self._grow_one(
                     qg,
                     qh,
@@ -1034,10 +1034,15 @@ class Booster:
             else jnp.ones((f_used,), jnp.float32)
         )
 
-    def _quant_grow_inputs(self, grad_k, hess_k):
+    def _quant_seed(self) -> np.uint32:
+        """The seed word of the rounding draws (ops.quantize.rounding_uniforms)."""
+        return np.uint32((self.config.seed or 0) & 0xFFFFFFFF)
+
+    def _quant_grow_inputs(self, grad_k, hess_k, kk: int):
         """Quantized-gradient training (GradientDiscretizer): tree growth
         sees grid-quantized gradients; leaf values are renewed from the true
-        ones afterwards when quant_train_renew_leaf."""
+        ones afterwards when quant_train_renew_leaf.  The launch scan calls
+        the same two functions inside its body (boosting/launch.py)."""
         cfg = self.config
         if not cfg.use_quantized_grad:
             return grad_k, hess_k
@@ -1046,15 +1051,22 @@ class Booster:
         qg, qh, g_scale, h_scale = quantize_gradients(
             grad_k,
             hess_k,
-            self._next_rng(),
+            self._quant_seed(),
+            np.int32(self._iter * self.num_tree_per_iteration + kk),
+            **self._quant_static(),
+        )
+        self._quant_scales = (g_scale, h_scale)  # for the integer kernels
+        return qg, qh
+
+    def _quant_static(self) -> Dict[str, Any]:
+        cfg = self.config
+        return dict(
             num_bins=cfg.num_grad_quant_bins,
             stochastic=cfg.stochastic_rounding,
             constant_hessian=bool(
                 self.objective is not None and self.objective.is_constant_hessian
             ),
         )
-        self._quant_scales = (g_scale, h_scale)  # for the int8 histogram
-        return qg, qh
 
     def _quant_renew(self, ta, leaf_id, grad_k, hess_k, mask):
         """RenewIntGradTreeOutput (gradient_discretizer.cpp:209) on device."""
@@ -1078,13 +1090,11 @@ class Booster:
         return ta._replace(leaf_value=lv)
 
     def _quant_scales_arg(self):
-        """Concrete scales operand for shard_map (the int8-without-
-        quantized-gradients config error is raised once at
-        _make_grower_params time)."""
-        scales = getattr(self, "_quant_scales", None)
-        if scales is None:
-            return (jnp.float32(1.0), jnp.float32(1.0))  # unused dummy
-        return scales
+        """The scales operand of the sharded grower: this tree's, or None
+        for a booster whose gradients are not on the grid (shard_map takes
+        the empty subtree; grow_tree engages the integer kernels on any
+        scales it is given)."""
+        return getattr(self, "_quant_scales", None)
 
     def _grow_one(self, grad_k, hess_k, mask, feature_mask, rng):
         """Grow one tree: serial grow_tree or the mesh-sharded shard_map path
@@ -1194,7 +1204,7 @@ class Booster:
             rng=rng,
             is_cat=self._is_cat,
             forced=self._forced,
-            quant_scales=getattr(self, "_quant_scales", None),
+            quant_scales=self._quant_scales_arg(),
             bundle_end=self._bundle_end,
             feature_contri=self._feature_contri,
         )
@@ -1321,14 +1331,16 @@ class Booster:
     def _seg_span_args(self) -> Dict[str, int]:
         """Plane groups of the packed row and the planes a group, and the
         histogram kernel's two-digit one-hot ("HxL", "1x<bpad>" where it
-        resolves to the full one-hot) with the features a matmul takes, for
-        the ``train/iteration`` and ``train/launch`` spans (none off the
-        segment path)."""
+        resolves to the full one-hot) with the features a matmul takes,
+        whether it is the kernel's integer form, and the gradient levels of
+        quantized training (0 when off), for the ``train/iteration`` and
+        ``train/launch`` spans (none off the segment path)."""
         p = getattr(self, "_grower_params", None)
         if p is None or p.hist_mode != "seg" or self.train_set is None:
             return {}
         from ..ops.pallas.seg import (
             group_shape, hist_bpad, hist_digits, hist_feature_block,
+            seg_int8_dispatch,
         )
 
         f = int(self._bins.shape[1]) // max(self._featpar or 1, 1)
@@ -1337,10 +1349,21 @@ class Booster:
         g, sub = group_shape(f, p.max_bin > 256)
         bpad = hist_bpad(p.max_bin)
         high, low = hist_digits(bpad)
+        cfg = self.config
         return {
             "seg_groups": g, "seg_group_planes": sub,
             "hist_digits": f"{high}x{low}",
             "hist_feature_block": hist_feature_block(f, bpad),
+            # which form of the histogram kernel: int8 operands and int32
+            # sums (quantized gradients, or hist_acc's int8 grid) or the
+            # three-term bf16 split
+            "hist_int8": bool(
+                (cfg.use_quantized_grad and seg_int8_dispatch())
+                or self._int8_engaged()
+            ),
+            "grad_quant_bins": (
+                int(cfg.num_grad_quant_bins) if cfg.use_quantized_grad else 0
+            ),
         }
 
     def _make_grower_params(self) -> GrowerParams:
@@ -1357,8 +1380,9 @@ class Booster:
         n_used = int(self._bins.shape[1]) if self.train_set else 0
         import jax as _jax
 
-        # the ONE config-time validation for int8 kernels (both seg and
-        # ordered paths; _quant_scales_arg relies on this running first)
+        # the ONE config-time validation for the explicit int8 kernel choice
+        # (the ordered path's; the seg path takes the integer kernels for
+        # any quantized booster, whatever hist_method says)
         if hist_method.startswith("pallas_int8") and not cfg.use_quantized_grad:
             raise ValueError(
                 "hist_method='pallas_int8' needs quantized gradients "
@@ -1572,6 +1596,8 @@ class Booster:
             ),
             # histogram engine v2: int8-by-default accumulation on the seg
             # TPU path ('auto'/'int8'), near-tie f32 re-accumulate tolerance
+            # no say under use_quantized_grad: gradients on the grid take
+            # the integer kernels (grow_tree, quant_scales)
             hist_acc=cfg.hist_acc,
             near_tie_tol=cfg.hist_near_tie_tol,
         )
@@ -2220,7 +2246,7 @@ class Booster:
         fleet trainer can substitute one batched grow for M solo grows and
         still reuse the per-member commit path unchanged."""
         cfg = self.config
-        qg, qh = self._quant_grow_inputs(grad[kk], hess[kk])
+        qg, qh = self._quant_grow_inputs(grad[kk], hess[kk], kk)
         ta, leaf_id = self._grow_one(qg, qh, mask, feature_mask, rng)
         ta = self._quant_renew(ta, leaf_id, grad[kk], hess[kk], mask)
         # two bulk transfers instead of ~14 small ones (remote TPU
@@ -3992,8 +4018,9 @@ class Booster:
         extra-trees thresholds than the uninterrupted 20-iteration run.
         Replaying the exact draw order of _update_impl — one gradient split,
         one bagging split (plus the BaggingStrategy mask refresh), then per
-        trained class one quantize split and one tree split when those
-        features are active — makes the continuation byte-identical.
+        trained class one tree split when bynode sampling or extra trees
+        are active — makes the continuation byte-identical (quantized
+        gradients draw no key: ops.quantize.rounding_uniforms).
         (Custom-fobj runs draw no gradient split and are not replayable.)
         """
         if not hasattr(self, "_rng"):
@@ -4007,8 +4034,6 @@ class Booster:
             else 0
         )
         per_class = 0
-        if cfg.use_quantized_grad:
-            per_class += 1  # _quant_grow_inputs
         if cfg.feature_fraction_bynode < 1.0 or cfg.extra_trees:
             per_class += 1  # _tree_rng
         for it in range(start_iter, start_iter + n_iters):

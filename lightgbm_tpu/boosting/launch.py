@@ -63,6 +63,7 @@ from ..obs.registry import get_session
 from ..obs.device import sample_device_memory
 from ..obs.trace import get_tracer
 from ..ops.grower import _pack_tree_arrays_impl, grow_tree, unpack_tree_arrays
+from ..ops.quantize import quantize_gradients, renew_leaf_values
 from ..resilience import NumericsError, chaos
 from ..utils.log import log_warning
 
@@ -103,9 +104,7 @@ def launch_ineligible_reason(booster) -> Optional[str]:
     The exclusions mirror the fleet trainer's: paths with per-iteration
     host work woven into the update (renew_tree_output's host leaf
     renewal, linear-tree least squares, CEGB's host-side used-feature
-    latch), per-iteration host RNG the scan cannot reproduce
-    (quantized-gradient stochastic rounding draws a key inside
-    ``_quant_grow_inputs``), subclassed boosting schedules (dart's drop
+    latch), subclassed boosting schedules (dart's drop
     state, rf's bag-of-iterations), multi-process feeding, and armed
     chaos drills (their kill/poison hooks are host-gated per iteration).
     ``hist_mode='seg'`` stays ELIGIBLE: the scan traces the two-launch
@@ -125,8 +124,6 @@ def launch_ineligible_reason(booster) -> Optional[str]:
         )
     if cfg.linear_tree:
         return "linear_tree fits leaf models on host each iteration"
-    if cfg.use_quantized_grad:
-        return "use_quantized_grad draws a host RNG key per iteration"
     if getattr(booster, "_cegb_coupled", None) is not None:
         return "CEGB updates its used-feature penalty on host each iteration"
     if getattr(booster, "_multiproc", False):
@@ -268,11 +265,12 @@ class LaunchRunner:
 
     # ----------------------------------------------------------- trace body
 
-    def _grow(self, bins, g, h, mask, fm, tkey):
+    def _grow(self, bins, g, h, mask, fm, tkey, quant_scales):
         """Per-class grow inside the scan body: the mesh-sharded shard_map
         path (unchanged executable semantics — shard_map traces cleanly
         under scan) or serial ``grow_tree`` with the fused dispatcher
-        forced to its XLA oracle."""
+        forced to its XLA oracle.  ``quant_scales``: the traced scales of
+        this tree's quantized gradients, or None."""
         b = self._b
         if b._mesh is not None:
             return b._sharded_grow(
@@ -289,7 +287,7 @@ class LaunchRunner:
                 b._iscat_arg,
                 b._forced,
                 *b._cegb_args(),
-                b._quant_scales_arg(),
+                quant_scales,
                 b._bundle_end_arg,
                 b._contri_arg,
             )
@@ -307,7 +305,7 @@ class LaunchRunner:
             rng=tkey,
             is_cat=b._is_cat,
             forced=b._forced,
-            quant_scales=None,
+            quant_scales=quant_scales,
             bundle_end=b._bundle_end,
             feature_contri=b._feature_contri,
         )
@@ -325,6 +323,8 @@ class LaunchRunner:
         fold_bag = "bagging_seed" in cfg.raw
         need_tkey = bool(cfg.feature_fraction_bynode < 1.0 or cfg.extra_trees)
         fold_extra = bool(cfg.extra_trees and "extra_seed" in cfg.raw)
+        quantized = bool(cfg.use_quantized_grad)
+        renew = quantized and bool(cfg.quant_train_renew_leaf)
 
         def step(carry, xs):
             score, rng, bag, finished, bad = carry
@@ -380,9 +380,23 @@ class LaunchRunner:
                     rng_cur, tkey = pair[0], pair[1]
                     if fold_extra:
                         tkey = jax.random.fold_in(tkey, cfg.extra_seed)
-                ta, leaf_id = self._grow(
-                    bins, grad[kk], hess[kk], mask, fm, tkey
-                )
+                # quantized-gradient training (serial: _quant_grow_inputs,
+                # _quant_renew): the same two functions, the tree's index
+                # traced, so every row rounds as in the per-iteration loop
+                g_k, h_k, scales = grad[kk], hess[kk], None
+                if quantized:
+                    g_k, h_k, *scales = quantize_gradients(
+                        g_k, h_k, b._quant_seed(), it * k + kk,
+                        **b._quant_static(),
+                    )
+                ta, leaf_id = self._grow(bins, g_k, h_k, mask, fm, tkey, scales)
+                if renew:
+                    ta = ta._replace(leaf_value=renew_leaf_values(
+                        leaf_id, grad[kk], hess[kk], mask, ta.num_leaves,
+                        self._L, cfg.lambda_l1, cfg.lambda_l2,
+                        cfg.max_delta_step,
+                        measure=self._params.measure_collectives,
+                    ))
                 has_split = ta.num_leaves > 1
                 upd = jnp.logical_and(live_step, has_split)
                 with jax.named_scope("score_update"):

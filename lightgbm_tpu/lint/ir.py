@@ -387,7 +387,7 @@ def _grow_operands(n_local: int, f: int):
         None,  # forced
         _sds((f,), f32),  # cegb_penalty (dummy)
         _sds((f,), jnp.bool_),  # cegb_used (dummy)
-        (_sds((), f32), _sds((), f32)),  # quant_scales (dummy)
+        None,  # quant_scales (not a quantized booster)
         _sds((1, 1), i32),  # bundle_end (dummy)
         _sds((f,), f32),  # feature_contri (dummy)
     )
@@ -681,9 +681,12 @@ def build_entry_specs() -> List[EntrySpec]:
     # ---- quantized training entries (perf-gate quantized scenario)
     def build_quantize():
         fn = quantize_mod.quantize_gradients
-        args = (_sds((N,), f32), _sds((N,), f32), _sds((2,), jnp.uint32))
+        args = (
+            _sds((N,), f32), _sds((N,), f32), _sds((), jnp.uint32),
+            _sds((), i32),
+        )
         return (
-            lambda g, h, r: fn(g, h, r, num_bins=4, stochastic=True),
+            lambda g, h, s, t: fn(g, h, s, t, num_bins=4, stochastic=True),
             args,
             {},
         )

@@ -164,6 +164,8 @@ class GrowerParams:
     # f32 grads quantized once per iteration, seg.QMAX grid) with an f32
     # re-accumulate pass for near-tie split decisions; "bf16" keeps the
     # 3-term bf16 split everywhere; "int8" is "auto" without the opt-out.
+    # Does not apply to quantized-gradient training (quant_scales): values
+    # already on an integer grid take the integer kernels, exactly.
     hist_acc: str = "auto"
     # relative gain gap below which the int8-default winner is considered
     # a near tie and its histogram is re-accumulated in f32 before the
@@ -325,7 +327,7 @@ def int8_acc_eligible(
         # feature slices, and the near-tie with_margin re-scan is not
         # plumbed through the feature-parallel election
         return False
-    return jax.default_backend() == "tpu" or _seg_mod._INTERPRET
+    return _seg_mod.seg_int8_dispatch()
 
 
 def live_plane_fraction(
@@ -756,7 +758,8 @@ def grow_tree(
     forced: Optional[Tuple] = None,  # (leaf, feat, bin, is_cat) arrays [n_forced]
     cegb_penalty: Optional[jnp.ndarray] = None,  # [F] f32 (use_cegb)
     cegb_used: Optional[jnp.ndarray] = None,  # [F] bool — already-bought features
-    quant_scales=None,  # (g_scale, h_scale) for hist_method='pallas_int8'
+    quant_scales=None,  # (g_scale, h_scale) of quantized-gradient training:
+    #   grad/hess are integer multiples of them (ops/quantize.py)
     bundle_end: Optional[jnp.ndarray] = None,  # [F, B] i32 — EFB sub-range
     #   ends per plane bin (bundling.py / ops/split.py), -1 off-bundle
     feature_contri: Optional[jnp.ndarray] = None,  # [F] f32 gain multipliers
@@ -1103,13 +1106,11 @@ def grow_tree(
                 "leaf_batch=1"
             )
 
-        # explicit int8 opt-in (hist_method='pallas_int8' + quantized
-        # gradients): integer grid accumulation, exact and ~2x throughput
-        seg_qs = (
-            quant_scales
-            if (p.hist_method.startswith("pallas_int8") and quant_scales is not None)
-            else None
-        )
+        # quantized-gradient training: the values are integer multiples of
+        # the scales, so the histogram kernels take their integer form
+        # (int8 operands, exact int32 sums) whatever hist_method and
+        # hist_acc say — neither applies to gradients already on the grid
+        seg_qs = quant_scales
         # histogram engine v2: int8 2-digit accumulation is the DEFAULT on
         # the single-host seg TPU path — the true f32 grads are scaled onto
         # the QMAX grid once per iteration and every histogram launch runs

@@ -148,6 +148,13 @@ QMAX = 127 * 128
 _INTERPRET = False
 
 
+def seg_int8_dispatch() -> bool:
+    """Whether ``seg_hist`` given scales runs the integer kernels in this
+    process (a TPU, or the interpret hook): elsewhere the reference path
+    sums the same values in f32."""
+    return jax.default_backend() == "tpu" or _INTERPRET
+
+
 def bin_lanes(f: int, wide: bool = False) -> int:
     """i16 lanes holding bins: byte-packed two per plane normally, one u16
     plane per feature when max_bin > 256 (``wide`` — the reference's

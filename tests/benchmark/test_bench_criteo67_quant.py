@@ -1,0 +1,216 @@
+"""What PR 33 added to the benchmark: the ``criteo67-quant`` configuration
+(LightGBM 4's quantized training on the Criteo-shaped table), its cell
+``criteo67-quant.fit``, a plain reference that discretizes by itself, and the readers
+``grad_quantize_ms_per_iter`` and ``leaf_renew_ms_per_iter``.  Here: the
+cells' parameters, the program's rounding draws against the reference's NumPy
+mix bit for bit, the system against the reference on a rehearsal, the control
+and the deterministic-rounding fault failing it, and the readers on a
+hand-built trace."""
+
+import json
+import os
+
+import numpy as np
+import pytest
+
+from benchmark import contract, data as bdata, run as brun
+from benchmark.trace_reduce import Op, Trace
+
+NEW_METRICS = {"grad_quantize_ms_per_iter", "leaf_renew_ms_per_iter"}
+
+
+def _reference():
+    return contract.load_module(
+        contract.Manifest().reference_path("criteo67-quant"),
+        "benchmark_reference_criteo67_quant")
+
+
+def _reader(metric):
+    return contract.load_module(
+        os.path.join(contract.BENCH_DIR, "layers", metric + ".py"),
+        "benchmark_layer_" + metric)
+
+
+def _names(cell):
+    return {m["name"] for m in cell.per_layer}
+
+
+def test_quant_cell_is_the_issue_s():
+    m = contract.Manifest()
+    cell = m.cell("criteo67-quant.fit")
+    cfg, base = cell.config, m.cell("criteo67.fit").config
+    assert (cfg["rows_per_chip"], cfg["features"], cell.chips) == (8_000_000, 67, 1)
+    params = dict(cfg["params"])
+    assert isinstance(params.pop("seed"), int)
+    assert params == {
+        **{k: v for k, v in base["params"].items() if k != "hist_acc"},
+        "use_quantized_grad": True, "num_grad_quant_bins": 4,
+        "quant_train_renew_leaf": True, "stochastic_rounding": True,
+    }
+    # no engine knob: the program resolves the segment path, the scan and the
+    # integer kernels by itself
+    assert not set(params) & {"hist_mode", "hist_method", "hist_acc", "grow_fused",
+                              "train_steps_per_launch"}
+    assert cfg["data"] == base["data"]
+    entry = next(c for c in m.doc["configs"] if c["name"] == "criteo67-quant")
+    assert entry["reduced"] == ["rows_per_chip"] and entry["source"] == cfg["source"]
+    assert cell.job["name"] == "fit" and cell.job["expect"] == {
+        "hist_mode": "seg", "launch_steps": 8}
+    assert set(cfg["limits"]) == {
+        "count_mismatch", "floor_violation", "leaf_value_rms_gap",
+        "split_gain_rms_gap", "split_regret"}
+    assert cfg["limits"]["count_mismatch"] == 0 and cfg["limits"]["floor_violation"] == 0
+    assert set(cfg["limits_why"]) == set(cfg["limits"])  # each limit with its reason
+    # the launch-scan cells' metrics, and its own two
+    assert _names(cell) == _names(m.cell("criteo67.fit")) | NEW_METRICS
+
+
+def test_the_benchmark_has_five_cells_on_one_chip():
+    """``higgs.fit-eval`` was measured with this PR and left queued: its rate
+    spread 0.61 % and 0.37 % in two sets of six runs, and a new cell is
+    admitted under half the 1 % bound (PERF.md section 7)."""
+    m = contract.Manifest()
+    assert m.workload_names() == [
+        "criteo67.fit-eval", "higgs.fit", "epsilon.fit-eval", "criteo67.fit",
+        "criteo67-quant.fit"]
+    assert all(m.cell(n).chips == 1 for n in m.workload_names())
+
+
+@pytest.mark.parametrize("old", ["higgs.fit", "criteo67.fit", "criteo67.fit-eval",
+                                 "epsilon.fit-eval"])
+def test_new_metrics_are_the_new_cell_s_alone(old):
+    m = contract.Manifest()
+    assert not _names(m.cell(old)) & NEW_METRICS
+
+
+@pytest.mark.parametrize("seed,tree", [(0, 0), (20261003, 2), (2_147_483_659, 7),
+                                       (4_294_967_295, 100_000)])
+def test_program_draws_equal_the_reference_mix_bit_for_bit(seed, tree):
+    """250,000 rows a case and stream: a million (seed, tree, row) triples in all."""
+    from lightgbm_tpu.ops.quantize import rounding_uniforms
+
+    ref = _reference()
+    for stream in (0, 1):
+        got = np.asarray(rounding_uniforms(np.uint32(seed), np.int32(tree), 250_000, stream))
+        want = ref.rounding_uniforms(seed, tree, 250_000, stream)
+        assert got.dtype == np.float32 and np.array_equal(got.astype(np.float64), want)
+
+
+def test_program_levels_equal_the_reference_levels():
+    """float32 against float64: the same levels, but for a row whose g / s + u
+    lies within float32 rounding of a whole number."""
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops.quantize import quantize_gradients
+
+    ref = _reference()
+    rng = np.random.default_rng(1)
+    p = 1.0 / (1.0 + np.exp(-rng.normal(size=200_000)))
+    y = (rng.random(200_000) < 0.5).astype(np.float64)
+    g, h = (p - y).astype(np.float32), (p * (1 - p)).astype(np.float32)
+    params = {"num_grad_quant_bins": 4, "seed": 5}
+    qg, qh, gs, hs = quantize_gradients(jnp.asarray(g), jnp.asarray(h), np.uint32(5), np.int32(3))
+    k_g, k_h, s_g, s_h = ref.discretize(g.astype(np.float64), h.astype(np.float64), params, 3)
+    assert float(gs) == pytest.approx(s_g, rel=1e-6) and float(hs) == pytest.approx(s_h, rel=1e-6)
+    got_g = np.rint(np.asarray(qg) / float(gs))
+    got_h = np.rint(np.asarray(qh) / float(hs))
+    assert np.abs(got_g - k_g).max() <= 1 and (got_g != k_g).sum() <= 4
+    assert np.abs(got_h - k_h).max() <= 1 and (got_h != k_h).sum() <= 4
+    assert set(np.unique(k_g)) <= {-2.0, -1.0, 0.0, 1.0, 2.0} and k_h.max() <= 4
+
+
+# ------------------------------------------------ the run, on a rehearsal
+
+
+@pytest.fixture
+def small(monkeypatch):
+    monkeypatch.setattr(brun, "REHEARSE_ROWS", 24_000)
+    monkeypatch.setattr(bdata, "BLOCK_ROWS", 6_000)
+
+
+def _run(capsys, workload, *extra, seed=2_147_483_777):
+    rc = brun.main(["--workload", workload, "--seed", str(seed), "--seconds", "0.5",
+                    "--trace", "0", "--rehearse", *extra])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0 and out, rc
+    return json.loads(out[-1])
+
+
+def failing(line):
+    return sorted(n for n, c in line["checks"].items() if c["value"] > c["limit"])
+
+
+@pytest.mark.parametrize("seed", [2_147_483_777, 7])
+def test_sound_rehearsal_reads_every_number_under_its_limit(capsys, small, seed):
+    workload = "criteo67-quant.fit"
+    line = _run(capsys, workload, seed=seed)
+    assert line["correct"] is True and line["failed"] == 0, failing(line)
+    want = set(contract.Manifest().cell(workload).config["limits"])
+    assert want <= set(line["checks"])
+    assert line["checks"]["count_mismatch"]["value"] == 0
+
+
+def test_the_coarser_grid_control_fails_the_limits(capsys, small):
+    line = _run(capsys, "criteo67-quant.fit", "--control", "coarser")
+    limits = contract.Manifest().cell("criteo67-quant.fit").config["limits"]
+    control = line["facts"]["control"]
+    over = [n for n, v in control.items() if v > limits[n]]
+    assert "split_gain_rms_gap" in over, control
+    assert line["correct"] is True  # the run's own numbers are sound
+    assert line["facts"]["detail"][0]["largest_bin_sum_units"] < 2**24
+
+
+def test_fault_deterministic_rounding(capsys, small, monkeypatch):
+    """The rounding offset fixed at a half (stochastic rounding left out):
+    every row lands on its nearest level, the sums are another model's."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.ops import quantize
+
+    monkeypatch.setattr(quantize, "rounding_uniforms", lambda *a: jnp.float32(0.5))
+    jax.clear_caches()
+    try:
+        line = _run(capsys, "criteo67-quant.fit")
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert line["correct"] is False
+    assert set(failing(line)) & {"split_gain_rms_gap", "count_mismatch"}, failing(line)
+
+
+# ------------------------------------------------------------- the readers
+
+
+def _facts():
+    trace = Trace(
+        window_s=10.0, planes_found=["/device:TPU:0"], host=[],
+        devices={"/device:TPU:0": [
+            Op("fusion.1", 1.0, 0.25, ""), Op("scatter.2", 1.5, 0.5, ""),
+            Op("seg_hist_pallas_batch.3", 2.0, 1.0, "mosaic s32[1,9,8,2048]"),
+            Op("fusion.4", 3.5, 0.125, ""),
+            Op("fusion.1", 6.0, 0.0625, "")]},
+        modules={"/device:TPU:0": [Op("jit__launch_impl", 0.5, 4.0, ""),
+                                   Op("jit_quantize_gradients", 5.5, 1.0, "")]},
+    )
+    scopes = {"jit__launch_impl": {"fusion.1": "while/body/quantize",
+                                   "scatter.2": "while/body/renew_leaf",
+                                   "fusion.4": "while/body/score_update"},
+              "jit_quantize_gradients": {"fusion.1": "quantize"}}
+    return {"trace": trace, "op_scopes": scopes, "trace_mark": [0, 0, 10, 2]}
+
+
+@pytest.mark.parametrize("metric,ms", [("grad_quantize_ms_per_iter", 156.25),
+                                       ("leaf_renew_ms_per_iter", 250.0)])
+def test_scope_readers_on_a_hand_built_trace(metric, ms):
+    r = _reader(metric)
+    facts = _facts()
+    # in whichever program runs the scope: the scan's body and the
+    # per-iteration loop's own dispatch
+    assert r.read(facts) == pytest.approx(ms)
+    # a program without the scope (no quantized gradients, the parent of
+    # PR 33): nothing to read, and no error
+    bare = {mod: {op: "while/body/bookkeeping" for op in ops}
+            for mod, ops in facts["op_scopes"].items()}
+    assert r.read(dict(facts, op_scopes=bare)) is None
+    assert r.read({"trace": None, "trace_mark": [0, 0, 10, 2]}) is None

@@ -8,7 +8,7 @@ Contracts under test:
     audited through the same CLI: a raw psum spliced into the grower's
     smaller-child election (spelled so the GL007 AST pass CANNOT see
     it) is caught by exactly GL011; dropping the dtype pin on
-    quantize_gradients' stochastic-rounding uniforms is caught by
+    quantize_gradients' stochastic-rounding row iota is caught by
     exactly GL012 (x64-invariance arm); stripping donate_argnums off
     the boosting score update is caught by exactly GL013; inflating a
     seg-kernel VMEM scratch block 16x past the v5e per-core arena is
@@ -138,8 +138,8 @@ def test_mutation_raw_psum_is_caught_by_gl011_only(tmp_path):
     assert f["path"] == "lightgbm_tpu/ops/grower.py"
 
 
-_DTYPE_PIN = "rg = jax.random.uniform(kg, grad.shape, dtype=jnp.float32)"
-_DTYPE_UNPINNED = "rg = jax.random.uniform(kg, grad.shape)"
+_DTYPE_PIN = "rows = jnp.arange(n, dtype=jnp.uint32)"
+_DTYPE_UNPINNED = "rows = jnp.arange(n)"
 
 
 def test_mutation_unpinned_dtype_is_caught_by_gl012_only(tmp_path):

@@ -348,8 +348,9 @@ class _Log:
 def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, digits,
                                             block, monkeypatch):
     """On a TPU the Booster takes the segment path whatever the width, with
-    no warning; the spans carry G and the planes a group, and the digits of
-    the histogram kernel's one-hot with the features a matmul takes."""
+    no warning; the spans carry G and the planes a group, the digits of the
+    histogram kernel's one-hot with the features a matmul takes, which form
+    of the kernel runs and the gradient levels of quantized training."""
     import jax
     import lightgbm_tpu as lgb
     from lightgbm_tpu.utils import log as log_mod
@@ -373,7 +374,10 @@ def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, digits,
     assert not [w for w in logs.warnings if "segment-resident" in w], logs.warnings
     assert booster._seg_span_args() == {
         "seg_groups": groups, "seg_group_planes": planes,
-        "hist_digits": digits, "hist_feature_block": block}
+        "hist_digits": digits, "hist_feature_block": block,
+        # the default hist_acc=auto takes the kernel's int8 form on a TPU;
+        # no quantized gradients (PR 33)
+        "hist_int8": True, "grad_quant_bins": 0}
 
 
 def test_the_gate_still_says_what_cannot_run(monkeypatch):

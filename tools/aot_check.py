@@ -462,6 +462,9 @@ def _grow_program(topo, n_devices=1, features=28, rows=_ROWS, **extra):
         grow = make_mesh_grow(mesh if n_devices > 1 else None, params, spec)
         booster._mesh = None
         booster._setup_sharded_grower()  # fills the dummy optional operands
+        if booster.config.use_quantized_grad:
+            # a tree's scales: with them the grower takes the integer kernels
+            booster._quant_scales = (jnp.float32(1.0), jnp.float32(1.0))
 
         def sds(a, role="replicated"):
             return jax.ShapeDtypeStruct(
@@ -498,6 +501,9 @@ _PROGRAMS = {
     "fused K=4 (leaf_batch=4)": dict(leaf_batch=4),
     "two-launch K=4 (leaf_batch=4)": dict(leaf_batch=4, grow_fused="off"),
     "tree_learner=data over 4 devices": dict(n_devices=4),
+    "tree_learner=data over 4 devices, quantized gradients": dict(
+        n_devices=4, use_quantized_grad=True
+    ),
 }
 
 
@@ -558,6 +564,50 @@ for _name, _kw in {
             hist_acc="bf16", **_kw,
         )
     )
+
+
+@check(
+    "launch scan, quantized gradients, 200k x 67, 255 leaves: "
+    "8 x (quantize + two-launch grow + renew)"
+)
+def _quantized_launch_scan(topo, rows=200_000, features=67):
+    """``lgb.train``'s eight-step ``lax.scan`` of a booster with
+    ``use_quantized_grad``, whole: a live Booster's operands (hence the
+    small table), the wrapper's program lowered for one abstract chip.  Held
+    to the grow programs' gate (no copy of ``hist_buf``'s size, no stat axis
+    on the lanes), and to the integer form of the histogram kernel: its raw
+    planes are int32."""
+    import lightgbm_tpu as lgb
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.boosting.launch import LaunchRunner
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(rows, features)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with _as_tpu_process():
+        booster = lgb.Booster(
+            dict(_HIGGS, use_quantized_grad=True, quant_train_renew_leaf=True),
+            lgb.Dataset(x, y, params={"max_bin": 255}),
+        )
+        assert booster._grower_params.hist_mode == "seg"
+        assert booster._seg_span_args()["hist_int8"] is True
+        runner = LaunchRunner(booster, 8)
+        operands = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            runner._operands(0)[0],
+        )
+        compiled = runner._fn.lower(*operands).compile()
+    text = compiled.as_text()
+    kernels = [
+        ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln
+    ]
+    assert any(re.search(r"= s32\[\d+,\d+,8,\d+\]", ln) for ln in kernels), (
+        "no histogram kernel with int32 raw planes"
+    )
+    faults = hist_form_faults(text, features)
+    assert not faults, faults
+    return compiled
 
 
 def main(selected=None):
