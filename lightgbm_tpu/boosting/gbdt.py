@@ -17,7 +17,6 @@ Per-iteration device work (all jitted, scores stay in HBM):
 
 from __future__ import annotations
 
-import functools
 import math
 import time
 import warnings
@@ -42,16 +41,21 @@ from ..resilience import NumericsError, chaos
 from ..obs.jit import instrumented_jit
 from ..ops.grower import (
     GrowerParams,
+    cat_mask_width,
     fetch_tree_arrays,
     grow_tree,
     pack_tree_arrays_donated,
     unpack_tree_arrays,
 )
+from ..ops.score_lookup import leaf_lookup, lookup_form
 from ..predict import (
     BinTreeBatch,
     StreamingPredictor,
     _add_tree_to_score_impl,
     add_tree_to_score,
+    count_valid_tree,
+    row_mesh_of,
+    valid_walk_form,
     stack_bin_trees,
     stack_real_trees,
 )
@@ -61,22 +65,19 @@ _EPS = 1e-15
 _MODEL_VERSION = "v4"
 
 
-@functools.partial(instrumented_jit, donate_argnums=(0,))
-def _apply_tree_score(
-    score: jnp.ndarray,  # [K, N] f32 (donated: rebound by every caller)
+def _tree_score_impl(
+    score: jnp.ndarray,  # [K, N] f32
     leaf_value: jnp.ndarray,  # [L] f32, ALREADY shrunk
     leaf_id: jnp.ndarray,  # [N] i32
     kk: jnp.ndarray,  # scalar i32 class row
 ) -> jnp.ndarray:
-    """Train-score update (one gather, reference UpdateScore :501) as a
-    donated entry: the old score cache goes back to the allocator instead
-    of coexisting with its successor for a full [K, N] f32."""
-    return score.at[kk].add(leaf_value[leaf_id])
+    """Train-score update (reference UpdateScore :501): the tree's output
+    for every row, looked up without a gather, added into row ``kk``."""
+    return score.at[kk].add(leaf_lookup(leaf_value, leaf_id))
 
 
-@functools.partial(instrumented_jit, donate_argnums=(0,))
-def _apply_tree_valid_score(
-    score: jnp.ndarray,  # [K, N] f32 (donated)
+def _tree_valid_score_impl(
+    score: jnp.ndarray,  # [K, N] f32
     bins: jnp.ndarray,  # [N, F_used]
     nan_bins: jnp.ndarray,  # [F_used]
     split_feature: jnp.ndarray,  # [L-1]
@@ -88,11 +89,11 @@ def _apply_tree_valid_score(
     split_is_cat: jnp.ndarray,  # [L-1] bool
     cat_mask: jnp.ndarray,  # [L-1, Bm] bool
     kk: jnp.ndarray,  # scalar i32 class row
+    row_mesh=None,  # static: predict.row_mesh_of(bins)
 ) -> jnp.ndarray:
-    """Valid-score update: bin-space walk of the new tree added into row
-    ``kk`` of the donated [K, N] score cache (one entry instead of a
-    slice/walk/set chain, so the whole old cache is donated — not just the
-    [N] row the walk reads)."""
+    """Valid-score update: the new tree scored in bin space and added into
+    row ``kk`` of the [K, N] score cache (one entry instead of a
+    slice/score/set chain)."""
     new_row = _add_tree_to_score_impl(
         score[kk],
         bins,
@@ -105,8 +106,30 @@ def _apply_tree_valid_score(
         leaf_value,
         split_is_cat,
         cat_mask,
+        row_mesh=row_mesh,
     )
     return score.at[kk].set(new_row)
+
+
+# The per-iteration loop rebinds the score to the result, so the old cache is
+# donated: it goes back to the allocator instead of coexisting with its
+# successor for a full [K, N] f32.  The pipelined loop keeps the old caches
+# as its snapshots (an iteration dispatched after training finished must
+# leave no trace), so its entries (``_kept``) donate nothing.
+_apply_tree_score = instrumented_jit(
+    _tree_score_impl, label="_apply_tree_score", donate_argnums=(0,)
+)
+_apply_tree_score_kept = instrumented_jit(
+    _tree_score_impl, label="_apply_tree_score_kept"
+)
+_apply_tree_valid_score = instrumented_jit(
+    _tree_valid_score_impl, label="_apply_tree_valid_score",
+    donate_argnums=(0,), static_argnames=("row_mesh",),
+)
+_apply_tree_valid_score_kept = instrumented_jit(
+    _tree_valid_score_impl, label="_apply_tree_valid_score_kept",
+    static_argnames=("row_mesh",),
+)
 
 
 def _ceil_pow2(x: int) -> int:
@@ -423,23 +446,12 @@ class Booster:
                 ta = self._quant_renew(ta, leaf_id, grad[kk], hess[kk], mask)
                 with get_tracer().span("train/score_update", phase="score_update"):
                     shrunk = ta.leaf_value * self._shrinkage_rate
-                    self._score = self._score.at[kk].add(shrunk[leaf_id])
-                    for entry in self._valid:
-                        entry.score = entry.score.at[kk].set(
-                            add_tree_to_score(
-                                entry.score[kk],
-                                entry.bins,
-                                self._nan_bins,
-                                ta.split_feature,
-                                ta.split_bin,
-                                ta.default_left,
-                                ta.left_child,
-                                ta.right_child,
-                                shrunk,
-                                ta.split_is_cat,
-                                ta.cat_mask,
-                            )
-                        )
+                    self._score = _apply_tree_score_kept(
+                        self._score, shrunk, leaf_id, jnp.int32(kk)
+                    )
+                    self._score_valid_tree(
+                        _apply_tree_valid_score_kept, ta, shrunk, kk
+                    )
                     get_session().sync(self._score)
                 # ta is dead after the pack (only .shape metadata is read
                 # below): donation retires its ~18 buffers at dispatch
@@ -1328,6 +1340,48 @@ class Booster:
             jnp.asarray(arr[:, 3].astype(bool)),
         )
 
+    def _score_valid_tree(self, entry_fn, ta, shrunk, kk: int) -> None:
+        """Add the new tree's (shrunk) outputs to row ``kk`` of every
+        validation score through ``entry_fn`` (the donating entry, or the
+        pipelined loop's keeping one)."""
+        for entry in self._valid:
+            count_valid_tree(shrunk, ta.cat_mask)
+            entry.score = entry_fn(
+                entry.score,
+                entry.bins,
+                self._nan_bins,
+                ta.split_feature,
+                ta.split_bin,
+                ta.default_left,
+                ta.left_child,
+                ta.right_child,
+                shrunk,
+                ta.split_is_cat,
+                ta.cat_mask,
+                jnp.int32(kk),
+                row_mesh=row_mesh_of(entry.bins),
+            )
+
+    def _score_span_args(self) -> Dict[str, str]:
+        """Which forms the score update takes, for the ``train/iteration``
+        and ``train/launch`` spans: ``score_lookup`` (``leaf_value[leaf_id]``
+        over the training rows: "onehot" | "gather") and ``valid_walk`` (a
+        new tree over a validation set: "contract" | "walk", "none" where no
+        device walk runs: no validation set, or linear trees, whose scores
+        advance on the host).  Both follow static shapes alone."""
+        p = getattr(self, "_grower_params", None)
+        if p is None:
+            return {}
+        if not self._valid or self.config.linear_tree:
+            walk = "none"
+        else:
+            # the width the grower gives its trees' cat_mask (the Booster
+            # hands it ``is_cat`` / ``bundle_end`` exactly when these flags
+            # are set), which is what ``count_valid_tree`` reads off the tree
+            width = cat_mask_width(p.use_cat, p.use_bundle, int(p.max_bin))
+            walk = valid_walk_form(int(p.num_leaves), width)
+        return {"score_lookup": lookup_form(int(p.num_leaves)), "valid_walk": walk}
+
     def _seg_span_args(self) -> Dict[str, int]:
         """Plane groups of the packed row and the planes a group, and the
         histogram kernel's two-digit one-hot ("HxL", "1x<bpad>" where it
@@ -1991,7 +2045,8 @@ class Booster:
             finished = False
             with tracer.span(
                 "train/iteration", timer="boosting/update",
-                args={"iter": it, **self._seg_span_args()}, ambient=True,
+                args={"iter": it, **self._seg_span_args(),
+                      **self._score_span_args()}, ambient=True,
             ) as sp:
                 try:
                     finished = self._update_impl(train_set, fobj)
@@ -2018,7 +2073,8 @@ class Booster:
         # ambient parents the collective io_callback spans fired off-thread
         with tracer.span(
             "train/iteration", timer="boosting/update",
-            args={"iter": it, **self._seg_span_args()}, ambient=True,
+            args={"iter": it, **self._seg_span_args(),
+                  **self._score_span_args()}, ambient=True,
         ) as sp:
             ses.begin_iteration()
             try:
@@ -2328,22 +2384,10 @@ class Booster:
                         self._score = _apply_tree_score(
                             self._score, shrunk, leaf_id, jnp.int32(kk)
                         )
-                    # valid score updates: bin-space walk of the new tree
-                    for entry in self._valid:
-                        entry.score = _apply_tree_valid_score(
-                            entry.score,
-                            entry.bins,
-                            self._nan_bins,
-                            ta.split_feature,
-                            ta.split_bin,
-                            ta.default_left,
-                            ta.left_child,
-                            ta.right_child,
-                            shrunk,
-                            ta.split_is_cat,
-                            ta.cat_mask,
-                            jnp.int32(kk),
-                        )
+                    # valid score updates: the new tree scored in bin space
+                    self._score_valid_tree(
+                        _apply_tree_valid_score, ta, shrunk, kk
+                    )
             if abs(init_scores[kk]) > _EPS:
                 tree.add_bias(init_scores[kk])
             nn = n_leaves - 1

@@ -64,6 +64,7 @@ from ..obs.device import sample_device_memory
 from ..obs.trace import get_tracer
 from ..ops.grower import _pack_tree_arrays_impl, grow_tree, unpack_tree_arrays
 from ..ops.quantize import quantize_gradients, renew_leaf_values
+from ..ops.score_lookup import leaf_lookup
 from ..resilience import NumericsError, chaos
 from ..utils.log import log_warning
 
@@ -251,6 +252,12 @@ class LaunchRunner:
             self._launch_impl,
             label=f"grow/scan{self._n}",
             donate_argnums=(0,),
+            # the body closes over the grower's parameters, the sampler, the
+            # objective and the quantization, all made from the Config: the
+            # whole of it is a superset, and a superset errs toward a second
+            # map file with the same content, a subset toward two programs
+            # reading one map (PR 34)
+            closure_key=repr(booster.config),
         )
 
     def stale(self, booster) -> bool:
@@ -404,7 +411,7 @@ class LaunchRunner:
                     # whole-array select (NOT add-of-masked-delta): a
                     # skipped step must keep the old score bit patterns,
                     # -0.0 included
-                    cand = new_score.at[kk].add(shrunk[leaf_id])
+                    cand = new_score.at[kk].add(leaf_lookup(shrunk, leaf_id))
                     new_score = jnp.where(upd, cand, new_score)
                 any_split = jnp.logical_or(any_split, has_split)
                 ii, ff = _pack_tree_arrays_impl(ta)
@@ -520,7 +527,7 @@ class LaunchRunner:
             "train/launch",
             timer="boosting/update",
             args={"launch_begin": it0, "steps_per_launch": self._n,
-                  **b._seg_span_args()},
+                  **b._seg_span_args(), **b._score_span_args()},
             ambient=True,
         ) as lsp:
             return self._run_window(lsp, it0, init_scores)
@@ -765,6 +772,7 @@ class FleetLaunchRunner:
             self._launch_impl,
             label=f"fleet/scan{self._n}",
             donate_argnums=(0,),
+            closure_key=repr([b.config for b in trainer.boosters]),
         )
 
     def _launch_impl(self, scores, rngs, bags, halted0, its, fms, bins):
@@ -899,7 +907,9 @@ class FleetLaunchRunner:
                     shrunk = fta.leaf_value[i] * float(
                         boosters[i]._shrinkage_rate
                     )
-                    cand = new_scores[i].at[kk].add(shrunk[fleaf[i]])
+                    cand = new_scores[i].at[kk].add(
+                        leaf_lookup(shrunk, fleaf[i])
+                    )
                     new_scores[i] = jnp.where(upd, cand, new_scores[i])
                     any_split[i] = jnp.logical_or(any_split[i], has_split)
             finished2 = [

@@ -16,10 +16,25 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..dataset import Dataset
+from ..obs.jit import instrumented_jit
 from ..ops.grower import fetch_tree_arrays, grow_tree
+from ..ops.score_lookup import leaf_lookup
 from ..predict import add_tree_to_score
 from ..tree import Tree
 from .gbdt import Booster, _EPS
+
+
+def _add_leaf_values_impl(row, leaf_value, leaf_id):
+    """``row + leaf_value[leaf_id]`` ([N] f32) as ONE program: only under jit
+    does the compiler build ``leaf_lookup``'s one-hot inside the matmul's
+    fusion; dispatched eagerly it is an [Lp, N] array (3 * Lp bytes a row:
+    6 GB at 8M rows and 255 leaves)."""
+    return row + leaf_lookup(leaf_value, leaf_id)
+
+
+_add_leaf_values = instrumented_jit(
+    _add_leaf_values_impl, label="rf/score_update", donate_argnums=(0,)
+)
 
 
 class RFBooster(Booster):
@@ -96,11 +111,12 @@ class RFBooster(Booster):
                         leaf_value=np.asarray(ta_host.leaf_value, dtype=np.float64)
                         + self._init_scores[kk]
                     )
-                # running average: score = (score*t + tree)/(t+1)  (rf.hpp:149)
+                # running average: score = (score*t + tree)/(t+1)  (rf.hpp:149);
+                # scaled, added and divided in three steps, as the validation
+                # scores below are, so both round the same way
                 t = float(self._iter)
-                self._score = self._score.at[kk].set(
-                    (self._score[kk] * t + leaf_value[leaf_id]) / (t + 1.0)
-                )
+                summed = _add_leaf_values(self._score[kk] * t, leaf_value, leaf_id)
+                self._score = self._score.at[kk].set(summed / (t + 1.0))
                 for entry in self._valid:
                     updated = add_tree_to_score(
                         entry.score[kk] * t,

@@ -10,7 +10,7 @@ they become build-time errors instead.  Run it as
     python -m lightgbm_tpu.lint [--baseline lint_baseline.json] [paths...]
     python -m lightgbm_tpu.lint --changed-only   # dev-loop fast mode
     python -m lightgbm_tpu.lint --json           # incl. per-rule timings
-    python -m lightgbm_tpu.lint --ir             # + GL011-GL015 jaxpr audit
+    python -m lightgbm_tpu.lint --ir             # + GL011-GL016 jaxpr audit
     python -m lightgbm_tpu.lint --format=github  # ::error annotations
 
 or through the pytest gate (tests/test_lint.py) and the hard CI gate at
@@ -50,6 +50,9 @@ GL014  pallas kernel's static VMEM working set (2x operand blocks +
        scratch) exceeds the 16 MiB v5e per-core arena
 GL015  host callback compiled into a hot entry outside the sanctioned
        obs.collectives wrappers (per-iteration device->host round trip)
+GL016  gather of the table's rows in the score update of a
+       score-update entry (~8 ns an element on the TPU; the one-hot
+       contractions of ops/score_lookup.py run at memory speed)
 =====  ==============================================================
 
 GL007–GL010 share one SPMD index (``callgraph.SpmdIndex``): a

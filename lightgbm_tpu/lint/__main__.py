@@ -7,7 +7,7 @@ justifications for review.
 
 ``--ir`` additionally traces the real jit/shard_map entries to jaxprs
 (lint.ir config matrix, CPU-only abstract tracing) and runs the
-GL011-GL015 IR audits; with ``--changed-only`` the IR matrix is scoped
+GL011-GL016 IR audits; with ``--changed-only`` the IR matrix is scoped
 to entries whose transitive module closure intersects the changed
 files (CI runs the full matrix).  ``--format=github`` emits
 ``::error file=...,line=...::`` annotations for both passes.
@@ -94,7 +94,7 @@ def main(argv=None) -> int:
         "--ir",
         action="store_true",
         help="also trace the jit/shard_map entry matrix to jaxprs and "
-        "run the GL011-GL015 IR audits (imports the package; still "
+        "run the GL011-GL016 IR audits (imports the package; still "
         "CPU-only abstract tracing, no device execution)",
     )
     parser.add_argument(

@@ -137,12 +137,19 @@ RULES: Dict[str, Tuple[str, str]] = {
         "obs.collectives wrappers so the transfer is measured and "
         "gated",
     ),
+    "GL016": (
+        "gather of the table's rows inside the score update of a "
+        "score-update entry",
+        "look the tree's output up with ops.score_lookup.leaf_lookup / "
+        "tree_values (one-hot contractions): a TPU gather costs ~8 ns an "
+        "element whatever the table's size",
+    ),
 }
 
 # rules produced by the IR pass (rules_ir.py): their baseline entries are
 # only checked for staleness when the FULL entry matrix was traced
 IR_RULE_CODES = frozenset(
-    {"GL011", "GL012", "GL013", "GL014", "GL015"}
+    {"GL011", "GL012", "GL013", "GL014", "GL015", "GL016"}
 )
 
 _SUPPRESS_RE = re.compile(
@@ -437,7 +444,7 @@ def run_lint(
     untouched entries as stale.
 
     ``ir=True`` additionally traces the lint.ir entry matrix and runs
-    the GL011-GL015 jaxpr audits (this IMPORTS the package — see the
+    the GL011-GL016 jaxpr audits (this IMPORTS the package — see the
     ir.py docstring).  ``ir_entry_filter`` (name prefixes) and
     ``ir_changed_modules`` (package-relative paths) scope which entries
     are traced; when either scopes the matrix down, IR-rule baseline

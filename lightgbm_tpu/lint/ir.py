@@ -1,5 +1,5 @@
 """graftlint IR pass: trace the real jit/shard_map entries to jaxprs and
-collect the facts the GL011-GL015 rules audit.
+collect the facts the GL011-GL016 rules audit.
 
 Unlike the AST pass (core.py docstring: never imports the scanned
 modules), the IR pass deliberately IMPORTS the library and traces its
@@ -30,7 +30,12 @@ jaxpr: pjit, scan, while, cond branches, shard_map, pallas_call):
   f64 the moment someone flips x64 on);
 * the entry's ``donate_argnums`` (read off the ``instrumented_jit``
   wrapper) and per-argument byte sizes — GL013's donation audit of the
-  per-iteration carried buffers declared in each spec.
+  per-iteration carried buffers declared in each spec;
+* ``gather`` eqns with their output shape and ``jax.named_scope`` path —
+  GL016 holds the score update of the entries that declare their table's
+  rows (the whole entry, or the part under a named scope) to no gather of
+  that many rows (8 ns an element on the TPU; ops/score_lookup.py has the
+  contractions).
 
 The entry registry is explicit: every spec names its expected collective
 axes, its donation-required (carried) arguments and its root modules, so
@@ -128,8 +133,16 @@ class WideDtypeFact:
 
 
 @dataclasses.dataclass
+class GatherFact:
+    out_shape: Tuple[int, ...]
+    scope: str  # the eqn's jax.named_scope path
+    frames: Tuple[SrcFrame, ...]
+
+
+@dataclasses.dataclass
 class TraceFacts:
     collectives: List[CollectiveFact] = dataclasses.field(default_factory=list)
+    gathers: List[GatherFact] = dataclasses.field(default_factory=list)
     callbacks: List[CallbackFact] = dataclasses.field(default_factory=list)
     pallas: List[PallasFact] = dataclasses.field(default_factory=list)
     wide: List[WideDtypeFact] = dataclasses.field(default_factory=list)
@@ -159,6 +172,11 @@ class EntrySpec:
     psum_model: Optional[Callable[[], Dict[str, FrozenSet[int]]]] = None
     hot: bool = True  # reachable every training/predict iteration (GL015)
     root_modules: Tuple[str, ...] = ()  # package-relative .py paths
+    # rows of the entry's table: GL016 holds the score update of such an
+    # entry to no gather with that many rows — the whole entry, or where
+    # the entry does more (the launch scan) what lies under ``score_scope``
+    score_rows: Optional[int] = None
+    score_scope: Optional[str] = None
 
 
 @dataclasses.dataclass
@@ -331,6 +349,16 @@ def walk_jaxpr(jaxpr, facts: TraceFacts) -> None:
             pf = _pallas_fact(eqn)
             if pf is not None:
                 facts.pallas.append(pf)
+        elif name == "gather":
+            facts.gathers.append(
+                GatherFact(
+                    out_shape=tuple(
+                        int(d) for d in eqn.outvars[0].aval.shape
+                    ),
+                    scope=str(eqn.source_info.name_stack),
+                    frames=_pkg_frames(eqn),
+                )
+            )
         for v in eqn.outvars:
             dn = _dtype_name(getattr(v, "aval", None))
             if dn in _WIDE_DTYPES:
@@ -670,11 +698,14 @@ def build_entry_specs() -> List[EntrySpec]:
                 "boosting/launch.py",
                 "boosting/gbdt.py",
                 "ops/grower.py",
+                "ops/score_lookup.py",
                 "parallel/mesh.py",
                 "obs/collectives.py",
                 "ops/histogram.py",
                 "ops/split.py",
             ),
+            score_rows=N,
+            score_scope="score_update",
         )
     )
 
@@ -746,7 +777,26 @@ def build_entry_specs() -> List[EntrySpec]:
             anchor=_anchor(gbdt_mod, "_apply_tree_score"),
             carried=((0, "score"),),
             x64_strict=True,
-            root_modules=("boosting/gbdt.py",),
+            root_modules=("boosting/gbdt.py", "ops/score_lookup.py"),
+            score_rows=N,
+        )
+    )
+
+    from ..boosting import rf as rf_mod
+
+    def build_rf_score_update():
+        args = (_sds((N,), f32), _sds((L,), f32), _sds((N,), i32))
+        return rf_mod._add_leaf_values, args, {}
+
+    specs.append(
+        EntrySpec(
+            name="rf/score_update",
+            build=build_rf_score_update,
+            anchor=_anchor(rf_mod, "_add_leaf_values_impl"),
+            carried=((0, "row"),),
+            x64_strict=True,
+            root_modules=("boosting/rf.py", "ops/score_lookup.py"),
+            score_rows=N,
         )
     )
 
@@ -775,7 +825,10 @@ def build_entry_specs() -> List[EntrySpec]:
             anchor=_anchor(gbdt_mod, "_apply_tree_valid_score"),
             carried=((0, "score"),),
             x64_strict=True,
-            root_modules=("boosting/gbdt.py", "predict.py"),
+            root_modules=(
+                "boosting/gbdt.py", "predict.py", "ops/score_lookup.py"
+            ),
+            score_rows=N,
         )
     )
 
@@ -900,7 +953,7 @@ def build_entry_specs() -> List[EntrySpec]:
     )
 
     def build_add_tree():
-        fn = predict_mod.add_tree_to_score
+        fn = predict_mod._add_tree_to_score_jit
         args = (
             _sds((N,), f32),  # score_k (donated)
             _sds((N, F), jnp.uint8),
@@ -921,7 +974,8 @@ def build_entry_specs() -> List[EntrySpec]:
             anchor=_anchor(predict_mod, "add_tree_to_score"),
             carried=((0, "score_k"),),
             x64_strict=True,
-            root_modules=("predict.py",),
+            root_modules=("predict.py", "ops/score_lookup.py"),
+            score_rows=N,
         )
     )
 

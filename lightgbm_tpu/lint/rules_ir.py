@@ -1,4 +1,4 @@
-"""graftlint IR rules GL011-GL015: audits over traced jaxprs.
+"""graftlint IR rules GL011-GL016: audits over traced jaxprs.
 
 The AST pass (rules_spmd et al.) models what the source SAYS; this pass
 checks what jax actually TRACES for the real entry points (lint.ir's
@@ -348,12 +348,45 @@ def check_host_transfers(
     return out
 
 
+# ------------------------------------------------------------------ GL016
+def check_score_row_gathers(
+    project: Project, entries: Sequence[TracedEntry]
+) -> List[Finding]:
+    out: List[Finding] = []
+    for te in entries:
+        rows = te.spec.score_rows
+        if te.error or rows is None:
+            continue
+        for g in te.facts.gathers:
+            scope = te.spec.score_scope
+            if scope is not None and scope not in g.scope.split("/"):
+                continue
+            if rows not in g.out_shape:
+                continue
+            fr = g.frames[0]
+            out.append(
+                Finding(
+                    "GL016",
+                    fr.path,
+                    fr.line,
+                    f"row_gather:{te.spec.name}:{fr.func}",
+                    f"entry '{te.spec.name}' gathers {list(g.out_shape)} "
+                    f"in its score update: a gather of the "
+                    f"table's {rows} rows costs ~8 ns an element on the "
+                    f"TPU where ops/score_lookup.py's contractions run at "
+                    f"memory speed",
+                )
+            )
+    return out
+
+
 RULE_CHECKS = {
     "GL011": check_collective_congruence,
     "GL012": check_dtype_promotion,
     "GL013": check_donation,
     "GL014": check_vmem_budget,
     "GL015": check_host_transfers,
+    "GL016": check_score_row_gathers,
 }
 
 
@@ -362,7 +395,7 @@ def run_ir_rules(
     entry_filter: Optional[Sequence[str]] = None,
     changed_modules: Optional[Sequence[str]] = None,
 ) -> Tuple[List[Finding], Dict[str, float], float]:
-    """Trace the entry matrix and run GL011-GL015.
+    """Trace the entry matrix and run GL011-GL016.
 
     ``entry_filter``: optional entry-name prefixes.  ``changed_modules``:
     optional package-relative .py paths (the --changed-only set) — an

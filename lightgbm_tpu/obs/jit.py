@@ -178,6 +178,9 @@ class _InstrumentedJit:
 
     def __init__(self, fun, label: str, jit_kwargs: Dict[str, Any]) -> None:
         self._label = label
+        # what the program closes over that its arguments do not show (see
+        # ``_signature``); not a jit option
+        self._closure_key = str(jit_kwargs.pop("closure_key", ""))
         # introspectable by the lint IR pass (GL013 donation audit) and any
         # other tooling that needs the entry's declared jit contract
         self.jit_kwargs: Dict[str, Any] = dict(jit_kwargs)
@@ -336,14 +339,20 @@ def _abstract(x: Any) -> Any:
     return x
 
 
-def _signature(abstract: Any) -> str:
+def _signature(abstract: Any, closure_key: str = "") -> str:
     """Hash of what selects an executable among those of one function:
-    argument shapes, dtypes, shardings and static values, the backend, and
-    the code."""
+    argument shapes, dtypes, shardings and static values, the backend, the
+    code, and ``closure_key``: what a bound method's program closes over
+    (``instrumented_jit(..., closure_key=...)``).  Without it the launch
+    scans of a quantized and a plain booster on tables of one shape shared a
+    key, and whichever compiled first wrote the map both then read (PR 34:
+    ``criteo67-quant.fit`` traced after ``criteo67.fit`` found no scope
+    ``quantize``)."""
     leaves, treedef = jax.tree_util.tree_flatten(abstract)
     dev = jax.devices()[0]
     h = hashlib.sha1(
-        f"{_code_fingerprint()}|{dev.platform}|{dev.device_kind}|{treedef}".encode()
+        f"{_code_fingerprint()}|{dev.platform}|{dev.device_kind}|{treedef}"
+        f"|{closure_key}".encode()
     )
     for leaf in leaves:
         h.update(repr(leaf).encode())
@@ -360,7 +369,7 @@ def _note_traced(inst: "_InstrumentedJit", args, kwargs, seconds: float) -> None
             return  # traced inside another program: that program's map has it
         abstract = jax.tree_util.tree_map(_abstract, (args, kwargs))
         module = re.sub(r"[^\w.\-]", "_", "jit_" + inst.__name__)
-        key = (module, _signature(abstract))
+        key = (module, _signature(abstract, inst._closure_key))
         _traced[key] = (weakref.ref(inst), abstract[0], abstract[1])
         directory = _scopes_dir()
         if directory is None or seconds < float(
@@ -558,6 +567,10 @@ def instrumented_jit(fun=None, *, label: Optional[str] = None, **jit_kwargs):
         def g(x): ...
         @functools.partial(instrumented_jit, static_argnames=("n",))
         def h(x, n): ...
+
+    ``closure_key`` (a string, not a jit option) tells programs of one
+    function and one argument signature apart where a bound method closes
+    over configuration: it enters the key of the program's ``op_scopes`` map.
     """
     if fun is None:
         return functools.partial(instrumented_jit, label=label, **jit_kwargs)

@@ -227,6 +227,15 @@ def _part_caps(n: int) -> list:
     return sorted(caps)  # ascending
 
 
+def cat_mask_width(use_cat: bool, use_bundle: bool, num_bins: int) -> int:
+    """Bins a grown tree's ``cat_mask`` spans (static): 1, a no-op, for a
+    numeric tree; the whole bin axis where splits can be categorical, and for
+    EFB bundles too, whose splits ride the same mask machinery.  The one rule
+    the grower builds its trees by and the Booster names their score update
+    by (``predict.valid_walk_form``)."""
+    return num_bins if (use_cat or use_bundle) else 1
+
+
 class TreeArrays(NamedTuple):
     """SoA tree, mirroring the reference Tree (include/LightGBM/tree.h:497).
 
@@ -827,9 +836,7 @@ def grow_tree(
             out = out * ratio / (ratio + 1.0) + pouts / (ratio + 1.0)
         return jnp.clip(out, lb_, ub_)
     use_cat = p.use_cat and is_cat is not None
-    # cat-mask width (1 = static no-op); bundle splits ride the same mask
-    # machinery, so bundling widens it too
-    Bm = B if (use_cat or use_bundle) else 1
+    Bm = cat_mask_width(use_cat, use_bundle, B)
     is_cat_arr = is_cat if use_cat else None
     use_cegb = p.use_cegb and cegb_penalty is not None
     # per-feature gain multipliers (reference feature_contri /

@@ -39,6 +39,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..obs.jit import instrumented_jit
 from ..ops.grower import GrowerParams, grow_tree
+from ..ops.score_lookup import leaf_lookup
 
 DATA_AXIS = "data"
 
@@ -261,7 +262,7 @@ def make_data_parallel_train_step(
         tree, leaf_id = grow_tree(
             bins, grad, hess, mask, num_bins, nan_bins, feature_mask, p
         )
-        new_score = score + learning_rate * tree.leaf_value[leaf_id]
+        new_score = score + learning_rate * leaf_lookup(tree.leaf_value, leaf_id)
         return new_score, tree
 
     sharded = P(axis_name)

@@ -29,10 +29,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .obs.device import sample_device_memory
 from .obs.jit import instrumented_jit, note_executable
 from .obs.registry import get_session
+from .ops.score_lookup import lookup_form, tree_values
 from .ops.tensor_forest import (
     _tensor_bins_leaves_impl,
     _tensor_bins_pertree_impl,
@@ -296,6 +298,56 @@ def _stacked_bins_leaves_impl(batch: BinTreeBatch, nan_bins: jnp.ndarray, bins: 
     return _predict_bins_leaves_impl(batch, bins, nan_bins)
 
 
+def _cat_width(cat_mask) -> int:
+    """Bins a tree's categorical bitmaps span: 1 (or no mask) = none."""
+    return 1 if cat_mask is None else cat_mask.shape[-1]
+
+
+def valid_walk_form(num_leaves: int, cat_width: int) -> str:
+    """Which form scores one bin-space tree over a binned matrix, from the two
+    static shapes the choice rests on: ``"contract"`` (ops/score_lookup.py:
+    node decisions and a path match on the MXU) for a numeric tree,
+    ``"walk"`` (the ``while_loop`` of per-row gathers below) for a tree that
+    may hold categorical splits — their predicate indexes a per-node bitmap
+    by the row's bin, a gather by nature — or more leaves than the
+    contraction's [L, L] tables are worth."""
+    if cat_width > 1 or lookup_form(num_leaves) == "gather":
+        return "walk"
+    return "contract"
+
+
+def _walk_tree_values(
+    bins, nan_bins, split_feature, split_bin, default_left, left_child,
+    right_child, leaf_value, split_is_cat=None, cat_mask=None,
+) -> jnp.ndarray:
+    """[N] leaf values by a level-synchronous walk: seven gathers a level."""
+    n = bins.shape[0]
+    use_cat = cat_mask is not None and cat_mask.shape[-1] > 1
+
+    def cond(nodes):
+        return jnp.any(nodes >= 0)
+
+    def body(nodes):
+        cur = jnp.maximum(nodes, 0)
+        feat = split_feature[cur]
+        tbin = split_bin[cur]
+        dl = default_left[cur]
+        # GL012: as in _predict_bins_leaves_impl, the int64 is jax's own
+        # index conversion under enable_x64
+        fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]  # graftlint: disable=GL012
+        nb = nan_bins[feat]
+        go_left = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
+        if use_cat:
+            bm = cat_mask.shape[-1]
+            gl_cat = cat_mask[cur, jnp.minimum(fval, bm - 1)] & (fval < bm)
+            go_left = jnp.where(split_is_cat[cur], gl_cat, go_left)
+        nxt = jnp.where(go_left, left_child[cur], right_child[cur])
+        return jnp.where(nodes >= 0, nxt, nodes)
+
+    nodes = lax.while_loop(cond, body, jnp.zeros((n,), jnp.int32))
+    return leaf_value[~nodes]
+
+
 def _add_tree_to_score_impl(
     score_k: jnp.ndarray,  # [N] f32 (donated in the jitted wrappers)
     bins: jnp.ndarray,  # [N, F_used]
@@ -308,44 +360,75 @@ def _add_tree_to_score_impl(
     leaf_value: jnp.ndarray,  # [L] ALREADY shrunk
     split_is_cat: Optional[jnp.ndarray] = None,  # [L-1] bool
     cat_mask: Optional[jnp.ndarray] = None,  # [L-1, Bm] bool
+    row_mesh: Optional[Tuple[Any, Any]] = None,  # static (mesh, row axis)
 ) -> jnp.ndarray:
-    """Walk one bin-space tree over a dataset and add leaf outputs to score —
-    the valid-set ScoreUpdater::AddScore (src/boosting/score_updater.hpp:54)."""
-    n = bins.shape[0]
-    use_cat = cat_mask is not None and cat_mask.shape[-1] > 1
+    """Score one bin-space tree over a dataset and add leaf outputs to score —
+    the valid-set ScoreUpdater::AddScore (src/boosting/score_updater.hpp:54).
+    The form follows the tree's static shapes (``valid_walk_form``); both
+    give the same leaf and so the same bits.  ``row_mesh`` (``row_mesh_of``
+    the binned matrix) makes the work local to each shard of the rows: a
+    row's leaf depends on that row alone, so no collective is needed, and the
+    contraction's row blocks stay blocks of the local rows."""
+    tree = (split_feature, split_bin, default_left, left_child, right_child,
+            leaf_value)
+    if valid_walk_form(leaf_value.shape[0], _cat_width(cat_mask)) == "contract":
+        values = tree_values
+    else:
+        values = _walk_tree_values
+        if cat_mask is not None:
+            tree += (split_is_cat, cat_mask)
 
     with jax.named_scope("score_update"):
-        def cond(nodes):
-            return jnp.any(nodes >= 0)
+        if row_mesh is not None:
+            from .parallel import _shard_map
 
-        def body(nodes):
-            cur = jnp.maximum(nodes, 0)
-            feat = split_feature[cur]
-            tbin = split_bin[cur]
-            dl = default_left[cur]
-            # GL012: as in _predict_bins_leaves_impl, the int64 is jax's own
-            # index conversion under enable_x64.  This is the validation walk
-            # (269 ms an iteration in criteo67.fit-eval, PERF.md section 5):
-            # suppressed, not rewritten, until that walk is the subject
-            fval = jnp.take_along_axis(bins, feat[:, None], axis=1)[:, 0]  # graftlint: disable=GL012
-            nb = nan_bins[feat]
-            go_left = (fval <= tbin) | (dl & (nb >= 0) & (fval == nb))
-            if use_cat:
-                bm = cat_mask.shape[-1]
-                gl_cat = cat_mask[cur, jnp.minimum(fval, bm - 1)] & (fval < bm)
-                go_left = jnp.where(split_is_cat[cur], gl_cat, go_left)
-            nxt = jnp.where(go_left, left_child[cur], right_child[cur])
-            return jnp.where(nodes >= 0, nxt, nodes)
-
-        nodes = lax.while_loop(cond, body, jnp.zeros((n,), jnp.int32))
-        return score_k + leaf_value[~nodes]
+            mesh, axis = row_mesh
+            values = _shard_map(
+                values, mesh=mesh,
+                in_specs=(P(axis, None),) + (P(),) * (1 + len(tree)),
+                out_specs=P(axis),
+            )
+        return score_k + values(bins, nan_bins, *tree)
 
 
-# standalone entry (valid-score updates call it once per tree with a dead
-# score row: the old buffer is donated back to the allocator)
-add_tree_to_score = instrumented_jit(
-    _add_tree_to_score_impl, label="add_tree_to_score", donate_argnums=(0,)
+def row_mesh_of(bins) -> Optional[Tuple[Any, Any]]:
+    """``(mesh, axis)`` where the rows of a placed array are sharded over a
+    mesh axis (``tree_learner=data``), else None: what the jitted entries of
+    the validation score take as their static ``row_mesh``."""
+    sh = getattr(bins, "sharding", None)
+    if not isinstance(sh, NamedSharding) or not len(sh.spec):
+        return None
+    axis = sh.spec[0]
+    if axis is None or sh.mesh.size == 1:
+        return None
+    return sh.mesh, axis
+
+
+_add_tree_to_score_jit = instrumented_jit(
+    _add_tree_to_score_impl, label="add_tree_to_score", donate_argnums=(0,),
+    static_argnames=("row_mesh",),
 )
+
+
+def count_valid_tree(leaf_value, cat_mask) -> None:
+    """One tree scored over one binned matrix: the session counter of its
+    form (``score/valid_contract_trees`` / ``score/valid_walk_trees``)."""
+    form = valid_walk_form(leaf_value.shape[0], _cat_width(cat_mask))
+    get_session().inc(f"score/valid_{form}_trees")
+
+
+def add_tree_to_score(
+    score_k, bins, nan_bins, split_feature, split_bin, default_left,
+    left_child, right_child, leaf_value, split_is_cat=None, cat_mask=None,
+):
+    """Standalone entry (valid-score updates call it once per tree with a
+    dead score row: the old buffer is donated back to the allocator)."""
+    count_valid_tree(leaf_value, cat_mask)
+    return _add_tree_to_score_jit(
+        score_k, bins, nan_bins, split_feature, split_bin, default_left,
+        left_child, right_child, leaf_value, split_is_cat, cat_mask,
+        row_mesh=row_mesh_of(bins),
+    )
 
 
 # ---------------------------------------------------------------------------

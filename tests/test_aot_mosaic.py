@@ -75,3 +75,34 @@ def test_named_scopes_survive_the_tpu_compiler(topo):
     assert {"split_scan", "leaf_loop/bookkeeping", "leaf_loop/candidate_refresh"} <= paths
     assert any(name.startswith("fusion") or name.endswith("fusion") or "fusion." in name
                for name, path in scopes.items() if path)
+
+
+def test_the_row_gather_gate_sees_the_gathers_it_replaced(topo):
+    """``row_gather_faults`` on the parent's forms, compiled for the chip:
+    ``leaf_value[leaf_id]`` and the walker both show gathers with the
+    table's rows, whole-program and under the scope the launch scan uses."""
+    import jax
+    import jax.numpy as jnp
+
+    from lightgbm_tpu.predict import _walk_tree_values
+
+    rows, leaves, features = 100_000, 255, 28
+    s = aot_check.s
+
+    def lookup(score, leaf_value, leaf_id):
+        with jax.named_scope("score_update"):
+            return score + leaf_value[leaf_id]
+
+    text = aot_check.compile_on_topo(
+        topo, lookup, s((rows,), jnp.float32), s((leaves,), jnp.float32),
+        s((rows,), jnp.int32),
+    ).as_text()
+    assert aot_check.row_gather_faults(text, rows)
+    assert aot_check.row_gather_faults(text, rows, scope="score_update")
+    assert not aot_check.row_gather_faults(text, rows, scope="histogram")
+    assert not aot_check.row_gather_faults(text, rows + 1)
+    walk = aot_check.compile_on_topo(
+        topo, _walk_tree_values, s((rows, features), jnp.uint8),
+        s((features,), jnp.int32), *aot_check._numeric_tree(leaves),
+    ).as_text()
+    assert aot_check.row_gather_faults(walk, rows)

@@ -1005,9 +1005,9 @@ def test_cli_changed_only_smoke():
 
 def test_rule_table_is_complete():
     """Every rule has a summary and an actionable autofix hint, and the
-    fifteen shipped codes (ten AST + five IR) are exactly the documented
+    sixteen shipped codes (ten AST + six IR) are exactly the documented
     set."""
-    assert set(RULES) == {f"GL{i:03d}" for i in range(1, 16)}
-    assert IR_RULE_CODES == {f"GL{i:03d}" for i in range(11, 16)}
+    assert set(RULES) == {f"GL{i:03d}" for i in range(1, 17)}
+    assert IR_RULE_CODES == {f"GL{i:03d}" for i in range(11, 17)}
     for code, (summary, hint) in RULES.items():
         assert summary and hint, code

@@ -1,4 +1,4 @@
-"""graftlint IR pass (lint.ir + rules_ir) — the GL011-GL015 jaxpr gate.
+"""graftlint IR pass (lint.ir + rules_ir) — the GL011-GL016 jaxpr gate.
 
 Contracts under test:
   * the REAL tree is clean: the full entry matrix traces and produces
@@ -161,10 +161,9 @@ def test_mutation_unpinned_dtype_is_caught_by_gl012_only(tmp_path):
 
 
 _DONATED_DECOR = (
-    "@functools.partial(instrumented_jit, donate_argnums=(0,))\n"
-    "def _apply_tree_score("
+    '_tree_score_impl, label="_apply_tree_score", donate_argnums=(0,)\n'
 )
-_UNDONATED_DECOR = "@instrumented_jit\ndef _apply_tree_score("
+_UNDONATED_DECOR = '_tree_score_impl, label="_apply_tree_score"\n'
 
 
 def test_mutation_dropped_donation_is_caught_by_gl013_only(tmp_path):
@@ -191,6 +190,49 @@ def test_mutation_dropped_donation_is_caught_by_gl013_only(tmp_path):
         r"'score'",
         annotations[0],
     ), annotations[0]
+
+
+_LOOKUP = "return score.at[kk].add(leaf_lookup(leaf_value, leaf_id))"
+_LOOKUP_GATHER = "return score.at[kk].add(leaf_value[leaf_id])"
+_SCAN_LOOKUP = "cand = new_score.at[kk].add(leaf_lookup(shrunk, leaf_id))"
+_SCAN_GATHER = "cand = new_score.at[kk].add(shrunk[leaf_id])"
+_RF_LOOKUP = "return row + leaf_lookup(leaf_value, leaf_id)"
+_RF_GATHER = "return row + leaf_value[leaf_id]"
+_WALK_RULE = 'if cat_width > 1 or lookup_form(num_leaves) == "gather":'
+_WALK_ALWAYS = "if True:"
+
+
+@pytest.mark.parametrize(
+    "rel,old,new,entry,path",
+    [
+        ("boosting/gbdt.py", _LOOKUP, _LOOKUP_GATHER, "boost/score_update",
+         "lightgbm_tpu/boosting/gbdt.py"),
+        ("boosting/launch.py", _SCAN_LOOKUP, _SCAN_GATHER, "grow/scan4_data8",
+         "lightgbm_tpu/boosting/launch.py"),
+        ("boosting/rf.py", _RF_LOOKUP, _RF_GATHER, "rf/score_update",
+         "lightgbm_tpu/boosting/rf.py"),
+        ("predict.py", _WALK_RULE, _WALK_ALWAYS, "boost/valid_score_update",
+         "lightgbm_tpu/predict.py"),
+        ("predict.py", _WALK_RULE, _WALK_ALWAYS, "predict/add_tree_to_score",
+         "lightgbm_tpu/predict.py"),
+    ],
+    ids=["score_update", "launch_scan", "rf_score_update", "valid_score_update",
+         "add_tree_to_score"],
+)
+def test_mutation_row_gather_in_the_score_update_is_caught_by_gl016_only(
+    tmp_path, rel, old, new, entry, path
+):
+    """Putting ``leaf_value[leaf_id]`` (or the walker, for a numeric tree)
+    back into a score-update entry is a gather of the table's rows under
+    scope ``score_update``: 8 ns an element on the TPU (PR 34)."""
+    root = _tree_copy(tmp_path)
+    _mutate(root, rel, old, new)
+    proc = _run_cli(root, "--ir", "--ir-entries", entry, "--json")
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    ir_new, _ = _ir_new(proc)
+    assert ir_new and {f["rule"] for f in ir_new} == {"GL016"}
+    assert all(f["ident"].startswith(f"row_gather:{entry}:") for f in ir_new)
+    assert {f["path"] for f in ir_new} == {path}
 
 
 _SEG_TILE = "TILE = 512  # rows per DMA tile in seg_hist"

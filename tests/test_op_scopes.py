@@ -207,6 +207,26 @@ def test_two_executables_of_one_module_name(scopes_cache):
     assert sorted(d["signature"] for d in kept) == ["a" * 16, "b" * 16]
 
 
+def test_launch_scans_of_one_shape_and_another_configuration_do_not_share_a_map():
+    """The scan's body closes over the booster's configuration, which its
+    arguments do not show: a quantized and a plain booster on tables of one
+    shape used to share a key, and the first to compile wrote the map both
+    read (on the chip, PR 34: no scope ``quantize`` in the quantized cell's
+    trace after the plain cell had run)."""
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(2000, 6)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.float32)
+    base = {"objective": "binary", "verbosity": -1, "num_leaves": 7,
+            "train_steps_per_launch": 4}
+    quant = {"use_quantized_grad": True, "num_grad_quant_bins": 4,
+             "quant_train_renew_leaf": True, "seed": 3}
+    before = set(obs_jit._traced)
+    lgb.train(base, lgb.Dataset(X, y), 4)
+    lgb.train({**base, **quant}, lgb.Dataset(X, y), 4)
+    new = [k for k in set(obs_jit._traced) - before if k[0] == "jit__launch_impl"]
+    assert len(new) == 2 and new[0][1] != new[1][1]
+
+
 # ------------------------------------------------- R2: a later process reads
 _WRITER = """
 import numpy as np, jax

@@ -534,3 +534,22 @@ def test_tracing_adds_zero_retraces():
     assert b.dump_trace  # API exists on every Booster
     after = compile_counts_by_label()
     assert after == before
+
+
+@pytest.mark.parametrize("steps,valid,span,want", [
+    (1, True, "train/iteration", {"score_lookup": "onehot", "valid_walk": "contract"}),
+    (1, False, "train/iteration", {"score_lookup": "onehot", "valid_walk": "none"}),
+    (3, False, "train/launch", {"score_lookup": "onehot", "valid_walk": "none"}),
+], ids=["iteration_with_a_validation_set", "iteration_without", "launch"])
+def test_the_score_updates_forms_ride_on_the_top_spans(steps, valid, span, want):
+    """PR 34: which form looks a tree's output up (``score_lookup``) and which
+    scores a validation set (``valid_walk``), beside the kernels' own args."""
+    X, y = _data()
+    train = lgb.Dataset(X, y)
+    valid_sets = [lgb.Dataset(*_data(seed=1), reference=train)] if valid else None
+    lgb.train(dict(_PARAMS, telemetry=True, train_steps_per_launch=steps),
+              train, 3, valid_sets=valid_sets)
+    tops = [s for s in get_tracer().spans() if s["name"] == span]
+    assert tops
+    for s in tops:
+        assert {k: s["args"].get(k) for k in want} == want

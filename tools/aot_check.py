@@ -575,8 +575,8 @@ def _quantized_launch_scan(topo, rows=200_000, features=67):
     ``use_quantized_grad``, whole: a live Booster's operands (hence the
     small table), the wrapper's program lowered for one abstract chip.  Held
     to the grow programs' gate (no copy of ``hist_buf``'s size, no stat axis
-    on the lanes), and to the integer form of the histogram kernel: its raw
-    planes are int32."""
+    on the lanes), to the integer form of the histogram kernel (its raw
+    planes are int32), and to a score update without a gather of the rows."""
     import lightgbm_tpu as lgb
     from jax.sharding import SingleDeviceSharding
     from lightgbm_tpu.boosting.launch import LaunchRunner
@@ -607,6 +607,135 @@ def _quantized_launch_scan(topo, rows=200_000, features=67):
     )
     faults = hist_form_faults(text, features)
     assert not faults, faults
+    return compiled
+
+
+_HLO_GATHER = re.compile(r" = \w+\[([\d,]*)\]\S* gather\(")
+
+
+def row_gather_faults(text, rows, scope=None):
+    """Gathers of the optimized HLO whose output has ``rows`` rows (8 ns an
+    element on the chip, PERF.md section 6, PR 34), in the whole program or
+    under one ``jax.named_scope`` of it."""
+    faults = set()
+    for line in text.splitlines():
+        m = _HLO_GATHER.search(line)
+        if m is None or (scope is not None and f"/{scope}/" not in line):
+            continue
+        if rows in [int(d) for d in m.group(1).split(",") if d]:
+            faults.add(line.strip()[:160])
+    return sorted(faults)
+
+
+def _checked_score_entry(topo, fn, rows, *args, **static):
+    """A score-update entry at a cell's size: no gather of its rows, and
+    temporaries that do not hold a [rows, leaves] one-hot (the compiler
+    builds it inside the matmul's fusion; the contraction's row blocks keep
+    the validation walk's temporaries from growing with the rows)."""
+    compiled = compile_on_topo(topo, fn, *args, **static)
+    faults = row_gather_faults(compiled.as_text(), rows)
+    assert not faults, faults
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= max(64 * rows, 512 << 20), temp
+    return compiled
+
+
+def _numeric_tree(leaves):
+    nn, i32 = leaves - 1, jnp.int32
+    return (
+        s((nn,), i32), s((nn,), i32), s((nn,), jnp.bool_), s((nn,), i32),
+        s((nn,), i32), s((leaves,), jnp.float32),
+    )
+
+
+@check("score update 8M rows, 255 leaves (boost/score_update): no row gather")
+def _score_update_entry(topo, rows=8_000_000, leaves=255):
+    from lightgbm_tpu.boosting.gbdt import _tree_score_impl
+
+    return _checked_score_entry(
+        topo, _tree_score_impl, rows,
+        s((1, rows), jnp.float32), s((leaves,), jnp.float32),
+        s((rows,), jnp.int32), s((), jnp.int32),
+    )
+
+
+@check(
+    "RF score update 8M rows x 255 leaves and 1M x 4,095 (rf/score_update): "
+    "no row gather, no [leaves, rows] one-hot"
+)
+def _rf_score_update_entry(topo):
+    from lightgbm_tpu.boosting.rf import _add_leaf_values_impl
+
+    for rows, leaves in ((8_000_000, 255), (1_000_000, 4095)):
+        compiled = _checked_score_entry(
+            topo, _add_leaf_values_impl, rows,
+            s((rows,), jnp.float32), s((leaves,), jnp.float32),
+            s((rows,), jnp.int32),
+        )
+    return compiled
+
+
+@check(
+    "valid score update 400k x 67, 255 leaves, numeric tree "
+    "(boost/valid_score_update): no row gather"
+)
+def _valid_score_update_entry(topo, rows=400_000, features=67, leaves=255):
+    from lightgbm_tpu.boosting.gbdt import _tree_valid_score_impl
+
+    nn = leaves - 1
+    return _checked_score_entry(
+        topo, _tree_valid_score_impl, rows,
+        s((1, rows), jnp.float32), s((rows, features), jnp.uint8),
+        s((features,), jnp.int32), *_numeric_tree(leaves),
+        s((nn,), jnp.bool_), s((nn, 1), jnp.bool_), s((), jnp.int32),
+    )
+
+
+@check(
+    "add_tree_to_score 8M x 67, 255 leaves, numeric tree "
+    "(predict/add_tree_to_score): no row gather"
+)
+def _add_tree_entry(topo, rows=8_000_000, features=67, leaves=255):
+    from lightgbm_tpu.predict import _add_tree_to_score_impl
+
+    return _checked_score_entry(
+        topo, _add_tree_to_score_impl, rows,
+        s((rows,), jnp.float32), s((rows, features), jnp.uint8),
+        s((features,), jnp.int32), *_numeric_tree(leaves),
+    )
+
+
+@check(
+    "add_tree_to_score 4 x 400k x 67 rows over four chips (row_mesh): "
+    "each shard's rows contracted locally, no collective, no row gather"
+)
+def _add_tree_row_sharded(topo, rows=1_600_000, features=67, leaves=255):
+    from lightgbm_tpu.predict import _add_tree_to_score_impl
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    by_rows, whole = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    tree = _numeric_tree(leaves)
+
+    def call(score_k, bins, nan_bins, *t):
+        return _add_tree_to_score_impl(
+            score_k, bins, nan_bins, *t, row_mesh=(mesh, "data")
+        )
+
+    compiled = jax.jit(
+        call,
+        in_shardings=[by_rows, NamedSharding(mesh, P("data", None))]
+        + [whole] * (1 + len(tree)),
+        out_shardings=by_rows,
+    ).lower(
+        s((rows,), jnp.float32), s((rows, features), jnp.uint8),
+        s((features,), jnp.int32), *tree,
+    ).compile()
+    text = compiled.as_text()
+    assert not row_gather_faults(text, rows // 4), row_gather_faults(text, rows // 4)
+    talk = re.findall(r"\b(all-reduce|all-gather|all-to-all|collective-permute)\(", text)
+    assert not talk, sorted(set(talk))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 512 << 20, temp
     return compiled
 
 

@@ -25,7 +25,8 @@ python -m lightgbm_tpu.lint --baseline lint_baseline.json || rc=$?
 
 # graftlint IR gate: trace the real jit/shard_map entry matrix to jaxprs
 # (abstract CPU tracing, no execution) and audit collectives, dtype
-# promotion, donation and Pallas VMEM budgets (GL011-GL015).  Also a
+# promotion, donation, Pallas VMEM budgets and row gathers in the score
+# update (GL011-GL016).  Also a
 # hard gate, full matrix in CI (--changed-only scopes it in the dev
 # loop); budgeted <30 s on top of the AST pass.
 echo "=== graftlint IR (python -m lightgbm_tpu.lint --ir --baseline lint_baseline.json) ==="
