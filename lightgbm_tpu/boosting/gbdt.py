@@ -899,6 +899,7 @@ class Booster:
             cfg, n_sampler, is_pos, query_sizes=query_sizes,
             pad_query_mask=pad_query_mask,
         )
+        self._sampler.set_live_count(self._rows_to_sample())
         self._gathered_label = None  # free the init-time global label copy
 
         # metrics for the training set.  Multi-process pre_partition: metric
@@ -1422,6 +1423,7 @@ class Booster:
 
     def _make_grower_params(self) -> GrowerParams:
         from ..ops.split import CatParams
+        from .sampling import sampling_is_active
 
         cfg = self.config
         hist_method = str(self.params.get("hist_method", "auto"))
@@ -1654,6 +1656,17 @@ class Booster:
             # the integer kernels (grow_tree, quant_scales)
             hist_acc=cfg.hist_acc,
             near_tie_tol=cfg.hist_near_tie_tol,
+            # trees grown on a subset of the rows (GOSS, bagging, rf, a
+            # fixed row mask) get the in-bag window on the segment path;
+            # grow_tree gates it further (grower.bag_window_ok).  A booster
+            # that samples nothing keeps the program it always traced
+            bag_window=(
+                hist_mode == "seg"
+                and (
+                    sampling_is_active(cfg)
+                    or getattr(self, "_fixed_row_mask", None) is not None
+                )
+            ),
         )
 
     def _fit_linear_leaves(
@@ -1976,7 +1989,8 @@ class Booster:
         if row_mask is None:
             self._fixed_row_mask = None
             if sampler is not None:
-                sampler.set_live_count(None)
+                sampler.set_live_count(self._rows_to_sample())
+            self._refresh_grower_params()
             return
         m = np.asarray(row_mask, dtype=np.float32).reshape(-1)
         if m.shape[0] != self.train_set.num_data:
@@ -1992,6 +2006,26 @@ class Booster:
         self._fixed_row_mask = jnp.asarray(m)
         if sampler is not None:
             sampler.set_live_count(live)
+        self._refresh_grower_params()
+
+    def _rows_to_sample(self) -> Optional[int]:
+        """The sampler's live count without a fixed row mask: the table's
+        own rows where a mesh padded them (GOSS sizes its top and rest sets
+        against real rows, so a mesh and one device draw the same bag), else
+        None (the sampler's width is the table)."""
+        if self._pad_rows and not self._multiproc:
+            return int(self.train_set.num_data)
+        return None
+
+    def _refresh_grower_params(self) -> None:
+        """A fixed row mask set or cleared moves ``bag_window``: rebuild the
+        grower's parameters (and the sharded grower made from them) when
+        they changed, and only then."""
+        params = self._make_grower_params()
+        if params != self._grower_params:
+            self._grower_params = params
+            if self._mesh is not None:
+                self._setup_sharded_grower()
 
     def _sample(self, grad, hess):
         """Bagging/GOSS row sampling; padded (mesh-fill) rows never count.

@@ -66,12 +66,19 @@ from ..ops.grower import _pack_tree_arrays_impl, grow_tree, unpack_tree_arrays
 from ..ops.quantize import quantize_gradients, renew_leaf_values
 from ..ops.score_lookup import leaf_lookup
 from ..resilience import NumericsError, chaos
+from .sampling import SampleStrategy
 from ..utils.log import log_warning
 
 _EPS = 1e-15
 
 
 # --------------------------------------------------------------- validation
+
+
+# what a sampling booster's launch reports an iteration (flight recorder's
+# ``launch`` event, ``train/launch`` span args): rows in the bag, GOSS's rows
+# at or above its threshold, and the rows equal to it
+_BAG_COUNTERS = ("in_bag_rows", "top_rows", "threshold_ties")
 
 
 def resolve_requested_steps(cfg) -> int:
@@ -248,6 +255,12 @@ class LaunchRunner:
         self._snap_objective = booster.objective
         self._snap_grower_params = booster._grower_params
         self._snap_bins_shape = booster._bins.shape
+        # a sampling booster's bag counters (in-bag rows, GOSS's top rows
+        # and threshold ties) ride out at the tail of every packed ints row;
+        # a booster that samples nothing keeps the scan it always traced
+        self._bag_tail = (
+            len(_BAG_COUNTERS) if type(booster._sampler) is not SampleStrategy else 0
+        )
         self._fn = instrumented_jit(
             self._launch_impl,
             label=f"grow/scan{self._n}",
@@ -371,6 +384,11 @@ class LaunchRunner:
                 mask = mask * ones_mask
             if has_fixed:
                 mask = mask * fixed
+            if self._bag_tail:
+                bag_tail = jnp.stack([
+                    jnp.sum(mask > 0, dtype=jnp.int32),
+                    *sampler.scan_counters(),
+                ])
             # 5) per-class grow + gated score update
             rng_cur = rng_b
             new_score = score
@@ -415,6 +433,8 @@ class LaunchRunner:
                     new_score = jnp.where(upd, cand, new_score)
                 any_split = jnp.logical_or(any_split, has_split)
                 ii, ff = _pack_tree_arrays_impl(ta)
+                if self._bag_tail:
+                    ii = jnp.concatenate([ii, bag_tail])
                 ints_rows[kk] = ii
                 floats_rows[kk] = ff
             zi = next(v for v in ints_rows if v is not None)
@@ -610,8 +630,12 @@ class LaunchRunner:
                 for kk in range(k):
                     grown = None
                     if self._trains[kk]:
+                        row = ints[s, kk]
+                        if self._bag_tail:
+                            row, tail = np.split(row, [-self._bag_tail])
+                            rec.update(zip(_BAG_COUNTERS, map(int, tail)))
                         ta_host = unpack_tree_arrays(
-                            ints[s, kk], floats[s, kk], self._nn, self._L
+                            row, floats[s, kk], self._nn, self._L
                         )
                         if cfg.check_numerics:
                             b._guard_tree(ta_host, it)
@@ -672,6 +696,14 @@ class LaunchRunner:
             "records": records,
             "finished": bool(is_finished),
         }
+        if self._bag_tail:
+            # one value an iteration, in iteration order
+            bag = {
+                name: [r.get(name, 0) for r in records] for name in _BAG_COUNTERS
+            }
+            event.update(bag)
+            if span is not None:
+                span.args.update(bag)
         if phases:
             event["phases"] = {k2: v * 1e3 for k2, v in phases.items()}
         if (

@@ -36,6 +36,7 @@ from ..obs.collectives import timed_pmax, timed_pmin, timed_psum
 from ..obs.jit import instrumented_jit
 from ..obs.trace import get_tracer
 from .histogram import leaf_histogram
+from .score_lookup import ONEHOT_MAX_LEAVES, tree_leaves
 from .split import CatParams, SplitCandidate, best_split, leaf_gain, leaf_output
 
 
@@ -191,6 +192,30 @@ class GrowerParams:
     # switch indices — measured ~8x per-member at 64k rows).  Capacity only
     # pads, so the max member's bucket is value-preserving for the rest.
     fleet_axis_name: Optional[str] = None
+    # row sampling on the segment path (GOSS, bagging, rf, a fixed row
+    # mask): after pack_rows ONE stable partition on "mask plane > 0"
+    # brings the in-bag rows to the front of the packed buffer, the root
+    # window is [0, n_in_bag), and every later partition and histogram call
+    # sees in-bag rows only (upstream's bag_data_indices; the windows are
+    # dynamic over a static buffer, so no capacity is guessed).  Rows that
+    # were never partitioned have no segment position: every row's leaf
+    # comes from the tree's bin-space walk (score_lookup.tree_leaves) in
+    # place of the leaf_ids sort, so the returned leaf_id is whole.  False
+    # (no sampler) traces the program as it was.  Engages only where
+    # bag_window_ok holds; boosting/gbdt.py resolves it.
+    bag_window: bool = False
+
+
+def bag_window_ok(p: "GrowerParams", cat_width: int) -> bool:
+    """Whether a tree of these parameters can be grown on the in-bag window
+    alone: the segment path, rows not replicated over feature shards, and a
+    numeric tree small enough for the contraction walk that finds the
+    out-of-bag rows' leaves (a wider ``cat_mask`` or more leaves keep the
+    masked whole-table path)."""
+    return (
+        p.hist_mode == "seg" and p.feature_shard <= 1 and cat_width <= 1
+        and p.num_leaves <= ONEHOT_MAX_LEAVES
+    )
 
 
 def _hist_caps(n: int, full_range: bool = False) -> list:
@@ -891,6 +916,10 @@ def grow_tree(
         return m
 
     use_seg = p.hist_mode == "seg" and f > 0 and n > 1
+    # grown on the in-bag rows alone (GrowerParams.bag_window); n_root is
+    # the root window's row count: n, or the in-bag rows once compacted
+    bag_window = p.bag_window and use_seg and bag_window_ok(p, Bm)
+    n_root = n
     use_ordered = p.hist_mode == "ordered" and f > 0 and n > 1
     use_gather = p.hist_mode == "gather" and f > 0 and n > 1
     # voting-parallel: histograms stay LOCAL; only elected slices are
@@ -1106,6 +1135,21 @@ def grow_tree(
         # plane, and the kernels whose predicate reads that plane in place
         # (the fused step, the batched partition) are not its to take
         seg_grouped = is_grouped(seg0)
+        if bag_window:
+            # in-bag rows to the front, in their own order; with every row
+            # in the bag (GOSS's first iterations) nothing moves
+            with jax.named_scope("bag_compact"):
+                in_bag = jnp.pad(
+                    (count_mask > 0).astype(jnp.float32), (0, n_pad_seg - n)
+                )
+                zero = jnp.int32(0)
+                seg0, n_root, _ = sort_partition(
+                    seg0, zero, jnp.int32(n), zero, zero, zero,
+                    jnp.int32(-1), zero, jnp.zeros((1,), jnp.float32),
+                    f=f_seg, n_pad=n_pad_seg, wide=seg_wide, gl_vec=in_bag,
+                    fleet_axis_name=p.fleet_axis_name,
+                    measure=p.measure_collectives, bag_compact=True,
+                )
         if seg_grouped and leaf_k > 1:
             raise ValueError(
                 f"leaf_batch > 1 does not support a packed row of "
@@ -1367,7 +1411,7 @@ def grow_tree(
     )
     with jax.named_scope("root_histogram"):  # jax.profiler trace labels
         if use_seg:
-            hist0 = _seg_hist(seg0, jnp.int32(0), jnp.int32(n))
+            hist0 = _seg_hist(seg0, jnp.int32(0), jnp.int32(n_root))
         else:
             hist0 = leaf_histogram(
                 bins_loc, grad, hess, count_mask, B,
@@ -1419,7 +1463,7 @@ def grow_tree(
         near0 = margin0 < p.near_tie_tol
         hist0_f = _seg_hist(
             seg0, jnp.int32(0),
-            jnp.where(near0, n, 0).astype(jnp.int32), qs=None,
+            jnp.where(near0, n_root, 0).astype(jnp.int32), qs=None,
         )
         cand0 = cand_for_leaf(
             jnp.where(near0, hist0_f, hist0),
@@ -1460,7 +1504,7 @@ def grow_tree(
             # the order slot carries the packed segment matrix in seg mode
             order0 = seg0
             leaf_begin0 = jnp.zeros((L,), jnp.int32)
-            leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n)
+            leaf_nrows0 = jnp.zeros((L,), jnp.int32).at[0].set(n_root)
             leaf_id0 = jnp.zeros((0,), jnp.int32)
         else:
             order0 = jnp.zeros((0,), jnp.int32)
@@ -2987,6 +3031,18 @@ def grow_tree(
         cat_mask=state.node_cat_mask,
     )
 
+    if bag_window:
+        # the rows past the root window were never partitioned, so their
+        # leaf is where the tree's own walk takes them; the walk gives the
+        # in-bag rows the partition's leaf too (one predicate over one
+        # matrix), and at 8M x 67 it reads 11 ms where leaf_ids' marker
+        # cumsum, gather and sort read 93 (PERF.md section 6, PR 35): every
+        # row's leaf comes from it
+        with jax.named_scope("oob_score"):
+            return tree, tree_leaves(
+                bins, nan_bins, tree.split_feature, tree.split_bin,
+                tree.default_left, tree.left_child, tree.right_child,
+            )
     if use_seg:
         # leaf per segment position (marker-cumsum) -> row order via ONE sort
         # (the scatter alternative serializes on TPU)

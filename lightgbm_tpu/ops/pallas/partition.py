@@ -563,12 +563,7 @@ def _seg_partition_kernel(
     )
 
 
-@functools.partial(
-    instrumented_jit,
-    static_argnames=("f", "n_pad", "use_cat", "wide", "interpret",
-                     "read_via_input"),
-)
-def seg_partition_pallas(
+def _seg_partition(
     seg: jnp.ndarray,  # [LANES, n_pad] i16 plane-major packed rows
     scal: jnp.ndarray,  # [8] i32: sbegin, cnt, feat, tbin, dl, nanb, iscat, 0
     catmask: jnp.ndarray,  # [1, bmt] f32 (bmt >= 256, 128-multiple)
@@ -641,6 +636,27 @@ def seg_partition_pallas(
         interpret=interpret,
     )(scal.reshape(1, 8), seg, catmask, tri, gl_arr)
     return seg_new, nl[0, 0]
+
+
+_STATIC = ("f", "n_pad", "use_cat", "wide", "interpret", "read_via_input")
+
+
+@functools.partial(instrumented_jit, static_argnames=_STATIC)
+def seg_partition_pallas(*args, **kwargs):
+    return _seg_partition(*args, **kwargs)
+
+
+seg_partition_pallas.__doc__ = _seg_partition.__doc__
+
+
+@functools.partial(instrumented_jit, static_argnames=_STATIC)
+def bag_compact_pallas(*args, **kwargs):
+    """``seg_partition_pallas`` under a name of its own: the once-a-tree
+    stable partition on the bag's bits (``gl_vec`` = in-bag) that brings the
+    in-bag rows to the front of the packed buffer (ops/grower.py,
+    ``bag_window``).  A device trace names a kernel by its entry, and the
+    splits' partitions are read against the splits' work."""
+    return _seg_partition(*args, **kwargs)
 
 
 @functools.partial(

@@ -40,25 +40,36 @@ def _fmix32(x: jnp.ndarray) -> jnp.ndarray:
     return x ^ (x >> 16)
 
 
-def rounding_uniforms(seed, tree_index, n: int, stream: int) -> jnp.ndarray:
-    """[n] f32 in [0, 1): the stochastic-rounding offset of every row, a
-    pure function of (seed, tree index, row index, stream 0 = gradient |
-    1 = hessian) and of nothing else.  No key, no host state: row r of the
-    global [N] array draws the same offset inside the launch scan and out
-    of it, on one device and under any mesh layout (the iota is the global
-    row index under SPMD partitioning), and an independent reference can
-    write the same 24 bits in NumPy (benchmark/reference/criteo67-quant.py).
-
-    ``u = (fmix32(row ^ fmix32(seed ^ (2 * tree_index + stream) *
-    0x9E3779B9)) >> 8) * 2**-24``; ``tree_index`` is iteration *
-    trees-per-iteration + class."""
+def hashed_uniforms(seed, word, n: int) -> jnp.ndarray:
+    """[n] f32 in [0, 1), 24 bits each: ``u = (fmix32(row ^ fmix32(seed ^
+    word * 0x9E3779B9)) >> 8) * 2**-24`` for the rows 0..n-1 of the global
+    [N] array — a pure function of (seed, word, row index).  No key, no host
+    state: row r draws the same value inside the launch scan and out of it,
+    on one device and under any mesh layout (the iota is the global row
+    index under SPMD partitioning), and an independent reference can write
+    the same 24 bits in NumPy.  ``word`` names the stream: the rounding
+    offsets of quantized training take the words below 2**31
+    (:func:`rounding_uniforms`), GOSS's rest draws those above
+    (boosting/sampling.py)."""
     u32 = jnp.uint32
-    word = jnp.asarray(tree_index).astype(u32) * u32(2) + u32(stream)
+    word = jnp.asarray(word).astype(u32)
     key = _fmix32(jnp.asarray(seed).astype(u32) ^ (word * u32(0x9E3779B9)))
     rows = jnp.arange(n, dtype=jnp.uint32)
     x = _fmix32(rows ^ key)
     # 24 bits: every value is an exact f32 and u < 1
     return (x >> 8).astype(jnp.int32).astype(jnp.float32) * jnp.float32(2.0**-24)
+
+
+def rounding_uniforms(seed, tree_index, n: int, stream: int) -> jnp.ndarray:
+    """[n] f32 in [0, 1): the stochastic-rounding offset of every row, a
+    pure function of (seed, tree index, row index, stream 0 = gradient |
+    1 = hessian) and of nothing else (:func:`hashed_uniforms` on the word
+    ``2 * tree_index + stream``; benchmark/reference/criteo67-quant.py writes
+    the same bits in NumPy).  ``tree_index`` is iteration *
+    trees-per-iteration + class."""
+    u32 = jnp.uint32
+    word = jnp.asarray(tree_index).astype(u32) * u32(2) + u32(stream)
+    return hashed_uniforms(seed, word, n)
 
 
 @functools.partial(

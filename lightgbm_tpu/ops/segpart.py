@@ -236,7 +236,7 @@ def sort_partition_xla(
 def sort_partition(
     seg, sbegin, cnt, feat, tbin, dl, nanb, iscat, catmask, *, f: int,
     n_pad: int, wide: bool = False, gl_vec=None, fleet_axis_name=None,
-    measure: bool = False,
+    measure: bool = False, bag_compact: bool = False,
 ):
     """Platform dispatch for the segment partition: the Pallas streaming
     kernel on TPU (ops/pallas/partition.py — exact window, in place, no
@@ -246,9 +246,16 @@ def sort_partition(
     ``gl_vec`` (feature-parallel seg, grouped matrices): the go-left
     decision comes from a precomputed [n_pad] bit vector (``go_left_bits``);
     the Pallas kernel DMAs a bits tile per row tile instead of reading the
-    feature column.  A grouped matrix cannot do without it."""
+    feature column.  A grouped matrix cannot do without it.
+
+    ``bag_compact``: the once-a-tree pass that brings the in-bag rows to the
+    front (ops/grower.py, ``bag_window``).  The same kernel under a name of
+    its own (``bag_compact_pallas``), so a device trace tells it from the
+    splits' partitions."""
     from .pallas import partition as _part
-    from .pallas.partition import seg_partition_pallas
+    from .pallas.partition import bag_compact_pallas, seg_partition_pallas
+
+    kernel = bag_compact_pallas if bag_compact else seg_partition_pallas
     from ..obs.collectives import timed_pmax
 
     use_gl = gl_vec is not None
@@ -276,7 +283,7 @@ def sort_partition(
         scal = jnp.stack(
             [sbegin, cnt, feat, tbin, dl, nanb, iscat, jnp.int32(0)]
         ).astype(jnp.int32)
-        seg_new, nl = seg_partition_pallas(
+        seg_new, nl = kernel(
             seg, scal, catm, maybe_gl[0] if maybe_gl else None,
             f=f, n_pad=n_pad, use_cat=bm > 1, wide=wide,
             interpret=interpret,

@@ -128,6 +128,10 @@ def test_every_scope_of_the_grower_and_the_launch_scan_is_published(scopes_cache
         {"hist_mode": "seg"},
         {"hist_mode": "seg", "grow_fused": "off"},
         {"train_steps_per_launch": 2, "bagging_fraction": 0.6, "bagging_freq": 1},
+        # a sampling booster on the segment path: the in-bag window's scopes
+        # (bag_compact, oob_score)
+        {"hist_mode": "seg", "train_steps_per_launch": 2, "bagging_fraction": 0.6,
+         "bagging_freq": 1},
     ):
         lgb.train(dict(_PARAMS, **extra), lgb.Dataset(X, y), 2)
     # a packed row of two plane groups: the go-left pass ahead of the
@@ -138,9 +142,12 @@ def test_every_scope_of_the_grower_and_the_launch_scan_is_published(scopes_cache
     monkeypatch.setattr(partition, "_INTERPRET", True)
     Xw, yw = _data(n=600, f=243)
     lgb.train(dict(_PARAMS, hist_mode="seg", num_leaves=4), lgb.Dataset(Xw, yw), 1)
+    # map by map: ``op_scopes()`` merges the maps of one module name and calls
+    # an instruction two of them scope differently ambiguous, and a grow
+    # program an earlier test of this process traced is such a second map
     published = set()
-    for scopes in op_scopes().values():
-        for path in scopes.values():
+    for doc in obs_jit.op_scope_maps():
+        for path in doc["scopes"].values():
             published.update(path.split("/"))
     used = set()
     for rel in ("lightgbm_tpu/ops/grower.py", "lightgbm_tpu/boosting/launch.py"):

@@ -610,6 +610,111 @@ def _quantized_launch_scan(topo, rows=200_000, features=67):
     return compiled
 
 
+_GOSS = dict(data_sample_strategy="goss", top_rate=0.2, other_rate=0.1)
+
+
+def _kernel_names(text):
+    return {
+        m.group(1)
+        for m in re.finditer(r"%([\w.\-]+?)(?:\.\d+)? = .*custom-call\(.*tpu_custom_call", text)
+    }
+
+
+def _goss_scan_body(topo, rows=8_000_000, features=67):
+    """The grow program the launch scan's body traces for ``criteo67-goss``
+    (two-launch kernels, ``bag_window``), at the cell's own size: the
+    compaction kernel under its own name, the splits' partition under its,
+    the out-of-bag walk without a gather of the rows."""
+    compiled = _checked_grow_program(
+        topo, features=features, rows=rows, grow_fused="off", hist_acc="bf16",
+        **_GOSS,
+    )
+    text = compiled.as_text()
+    kernels = _kernel_names(text)
+    assert {"bag_compact_pallas", "seg_partition_pallas"} <= kernels, kernels
+    # leaf_ids keeps the gather it has in every grow program (a leaf per
+    # segment position, PERF.md section 7); what this PR adds has none
+    faults = [
+        f for scope in ("bag_compact", "oob_score")
+        for f in row_gather_faults(text, rows, scope=scope)
+    ]
+    assert not faults, faults
+    return compiled
+
+
+CHECKS[
+    "grow program 8M x 67, 255 leaves, GOSS (criteo67-goss): launch-scan body "
+    "on the in-bag window"
+] = _goss_scan_body
+
+
+@check("GOSS sample 8M rows (sampling/goss_sample): no sort, no row gather")
+def _goss_sample_entry(topo, rows=8_000_000):
+    """The threshold is an exact selection by comparisons and sums: no sort
+    of the rows (the parent's ``jnp.sort``), no gather of them, and
+    temporaries that hold no [15, rows] compare."""
+    from lightgbm_tpu.boosting.sampling import goss_sample
+
+    compiled = compile_on_topo(
+        topo, goss_sample,
+        s((1, rows), jnp.float32), s((1, rows), jnp.float32),
+        s((), jnp.uint32), s((), jnp.uint32),
+        n=rows, top_k=rows // 5, other_k=rows // 10,
+    )
+    text = compiled.as_text()
+    assert not re.search(r" sort\(", text), "a sort in goss_sample"
+    faults = row_gather_faults(text, rows)
+    assert not faults, faults
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 64 * rows, temp
+    return compiled
+
+
+@check(
+    "launch scan, GOSS, 200k x 67, 255 leaves: 8 x (sample + compaction + "
+    "two-launch grow on the window + out-of-bag walk)"
+)
+def _goss_launch_scan(topo, rows=200_000, features=67):
+    """``lgb.train``'s eight-step ``lax.scan`` of a booster with
+    ``data_sample_strategy=goss``, whole (a live Booster's operands, hence
+    the small table): the compaction kernel is in it, no sort but the
+    ``leaf_ids`` one a tree, no gather of the rows."""
+    import lightgbm_tpu as lgb
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.boosting.launch import LaunchRunner
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(rows, features)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with _as_tpu_process():
+        booster = lgb.Booster(
+            dict(_HIGGS, hist_acc="bf16", **_GOSS),
+            lgb.Dataset(x, y, params={"max_bin": 255}),
+        )
+        assert booster._grower_params.hist_mode == "seg"
+        assert booster._grower_params.bag_window
+        runner = LaunchRunner(booster, 8)
+        operands = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            runner._operands(0)[0],
+        )
+        compiled = runner._fn.lower(*operands).compile()
+    text = compiled.as_text()
+    assert "bag_compact_pallas" in _kernel_names(text), _kernel_names(text)
+    sorts = [
+        ln for ln in text.splitlines()
+        if re.search(r" sort\(", ln) and f"[{rows}]" in ln
+    ]
+    assert len(sorts) <= 1, f"{len(sorts)} sorts of the rows (leaf_ids' is the one)"
+    faults = hist_form_faults(text, features) + [
+        f for scope in ("sample", "bag_compact", "oob_score", "score_update")
+        for f in row_gather_faults(text, rows, scope=scope)
+    ]
+    assert not faults, faults
+    return compiled
+
+
 _HLO_GATHER = re.compile(r" = \w+\[([\d,]*)\]\S* gather\(")
 
 
