@@ -41,13 +41,14 @@ from ..resilience import NumericsError, chaos
 from ..obs.jit import instrumented_jit
 from ..ops.grower import (
     GrowerParams,
+    bag_window_ok,
     cat_mask_width,
     fetch_tree_arrays,
     grow_tree,
     pack_tree_arrays_donated,
     unpack_tree_arrays,
 )
-from ..ops.score_lookup import leaf_lookup, lookup_form
+from ..ops.score_lookup import leaf_ids_form, leaf_lookup, lookup_form
 from ..predict import (
     BinTreeBatch,
     StreamingPredictor,
@@ -1369,19 +1370,33 @@ class Booster:
         over the training rows: "onehot" | "gather") and ``valid_walk`` (a
         new tree over a validation set: "contract" | "walk", "none" where no
         device walk runs: no validation set, or linear trees, whose scores
-        advance on the host).  Both follow static shapes alone."""
+        advance on the host), and ``leaf_ids`` (how a tree grown on the
+        segment path gives every row its leaf: "walk" | "segment", "none"
+        off that path).  All follow static shapes alone."""
         p = getattr(self, "_grower_params", None)
         if p is None:
             return {}
+        # the width the grower gives its trees' cat_mask (the Booster hands
+        # it ``is_cat`` / ``bundle_end`` exactly when these flags are set),
+        # which is what ``count_valid_tree`` reads off the tree
+        width = cat_mask_width(p.use_cat, p.use_bundle, int(p.max_bin))
         if not self._valid or self.config.linear_tree:
             walk = "none"
         else:
-            # the width the grower gives its trees' cat_mask (the Booster
-            # hands it ``is_cat`` / ``bundle_end`` exactly when these flags
-            # are set), which is what ``count_valid_tree`` reads off the tree
-            width = cat_mask_width(p.use_cat, p.use_bundle, int(p.max_bin))
             walk = valid_walk_form(int(p.num_leaves), width)
-        return {"score_lookup": lookup_form(int(p.num_leaves)), "valid_walk": walk}
+        if p.hist_mode != "seg" or self.train_set is None:
+            leaf_ids = "none"
+        elif p.bag_window and bag_window_ok(p, width):
+            leaf_ids = "walk"
+        else:
+            leaf_ids = leaf_ids_form(
+                int(p.num_leaves), int(self._bins.shape[1]), width,
+                int(p.feature_shard),
+            )
+        return {
+            "score_lookup": lookup_form(int(p.num_leaves)),
+            "valid_walk": walk, "leaf_ids": leaf_ids,
+        }
 
     def _seg_span_args(self) -> Dict[str, int]:
         """Plane groups of the packed row and the planes a group, and the

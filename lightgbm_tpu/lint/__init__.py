@@ -51,8 +51,9 @@ GL014  pallas kernel's static VMEM working set (2x operand blocks +
 GL015  host callback compiled into a hot entry outside the sanctioned
        obs.collectives wrappers (per-iteration device->host round trip)
 GL016  gather of the table's rows in the score update of a
-       score-update entry (~8 ns an element on the TPU; the one-hot
-       contractions of ops/score_lookup.py run at memory speed)
+       score-update entry, or under scope leaf_ids of a grow program
+       whose shapes take the walk (~8 ns an element on the TPU; the
+       one-hot contractions of ops/score_lookup.py run at memory speed)
 =====  ==============================================================
 
 GL007–GL010 share one SPMD index (``callgraph.SpmdIndex``): a

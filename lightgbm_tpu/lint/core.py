@@ -139,10 +139,11 @@ RULES: Dict[str, Tuple[str, str]] = {
     ),
     "GL016": (
         "gather of the table's rows inside the score update of a "
-        "score-update entry",
+        "score-update entry, or under scope leaf_ids of a grow program "
+        "whose shapes take the walk",
         "look the tree's output up with ops.score_lookup.leaf_lookup / "
-        "tree_values (one-hot contractions): a TPU gather costs ~8 ns an "
-        "element whatever the table's size",
+        "tree_values / tree_leaves (one-hot contractions): a TPU gather "
+        "costs ~8 ns an element whatever the table's size",
     ),
 }
 

@@ -33,8 +33,10 @@ jaxpr: pjit, scan, while, cond branches, shard_map, pallas_call):
   per-iteration carried buffers declared in each spec;
 * ``gather`` eqns with their output shape and ``jax.named_scope`` path —
   GL016 holds the score update of the entries that declare their table's
-  rows (the whole entry, or the part under a named scope) to no gather of
-  that many rows (8 ns an element on the TPU; ops/score_lookup.py has the
+  rows (the whole entry, or the part under a named scope: ``score_update``
+  in the launch scan, ``leaf_ids`` in the segment-path grow program where
+  ``score_lookup.leaf_ids_form`` says "walk") to no gather of that many
+  rows (8 ns an element on the TPU; ops/score_lookup.py has the
   contractions).
 
 The entry registry is explicit: every spec names its expected collective
@@ -174,7 +176,8 @@ class EntrySpec:
     root_modules: Tuple[str, ...] = ()  # package-relative .py paths
     # rows of the entry's table: GL016 holds the score update of such an
     # entry to no gather with that many rows — the whole entry, or where
-    # the entry does more (the launch scan) what lies under ``score_scope``
+    # the entry does more (the launch scan, a grow program) what lies
+    # under ``score_scope``
     score_rows: Optional[int] = None
     score_scope: Optional[str] = None
 
@@ -520,7 +523,8 @@ def build_entry_specs() -> List[EntrySpec]:
 
     # ---- grower entries (serial / data / batched+overlap / hybrid)
     def grow_entry(name, layout, data, feature, leaf_batch=1, overlap=False,
-                   measure=False, hist_mode="ordered"):
+                   measure=False, hist_mode="ordered", score_rows=None,
+                   score_scope=None):
         def build():
             from ..parallel.mesh import MeshSpec, make_mesh_grow
 
@@ -560,7 +564,10 @@ def build_entry_specs() -> List[EntrySpec]:
                 "obs/collectives.py",
                 "ops/histogram.py",
                 "ops/split.py",
+                "ops/score_lookup.py",
             ),
+            score_rows=score_rows,
+            score_scope=score_scope,
         )
 
     specs.append(grow_entry("grow/serial", "data", 1, 1))
@@ -581,7 +588,18 @@ def build_entry_specs() -> List[EntrySpec]:
     )
     # fused grow step (hist_mode="seg" implies grow_fused): the TPU
     # production path — traces the seg/partition pallas kernels for GL014
-    specs.append(grow_entry("grow/seg_fused", "data", 1, 1, hist_mode="seg"))
+    # where score_lookup.leaf_ids_form says "walk" (these shapes do), GL016
+    # holds the scope ``leaf_ids`` to no gather of the table's rows: the
+    # segment form's ``sorted_leaf[seg_ord]`` is one
+    from ..ops.score_lookup import leaf_ids_form
+
+    walks = leaf_ids_form(L, F, 1, 1) == "walk"
+    specs.append(
+        grow_entry(
+            "grow/seg_fused", "data", 1, 1, hist_mode="seg",
+            score_rows=N if walks else None, score_scope="leaf_ids",
+        )
+    )
 
     # ---- fleet grow (perf-gate fleet scenario): the M=4 vmapped grow
     # step on the data mesh.  Every collective payload inside the member

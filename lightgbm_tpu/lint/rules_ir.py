@@ -364,6 +364,10 @@ def check_score_row_gathers(
             if rows not in g.out_shape:
                 continue
             fr = g.frames[0]
+            where = (
+                "in its score update" if scope in (None, "score_update")
+                else f"under scope '{scope}'"
+            )
             out.append(
                 Finding(
                     "GL016",
@@ -371,7 +375,7 @@ def check_score_row_gathers(
                     fr.line,
                     f"row_gather:{te.spec.name}:{fr.func}",
                     f"entry '{te.spec.name}' gathers {list(g.out_shape)} "
-                    f"in its score update: a gather of the "
+                    f"{where}: a gather of the "
                     f"table's {rows} rows costs ~8 ns an element on the "
                     f"TPU where ops/score_lookup.py's contractions run at "
                     f"memory speed",

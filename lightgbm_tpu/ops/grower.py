@@ -36,7 +36,7 @@ from ..obs.collectives import timed_pmax, timed_pmin, timed_psum
 from ..obs.jit import instrumented_jit
 from ..obs.trace import get_tracer
 from .histogram import leaf_histogram
-from .score_lookup import ONEHOT_MAX_LEAVES, tree_leaves
+from .score_lookup import ONEHOT_MAX_LEAVES, leaf_ids_form, tree_leaves
 from .split import CatParams, SplitCandidate, best_split, leaf_gain, leaf_output
 
 
@@ -3031,21 +3031,28 @@ def grow_tree(
         cat_mask=state.node_cat_mask,
     )
 
-    if bag_window:
-        # the rows past the root window were never partitioned, so their
-        # leaf is where the tree's own walk takes them; the walk gives the
-        # in-bag rows the partition's leaf too (one predicate over one
-        # matrix), and at 8M x 67 it reads 11 ms where leaf_ids' marker
-        # cumsum, gather and sort read 93 (PERF.md section 6, PR 35): every
-        # row's leaf comes from it
-        with jax.named_scope("oob_score"):
+    if bag_window or (
+        use_seg
+        and leaf_ids_form(p.num_leaves, f, Bm, p.feature_shard) == "walk"
+    ):
+        # every row's leaf from the tree's own walk: one predicate over one
+        # matrix, so a row gets the leaf the partition put it in, and at
+        # 8M x 67 it reads 11 ms where leaf_ids' marker cumsum, gather and
+        # sort read 93 (PERF.md section 6, PR 35).  Under bag_window it is
+        # the only form (the rows past the root window were never
+        # partitioned); elsewhere score_lookup.leaf_ids_form says where it
+        # is the cheaper one
+        members = lax.axis_size(p.fleet_axis_name) if p.fleet_axis_name else 1
+        with jax.named_scope("oob_score" if bag_window else "leaf_ids"):
             return tree, tree_leaves(
                 bins, nan_bins, tree.split_feature, tree.split_bin,
                 tree.default_left, tree.left_child, tree.right_child,
+                members=members,
             )
     if use_seg:
         # leaf per segment position (marker-cumsum) -> row order via ONE sort
-        # (the scatter alternative serializes on TPU)
+        # (the scatter alternative serializes on TPU): categorical and
+        # bundled trees, feature shards, trees past the walk's size
         with jax.named_scope("leaf_ids"):
             lp = leaf_of_positions(
                 state.leaf_begin, state.leaf_nrows, state.num_leaves, n

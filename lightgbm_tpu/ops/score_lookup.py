@@ -38,6 +38,22 @@ from jax import lax
 # no cell of the benchmark has more than 255 leaves
 ONEHOT_MAX_LEAVES = 4096
 
+# a booster that HAS ``leaf_ids`` to fall back on (the segment path: every
+# row sits at a segment position of its leaf) takes the walk only while it is
+# the cheaper form.  The walk is about 2 * N * Jp * (F + Lp) bf16
+# multiply-adds (column pick [Jp, F] x [F, N], path match [Jp, Lp] x [Jp, N];
+# Jp, Lp the node and leaf counts padded to 128); ``leaf_ids`` (a scatter of L
+# marks, a cumsum, a gather of one leaf a position, a sort of N pairs) costs
+# the rows alone.  Both forms alone on a v5e (PERF.md section 6, PR 36), walk
+# against segment in ms: 8M x 67 rows at 255 / 511 / 767 / 1,023 leaves 11.4
+# / 38.4 / 69.6 / 121.2 against 85.7 / 85.6 / 62.8 / 62.8; 10.5M x 28 14.2 /
+# 48.4 / 90.6 / 152.0 against 124.1 / 124.1 / 93.8 / 93.9; 400,000 x 2,000 at
+# 255 / 511 / 1,023 leaves 3.12 / 6.28 / 14.35 against 3.88 / 3.87 / 2.72.
+# The walk reads 1.2e-5 to 1.7e-5 ns a row a unit of Jp * (F + Lp), the
+# segment form 6.8 to 11.8 ns a row; they cross at 5.7e5 / 6.4e5 / 7.5e5 on
+# the three tables, so the bound on Jp * (F + Lp) is
+LEAF_WALK_MAX_WORK = 600_000
+
 # elements of one [leaves, rows] temporary of ``tree_values`` (32 MB in f32):
 # the rows of a block are this over the padded leaf count
 _BLOCK_ELEMS = 1 << 23
@@ -50,6 +66,22 @@ def _pad128(n: int) -> int:
 def lookup_form(num_leaves: int) -> str:
     """``"onehot"`` or ``"gather"`` for a leaf table of this (static) size."""
     return "onehot" if num_leaves <= ONEHOT_MAX_LEAVES else "gather"
+
+
+def leaf_ids_form(
+    num_leaves: int, num_features: int, cat_width: int, feature_shard: int
+) -> str:
+    """``"walk"`` or ``"segment"``: how a tree grown on the segment path
+    gives every row its leaf, from static shapes alone.  ``"walk"``
+    (``tree_leaves`` over the binned matrix) for a numeric tree
+    (``cat_width`` <= 1: no categorical column, no EFB bundle) whose rows
+    are not replicated over feature shards and whose contractions stay under
+    ``LEAF_WALK_MAX_WORK``; ``"segment"`` (``segpart.leaf_of_positions`` and
+    ``leaf_id_from_seg``) otherwise."""
+    jp, lp = _pad128(num_leaves - 1), _pad128(num_leaves)
+    numeric_local = cat_width <= 1 and feature_shard <= 1
+    small = jp * (num_features + lp) <= LEAF_WALK_MAX_WORK
+    return "walk" if numeric_local and small else "segment"
 
 
 def _pick(match: jnp.ndarray, leaf_value: jnp.ndarray) -> jnp.ndarray:
@@ -163,10 +195,14 @@ def tree_values(
     right_child: jnp.ndarray,  # [J] i32
     leaf_value: jnp.ndarray,  # [L] f32
     block_rows: Optional[int] = None,
+    members: int = 1,
 ) -> jnp.ndarray:
     """[N] f32: the value of the leaf the bin-space walk of one NUMERIC tree
     reaches for every row — the walker's leaf, the walker's bits.  Rows go in
-    blocks, so no temporary grows with N."""
+    blocks, so no temporary grows with N.  ``members``: how many trees a
+    ``vmap`` walks at once over the same rows (a fleet): a block's
+    temporaries are held once a member (34 MB each at 255 leaves by a
+    deviceless v5e compile), so the block shrinks by that count."""
     n, f = bins.shape
     j = split_feature.shape[0]
     jp, lp = _pad128(j), _pad128(leaf_value.shape[0])
@@ -196,7 +232,7 @@ def tree_values(
         )  # [lp, nb]
         return _pick(s == depth[:, None], leaf_value)
 
-    nb = block_rows or max(1024, _BLOCK_ELEMS // max(jp, lp))
+    nb = block_rows or max(1024, _BLOCK_ELEMS // (members * max(jp, lp)))
     if n <= nb:
         return block(bins)
 
@@ -217,6 +253,7 @@ def tree_leaves(
     default_left: jnp.ndarray,  # [J] bool
     left_child: jnp.ndarray,  # [J] i32
     right_child: jnp.ndarray,  # [J] i32
+    members: int = 1,
 ) -> jnp.ndarray:
     """[N] i32: the LEAF the bin-space walk of one numeric tree reaches for
     every row (``tree_values`` over the leaves' own indices, which travel as
@@ -224,9 +261,9 @@ def tree_leaves(
     (``GrowerParams.bag_window``) never partitioned the others, as upstream's
     ``ScoreUpdater::AddScore`` walks the out-of-bag indices; here the walk is
     cheap enough (11 ms for 8M x 67 rows on a v5e) to give every row its
-    leaf, the in-bag ones too."""
+    leaf, the in-bag ones too.  ``members``: ``tree_values``'."""
     leaves = jnp.arange(split_feature.shape[0] + 1, dtype=jnp.float32)
     return tree_values(
         bins, nan_bins, split_feature, split_bin, default_left, left_child,
-        right_child, leaves,
+        right_child, leaves, members=members,
     ).astype(jnp.int32)

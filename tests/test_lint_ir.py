@@ -200,6 +200,8 @@ _RF_LOOKUP = "return row + leaf_lookup(leaf_value, leaf_id)"
 _RF_GATHER = "return row + leaf_value[leaf_id]"
 _WALK_RULE = 'if cat_width > 1 or lookup_form(num_leaves) == "gather":'
 _WALK_ALWAYS = "if True:"
+_LEAF_FORM = 'and leaf_ids_form(p.num_leaves, f, Bm, p.feature_shard) == "walk"'
+_LEAF_SEGMENT = "and False"
 
 
 @pytest.mark.parametrize(
@@ -215,16 +217,21 @@ _WALK_ALWAYS = "if True:"
          "lightgbm_tpu/predict.py"),
         ("predict.py", _WALK_RULE, _WALK_ALWAYS, "predict/add_tree_to_score",
          "lightgbm_tpu/predict.py"),
+        # PR 36: the segment form's ``sorted_leaf[seg_ord]`` under scope
+        # ``leaf_ids`` of a grow program whose shapes take the walk
+        ("ops/grower.py", _LEAF_FORM, _LEAF_SEGMENT, "grow/seg_fused",
+         "lightgbm_tpu/ops/segpart.py"),
     ],
     ids=["score_update", "launch_scan", "rf_score_update", "valid_score_update",
-         "add_tree_to_score"],
+         "add_tree_to_score", "leaf_ids"],
 )
 def test_mutation_row_gather_in_the_score_update_is_caught_by_gl016_only(
     tmp_path, rel, old, new, entry, path
 ):
     """Putting ``leaf_value[leaf_id]`` (or the walker, for a numeric tree)
     back into a score-update entry is a gather of the table's rows under
-    scope ``score_update``: 8 ns an element on the TPU (PR 34)."""
+    scope ``score_update``: 8 ns an element on the TPU (PR 34); so is the
+    leaf of a segment position under scope ``leaf_ids`` (PR 36)."""
     root = _tree_copy(tmp_path)
     _mutate(root, rel, old, new)
     proc = _run_cli(root, "--ir", "--ir-entries", entry, "--json")

@@ -218,7 +218,8 @@ def test_a_numeric_booster_contracts_and_says_so(telemetry):
     train = lgb.Dataset(X, y)
     b = lgb.train({"objective": "binary", "verbosity": -1, "num_leaves": 7},
                   train, 3, valid_sets=[lgb.Dataset(Xv, yv, reference=train)])
-    assert b._score_span_args() == {"score_lookup": "onehot", "valid_walk": "contract"}
+    assert b._score_span_args() == {"score_lookup": "onehot", "valid_walk": "contract",
+                                    "leaf_ids": "none"}  # the CPU's hist_mode is not seg
     assert telemetry.counters.get("score/valid_contract_trees") == 3
     assert "score/valid_walk_trees" not in telemetry.counters
     plain = lgb.train({"objective": "binary", "verbosity": -1, "num_leaves": 7},
@@ -232,7 +233,8 @@ def test_a_categorical_tree_takes_the_walker_and_says_so(telemetry):
               "cat_smooth": 1.0, **extra}
     train = lgb.Dataset(X, y, categorical_feature=[3])
     b = lgb.train(params, train, 3, valid_sets=[lgb.Dataset(Xv, yv, reference=train)])
-    assert b._score_span_args() == {"score_lookup": "onehot", "valid_walk": "walk"}
+    assert b._score_span_args() == {"score_lookup": "onehot", "valid_walk": "walk",
+                                    "leaf_ids": "none"}
     assert telemetry.counters.get("score/valid_walk_trees") == 3
     assert "score/valid_contract_trees" not in telemetry.counters
 

@@ -536,18 +536,32 @@ def test_tracing_adds_zero_retraces():
     assert after == before
 
 
-@pytest.mark.parametrize("steps,valid,span,want", [
-    (1, True, "train/iteration", {"score_lookup": "onehot", "valid_walk": "contract"}),
-    (1, False, "train/iteration", {"score_lookup": "onehot", "valid_walk": "none"}),
-    (3, False, "train/launch", {"score_lookup": "onehot", "valid_walk": "none"}),
-], ids=["iteration_with_a_validation_set", "iteration_without", "launch"])
-def test_the_score_updates_forms_ride_on_the_top_spans(steps, valid, span, want):
+@pytest.mark.parametrize("steps,valid,span,extra,want", [
+    (1, True, "train/iteration", {},
+     {"score_lookup": "onehot", "valid_walk": "contract"}),
+    (1, False, "train/iteration", {}, {"score_lookup": "onehot", "valid_walk": "none"}),
+    (3, False, "train/launch", {}, {"score_lookup": "onehot", "valid_walk": "none"}),
+    # PR 36: how a tree grown on the segment path gives every row its leaf
+    (1, False, "train/iteration", {"hist_mode": "seg"}, {"leaf_ids": "walk"}),
+    (3, False, "train/launch", {"hist_mode": "seg"}, {"leaf_ids": "walk"}),
+    (1, False, "train/iteration", {"hist_mode": "seg", "num_leaves": 1023},
+     {"leaf_ids": "segment"}),
+    (3, False, "train/launch", {"hist_mode": "seg", "num_leaves": 1023},
+     {"leaf_ids": "segment"}),
+    (1, False, "train/iteration", {"hist_mode": "ordered"}, {"leaf_ids": "none"}),
+    (3, False, "train/launch", {"hist_mode": "ordered"}, {"leaf_ids": "none"}),
+], ids=["iteration_with_a_validation_set", "iteration_without", "launch",
+        "iteration_leaf_ids_walk", "launch_leaf_ids_walk",
+        "iteration_leaf_ids_segment", "launch_leaf_ids_segment",
+        "iteration_off_the_segment_path", "launch_off_the_segment_path"])
+def test_the_score_updates_forms_ride_on_the_top_spans(steps, valid, span, extra, want):
     """PR 34: which form looks a tree's output up (``score_lookup``) and which
-    scores a validation set (``valid_walk``), beside the kernels' own args."""
+    scores a validation set (``valid_walk``), beside the kernels' own args;
+    PR 36: which form gives every row its leaf (``leaf_ids``)."""
     X, y = _data()
     train = lgb.Dataset(X, y)
     valid_sets = [lgb.Dataset(*_data(seed=1), reference=train)] if valid else None
-    lgb.train(dict(_PARAMS, telemetry=True, train_steps_per_launch=steps),
+    lgb.train(dict(_PARAMS, telemetry=True, train_steps_per_launch=steps, **extra),
               train, 3, valid_sets=valid_sets)
     tops = [s for s in get_tracer().spans() if s["name"] == span]
     assert tops

@@ -566,6 +566,35 @@ for _name, _kw in {
     )
 
 
+def _launch_scan(topo, rows, features, **extra):
+    """``(booster, span_args, compiled)``: ``lgb.train``'s eight-step
+    ``lax.scan`` of a Higgs-shaped booster with ``extra`` parameters, whole,
+    lowered for one abstract chip from a live Booster's operands (hence the
+    small tables its callers pass); ``span_args`` are the forms the Booster
+    names on its top spans, resolved as on a TPU."""
+    import lightgbm_tpu as lgb
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu.boosting.launch import LaunchRunner
+
+    rng = np.random.default_rng(0)
+    x = rng.normal(size=(rows, features)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    with _as_tpu_process():
+        booster = lgb.Booster(
+            dict(_HIGGS, **extra), lgb.Dataset(x, y, params={"max_bin": 255})
+        )
+        assert booster._grower_params.hist_mode == "seg"
+        span_args = {**booster._seg_span_args(), **booster._score_span_args()}
+        runner = LaunchRunner(booster, 8)
+        operands = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+            runner._operands(0)[0],
+        )
+        compiled = runner._fn.lower(*operands).compile()
+    return booster, span_args, compiled
+
+
 @check(
     "launch scan, quantized gradients, 200k x 67, 255 leaves: "
     "8 x (quantize + two-launch grow + renew)"
@@ -577,27 +606,10 @@ def _quantized_launch_scan(topo, rows=200_000, features=67):
     to the grow programs' gate (no copy of ``hist_buf``'s size, no stat axis
     on the lanes), to the integer form of the histogram kernel (its raw
     planes are int32), and to a score update without a gather of the rows."""
-    import lightgbm_tpu as lgb
-    from jax.sharding import SingleDeviceSharding
-    from lightgbm_tpu.boosting.launch import LaunchRunner
-
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(rows, features)).astype(np.float32)
-    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    with _as_tpu_process():
-        booster = lgb.Booster(
-            dict(_HIGGS, use_quantized_grad=True, quant_train_renew_leaf=True),
-            lgb.Dataset(x, y, params={"max_bin": 255}),
-        )
-        assert booster._grower_params.hist_mode == "seg"
-        assert booster._seg_span_args()["hist_int8"] is True
-        runner = LaunchRunner(booster, 8)
-        operands = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            runner._operands(0)[0],
-        )
-        compiled = runner._fn.lower(*operands).compile()
+    _, span_args, compiled = _launch_scan(
+        topo, rows, features, use_quantized_grad=True, quant_train_renew_leaf=True
+    )
+    assert span_args["hist_int8"] is True
     text = compiled.as_text()
     kernels = [
         ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln
@@ -611,6 +623,15 @@ def _quantized_launch_scan(topo, rows=200_000, features=67):
 
 
 _GOSS = dict(data_sample_strategy="goss", top_rate=0.2, other_rate=0.1)
+
+
+def row_sorts(text, rows):
+    """Sorts of the optimized HLO with an operand of ``rows`` rows (the
+    segment form of ``leaf_ids`` has one a tree: ``leaf_id_from_seg``)."""
+    return [
+        ln.strip()[:160] for ln in text.splitlines()
+        if re.search(r" sort\(", ln) and f"[{rows}]" in ln
+    ]
 
 
 def _kernel_names(text):
@@ -632,8 +653,9 @@ def _goss_scan_body(topo, rows=8_000_000, features=67):
     text = compiled.as_text()
     kernels = _kernel_names(text)
     assert {"bag_compact_pallas", "seg_partition_pallas"} <= kernels, kernels
-    # leaf_ids keeps the gather it has in every grow program (a leaf per
-    # segment position, PERF.md section 7); what this PR adds has none
+    # every row's leaf comes from the walk under scope oob_score: no sort of
+    # the rows, and no gather of them under the window's scopes
+    assert not row_sorts(text, rows), row_sorts(text, rows)
     faults = [
         f for scope in ("bag_compact", "oob_score")
         for f in row_gather_faults(text, rows, scope=scope)
@@ -677,38 +699,73 @@ def _goss_sample_entry(topo, rows=8_000_000):
 def _goss_launch_scan(topo, rows=200_000, features=67):
     """``lgb.train``'s eight-step ``lax.scan`` of a booster with
     ``data_sample_strategy=goss``, whole (a live Booster's operands, hence
-    the small table): the compaction kernel is in it, no sort but the
-    ``leaf_ids`` one a tree, no gather of the rows."""
-    import lightgbm_tpu as lgb
-    from jax.sharding import SingleDeviceSharding
-    from lightgbm_tpu.boosting.launch import LaunchRunner
-
-    rng = np.random.default_rng(0)
-    x = rng.normal(size=(rows, features)).astype(np.float32)
-    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
-    one_chip = SingleDeviceSharding(topo.devices[0])
-    with _as_tpu_process():
-        booster = lgb.Booster(
-            dict(_HIGGS, hist_acc="bf16", **_GOSS),
-            lgb.Dataset(x, y, params={"max_bin": 255}),
-        )
-        assert booster._grower_params.hist_mode == "seg"
-        assert booster._grower_params.bag_window
-        runner = LaunchRunner(booster, 8)
-        operands = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-            runner._operands(0)[0],
-        )
-        compiled = runner._fn.lower(*operands).compile()
+    the small table): the compaction kernel is in it, no sort of the rows
+    (the threshold is a selection, every row's leaf the walk's), no gather
+    of them."""
+    booster, _, compiled = _launch_scan(topo, rows, features, hist_acc="bf16", **_GOSS)
+    assert booster._grower_params.bag_window
     text = compiled.as_text()
     assert "bag_compact_pallas" in _kernel_names(text), _kernel_names(text)
-    sorts = [
-        ln for ln in text.splitlines()
-        if re.search(r" sort\(", ln) and f"[{rows}]" in ln
-    ]
-    assert len(sorts) <= 1, f"{len(sorts)} sorts of the rows (leaf_ids' is the one)"
+    assert not row_sorts(text, rows), row_sorts(text, rows)
     faults = hist_form_faults(text, features) + [
         f for scope in ("sample", "bag_compact", "oob_score", "score_update")
+        for f in row_gather_faults(text, rows, scope=scope)
+    ]
+    assert not faults, faults
+    return compiled
+
+
+def _leaf_ids_grow_program(topo, walks, **kw):
+    """A grow program of a booster that samples nothing, held to the form
+    ``score_lookup.leaf_ids_form`` gives its shapes: the walk leaves no sort
+    of the rows and no gather of them under scope ``leaf_ids``; the segment
+    form keeps its one sort."""
+    from lightgbm_tpu.ops.score_lookup import leaf_ids_form
+
+    form = leaf_ids_form(kw.get("num_leaves", _HIGGS["num_leaves"]), kw["features"], 1, 0)
+    assert (form == "walk") == walks, form
+    compiled = _checked_grow_program(topo, **kw)
+    text, rows = compiled.as_text(), kw["rows"]
+    if walks:
+        assert not row_sorts(text, rows), row_sorts(text, rows)
+        faults = row_gather_faults(text, rows, scope="leaf_ids")
+        assert not faults, faults
+    else:
+        assert len(row_sorts(text, rows)) == 1, row_sorts(text, rows)
+    return compiled
+
+
+CHECKS[
+    "grow program 8M x 67, 255 leaves, no sampler (criteo67.fit): launch-scan "
+    "body, every row's leaf from the walk"
+] = functools.partial(
+    _leaf_ids_grow_program, walks=True, features=67, rows=_CRITEO_ROWS,
+    grow_fused="off", hist_acc="bf16",
+)
+CHECKS[
+    "grow program 1M x 28, 1,023 leaves, no sampler: past the walk's size, "
+    "leaf_ids keeps its one sort"
+] = functools.partial(
+    _leaf_ids_grow_program, walks=False, features=28, rows=_ROWS,
+    grow_fused="off", num_leaves=1023,
+)
+
+
+@check(
+    "launch scan, no sampler, 200k x 67, 255 leaves: 8 x (two-launch grow + "
+    "the walk for every row's leaf + score update)"
+)
+def _plain_launch_scan(topo, rows=200_000, features=67):
+    """``lgb.train``'s eight-step ``lax.scan`` of a booster that samples
+    nothing, whole (a live Booster's operands, hence the small table): no
+    sort of the rows, no gather of them under ``leaf_ids`` or the score
+    update."""
+    _, span_args, compiled = _launch_scan(topo, rows, features, hist_acc="bf16")
+    assert span_args["leaf_ids"] == "walk"
+    text = compiled.as_text()
+    assert not row_sorts(text, rows), row_sorts(text, rows)
+    faults = hist_form_faults(text, features) + [
+        f for scope in ("leaf_ids", "score_update")
         for f in row_gather_faults(text, rows, scope=scope)
     ]
     assert not faults, faults
