@@ -10,6 +10,12 @@ Public surface mirrors the reference python-package (lightgbm/__init__.py):
 ``Dataset``, ``Booster``, ``train``, ``cv``, callbacks, sklearn wrappers.
 """
 
+import sys as _sys
+import time as _time
+
+_IMPORT_T0_NS = _time.perf_counter_ns()  # before anything heavy: setup/import, below
+_JAX_PRELOADED = "jax" in _sys.modules
+
 from .basic import (  # noqa: F401
     LGBMDeprecationWarning,
     LightGBMError,
@@ -62,6 +68,15 @@ except Exception:  # pragma: no cover - sklearn not installed
     LGBMClassifier = LGBMModel = LGBMRanker = LGBMRegressor = None
 
 __version__ = "0.1.0"
+
+# the import as a span of the set-up's timeline (obs/trace.py, ``setup``)
+from .obs import get_tracer as _get_tracer
+
+_get_tracer().add_span(
+    "setup/import", "setup", _IMPORT_T0_NS // 1000,
+    (_time.perf_counter_ns() - _IMPORT_T0_NS) // 1000,
+    args={"jax_preloaded": _JAX_PRELOADED},
+)
 
 __all__ = [
     "LGBMError",

@@ -240,9 +240,6 @@ class FleetTrainer:
         ops: Dict[int, dict] = {}
         for i in active:
             ops[i] = boosters[i]._fleet_begin_iter()
-        if ses.enabled:
-            ses.set_gauge("fleet/size", m)
-            ses.set_gauge("fleet/active", len(active))
 
         should = {i: False for i in active}
         template = ops[active[0]]

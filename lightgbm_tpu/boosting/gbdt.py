@@ -35,7 +35,7 @@ from ..obs.flight import get_flight
 from ..obs.health import HealthWatchdog
 from ..obs.jit import compile_count as _obs_compile_count
 from ..obs.registry import get_session
-from ..obs.trace import get_tracer
+from ..obs.trace import get_tracer, traced_transfer
 from ..objectives import ObjectiveFunction, create_objective
 from ..resilience import NumericsError, chaos
 from ..obs.jit import instrumented_jit
@@ -377,7 +377,6 @@ class Booster:
             return
         refines = int(ta_host.refine_count)
         decisions = 2 * max(0, int(ta_host.num_leaves) - 1) + 1
-        ses.set_gauge("hist/near_tie_refines", float(refines))
         ses.set_gauge("hist/near_tie_refine_rate", refines / decisions)
         ses.inc("hist/near_tie_refines_total", refines)
 
@@ -537,6 +536,7 @@ class Booster:
                         )
         train_set.construct()
         self.train_set = train_set
+        self._first_step = True  # consumed by the first step's span
         cfg = self.config
         if cfg.telemetry:
             get_session().configure(
@@ -698,28 +698,31 @@ class Booster:
         # statistics (class priors, is_unbalance weights, percentiles) are
         # exact; only its per-row DEVICE arrays get padded + mesh-placed below
         if self.objective is not None:
-            if self._multiproc and not self.objective.need_query:
-                # global host statistics (reference: Network::Allreduce inside
-                # ObtainAutomaticInitialScore / label-count sync): gather the
-                # label/weight COLUMNS across processes — O(8 bytes/row),
-                # negligible next to the bin matrix which stays local.  The
-                # per-row device arrays are re-sliced to local rows below.
-                # Ranking objectives skip this: their init statistics are
-                # per-query and queries never straddle processes.
-                from ..parallel import allgather_host_varlen
+            with get_tracer().span(
+                "setup/objective_init", "setup", args={"objective": cfg.objective}
+            ):
+                if self._multiproc and not self.objective.need_query:
+                    # global host statistics (reference: Network::Allreduce inside
+                    # ObtainAutomaticInitialScore / label-count sync): gather the
+                    # label/weight COLUMNS across processes — O(8 bytes/row),
+                    # negligible next to the bin matrix which stays local.  The
+                    # per-row device arrays are re-sliced to local rows below.
+                    # Ranking objectives skip this: their init statistics are
+                    # per-query and queries never straddle processes.
+                    from ..parallel import allgather_host_varlen
 
-                glabel = allgather_host_varlen(np.asarray(md.label))
-                gweight = (
-                    allgather_host_varlen(np.asarray(md.weight))
-                    if md.weight is not None
-                    else None
-                )
-                self._gathered_label = glabel  # reused by pos/neg bagging
-                self.objective.init(glabel, gweight, None, None)
-            else:
-                self.objective.init(
-                    md.label, md.weight, md.query_boundaries, md.position
-                )
+                    glabel = allgather_host_varlen(np.asarray(md.label))
+                    gweight = (
+                        allgather_host_varlen(np.asarray(md.weight))
+                        if md.weight is not None
+                        else None
+                    )
+                    self._gathered_label = glabel  # reused by pos/neg bagging
+                    self.objective.init(glabel, gweight, None, None)
+                else:
+                    self.objective.init(
+                        md.label, md.weight, md.query_boundaries, md.position
+                    )
             self.num_class = self.objective.num_class
         else:
             self.num_class = max(1, cfg.num_class)
@@ -754,11 +757,13 @@ class Booster:
         if self._mesh is not None:
             from ..parallel import pad_rows_np, shard_cols, shard_rows
 
-            self._score = shard_cols(init, self._mesh, process_local=self._multiproc)
-            self._bins = shard_rows(
+            self._score = traced_transfer("score", lambda: shard_cols(
+                init, self._mesh, process_local=self._multiproc
+            ))
+            self._bins = traced_transfer("bins", lambda: shard_rows(
                 pad_rows_np(train_set.bins, pad), self._mesh,
                 process_local=self._multiproc,
-            )
+            ))
             # the objective's per-row device arrays ride the same sharding as
             # the score (zero-padded; padded rows' gradients are zeroed
             # explicitly in _sample — NOT via synthetic weights, which would
@@ -779,15 +784,13 @@ class Booster:
                         widths = [(0, 0)] * a.ndim
                         widths[axis] = (0, pad)
                         a = np.pad(a, widths)
-                    setattr(
-                        holder,
-                        name,
-                        shard_rows(a, self._mesh, process_local=self._multiproc)
-                        if axis == 0
-                        else shard_cols(a, self._mesh, process_local=self._multiproc),
-                    )
+                    place = shard_rows if axis == 0 else shard_cols
+                    setattr(holder, name, traced_transfer(
+                        "objective." + name,
+                        lambda: place(a, self._mesh, process_local=self._multiproc),
+                    ))
         else:
-            self._score = jnp.asarray(init)
+            self._score = traced_transfer("score", lambda: jnp.asarray(init))
             self._bins = train_set.device_bins()
         # per-COLUMN operand arrays: with EFB a bin-matrix column is a
         # bundle plane, without it a used feature (dataset plane accessors
@@ -813,6 +816,11 @@ class Booster:
             if self._has_bundle
             else None
         )
+        # the Pallas kernels' modules (jax.experimental.pallas and Mosaic
+        # behind them) load on a process's first booster: about a second
+        # that _make_grower_params' own import would otherwise hide
+        with get_tracer().span("setup/kernel_import", "setup"):
+            from ..ops.pallas import seg as _seg  # noqa: F401
         self._check_bundle_compat()
         self._setup_constraints()
         self._forced = self._build_forced_splits()
@@ -827,9 +835,9 @@ class Booster:
             # rows role: sharded over 'data', replicated over 'feature' —
             # on a pure-feature mesh the data axis is 1, so this IS the
             # old replicate placement
-            self._ones_mask = shard_rows(
+            self._ones_mask = traced_transfer("ones_mask", lambda: shard_rows(
                 base, self._mesh, process_local=self._multiproc
-            )
+            ))
             self._setup_sharded_grower()
         else:
             self._ones_mask = jnp.ones((n,), jnp.float32)
@@ -862,7 +870,7 @@ class Booster:
                 ip = np.asarray(md.label) > 0
                 if pad:
                     ip = np.concatenate([ip, np.zeros(pad, bool)])
-            is_pos = jnp.asarray(ip)
+            is_pos = traced_transfer("is_pos", lambda: jnp.asarray(ip))
         from .sampling import bagging_is_active
 
         query_sizes = None
@@ -1398,6 +1406,14 @@ class Booster:
             "valid_walk": walk, "leaf_ids": leaf_ids,
         }
 
+    def _first_step_args(self) -> Dict[str, bool]:
+        """``{"first": True}`` on the first ``train/iteration`` or
+        ``train/launch`` of a booster: the span whose ``compile/*`` children
+        say why it is long."""
+        if self.__dict__.pop("_first_step", False):
+            return {"first": True}
+        return {}
+
     def _seg_span_args(self) -> Dict[str, int]:
         """Plane groups of the packed row and the planes a group, and the
         histogram kernel's two-digit one-hot ("HxL", "1x<bpad>" where it
@@ -1771,6 +1787,10 @@ class Booster:
                 "validation sets are not supported under multi-process "
                 "pre_partition training; evaluate the saved model per process"
             )
+        with get_tracer().span("setup/add_valid", "setup", args={"name": name}):
+            return self._add_valid(data, name)
+
+    def _add_valid(self, data: Dataset, name: str) -> "Booster":
         data.construct()
         entry = _EvalEntry(name, data, self._create_metrics())
         md = data.metadata
@@ -1789,12 +1809,14 @@ class Booster:
         if self._mesh is not None:
             from ..parallel import pad_rows_np, shard_cols, shard_rows
 
-            entry.score = shard_cols(init, self._mesh)
-            entry.dev_bins = shard_rows(
-                pad_rows_np(data.bins, entry.pad), self._mesh
+            entry.score = traced_transfer(
+                "valid.score", lambda: shard_cols(init, self._mesh)
             )
+            entry.dev_bins = traced_transfer("valid.bins", lambda: shard_rows(
+                pad_rows_np(data.bins, entry.pad), self._mesh
+            ))
         else:
-            entry.score = jnp.asarray(init)
+            entry.score = traced_transfer("valid.score", lambda: jnp.asarray(init))
         # replay existing trees onto the valid score
         vbins = entry.bins
         vraw = None
@@ -2067,10 +2089,6 @@ class Booster:
             mask = mask * self._ones_mask
         if fixed is not None:
             mask = mask * fixed
-        ses = get_session()
-        if ses.enabled:
-            # host pull of a scalar; only paid when telemetry is on
-            ses.set_gauge("bagging_rows", int(jnp.sum(mask > 0)))
         return mask, grad, hess
 
     def update(self, train_set: Optional[Dataset] = None, fobj=None) -> bool:
@@ -2094,8 +2112,9 @@ class Booster:
             finished = False
             with tracer.span(
                 "train/iteration", timer="boosting/update",
-                args={"iter": it, **self._seg_span_args(),
-                      **self._score_span_args()}, ambient=True,
+                args={"iter": it, **self._first_step_args(),
+                      **self._seg_span_args(), **self._score_span_args()},
+                ambient=True,
             ) as sp:
                 try:
                     finished = self._update_impl(train_set, fobj)
@@ -2122,8 +2141,9 @@ class Booster:
         # ambient parents the collective io_callback spans fired off-thread
         with tracer.span(
             "train/iteration", timer="boosting/update",
-            args={"iter": it, **self._seg_span_args(),
-                  **self._score_span_args()}, ambient=True,
+            args={"iter": it, **self._first_step_args(),
+                  **self._seg_span_args(), **self._score_span_args()},
+            ambient=True,
         ) as sp:
             ses.begin_iteration()
             try:
@@ -2227,7 +2247,8 @@ class Booster:
             cache = self._launch_runners = {}
         runner = cache.get(int(n))
         if runner is None or runner.stale(self):
-            runner = cache[int(n)] = LaunchRunner(self, int(n))
+            with get_tracer().span("setup/launch_build", "setup", args={"steps": int(n)}):
+                runner = cache[int(n)] = LaunchRunner(self, int(n))
         return runner
 
     def update_launch(self, n: int) -> Tuple[int, bool]:

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 
 from ..obs.flight import get_flight
 from ..obs.jit import compile_count as _compile_count
-from ..obs.jit import instrumented_jit
+from ..obs.jit import closed_over_bytes, instrumented_jit
 from ..obs.registry import get_session
 from ..obs.device import sample_device_memory
 from ..obs.trace import get_tracer
@@ -271,6 +271,13 @@ class LaunchRunner:
             # map file with the same content, a subset toward two programs
             # reading one map (PR 34)
             closure_key=repr(booster.config),
+            # the scan body reads the objective's per-row arrays (labels,
+            # weights), the sampler's and the per-feature tables off the
+            # booster, not from its operands: they are constants of the
+            # executable, and a table with other labels compiles again
+            compile_args=lambda args, kwargs: {
+                "baked_bytes": closed_over_bytes(self._fn, args, kwargs)
+            },
         )
 
     def stale(self, booster) -> bool:
@@ -547,6 +554,7 @@ class LaunchRunner:
             "train/launch",
             timer="boosting/update",
             args={"launch_begin": it0, "steps_per_launch": self._n,
+                  **b._first_step_args(),
                   **b._seg_span_args(), **b._score_span_args()},
             ambient=True,
         ) as lsp:
@@ -748,7 +756,6 @@ class LaunchRunner:
             )
         if ses.enabled:
             ses.inc("iterations", steps_done)
-            ses.inc("launch/launches")
             ses.set_gauge("train/steps_per_launch_effective", float(steps_done))
             sample_device_memory("iteration")
             # per-iteration JSONL shape compatibility: one replayed
@@ -1144,8 +1151,6 @@ class FleetLaunchRunner:
         t._round += steps_done
         if ses.enabled:
             ses.inc("fleet/iterations", steps_done)
-            ses.set_gauge("fleet/size", m)
-            ses.set_gauge("fleet/active", len(t.active_members()))
             ses.set_gauge(
                 "train/steps_per_launch_effective", float(max(1, steps_done))
             )

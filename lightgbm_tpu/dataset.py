@@ -1785,7 +1785,11 @@ class Dataset:
             # keep the narrow host dtype (uint8/uint16): 4x less HBM traffic
             # for every gather in the grower and 4x smaller kernel tiles; the
             # Pallas kernel widens per-tile in VMEM
-            self._device_cache["bins"] = jnp.asarray(self.bins)
+            from .obs.trace import traced_transfer
+
+            self._device_cache["bins"] = traced_transfer(
+                "bins", lambda: jnp.asarray(self.bins)
+            )
         return self._device_cache["bins"]
 
     def device_label(self):

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 import copy
 import time
-from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
@@ -95,25 +94,28 @@ def train(
     valid_sets = list(valid_sets or [])
     valid_names = list(valid_names or [])
 
-    # create_booster -> the first iteration's start; unattached, so a raise
-    # on the way leaves no stale parent on the span stack
+    # create_booster -> the first iteration's start; begun unattached, so a
+    # raise on the way leaves no stale parent on the span stack, and made the
+    # parent of the booster's and the validation sets' set-up (transfers,
+    # objective, every compilation on the way) for as long as they run
     init_span = tracer.begin("setup/booster_init", "setup")
-    booster = create_booster(params, train_set)
-    if init_model is not None:
-        init_booster = (
-            init_model if isinstance(init_model, Booster) else Booster(model_file=init_model)
-        )
-        booster.merge_from(init_booster)
-
     is_valid_contain_train = False
     train_data_name = "training"
-    for i, vs in enumerate(valid_sets):
-        name = valid_names[i] if i < len(valid_names) else f"valid_{i}"
-        if vs is train_set:
-            is_valid_contain_train = True
-            train_data_name = name
-            continue
-        booster.add_valid(vs, name)
+    with tracer.under(init_span):
+        booster = create_booster(params, train_set)
+        if init_model is not None:
+            init_booster = (
+                init_model if isinstance(init_model, Booster)
+                else Booster(model_file=init_model)
+            )
+            booster.merge_from(init_booster)
+        for i, vs in enumerate(valid_sets):
+            name = valid_names[i] if i < len(valid_names) else f"valid_{i}"
+            if vs is train_set:
+                is_valid_contain_train = True
+                train_data_name = name
+                continue
+            booster.add_valid(vs, name)
 
     callbacks = list(callbacks or [])
     if cfg.early_stopping_round and cfg.early_stopping_round > 0:
@@ -139,7 +141,8 @@ def train(
     if resume_path:
         from .resilience.checkpoint import restore_checkpoint
 
-        restore_checkpoint(booster, resume_path)
+        with tracer.under(init_span):
+            restore_checkpoint(booster, resume_path)
         resumed = True
 
     # live ops plane: opt-in Prometheus endpoint for the run's duration,
@@ -188,14 +191,9 @@ def train(
                 "the compiled scan cannot observe"
             )
             launch_n = 1
-    # per-launch host overhead: wall between the end of one device dispatch
-    # and the start of the next (callbacks, eval, telemetry, Python loop).
-    # The sample window is bounded (long serial runs would otherwise grow
-    # one float per iteration, and the list outlives train()); running
-    # totals keep the whole-run average exact for bench reporting.
-    booster._host_overhead_ms = deque(maxlen=128)
-    booster._host_overhead_total_ms = 0.0
-    booster._host_overhead_n = 0
+    # per-launch host overhead (gauge train/host_overhead_ms): wall between
+    # the end of one device dispatch and the start of the next (callbacks,
+    # eval, telemetry, Python loop)
     prev_dispatch_end: Optional[float] = None
     # root span for the whole training run: iteration/launch spans created
     # by Booster.update / LaunchRunner.run attach as children (tls stack)
@@ -240,14 +238,11 @@ def train(
                 and it % launch_n == 0
                 and it + launch_n <= end_iteration
             )
-            t_dispatch = time.perf_counter()
-            if prev_dispatch_end is not None:
-                host_ms = (t_dispatch - prev_dispatch_end) * 1e3
-                booster._host_overhead_ms.append(host_ms)
-                booster._host_overhead_total_ms += host_ms
-                booster._host_overhead_n += 1
-                if ses.enabled:
-                    ses.set_gauge("train/host_overhead_ms", host_ms)
+            if ses.enabled and prev_dispatch_end is not None:
+                ses.set_gauge(
+                    "train/host_overhead_ms",
+                    (time.perf_counter() - prev_dispatch_end) * 1e3,
+                )
             # train/iteration and train/launch open inside these calls and
             # feed global_timer's boosting/update
             if use_launch:

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config import Config
+from ..obs.trace import traced_transfer
 
 _EPS = 1e-15
 
@@ -88,8 +89,12 @@ class ObjectiveFunction:
         self._label_np = np.asarray(label, dtype=np.float64)
         self._weight_np = None if weight is None else np.asarray(weight, dtype=np.float64)
         self.num_data = len(self._label_np)
-        self.label = jnp.asarray(self._label_np, dtype=jnp.float32)
-        self.weight = None if weight is None else jnp.asarray(self._weight_np, dtype=jnp.float32)
+        self.label = traced_transfer(
+            "objective.label", lambda: jnp.asarray(self._label_np, dtype=jnp.float32)
+        )
+        self.weight = None if weight is None else traced_transfer(
+            "objective.weight", lambda: jnp.asarray(self._weight_np, dtype=jnp.float32)
+        )
 
     def per_row_device_arrays(self):
         """Per-row DEVICE arrays consumed by ``get_gradients``, as
@@ -158,7 +163,9 @@ class RegressionL2(ObjectiveFunction):
         if self.sqrt:
             t = np.sign(self._label_np) * np.sqrt(np.abs(self._label_np))
             self._label_np = t
-            self.label = jnp.asarray(t, dtype=jnp.float32)
+            self.label = traced_transfer(
+                "objective.label", lambda: jnp.asarray(t, dtype=jnp.float32)
+            )
         self.is_constant_hessian = weight is None
 
     def get_gradients(self, score, rng=None):
@@ -369,7 +376,9 @@ class RegressionMAPE(RegressionL1):
         if self._weight_np is not None:
             lw = lw * self._weight_np
         self._label_weight_np = lw
-        self._label_weight = jnp.asarray(lw, dtype=jnp.float32)
+        self._label_weight = traced_transfer(
+            "objective.label_weight", lambda: jnp.asarray(lw, dtype=jnp.float32)
+        )
         self.is_constant_hessian = True
 
     def per_row_device_arrays(self):
@@ -452,7 +461,7 @@ class BinaryLogloss(ObjectiveFunction):
         label_weights[1] *= self.scale_pos_weight
         self._label_weights = label_weights
         self._pos_np = pos
-        pos_dev = jnp.asarray(pos)
+        pos_dev = traced_transfer("objective.is_pos", lambda: jnp.asarray(pos))
         self._y = jnp.where(pos_dev, 1.0, -1.0)  # label in {-1, +1}
         self._lw = jnp.where(pos_dev, label_weights[1], label_weights[0])
 
@@ -520,7 +529,9 @@ class MulticlassSoftmax(ObjectiveFunction):
             np.add.at(probs, li, self._weight_np)
             probs /= self._weight_np.sum()
         self.class_init_probs = probs
-        label_int = jnp.asarray(li, dtype=jnp.int32)
+        label_int = traced_transfer(
+            "objective.label_int", lambda: jnp.asarray(li, dtype=jnp.int32)
+        )
         self._onehot = jax.nn.one_hot(label_int, self.num_class, dtype=jnp.float32).T  # [K, N]
 
     def per_row_device_arrays(self):

@@ -34,10 +34,24 @@ more (a hit on the entry just written), parses instruction -> scope and
 writes the map beside the cache (``<cache dir>/op_scopes/``).  A later call,
 a later process, or an operator joining a ``profile_trace_dir`` trace reads
 the files; a warm run pays one existence check per traced label.
+
+Compilation timeline: one set of ``jax.monitoring`` listeners, registered at
+import, hears the durations jax emits while it traces, lowers, asks the
+persistent cache and compiles, on the thread that does it.  A call through
+:func:`instrumented_jit` that traced or compiled closes what was heard as one
+span ``compile/<label>`` (category ``compile``) in the trace ring, under the
+span open on that thread, with ``trace_s``, ``lower_s``,
+``backend_compile_s`` (the whole ``compile_or_get_cached``: on a cache hit the
+retrieval), ``cache_retrieval_s``, ``cache_hit`` and ``call_s``; the second
+``lower().compile()`` of the accounting and of ``op_scopes`` goes to
+``compile/op_scopes``; a compilation outside any instrumented call (an eager
+op, a ``jnp.asarray``) to ``compile/uninstrumented``.  A warm call pays one
+thread-local store and one comparison.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import glob
 import hashlib
@@ -51,8 +65,9 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-from .flight import _atomic_write_text
+from .flight import _atomic_write_text, get_flight
 from .registry import get_session
+from .trace import get_tracer
 
 _lock = threading.Lock()
 _count = 0
@@ -61,7 +76,9 @@ _by_label: Dict[str, int] = {}
 # "this call traced" without touching jax internals (a plain read: a trace
 # on another thread at worst costs one more existence check)
 _epoch = 0
-_tls = threading.local()  # .suppress set during the accounting re-lower
+# .suppress: set during the accounting re-lower; .call: the instrumented call
+# open on this thread; .heard: compilation events not yet closed into a span
+_tls = threading.local()
 
 
 def note_compile(label: str = "jit") -> None:
@@ -168,6 +185,176 @@ def note_executable(label: str, compiled: Any) -> None:
         ses.set_gauge_max(name, v)
 
 
+# --------------------------------------------------- compilation timeline
+_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+UNINSTRUMENTED = "uninstrumented"
+_HEARD_MAX = 4096  # events a thread keeps before they go to a span unasked
+OP_SCOPES_SPAN = "op_scopes"
+
+
+def _heard() -> List[Tuple[str, float, float, str]]:
+    """This thread's open record: (kind, start, end, jax's name for the
+    function) on ``time.perf_counter``'s clock, the span ring's."""
+    heard = getattr(_tls, "heard", None)
+    if heard is None:
+        heard = _tls.heard = []
+    return heard
+
+
+def _on_duration(event: str, duration: float, **kw: Any) -> None:
+    kind = _DURATIONS.get(event)
+    if kind is None:
+        return
+    end = time.perf_counter()
+    heard = _heard()
+    heard.append((kind, end - float(duration), end, str(kw.get("fun_name", ""))))
+    if getattr(_tls, "call", None) is not None or getattr(_tls, "suppress", False):
+        return  # the call that is open on this thread closes what it hears
+    if kind == "backend_compile_s":
+        # nobody's call: the span starts where this program's own trace (or
+        # lowering) did, by jax's name for it; what was heard before that is
+        # a trace no compilation followed (eval_shape, lower())
+        module = str(kw.get("fun_name", ""))
+        _close_compile_span(UNINSTRUMENTED, min(
+            a for k, a, _b, f in heard
+            if k == "backend_compile_s" or f == module or f"jit({f})" == module
+        ))
+    elif len(heard) >= _HEARD_MAX:
+        # a thread that traces and never compiles (eval_shape in a loop)
+        _close_compile_span(UNINSTRUMENTED, float("inf"))
+
+
+def _on_event(event: str, **_kw: Any) -> None:
+    kind = _CACHE_EVENTS.get(event)
+    if kind is not None:
+        now = time.perf_counter()
+        _heard().append((kind, now, now, ""))
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
+
+
+def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    """A traced function that calls a jitted one is heard twice, the inner
+    trace inside the outer: the union counts the seconds once."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+def _close_compile_span(name: str, t0: float, args=None) -> None:
+    """Close what this thread heard since ``t0`` as the span
+    ``compile/<name>`` ending now; what it heard before ``t0`` (a trace that
+    no compilation followed: ``eval_shape``, ``lower()``) goes to
+    ``compile/uninstrumented`` first.  ``args``: more span args, or a
+    function that gives them (called only when there is a span to put them
+    on)."""
+    now = time.perf_counter()
+    heard = _heard()
+    stale = [e for e in heard if e[2] < t0]
+    events = [e for e in heard if e[2] >= t0]
+    del heard[:]
+    if stale:  # from another time: not a child of what is open now
+        _emit_compile_span(UNINSTRUMENTED, stale[0][1], stale[-1][2], stale, None,
+                           parented=False)
+    if events:
+        _emit_compile_span(name, t0, now, events, args)
+
+
+def _emit_compile_span(name, t0, t1, events, args, parented=True) -> None:
+    """Never raises: the timeline must not break training."""
+    try:
+        if callable(args):
+            try:
+                args = args()
+            except Exception:
+                args = None  # the hook's fault leaves the clocks their span
+        seconds = {
+            kind: _union_seconds([(a, b) for k, a, b, _f in events if k == kind])
+            for kind in _DURATIONS.values()
+        }
+        hits = sum(k == "hit" for k, *_ in events)
+        misses = sum(k == "miss" for k, *_ in events)
+        traces = sum(k == "trace_s" for k, *_ in events)
+        compiled = [f for k, _a, _b, f in events if k == "backend_compile_s"]
+        # neither event fires without a cache directory, nor for a program
+        # the cache does not keep (it compiled in under
+        # jax_persistent_cache_min_compile_time_secs)
+        cache_hit = False if misses else (True if hits else None)
+        ses = get_session()
+        for counter, n in (("compile/cache_hits", hits),
+                           ("compile/cache_misses", misses),
+                           ("compile/traces", traces)):
+            if n:
+                ses.inc(counter, n)
+        if compiled:
+            # compiles_in_window counts these; the flight ring names them
+            get_flight().note_event({
+                "event": "compile", "label": name, "hit": cache_hit,
+                "seconds": seconds["backend_compile_s"],
+            })
+        tracer = get_tracer()
+        if not tracer.active:
+            return
+        span_args = {"label": name, "call_s": t1 - t0, "cache_hit": cache_hit,
+                     **seconds, **(args or {})}
+        if "module" not in span_args:
+            span_args["module"] = ",".join(sorted(set(compiled)))
+        parent = tracer.current() if parented else None
+        tracer.add_span(
+            f"compile/{name}", "compile", int(t0 * 1e6), int((t1 - t0) * 1e6),
+            trace_id=parent.trace_id if parent else None,
+            parent_id=parent.span_id if parent else None,
+            args=span_args,
+        )
+    except Exception:
+        pass
+
+
+@contextlib.contextmanager
+def _second_compile(label: str):
+    """The ``lower().compile()`` that accounting and ``op_scopes`` make
+    after a call: not a new logical trace, and a span of its own
+    (``compile/op_scopes``), never part of the label's numbers."""
+    t0 = time.perf_counter()
+    _tls.suppress = True
+    try:
+        yield
+    finally:
+        _tls.suppress = False
+        _close_compile_span(OP_SCOPES_SPAN, t0, {"of": label})
+
+
+def closed_over_bytes(inst: "_InstrumentedJit", args, kwargs) -> int:
+    """Bytes of the arrays the program of this call holds as constants: what
+    ``inst``'s function closes over instead of taking as an operand (a scan
+    body's labels), and what it makes from NumPy while it traces.  They are
+    part of the executable, so other values of them are another compilation
+    whatever the persistent cache holds.  Read from the constants of the
+    call's own jaxpr, the arrays themselves; called after the call, it finds
+    that jaxpr in jax's trace cache."""
+    abstract = jax.tree_util.tree_map(_abstract, (args, kwargs))
+    _tls.suppress = True  # a miss of that cache would not be a new logical trace
+    try:
+        consts = inst._jit.trace(*abstract[0], **abstract[1]).jaxpr.consts
+    finally:
+        _tls.suppress = False
+    return sum(int(getattr(c, "nbytes", 0)) for c in consts)
+
+
 def _has_tracer(leaves) -> bool:
     return any(isinstance(l, jax.core.Tracer) for l in leaves)
 
@@ -181,6 +368,9 @@ class _InstrumentedJit:
         # what the program closes over that its arguments do not show (see
         # ``_signature``); not a jit option
         self._closure_key = str(jit_kwargs.pop("closure_key", ""))
+        # (args, kwargs) of a call that compiled -> more args for its
+        # ``compile/<label>`` span; not a jit option
+        self._compile_args = jit_kwargs.pop("compile_args", None)
         # introspectable by the lint IR pass (GL013 donation audit) and any
         # other tooling that needs the entry's declared jit contract
         self.jit_kwargs: Dict[str, Any] = dict(jit_kwargs)
@@ -197,11 +387,19 @@ class _InstrumentedJit:
 
     def __call__(self, *args: Any, **kwargs: Any):
         before = _epoch
+        outer = getattr(_tls, "call", None)  # set while an outer call traces
+        _tls.call = self
         t0 = time.perf_counter()
-        out = self._jit(*args, **kwargs)
+        try:
+            out = self._jit(*args, **kwargs)
+        finally:
+            _tls.call = outer
         traced = _epoch != before  # bumped by every counted trace
-        if traced:
-            _note_traced(self, args, kwargs, time.perf_counter() - t0)
+        if traced or (outer is None and getattr(_tls, "heard", None)):
+            seconds = time.perf_counter() - t0
+            self._close_span(t0, args, kwargs)
+            if traced:
+                _note_traced(self, args, kwargs, seconds)
         ses = get_session()
         if not (ses.enabled and ses.device_accounting):
             return out
@@ -219,6 +417,21 @@ class _InstrumentedJit:
                     ses.set_gauge_max(name, v)
         return out
 
+    def _close_span(self, t0: float, args, kwargs) -> None:
+        """``compile/<label>`` of a call that traced or compiled.  A call
+        inside an outer call's trace leaves what it heard to that call: its
+        trace is part of the outer program's."""
+        if getattr(_tls, "call", None) is not None:
+            return
+
+        def span_args() -> Dict[str, Any]:
+            extra = {"module": re.sub(r"[^\w.\-]", "_", "jit_" + self.__name__)}
+            if self._compile_args is not None:
+                extra.update(self._compile_args(args, kwargs))
+            return extra
+
+        _close_compile_span(self._label, t0, span_args)
+
     def _capture(self, args, kwargs) -> None:
         """Re-lower with the call's concrete args and record the compiled
         artifact's analyses.  Never raises: accounting must not break
@@ -231,12 +444,9 @@ class _InstrumentedJit:
             # memoize the attempt (even an empty result) so a backend whose
             # executables expose no analyses is not re-lowered on every call
             _label_analyses.setdefault(self._label, {})
-            _tls.suppress = True
-            try:
+            with _second_compile(self._label):
                 lowered = self._jit.lower(*args, **kwargs)
                 compiled = lowered.compile()
-            finally:
-                _tls.suppress = False
             record_executable(self._label, compiled)
             self._record_donated(lowered)
         except Exception:
@@ -387,11 +597,8 @@ def _note_traced(inst: "_InstrumentedJit", args, kwargs, seconds: float) -> None
 
 def _build_map(inst, key, args, kwargs) -> Dict[str, Any]:
     t0 = time.perf_counter()
-    _tls.suppress = True  # not a new logical trace
-    try:
+    with _second_compile(inst._label):
         text = inst._jit.lower(*args, **kwargs).compile().as_text()
-    finally:
-        _tls.suppress = False
     module, scopes = parse_op_scopes(text)
     return {
         "schema": SCOPES_SCHEMA, "module": module, "label": inst._label,

@@ -35,8 +35,22 @@ the training loop blocks on the device):
                    ``per_iteration`` arg); ``train/eval`` >
                    ``train/eval_score`` > ``wait/eval_metric``;
                    ``train/callbacks``; ``train/checkpoint``
-* ``setup``      — ``setup/booster_init``; ``dataset/construct`` >
-                   {``dataset/bin_fit``, ``dataset/bundle``, ``dataset/pack``}
+* ``setup``      — ``setup/import`` (the package's import);
+                   ``dataset/construct`` > {``dataset/bin_fit``,
+                   ``dataset/bundle``, ``dataset/pack``};
+                   ``setup/booster_init`` > {``setup/objective_init``,
+                   ``setup/transfer`` (a host-to-device copy of a
+                   row-proportional array: ``what``, ``bytes``),
+                   ``setup/kernel_import``, ``setup/add_valid``};
+                   ``setup/launch_build``
+* ``compile``    — ``compile/<label>``: a call through ``instrumented_jit``
+                   that traced or compiled (``trace_s``, ``lower_s``,
+                   ``backend_compile_s``, ``cache_retrieval_s``,
+                   ``cache_hit``, ``call_s``; the launch scan's
+                   ``baked_bytes``), under whatever span was open;
+                   ``compile/op_scopes``, ``compile/uninstrumented``
+                   (``obs/jit.py``).  With these every second from the
+                   package's import to the first timed iteration has a name
 * ``collective`` — ``timed_psum``/``timed_pmax`` sites with payload bytes
 * ``serve``      — ``serve/batch`` > {``serve/request`` >
                    ``serve/queue_wait``, ``serve/batch_assembly``,
@@ -59,8 +73,9 @@ import re
 import threading
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Tuple, Union
+from typing import Any, Callable, Deque, Dict, List, Optional, Tuple, Union
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from ..utils.timer import global_timer
@@ -290,6 +305,25 @@ class TraceRecorder:
             self._ambient = h
             h._ambient = True
         return h
+
+    @contextlib.contextmanager
+    def under(self, handle: Optional[SpanHandle]):
+        """Inside the block, an open span that was begun unattached is the
+        parent of what this thread begins (and of the ``compile/*`` spans
+        built after the fact); a raise leaves nothing on the stack.  A None
+        handle is a no-op."""
+        if handle is None:
+            yield
+            return
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        stack.append(handle)
+        try:
+            yield
+        finally:
+            if handle in stack:
+                stack.remove(handle)
 
     def end(
         self,
@@ -527,6 +561,26 @@ _TRACER = TraceRecorder()
 def get_tracer() -> TraceRecorder:
     """The process-global trace recorder."""
     return _TRACER
+
+
+# ------------------------------------------------------------ set-up hooks
+def traced_transfer(what: str, copy: Callable[[], Any]) -> Any:
+    """``copy()`` (a host-to-device copy of a row-proportional array: the
+    binned matrix, labels, weights, scores) under a ``setup/transfer`` span
+    with ``what`` and the ``bytes`` that arrived.  The span waits for the
+    copy, so it is the copy and not its dispatch; with tracing off it is a
+    flag check and nothing waits."""
+    tr = _TRACER
+    if not tr.active:
+        return copy()
+    with tr.span("setup/transfer", "setup", args={"what": what}) as h:
+        out = jax.block_until_ready(copy())
+        if h is not None:
+            h.args["bytes"] = sum(
+                int(getattr(leaf, "nbytes", 0))
+                for leaf in jax.tree_util.tree_leaves(out)
+            )
+    return out
 
 
 # --------------------------------------------------------------- hot hooks

@@ -106,7 +106,6 @@ def save_checkpoint(booster, directory: str, keep_last: Optional[int] = None) ->
         if sp is not None:
             sp.args.update({"iter": state["iter"], "path": path})
     ses = get_session()
-    ses.inc("checkpoints_saved")
     event = {"event": "checkpoint", "iter": state["iter"], "path": path}
     ses.record(event, defer=True)
     # a fault dump names the newest durable checkpoint it pairs with
@@ -137,7 +136,6 @@ def restore_checkpoint(booster, path_or_dir: str) -> int:
         state = pickle.load(f)
     booster._restore_checkpoint_state(state)
     ses = get_session()
-    ses.inc("checkpoints_restored")
     ses.record(
         {"event": "checkpoint_restore", "iter": state["iter"], "path": path},
         defer=True,

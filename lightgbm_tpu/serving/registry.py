@@ -175,7 +175,6 @@ class ModelRegistry:
         ses = get_session()
         if ses.enabled:
             ses.inc("serve/fleet_register_total")
-            ses.set_gauge("serve/fleet_size", len(entries))
         return entries
 
     def hot_swap(self, model_id: str, booster) -> ModelEntry:

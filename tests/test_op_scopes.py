@@ -266,3 +266,168 @@ def test_a_later_process_reads_the_map_without_compiling(tmp_path):
                              capture_output=True, text=True, timeout=600)
         assert out.returncode == 0, out.stderr[-2000:]
     assert "READ" in out.stdout
+
+
+# ---------------------------------------- the compilation timeline (PR 37)
+from lightgbm_tpu.obs import get_flight, get_session, get_tracer  # noqa: E402
+
+_STAGES = ("trace_s", "lower_s", "backend_compile_s", "cache_retrieval_s")
+
+
+def _compile_spans(label=None):
+    return [s for s in get_tracer().spans() if s["cat"] == "compile"
+            and (label is None or s["name"] == "compile/" + label)]
+
+
+@pytest.fixture
+def fresh_ring():
+    tracer = get_tracer()
+    tracer.configure(active=True)
+    tracer.reset()
+    yield tracer
+    tracer.configure(active=True)
+
+
+@pytest.fixture
+def own_cache(tmp_path):
+    """A persistent compilation cache of the test's own that keeps every
+    program; the process's cache and settings come back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir", "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes", "jax_enable_compilation_cache")
+    before = {n: getattr(jax.config, n) for n in names}
+    for n, v in zip(names, (str(tmp_path / "cache"), 0.0, -1, True)):
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()
+    try:
+        yield str(tmp_path / "cache")
+    finally:
+        for n, v in before.items():
+            jax.config.update(n, v)
+        compilation_cache.reset_cache()
+
+
+def test_one_compile_span_a_traced_call_and_none_on_a_warm_one(fresh_ring):
+    f = obs_jit.instrumented_jit(lambda x: (x * 3.0).sum(), label="pr37/f")
+    x = jax.numpy.arange(64.0)
+    f(x)
+    (span,) = _compile_spans("pr37/f")
+    a = span["args"]
+    assert a["label"] == "pr37/f" and a["module"].startswith("jit_")
+    assert all(a[k] >= 0 for k in _STAGES)
+    assert a["trace_s"] > 0 and a["lower_s"] > 0 and a["backend_compile_s"] > 0
+    # the retrieval happens inside the backend's compile-or-get-cached
+    assert a["cache_retrieval_s"] <= a["backend_compile_s"]
+    assert a["trace_s"] + a["lower_s"] + a["backend_compile_s"] <= a["call_s"]
+    assert span["dur"] == pytest.approx(a["call_s"] * 1e6, abs=2)
+    f(x)
+    f(x + 1)
+    assert len(_compile_spans("pr37/f")) == 1  # a warm call builds nothing
+    f(jax.numpy.arange(32.0))  # another shape traces again
+    assert len(_compile_spans("pr37/f")) == 2
+
+
+def test_an_inner_instrumented_call_is_part_of_the_outer_span(fresh_ring):
+    inner = obs_jit.instrumented_jit(lambda x: x * 2.0, label="pr37/inner")
+    outer = obs_jit.instrumented_jit(lambda x: inner(x).sum() + inner(x + 1).sum(),
+                                     label="pr37/outer")
+    outer(jax.numpy.arange(16.0))
+    assert not _compile_spans("pr37/inner")
+    (span,) = _compile_spans("pr37/outer")
+    # the inner traces lie inside the outer one: counted once
+    assert span["args"]["trace_s"] <= span["args"]["call_s"]
+
+
+def test_cache_hit_is_false_on_a_miss_true_on_a_hit_none_unasked(fresh_ring, own_cache):
+    def g(x):
+        return (x * 5.0 + 1.0).sum()
+
+    x = jax.numpy.arange(48.0)
+    obs_jit.instrumented_jit(g, label="pr37/miss")(x)
+    (miss,) = _compile_spans("pr37/miss")
+    assert miss["args"]["cache_hit"] is False and miss["args"]["cache_retrieval_s"] == 0
+    jax.clear_caches()  # the executable is gone from memory, not from the directory
+    obs_jit.instrumented_jit(g, label="pr37/hit")(x)
+    (hit,) = _compile_spans("pr37/hit")
+    assert hit["args"]["cache_hit"] is True
+    assert 0 < hit["args"]["cache_retrieval_s"] <= hit["args"]["backend_compile_s"]
+    # a program the cache does not keep: neither a hit nor a miss
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 3600.0)
+    obs_jit.instrumented_jit(lambda x: (x - 7.0).sum(), label="pr37/unasked")(x)
+    (unasked,) = _compile_spans("pr37/unasked")
+    assert unasked["args"]["cache_hit"] is None
+
+
+def test_the_second_compile_for_op_scopes_has_a_span_of_its_own(fresh_ring, scopes_cache):
+    f = obs_jit.instrumented_jit(lambda x: (x * 11.0).sum(), label="pr37/scoped")
+    f(jax.numpy.arange(24.0))
+    assert os.listdir(scopes_cache)  # the map was written: a second lower().compile()
+    (own,) = _compile_spans("pr37/scoped")
+    (second,) = [s for s in _compile_spans("op_scopes") if s["args"]["of"] == "pr37/scoped"]
+    assert second["ts"] >= own["ts"] + own["dur"]  # after the call, never inside its numbers
+    assert second["args"]["call_s"] > 0  # jax answers it from memory where it can
+    assert obs_jit.compile_counts_by_label()["pr37/scoped"] == 1
+
+
+def test_a_compilation_outside_any_instrumented_call_and_the_sum(fresh_ring):
+    heard = []
+
+    def listen(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            heard.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        x = jax.numpy.arange(40.0)
+        jax.numpy.tanh(x * 13.0).block_until_ready()  # eager: nobody's label
+        obs_jit.instrumented_jit(lambda v: (v / 17.0).sum(), label="pr37/sum")(x)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    spans = _compile_spans()
+    loose = [s for s in spans if s["name"] == "compile/uninstrumented"]
+    assert loose and all(s["args"]["module"] for s in loose)
+    # what the harness's CompileClock hears is the sum over the spans
+    assert sum(s["args"]["backend_compile_s"] for s in spans) == pytest.approx(
+        sum(heard), rel=1e-6)
+
+
+def test_compile_events_counters_and_trace_spans_off(fresh_ring):
+    ses = get_session()
+    ses.reset()
+    ses.configure(enabled=True)
+    flight = get_flight()
+    try:
+        obs_jit.instrumented_jit(lambda x: (x * 19.0).sum(), label="pr37/on")(
+            jax.numpy.arange(8.0))
+        assert ses.counters["compile/traces"] >= 1
+        events = [e for e in flight.events() if e.get("event") == "compile"]
+        assert events[-1]["label"] == "pr37/on" and events[-1]["seconds"] > 0
+        assert "hit" in events[-1]
+        fresh_ring.configure(active=False)
+        obs_jit.instrumented_jit(lambda x: (x * 23.0).sum(), label="pr37/off")(
+            jax.numpy.arange(8.0))
+        assert not _compile_spans("pr37/off")  # the ring takes nothing ...
+        assert [e for e in flight.events() if e.get("label") == "pr37/off"]  # ... the flight ring does
+    finally:
+        ses.configure(enabled=False)
+        ses.reset()
+
+
+def test_baked_bytes_of_a_launch_scan_are_its_labels_and_weights(fresh_ring):
+    X, y = _data(n=1200, f=5)
+    w = np.linspace(0.5, 1.5, len(y))
+    params = {"objective": "regression", "num_leaves": 7, "verbosity": -1,
+              "train_steps_per_launch": 2}
+    b = lgb.train(params, lgb.Dataset(X, y, weight=w), 4)
+    (span,) = _compile_spans("grow/scan2")
+    labels_and_weights = b.objective.label.nbytes + b.objective.weight.nbytes
+    assert labels_and_weights == 2 * 4 * len(y)
+    # beside them only the two per-feature tables the grower reads off the booster
+    tables = b._num_bins.nbytes + b._nan_bins.nbytes
+    assert span["args"]["baked_bytes"] == labels_and_weights + tables
+    assert span["args"]["trace_s"] > 0
+    launches = [s for s in get_tracer().spans() if s["name"] == "train/launch"]
+    assert [s["args"].get("first", False) for s in launches] == [True, False]
+    assert span["parent_id"] in {s["span_id"] for s in get_tracer().spans()
+                                 if s["name"] in ("train/launch", "train/launch_dispatch")}

@@ -174,8 +174,9 @@ def test_train_iteration_spans_and_phase_children():
     assert all(s["parent_id"] == runs[0]["span_id"] for s in iters)
     assert all(s["trace_id"] == runs[0]["trace_id"] for s in iters)
     iter_ids = {s["span_id"] for s in iters}
-    kids = [s for s in spans if s["parent_id"] in iter_ids]
-    # the layer boundaries are the iteration's children, under fixed names;
+    kids = [s for s in spans if s["parent_id"] in iter_ids and s["cat"] != "compile"]
+    # the layer boundaries are the iteration's children, under fixed names
+    # (beside them only what compiled on the way, in category compile);
     # the pipelined path fetches a tree one iteration late, so the first
     # iteration has no wait/fetch_tree and the last tree is fetched by the
     # model's first reader, outside any iteration
@@ -225,7 +226,8 @@ def test_span_tree_is_in_the_profilers_host_plane(tmp_path):
     ours = [e for e in events if e[0].split("/")[0] in ("train", "wait", "setup", "dataset")]
     names = {e[0] for e in ours}
     assert names == {
-        "setup/booster_init", "dataset/construct", "dataset/bin_fit",
+        "setup/booster_init", "setup/objective_init", "setup/transfer",
+        "setup/kernel_import", "setup/add_valid", "dataset/construct", "dataset/bin_fit",
         "dataset/bundle", "dataset/pack",
         "train/run", "train/iteration", "train/gradients", "train/sample",
         "train/grow", "train/score_update", "wait/fetch_tree", "train/host_tree",
@@ -247,13 +249,21 @@ def test_span_tree_is_in_the_profilers_host_plane(tmp_path):
     assert len(inside("train/eval_score", "train/eval")) == 2
     assert len(inside("wait/eval_metric", "train/eval_score")) == 2
     assert len(inside("train/callbacks", "train/run")) == 2
-    assert len(inside("dataset/construct", "setup/booster_init")) == 1  # the valid set's
+    assert len(inside("dataset/construct", "setup/add_valid")) == 1  # the valid set's
+    assert len(inside("setup/add_valid", "setup/booster_init")) == 1
+    assert len(inside("setup/objective_init", "setup/booster_init")) == 1
+    transfers = [e for e in ours if e[0] == "setup/transfer"]
+    assert len(inside("setup/transfer", "setup/booster_init")) == len(transfers) >= 4
     assert not inside("train/iteration", "setup/booster_init")
 
     # the same names are in the ring, with parent links
     ring = get_tracer().spans()
     by_id = {s["span_id"]: s for s in ring}
     assert names <= {s["name"] for s in ring}
+    copies = {s["args"]["what"]: s["args"]["bytes"] for s in ring
+              if s["name"] == "setup/transfer"}
+    assert copies.keys() >= {"bins", "score", "objective.label", "valid.score"}
+    assert copies["objective.label"] == 4 * len(y) and copies["score"] == 4 * len(y)
     parents = {(s["name"], by_id[s["parent_id"]]["name"]) for s in ring
                if s["parent_id"] in by_id}
     assert {("train/iteration", "train/run"), ("wait/fetch_tree", "train/iteration"),
@@ -343,7 +353,7 @@ def test_launch_per_iteration_counters_match_serial(tmp_path):
     launch_ids = {s["span_id"] for s in launches}
     kids = {}
     for s in spans:
-        if s["parent_id"] in launch_ids:
+        if s["parent_id"] in launch_ids and s["cat"] != "compile":  # PR 37: what compiled is there too
             kids.setdefault(s["parent_id"], []).append(s["name"])
     assert all(sorted(v) == ["train/launch_dispatch", "train/launch_replay",
                              "wait/launch_fetch"] for v in kids.values()), kids
@@ -567,3 +577,82 @@ def test_the_score_updates_forms_ride_on_the_top_spans(steps, valid, span, extra
     assert tops
     for s in tops:
         assert {k: s["args"].get(k) for k in want} == want
+
+
+# ------------------------------------------- the set-up's timeline (PR 37)
+_IMPORT_PROBE = """
+import json, sys
+{first}
+import lightgbm_tpu
+from lightgbm_tpu.obs import get_tracer
+spans = [s for s in get_tracer().spans() if s["name"] == "setup/import"]
+print(json.dumps(spans))
+"""
+
+
+@pytest.mark.parametrize("jax_first", [False, True])
+def test_a_fresh_interpreters_import_is_a_span(jax_first):
+    import subprocess
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root)
+    out = subprocess.run(
+        [sys.executable, "-c", _IMPORT_PROBE.format(first="import jax" if jax_first else "")],
+        env=env, cwd=root, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    (span,) = json.loads(out.stdout.strip().splitlines()[-1])
+    assert span["cat"] == "setup" and span["dur"] > 0
+    assert span["args"] == {"jax_preloaded": jax_first}
+
+
+def _inside(child, parent):
+    return (parent["ts"] <= child["ts"]
+            and child["ts"] + child["dur"] <= parent["ts"] + parent["dur"])
+
+
+def test_booster_inits_children_lie_inside_it_and_name_its_parts():
+    X, y = _data()
+    Xv, yv = _data(n=120, seed=3)
+    jax.clear_caches()  # whatever an earlier test compiled compiles again
+    lgb.train(dict(_PARAMS, metric="l2"), lgb.Dataset(X, y), 2,
+              valid_sets=[lgb.Dataset(Xv, yv)])
+    spans = get_tracer().spans()
+    (init,) = [s for s in spans if s["name"] == "setup/booster_init"]
+    by_id = {s["span_id"]: s for s in spans}
+
+    def under_init(s):
+        while s is not None and s is not init:
+            s = by_id.get(s["parent_id"])
+        return s is init
+
+    kids = [s for s in spans if s is not init and under_init(s)]
+    assert {s["name"] for s in kids if s["cat"] == "setup"} == {
+        "setup/objective_init", "setup/transfer", "setup/kernel_import",
+        "setup/add_valid", "dataset/construct", "dataset/bin_fit", "dataset/bundle", "dataset/pack"}
+    assert all(_inside(s, init) for s in kids)
+    # what compiled on the way (eager conversions, the first programs) is
+    # there too, by label, and nothing of the run's iterations is
+    assert [s for s in kids if s["cat"] == "compile"]
+    assert {s["cat"] for s in kids} <= {"setup", "compile"}
+    (valid,) = [s for s in kids if s["name"] == "setup/add_valid"]
+    assert valid["args"] == {"name": "valid_0"}
+    assert {s["args"]["what"] for s in kids if s["name"] == "setup/transfer"
+            and _inside(s, valid)} == {"valid.score", "bins"}  # the set's own matrix
+    iters = [s for s in spans if s["name"] == "train/iteration"]
+    assert [s["args"].get("first", False) for s in iters] == [True, False]
+    assert all(s["ts"] >= init["ts"] + init["dur"] for s in iters)
+
+
+def test_a_raise_in_the_set_up_leaves_no_parent_on_the_stack():
+    X, y = _data()
+    with pytest.raises(ValueError):
+        lgb.train(dict(_PARAMS, boosting="no-such-boosting"), lgb.Dataset(X, y), 1)
+    assert get_tracer().current() is None
+
+
+def test_trace_spans_off_records_none_of_the_set_up():
+    X, y = _data(seed=11)
+    lgb.train(dict(_PARAMS, trace_spans=False, train_steps_per_launch=2),
+              lgb.Dataset(X, y), 2)
+    assert get_tracer().spans() == []
