@@ -1414,6 +1414,65 @@ class Booster:
             return {"first": True}
         return {}
 
+    def _hist_step(self) -> int:
+        """STEP of the histogram kernel at this booster's shapes
+        (``seg.hist_step``, the function the kernel's scratch is sized by);
+        0 off the segment path."""
+        p = getattr(self, "_grower_params", None)
+        if p is None or p.hist_mode != "seg" or self.train_set is None:
+            return 0
+        from ..ops.pallas.seg import (
+            hist_bpad, hist_step, hist_sub, padded_rows, plane_groups,
+        )
+
+        f = int(self._bins.shape[1]) // max(self._featpar or 1, 1)
+        if f <= 0:
+            return 0
+        wide = p.max_bin > 256
+        # the packed matrix a kernel call sees: a shard's rows under
+        # tree_learner=data
+        shards = (
+            self._mesh.size
+            if self._mesh is not None and not self._featpar else 1
+        )
+        return hist_step(
+            f, hist_bpad(p.max_bin),
+            hist_sub(f, wide, plane_groups(f, wide) > 1),
+            padded_rows(-(-int(self._bins.shape[0]) // shards)),
+        )
+
+    def _long_step_row_share(self) -> Optional[float]:
+        """Of the rows this booster's trees histogrammed — the root's and
+        the smaller child's of every split, by the counts of the model it
+        holds — the share that lay in whole STEP-row steps of the histogram
+        kernel, ``sum (cnt // STEP) * STEP / sum cnt``.  Computed when
+        asked, from the trees the host has materialized; None off the
+        segment path or before the first tree.  (Under ``tree_learner=data``
+        the counts are global where the windows are a shard's.)"""
+        step = self._hist_step()
+        if not step:
+            return None
+        rows = long_rows = 0
+        for tree in list(self._models_store):
+            n = int(tree.num_leaves)
+            if n < 2:
+                cnts = np.asarray(tree.leaf_count[:1], dtype=np.int64)
+            else:
+                counts = np.concatenate([
+                    np.asarray(tree.internal_count[: n - 1], np.int64),
+                    np.asarray(tree.leaf_count[:n], np.int64),
+                ])
+                # child c >= 0 is node c; c < 0 is leaf ~c, at n - 1 + ~c
+                lc = np.asarray(tree.left_child[: n - 1], np.int64)
+                rc = np.asarray(tree.right_child[: n - 1], np.int64)
+                left = counts[np.where(lc >= 0, lc, n - 1 + ~lc)]
+                right = counts[np.where(rc >= 0, rc, n - 1 + ~rc)]
+                cnts = np.concatenate(
+                    [counts[:1], np.minimum(left, right)])
+            rows += int(cnts.sum())
+            long_rows += int((cnts // step * step).sum())
+        return long_rows / rows if rows else None
+
     def _seg_span_args(self) -> Dict[str, int]:
         """Plane groups of the packed row and the planes a group, and the
         histogram kernel's two-digit one-hot ("HxL", "1x<bpad>" where it
@@ -1421,17 +1480,16 @@ class Booster:
         whether it is the kernel's integer form, and the gradient levels of
         quantized training (0 when off), for the ``train/iteration`` and
         ``train/launch`` spans (none off the segment path)."""
-        p = getattr(self, "_grower_params", None)
-        if p is None or p.hist_mode != "seg" or self.train_set is None:
+        step = self._hist_step()
+        if not step:  # off the segment path
             return {}
         from ..ops.pallas.seg import (
-            group_shape, hist_bpad, hist_digits, hist_feature_block,
+            TILE, group_shape, hist_bpad, hist_digits, hist_feature_block,
             seg_int8_dispatch,
         )
 
+        p = self._grower_params
         f = int(self._bins.shape[1]) // max(self._featpar or 1, 1)
-        if f <= 0:
-            return {}
         g, sub = group_shape(f, p.max_bin > 256)
         bpad = hist_bpad(p.max_bin)
         high, low = hist_digits(bpad)
@@ -1440,6 +1498,9 @@ class Booster:
             "seg_groups": g, "seg_group_planes": sub,
             "hist_digits": f"{high}x{low}",
             "hist_feature_block": hist_feature_block(f, bpad),
+            # the rows a step of the histogram kernel contracts: long steps
+            # where the window allows, the TILE-row tile for its ragged end
+            "hist_step": f"{step}+{TILE}" if step > TILE else str(TILE),
             # which form of the histogram kernel: int8 operands and int32
             # sums (quantized gradients, or hist_acc's int8 grid) or the
             # three-term bf16 split
@@ -2760,7 +2821,8 @@ class Booster:
             "enabled": ses.enabled,
             "events": list(ses.events),
             "counters": dict(ses.counters),
-            "gauges": dict(ses.gauges),
+            # the booster's own readings are computed here, when asked
+            "gauges": {**ses.gauges, **self._hist_gauges()},
             "compile_count": _obs_compile_count(),
             "compile_counts_by_label": compile_counts_by_label(),
         }
@@ -2772,7 +2834,16 @@ class Booster:
         (see README "Live observability")."""
         from ..obs.export import health_snapshot
 
+        # every ``GET /metrics`` scrape of ``obs_export_port`` asks here
+        # first, then renders the session's gauges
+        get_session().update_gauges(self._hist_gauges())
         return health_snapshot(getattr(self, "_watchdog", None))
+
+    def _hist_gauges(self) -> Dict[str, float]:
+        """``hist/long_step_row_share``, computed when ``telemetry()`` or
+        ``health()`` asks; nothing in the training loop sets it."""
+        share = self._long_step_row_share()
+        return {} if share is None else {"hist/long_step_row_share": share}
 
     def dump_trace(self, path: str) -> str:
         """Write the span recorder's ring as a Chrome trace-event JSON file

@@ -77,6 +77,7 @@ from .seg import (
     hist_group,
     hist_ngroups,
     hist_scratch,
+    hist_step,
     hist_sub,
 )
 
@@ -167,10 +168,10 @@ def _fused_grow_kernel(
     child_start = dec_ref[i, 2]
     child_cnt = dec_ref[i, 3]
 
-    def tile_dmas(slot, base_col):
+    def tile_dmas(slot, base_col, width):
         return [aliased_tile_dma(
-            seg_any, seg_out, hist_stage.at[slot], sem_hist.at[slot],
-            base_col, read_via_input=read_via_input,
+            seg_any, seg_out, hist_stage.at[slot, :, pl.ds(0, width)],
+            sem_hist.at[slot], base_col, read_via_input=read_via_input,
         )]
 
     _hist_window(
@@ -233,7 +234,9 @@ def fused_grow_step_pallas(
     acc_dtype = jnp.int32 if quantized else jnp.float32
     tri = jnp.tril(jnp.ones((T, T), jnp.bfloat16)).T  # tri[i, j] = i <= j
     gl_arr = jnp.zeros((1, COL_ALIGN), jnp.float32)
-    hist_refs = hist_scratch(f, bpad, sub_h, quantized)
+    hist_refs = hist_scratch(
+        f, bpad, sub_h, quantized,
+        step=hist_step(f, bpad, sub_h, seg.shape[-1]))
     kernel = functools.partial(
         _fused_grow_kernel, f=f, n_pad=n_pad, use_cat=use_cat, sub_p=sub_p,
         sub_h=sub_h, wide=wide, bmt=bmt, bpad=bpad, group=group,

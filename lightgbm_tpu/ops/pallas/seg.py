@@ -85,6 +85,28 @@ Histogram engine v2 (this file's kernel contract):
     2048, where 8 H would pass the MXU's 128 rows) is the full one-hot
     itself through the same code: ``A`` is the 8 stat rows, every result
     block diagonal.
+  * The window is walked in TWO LOOPS over one body (PR 38).  A 512-row
+    tile-program costs about 0.62 us on the v5e, of which 0.17 is paid a
+    step whatever its width: the DMA's descriptor and wait (a DMA of any
+    width takes 0.23 us alone), the plane dispatch, the MXU's fill and
+    drain, the pop and the add into the accumulators.  So where the
+    window's aligned span allows, a step takes ``STEP`` rows
+    (``hist_step``: the largest of 8, 4, 2 and 1 ``TILE`` that the packed
+    matrix is long enough for and whose scratch fits ``SEG_VMEM_BUDGET``
+    beside the partition's, from static shapes alone — 4096 at the usual
+    bpad 256 — and ``TILE`` itself, i.e. no long loop, where the form is
+    the full one-hot): ONE DMA, one dispatch, one matmul a feature
+    block contracting ``STEP`` rows, one add into ``acc``.  The rest of
+    the span, under ``STEP`` rows, runs ``TILE``-row steps in the first
+    ``TILE`` columns of the same scratch.  The tail exists because
+    ``padded_rows``, the CPU path's window ladder and every window shorter
+    than ``STEP`` (most windows of a wide, short table) rest on the 512-row
+    tile: no step reads a column the ``TILE``-row loop alone would not.
+    The read-ahead carries across the loops.  The f32 partial sums are
+    cut every ``STEP`` rows in the long loop where they were cut every
+    512; integer sums do not depend on the cut (the raw planes are the
+    parent kernel's bit for bit in int8, and within 1e-6 of an
+    accumulator's sum of absolute addends in bf16: PERF.md section 6).
   * Kernels emit RAW 8-sublane accumulator planes, ``[K, G, 8, group *
     bpad]`` (f32 for the bf16 path, i32 for the int8 paths; column
     ``j * bpad + b`` of feature j of the group); the digit-recombine/
@@ -449,20 +471,50 @@ def hist_variants(group: int, wide: bool) -> Tuple[int, int]:
     return ppp, STAT_BLOCK // ppp
 
 
+def hist_step(f: int, bpad: int, sub: int, n_pad: Optional[int] = None) -> int:
+    """STEP, the rows one long step of ``_hist_window`` contracts, from
+    static shapes alone: the largest of 8, 4, 2 and 1 ``TILE`` (4096 ..
+    512) that is no longer than the packed matrix (``n_pad`` columns: a DMA
+    is a static slice of it; None = any length) and whose
+    ``hist_scratch_bytes`` fits ``SEG_VMEM_BUDGET`` beside the
+    partition's scratch at its widest (the fused grow step holds both; 128
+    planes, so the rule needs neither the bin width nor the row's form):
+    4096 at bpad 256 while a tile is 48 planes or fewer (82 byte-binned
+    features a one-group row; any grouped row), 2048 on wider one-group
+    rows and at bpad 128.  ``TILE`` (no long step: the kernel as it was)
+    where the form is the full one-hot, H = 1: ``B`` is then ``bpad`` rows a feature, 8 MB at 512 columns and
+    bpad 8192, and the matmul has 8 live rows whatever it contracts.  On
+    the v5e 0.17 of a 512-row tile-program's 0.62 us is paid a step, not a
+    row; kernel alone 4096 reads 23 % under 512, 2048 20 %, 1024 12 %
+    (PERF.md section 5, PR 38)."""
+    if hist_digits(bpad)[0] == 1:
+        return TILE
+    from .partition import partition_scratch_bytes
+
+    room = SEG_VMEM_BUDGET - partition_scratch_bytes(LANES)
+    for step in (8 * TILE, 4 * TILE, 2 * TILE):
+        if (n_pad is None or step <= n_pad) and (
+                hist_scratch_bytes(f, bpad, sub, step) <= room):
+            return step
+    return TILE
+
+
 def hist_scratch(f: int, bpad: int, sub: int, quantized: bool,
-                 grouped: bool = False):
+                 grouped: bool = False, *, step: int):
     """Scratch of ``_hist_window`` in its argument order, then the DMA
-    semaphores of the caller's ``tile_dmas`` (two slots x the DMAs a tile
+    semaphores of the caller's ``tile_dmas`` (two slots x the DMAs a step
     takes) — the one list ``seg_hist_pallas_batch`` and the fused grow step
-    allocate."""
+    allocate.  Staging slots and operands are ``step`` columns wide
+    (``hist_step`` of the shapes and the matrix's length); a ``TILE``-row
+    step uses their first ``TILE`` columns."""
     nblk, arows, brows = hist_operands(f, bpad)
     high = hist_digits(bpad)[0]
     op = jnp.int8 if quantized else jnp.bfloat16
     return [
-        pltpu.VMEM((2, sub, TILE), jnp.int16),  # stage: two staging slots
-        pltpu.VMEM((_bin_rows(hist_group(f, bpad)), TILE), jnp.int32),  # bins
-        pltpu.VMEM((nblk, arows, TILE), op),  # a_op: masked stats, per block
-        pltpu.VMEM((nblk, brows, TILE), op),  # b_op: low-digit one-hots
+        pltpu.VMEM((2, sub, step), jnp.int16),  # stage: two staging slots
+        pltpu.VMEM((_bin_rows(hist_group(f, bpad)), step), jnp.int32),  # bins
+        pltpu.VMEM((nblk, arows, step), op),  # a_op: masked stats, per block
+        pltpu.VMEM((nblk, brows, step), op),  # b_op: low-digit one-hots
         pltpu.VMEM(  # acc: every block's whole matmul result
             (nblk, 8 if high == 1 else arows, brows),
             jnp.int32 if quantized else jnp.float32,
@@ -471,19 +523,23 @@ def hist_scratch(f: int, bpad: int, sub: int, quantized: bool,
     ]
 
 
-def hist_scratch_bytes(f: int, bpad: int, sub: int) -> int:
+def hist_scratch_bytes(f: int, bpad: int, sub: int,
+                       step: Optional[int] = None) -> int:
     """VMEM bytes of ``hist_scratch`` at the wider operand type (a last
     dimension under 128 still takes whole lane tiles), the pipelined raw
-    output block and the tile loop's own temporaries (the unpacked tile,
-    one low-digit compare and one 32-row block of ``A``)."""
+    output block and the step's own temporaries (the unpacked tile, one
+    low-digit compare and one 32-row block of ``A``); at ``hist_step`` of
+    a matrix of any length unless ``step`` says otherwise."""
+    if step is None:
+        step = hist_step(f, bpad, sub)
     scratch = sum(
         math.prod(ref.shape[:-1]) * max(ref.shape[-1], 128)
         * jnp.dtype(ref.dtype).itemsize
-        for ref in hist_scratch(f, bpad, sub, quantized=False)[:-1]
+        for ref in hist_scratch(f, bpad, sub, quantized=False, step=step)[:-1]
     )
     out = 2 * 8 * hist_group(f, bpad) * bpad * 4
     low = min(hist_digits(bpad)[1], 128)
-    temps = sub * TILE * 4 + 2 * low * TILE * 4 + 2 * 32 * TILE * 4
+    temps = sub * step * 4 + 2 * low * step * 4 + 2 * 32 * step * 4
     return scratch + out + temps
 
 
@@ -498,14 +554,14 @@ def _hist_window(
     start,  # scalar i32 — window begin (data-row index)
     cnt,  # scalar i32 — window rows (0 = all-zero histogram)
     pt,  # scalar i32 — this program's feature-plane group (grid dim 1)
-    live,  # scalar i32 — 0 skips the tile loop entirely (dead plane group)
-    tile_dmas,  # (slot, base_col) -> the DMAs that stage one [SUB, TILE] tile
+    live,  # scalar i32 — 0 skips the step loops entirely (dead plane group)
+    tile_dmas,  # (slot, base_col, width) -> the DMAs staging [SUB, width]
     scales_ref,  # SMEM [2] f32: g_scale, h_scale (quantized mode; else 1s)
     out,  # VMEM [8, group * bpad] f32 | i32 — the program's RAW output block
-    stage,  # VMEM [2, SUB, TILE] i16 — tile t in slot t % 2
-    bins,  # VMEM [_bin_rows(group), TILE] i32 — the program's features' bins
-    a_op,  # VMEM [nblk, A rows, TILE] bf16 | i8
-    b_op,  # VMEM [nblk, B rows, TILE] bf16 | i8
+    stage,  # VMEM [2, SUB, STEP] i16 — step s in slot s % 2
+    bins,  # VMEM [_bin_rows(group), STEP] i32 — the program's features' bins
+    a_op,  # VMEM [nblk, A rows, STEP] bf16 | i8
+    b_op,  # VMEM [nblk, B rows, STEP] bf16 | i8
     acc,  # VMEM [nblk, A rows | 8, B rows] f32 | i32
     *,
     f: int,
@@ -530,23 +586,37 @@ def _hist_window(
 
         hist[s, j, b] = sum_r (stats[s, r] * [hi_j(r) = hi]) * [lo_j(r) = lo]
 
-    so for a block of nf = ``hist_feature_block`` features a tile builds,
-    rows of the tile on the lanes as they arrive (no transpose),
+    so for a block of nf = ``hist_feature_block`` features a step builds,
+    its rows on the lanes as they arrive (no transpose),
     ``A[(j, hi, s), r]`` = the stat row s where feature j's high digit is
     hi, else 0, and ``B[(j', lo), r]`` = [feature j' has low digit lo], and
     issues ONE ``A . B^T`` contracting the rows.  The [A rows, nf * L] result's
     diagonal blocks j = j' are the nf histograms as [(hi, s), lo]; the
     off-diagonal blocks are joint counts of two different features and are
     never read.  Every block's whole result accumulates in ``acc`` across
-    tiles and the diagonal blocks are copied to ``out`` once, after the
-    loop.  The sums are the same addends as a full one-hot's: the same
+    steps and the diagonal blocks are copied to ``out`` once, after the
+    loops.  The sums are the same addends as a full one-hot's: the same
     bf16 | int8 terms times exact 0/1, added in f32 | i32 over the same rows
     in the same order, zeros elsewhere.  H = 1 is the full one-hot itself:
     ``A`` is the 8 stat rows, shared by all the program's features, ``B``
     their whole one-hots, every result block "diagonal".
 
-    The read of tile t+1 is started before tile t is waited for, into the
-    other of the two staging slots.
+    Two loops over one body.  With STEP the width of the staging slots
+    (``hist_step``), the window's aligned span ``off + cnt`` is walked in
+    ``span // STEP`` long steps — each one DMA of STEP columns, one plane
+    dispatch, one matmul a feature block contracting STEP rows, one add
+    into ``acc`` — and the rest in ``TILE``-row steps in the first ``TILE``
+    columns of the same scratch, so no step reads a column the ``TILE``-row
+    loop alone would not (``padded_rows`` holds) and a window under STEP
+    rows runs the short loop and nothing else.  What a step costs whatever
+    its width (the DMA's fixed part, the dispatch, the MXU's fill, drain and
+    pops) is paid once for STEP rows in the long loop, not once for 512.
+    The f32 partial sums are cut every STEP rows there, every ``TILE`` in
+    the tail; integer sums are the same whatever the cut.
+
+    The read of step s+1 is started before step s is waited for, into the
+    other of the two staging slots, across both loops: the last long step
+    starts the first short one's.
 
     ``grouped``: a tile is the program's own aligned 16-plane bin block
     over the stat block (``hist_sub``); the program's planes sit in the
@@ -555,15 +625,18 @@ def _hist_window(
     nf = hist_feature_block(f, bpad)
     nblk, arows, brows = hist_operands(f, bpad)
     drows = 0 if high == 1 else _digit_rows(bpad)  # H = 1: shared stat rows
+    step = stage.shape[-1]
     abegin = (start // COL_ALIGN) * COL_ALIGN
     off = start - abegin
-    nt = (off + cnt + TILE - 1) // TILE
     # dead plane group (feature_fraction / EFB bundling): zero trips — the
     # output block stays zero and the grower never reads those rows
-    nt = jnp.where(live != 0, nt, 0)
+    span = jnp.where(live != 0, off + cnt, 0)
+    n_long = span // step if step > TILE else 0
+    tail0 = n_long * step  # where the TILE-row steps begin, from abegin
+    n_short = (span - tail0 + TILE - 1) // TILE
     acc[...] = jnp.zeros_like(acc)
-    # hoisted out of the tile loop: reciprocal-multiply instead of two
-    # full-width divides per tile (quotients round to integers, so the
+    # hoisted out of the step loops: reciprocal-multiply instead of two
+    # full-width divides per step (quotients round to integers, so the
     # rounding difference cannot change the result)
     inv_g = 1.0 / scales_ref[0]
     inv_h = 1.0 / scales_ref[1]
@@ -574,11 +647,6 @@ def _hist_window(
         GLO, GHI, HLO, HHI, M, _, _ = stat_lanes(f, wide)
     ngroups = hist_ngroups(f, bpad)
     half = bins.shape[0] // 2
-    iota_pos = jax.lax.broadcasted_iota(jnp.int32, (1, TILE), 1)
-    row8 = jax.lax.broadcasted_iota(jnp.int32, (8, TILE), 0)
-    is_g = (row8 == 0) | (row8 == 3) | (row8 == 6)
-    hi32 = jax.lax.broadcasted_iota(jnp.int32, (32, TILE), 0) >> 3
-    iota_lo = jax.lax.broadcasted_iota(jnp.int32, (min(low, 128), TILE), 0)
     op_dtype = jnp.int8 if quantized else jnp.bfloat16
     pref = jnp.int32 if quantized else jnp.float32
 
@@ -586,139 +654,186 @@ def _hist_window(
         """Row of ``bins`` that holds feature j of the program."""
         return j if wide else (j >> 1) + (j & 1) * half
 
-    def start_tile(t):
-        for dma in tile_dmas(t % 2, abegin + t * TILE):
+    def start_read(s, pos0, width):
+        """Start step s's read: ``width`` columns from ``abegin + pos0``."""
+        for dma in tile_dmas(s % 2, abegin + pos0, width):
             dma.start()
 
-    @pl.when(nt > 0)
-    def _first_read():
-        start_tile(0)
+    def step_of(width):
+        """The work of one step of ``width`` rows in the first ``width``
+        columns of the scratch, as ``run(s, pos0)``."""
+        cols = slice(0, width)
+        iota_pos = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        row8 = jax.lax.broadcasted_iota(jnp.int32, (8, width), 0)
+        is_g = (row8 == 0) | (row8 == 3) | (row8 == 6)
+        hi32 = jax.lax.broadcasted_iota(jnp.int32, (32, width), 0) >> 3
+        iota_lo = jax.lax.broadcasted_iota(
+            jnp.int32, (min(low, 128), width), 0)
 
-    def body(t, _):
-        @pl.when(t + 1 < nt)
-        def _read_ahead():
-            start_tile(t + 1)
+        def run(s, pos0):
+            for dma in tile_dmas(s % 2, abegin + pos0, width):
+                dma.wait()
+            xu = stage[s % 2, :, cols].astype(jnp.int32) & 0xFFFF  # [SUB, w]
+            pos = iota_pos + pos0
+            valid = ((pos >= off) & (pos < off + cnt)).astype(jnp.float32)
+            m = xu[M:M + 1].astype(jnp.float32) * valid  # [1, width]
+            # the 8 stat rows at once, g's on rows 0/3/6 and h's on 1/4/7
+            # (one row costs a vreg a 128 lanes like eight do)
+            lo16 = jnp.where(is_g, xu[GLO:GLO + 1], xu[HLO:HLO + 1])
+            hi16 = jnp.where(is_g, xu[GHI:GHI + 1], xu[HHI:HHI + 1])
+            vm = lax.bitcast_convert_type(
+                (lo16 | (hi16 << 16)).astype(jnp.uint32), jnp.float32
+            ) * m  # [8, width]: g * m | h * m
+            if quantized:
+                # int8 MXU path (2x bf16 throughput), 2-DIGIT: q is clipped
+                # to +-QMAX and split q = hi*128 + lo (|hi| <= 127,
+                # |lo| <= 64 — the +64 bias makes the shift round-to-nearest
+                # so the low digit stays in int8 range).  Quantized-gradient
+                # training (gradient_discretizer.cpp:70 grid, |q| <= 127 so
+                # hi is just the sign spill) stays EXACT like the old
+                # 1-digit path: per-bin integer sums are exact to 2^31/192
+                # rows (~11M at the |q|=127 extreme) in i32 and the f32
+                # recombine is exact below 2^24.  As the default hist
+                # accumulator the grid carries ~14 bits per addend — near
+                # ties are re-accumulated in bf16/f32 by the grower before
+                # any structure decision.
+                q = jnp.clip(
+                    jnp.round(vm * jnp.where(is_g, inv_g, inv_h)),
+                    -QMAX, QMAX,
+                ).astype(jnp.int32)
+                q_hi = (q + 64) >> 7
+                q_lo = q - (q_hi << 7)
+                # 5 live rows pad to the i32 tile's 8 sublanes anyway
+                stats = jnp.where(
+                    row8 < 2, q_hi,
+                    jnp.where(row8 == 2, m.astype(jnp.int32),
+                              jnp.where(row8 < 5, q_lo, 0)),
+                )
+            else:
+                # THREE-term bf16 split of each f32 addend (~26 mantissa
+                # bits) — the stat rows pad 6 -> 8 sublanes anyway, so the
+                # two extra residual rows are free (ADVICE r2: tighter
+                # precision contract at zero cost).  Kept in f32 registers:
+                # every value is a bf16.
+                v_hi = vm.astype(jnp.bfloat16).astype(jnp.float32)
+                r1 = vm - v_hi
+                v_lo = r1.astype(jnp.bfloat16).astype(jnp.float32)
+                v_lo2 = (r1 - v_lo).astype(jnp.bfloat16).astype(jnp.float32)
+                stats = jnp.where(
+                    row8 < 2, v_hi,
+                    jnp.where(row8 == 2, m,
+                              jnp.where(row8 < 5, v_lo,
+                                        jnp.where(row8 == 5, 0.0, v_lo2))),
+                )
+            stats32 = jnp.concatenate([stats] * 4, axis=0)  # [32, width]
 
-        for dma in tile_dmas(t % 2, abegin + t * TILE):
-            dma.wait()
-        xu = stage[t % 2].astype(jnp.int32) & 0xFFFF  # [SUB, TILE]
-        pos = iota_pos + t * TILE
-        valid = ((pos >= off) & (pos < off + cnt)).astype(jnp.float32)
-        m = xu[M:M + 1].astype(jnp.float32) * valid  # [1, TILE]
-        # the 8 stat rows at once, g's on rows 0/3/6 and h's on 1/4/7 (one
-        # row costs a vreg a 128 lanes like eight do)
-        lo16 = jnp.where(is_g, xu[GLO:GLO + 1], xu[HLO:HLO + 1])
-        hi16 = jnp.where(is_g, xu[GHI:GHI + 1], xu[HHI:HHI + 1])
-        vm = lax.bitcast_convert_type(
-            (lo16 | (hi16 << 16)).astype(jnp.uint32), jnp.float32
-        ) * m  # [8, TILE]: g * m | h * m
-        if quantized:
-            # int8 MXU path (2x bf16 throughput), 2-DIGIT: q is clipped to
-            # +-QMAX and split q = hi*128 + lo (|hi| <= 127, |lo| <= 64 —
-            # the +64 bias makes the shift round-to-nearest so the low
-            # digit stays in int8 range).  Quantized-gradient training
-            # (gradient_discretizer.cpp:70 grid, |q| <= 127 so hi is just
-            # the sign spill) stays EXACT like the old 1-digit path: per-
-            # bin integer sums are exact to 2^31/192 rows (~11M at the
-            # |q|=127 extreme) in i32 and the f32 recombine is exact below
-            # 2^24.  As the default hist accumulator the grid carries ~14
-            # bits per addend — near ties are re-accumulated in bf16/f32
-            # by the grower before any structure decision.
-            q = jnp.clip(
-                jnp.round(vm * jnp.where(is_g, inv_g, inv_h)), -QMAX, QMAX
-            ).astype(jnp.int32)
-            q_hi = (q + 64) >> 7
-            q_lo = q - (q_hi << 7)
-            # 5 live rows pad to the i32 tile's 8 sublanes anyway
-            stats = jnp.where(
-                row8 < 2, q_hi,
-                jnp.where(row8 == 2, m.astype(jnp.int32),
-                          jnp.where(row8 < 5, q_lo, 0)),
+            def take_planes(p0, nfl):
+                """The program's bins from planes [p0, ...) of the tile, at
+                STATIC offsets (hence the unrolled dispatch on the dynamic
+                program id below); -1, which matches no digit, for the
+                features a last group lacks."""
+                if nfl < group:
+                    bins[:, cols] = jnp.full(
+                        (bins.shape[0], width), -1, jnp.int32)
+                if wide:
+                    bins[0:nfl, cols] = xu[p0:p0 + nfl]  # a u16 plane each
+                    return
+                planes = xu[p0:p0 + (nfl + 1) // 2]
+                bins[0:(nfl + 1) // 2, cols] = planes & 0xFF
+                if nfl > 1:
+                    bins[half:half + nfl // 2, cols] = (
+                        planes[:nfl // 2] >> 8) & 0xFF
+
+            if grouped:
+                # the last program's features past F read planes of the
+                # padding; ``combine_hist_raw`` drops their columns
+                for v in range(nvar):
+                    pl.when(pt % nvar == v)(
+                        functools.partial(take_planes, v * ppp, group))
+            elif ngroups == 1:
+                take_planes(0, f)
+            else:
+                for gi in range(ngroups):
+                    basef = gi * group
+                    pl.when(pt == gi)(functools.partial(
+                        take_planes, basef if wide else basef >> 1,
+                        min(group, f - basef)))
+            b = bins[:, cols]
+            lo_dig = b if high == 1 else b & (low - 1)
+            hi_dig = (
+                jnp.zeros_like(b) if high == 1
+                else b >> (low.bit_length() - 1)
             )
-        else:
-            # THREE-term bf16 split of each f32 addend (~26 mantissa bits)
-            # — the stat rows pad 6 -> 8 sublanes anyway, so the two extra
-            # residual rows are free (ADVICE r2: tighter precision contract
-            # at zero cost).  Kept in f32 registers: every value is a bf16.
-            v_hi = vm.astype(jnp.bfloat16).astype(jnp.float32)
-            r1 = vm - v_hi
-            v_lo = r1.astype(jnp.bfloat16).astype(jnp.float32)
-            v_lo2 = (r1 - v_lo).astype(jnp.bfloat16).astype(jnp.float32)
-            stats = jnp.where(
-                row8 < 2, v_hi,
-                jnp.where(row8 == 2, m,
-                          jnp.where(row8 < 5, v_lo,
-                                    jnp.where(row8 == 5, 0.0, v_lo2))),
-            )
-        stats32 = jnp.concatenate([stats] * 4, axis=0)  # [32, TILE]
-
-        def take_planes(p0, nfl):
-            """The program's bins from planes [p0, ...) of the tile, at
-            STATIC offsets (hence the unrolled dispatch on the dynamic
-            program id below); -1, which matches no digit, for the
-            features a last group lacks."""
-            if nfl < group:
-                bins[...] = jnp.full(bins.shape, -1, jnp.int32)
-            if wide:
-                bins[0:nfl] = xu[p0:p0 + nfl]  # a u16 plane a feature
-                return
-            planes = xu[p0:p0 + (nfl + 1) // 2]
-            bins[0:(nfl + 1) // 2] = planes & 0xFF
-            if nfl > 1:
-                bins[half:half + nfl // 2] = (planes[:nfl // 2] >> 8) & 0xFF
-
-        if grouped:
-            # the last program's features past F read planes of the
-            # padding; ``combine_hist_raw`` drops their columns
-            for v in range(nvar):
-                pl.when(pt % nvar == v)(
-                    functools.partial(take_planes, v * ppp, group))
-        elif ngroups == 1:
-            take_planes(0, f)
-        else:
-            for gi in range(ngroups):
-                basef = gi * group
-                pl.when(pt == gi)(functools.partial(
-                    take_planes, basef if wide else basef >> 1,
-                    min(group, f - basef)))
-        b = bins[...]
-        lo_dig = b if high == 1 else b & (low - 1)
-        hi_dig = jnp.zeros_like(b) if high == 1 else b >> (low.bit_length() - 1)
-        for fb in range(nblk):
-            for c in range(arows // 32):
-                # 32 rows of A: feature j's stat rows under four high digits
-                j, h0 = (0, 0) if high == 1 else (
-                    (32 * c) // drows, (32 * c) % drows // 8)
-                jg = fb * nf + j
-                if j >= nf or jg >= group:
-                    blk = jnp.zeros((32, TILE), op_dtype)
-                else:
-                    r = bin_row(jg)
-                    blk = jnp.where(
-                        hi_dig[r:r + 1] == hi32 + h0, stats32, 0
-                    ).astype(op_dtype)
-                a_op[fb, 32 * c:32 * (c + 1)] = blk
-            for j in range(nf):
-                jg = fb * nf + j
-                for c0 in range(0, low, iota_lo.shape[0]):  # H = 1: by 128s
-                    rows = pl.ds(j * low + c0, iota_lo.shape[0])
-                    if jg >= group:
-                        b_op[fb, rows] = jnp.zeros(iota_lo.shape, op_dtype)
+            for fb in range(nblk):
+                for c in range(arows // 32):
+                    # 32 rows of A: feature j's stat rows under four high
+                    # digits
+                    j, h0 = (0, 0) if high == 1 else (
+                        (32 * c) // drows, (32 * c) % drows // 8)
+                    jg = fb * nf + j
+                    if j >= nf or jg >= group:
+                        blk = jnp.zeros((32, width), op_dtype)
                     else:
                         r = bin_row(jg)
-                        b_op[fb, rows] = (
-                            lo_dig[r:r + 1] == iota_lo + c0
+                        blk = jnp.where(
+                            hi_dig[r:r + 1] == hi32 + h0, stats32, 0
                         ).astype(op_dtype)
-            # ONE matmul a feature block a tile
-            part = jax.lax.dot_general(
-                a_op[fb], b_op[fb],
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=pref,
-            )
-            acc[fb] += part[:acc.shape[1]]
+                    a_op[fb, 32 * c:32 * (c + 1), cols] = blk
+                for j in range(nf):
+                    jg = fb * nf + j
+                    # H = 1: by 128s
+                    for l0 in range(0, low, iota_lo.shape[0]):
+                        rows = pl.ds(j * low + l0, iota_lo.shape[0])
+                        if jg >= group:
+                            b_op[fb, rows, cols] = jnp.zeros(
+                                iota_lo.shape, op_dtype)
+                        else:
+                            r = bin_row(jg)
+                            b_op[fb, rows, cols] = (
+                                lo_dig[r:r + 1] == iota_lo + l0
+                            ).astype(op_dtype)
+                # ONE matmul a feature block a step
+                part = jax.lax.dot_general(
+                    a_op[fb, :, cols], b_op[fb, :, cols],
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=pref,
+                )
+                acc[fb] += part[:acc.shape[1]]
+
+        return run
+
+    if step > TILE:
+        pl.when(n_long > 0)(lambda: start_read(0, 0, step))
+        pl.when((n_long == 0) & (n_short > 0))(
+            lambda: start_read(0, 0, TILE))
+        run_long = step_of(step)
+
+        def long_body(t, _):
+            @pl.when(t + 1 < n_long)
+            def _read_ahead():
+                start_read(t + 1, (t + 1) * step, step)
+
+            @pl.when((t + 1 == n_long) & (n_short > 0))
+            def _read_tail_ahead():
+                start_read(t + 1, tail0, TILE)
+
+            run_long(t, t * step)
+            return 0
+
+        lax.fori_loop(0, n_long, long_body, 0)
+    else:
+        pl.when(n_short > 0)(lambda: start_read(0, 0, TILE))
+    run_short = step_of(TILE)
+
+    def short_body(u, _):
+        @pl.when(u + 1 < n_short)
+        def _read_ahead():
+            start_read(n_long + u + 1, tail0 + (u + 1) * TILE, TILE)
+
+        run_short(n_long + u, tail0 + u * TILE)
         return 0
 
-    lax.fori_loop(0, nt, body, 0)
+    lax.fori_loop(0, n_short, short_body, 0)
     # the diagonal blocks, once a program: out[s, (jg, hi, lo)]
     for jg in range(group):
         fb, j = divmod(jg, nf)
@@ -782,11 +897,12 @@ def _seg_hist_kernel(
     i = pl.program_id(0)
     pt = pl.program_id(1)
 
-    def tile_dmas(slot, base_col):
-        cols = pl.ds(pl.multiple_of(base_col, COL_ALIGN), TILE)
+    def tile_dmas(slot, base_col, width):
+        cols = pl.ds(pl.multiple_of(base_col, COL_ALIGN), width)
+        into = pl.ds(0, width)  # a TILE-row step: the slot's first columns
         if not grouped:
             return [pltpu.make_async_copy(
-                seg_any.at[pl.ds(0, sub), cols], stage.at[slot],
+                seg_any.at[pl.ds(0, sub), cols], stage.at[slot, :, into],
                 sem_in.at[slot],
             )]
         # the program's aligned bin block, then the stat block: flat
@@ -801,7 +917,7 @@ def _seg_hist_kernel(
                     pl.ds(pl.multiple_of(r % gsub, STAT_BLOCK), STAT_BLOCK),
                     cols,
                 ],
-                stage.at[slot, pl.ds(k * STAT_BLOCK, STAT_BLOCK)],
+                stage.at[slot, pl.ds(k * STAT_BLOCK, STAT_BLOCK), into],
                 sem_in.at[2 * slot + k],
             )
             for k, r in enumerate((row0, stat0))
@@ -909,7 +1025,9 @@ def seg_hist_pallas_batch(
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((k, ngroups, 8, group * bpad), acc_dtype),
-        scratch_shapes=hist_scratch(f, bpad, sub, quantized, grouped),
+        scratch_shapes=hist_scratch(
+            f, bpad, sub, quantized, grouped,
+            step=hist_step(f, bpad, sub, seg.shape[-1])),
         interpret=interpret,
     )(
         scal.astype(jnp.int32), scales.astype(jnp.float32),

@@ -216,21 +216,24 @@ def test_fused_kernel_interpret_matches_oracle():
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("f,num_bins,k", [
-    (11, 256, 2), (28, 256, 1), (28, 128, 4), (67, 256, 1), (7, 100, 2),
+@pytest.mark.parametrize("f,num_bins,k,n", [
+    (11, 256, 2, 2600), (28, 256, 1, 2600), (28, 128, 4, 2600),
+    (67, 256, 1, 2600), (7, 100, 2, 2600),
+    # the elected child takes long steps and a ragged tail (n over 3 x STEP)
+    (28, 256, 1, 13000), (11, 256, 2, 26000), (7, 100, 3, 30000),
 ])
-def test_fused_histogram_is_the_two_launch_histogram(f, num_bins, k, quantized,
-                                                     full_onehot):
+def test_fused_histogram_is_the_two_launch_histogram(f, num_bins, k, n,
+                                                     quantized, full_onehot):
     """The fused kernel's phase 3 is ``seg._hist_window`` reading through
     the output alias, two staging slots deep: its histogram of the elected
     child equals, bit for bit, the two-launch kernel's over the partitioned
-    matrix, and the H = 1 form's (int8 exactly; bf16 to rounding)."""
+    matrix (both run the one function, long steps and tail alike), and the
+    H = 1 form's (int8 exactly; bf16 to rounding)."""
     from lightgbm_tpu.ops.pallas.seg import (
         QMAX, hist_bpad, hist_ngroups, seg_hist_pallas_batch,
     )
 
     rng = np.random.default_rng(11 + f)
-    n = 2600
     n_pad = padded_rows(n)
     bins = rng.integers(0, num_bins, size=(n, f)).astype(np.int32)
     seg = pack_rows(
@@ -263,6 +266,8 @@ def test_fused_histogram_is_the_two_launch_histogram(f, num_bins, k, quantized,
         seg2, dec[:, 2:4], scales, live, **kw))
     np.testing.assert_array_equal(hist, two_launch)
     assert hist[0].any() and (k == 1 or not hist[k - 1].any())
+    if n > 2600:  # the smaller child is long enough for a long step
+        assert int(np.asarray(dec)[0, 3]) > 2048
     with full_onehot():
         seg1, dec1, hist1 = run()
     np.testing.assert_array_equal(np.asarray(seg1), np.asarray(seg2))

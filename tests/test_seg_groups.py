@@ -171,23 +171,31 @@ def test_grouped_wide_rows_equal_numpy():
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("f,groups,wide,num_bins", [
-    (500, None, False, 256), (28, 2, False, 256), (300, None, False, 128),
-    (130, None, True, 512), (125, None, True, 1000),
+@pytest.mark.parametrize("f,groups,wide,num_bins,long", [
+    (500, None, False, 256, False), (28, 2, False, 256, False),
+    (300, None, False, 128, False), (130, None, True, 512, False),
+    (125, None, True, 1000, False),
+    (28, 2, False, 256, True), (243, None, False, 256, True),
+    (130, None, True, 512, True),
 ])
 def test_grouped_two_digit_onehot_equals_numpy_and_full_onehot(
-        f, groups, wide, num_bins, quantized, full_onehot):
+        f, groups, wide, num_bins, long, quantized, full_onehot):
     """The grouped row's histogram programs (an aligned 16-plane bin block
     over the stat block, the planes picked at one of a few static offsets)
     through the two-digit one-hot: exactly NumPy's ``bincount`` and exactly
     the H = 1 form, K = 2 windows (one off a 128-column boundary, one of
     cnt = 0), both dtypes (grad and hess are multiples of 1/8, so every
-    order of summation is exact and the int8 grid holds them whole)."""
-    n = 1200
+    order of summation is exact and the int8 grid holds them whole).
+    ``long``: the first window takes three long steps (two DMAs each) and a
+    ragged tail, a third ends on a long step's edge; the H = 1 form keeps
+    the TILE-row loop alone."""
+    step = seg.hist_step(f, seg.hist_bpad(num_bins), seg.hist_sub(f, wide, True))
+    assert step > seg.TILE
+    n, first = (3 * step + 600, (77, 3 * step + 37)) if long else (1200, (133, 900))
     bins, grad, hess = _table(f, n, f + 1, num_bins=num_bins)
     mat, n_pad = _pack(bins, grad, hess, groups=groups, wide=wide)
     assert seg.is_grouped(mat)
-    scal = jnp.asarray([(133, 900), (40, 0)], jnp.int32)
+    scal = jnp.asarray([first, (40, 0), (393, 2 * step - 9)][:2 + long], jnp.int32)
     kw = dict(f=f, num_bins=num_bins, n_pad=n_pad, wide=wide,
               quantized=quantized, interpret=True)
     scales = jnp.full((2,), 1 / 8, jnp.float32)
@@ -195,9 +203,11 @@ def test_grouped_two_digit_onehot_equals_numpy_and_full_onehot(
     with full_onehot():
         want = np.asarray(seg.seg_hist_pallas_batch(mat, scal, scales, **kw))
     assert np.array_equal(got, want)
-    assert np.array_equal(
-        got[0], _numpy_hist(bins, grad, hess, np.arange(133, 1033),
-                            num_bins=num_bins))
+    for i in (0, 2)[:1 + long]:
+        st, cnt = (int(v) for v in scal[i])
+        assert np.array_equal(
+            got[i], _numpy_hist(bins, grad, hess, np.arange(st, st + cnt),
+                                num_bins=num_bins))
     assert not got[1].any()
 
 
@@ -340,13 +350,17 @@ class _Log:
         self.warnings.append(str(msg))
 
 
-@pytest.mark.parametrize("f,max_bin,groups,planes,digits,block", [
-    (2000, 255, 8, 128, "8x32", 2), (243, 255, 2, 80, "8x32", 2),
-    (242, 255, 1, 128, "8x32", 2), (130, 511, 2, 80, "8x64", 2),
-    (28, 127, 1, 32, "4x32", 4), (3, 4000, 1, 32, "16x64", 1),
+@pytest.mark.parametrize("f,max_bin,groups,planes,digits,block,step", [
+    # a table of 600 rows is packed 2,048 columns long: no step is longer
+    (2000, 255, 8, 128, "8x32", 2, "2048+512"),
+    (243, 255, 2, 80, "8x32", 2, "2048+512"),
+    (242, 255, 1, 128, "8x32", 2, "2048+512"),
+    (130, 511, 2, 80, "8x64", 2, "2048+512"),
+    (28, 127, 1, 32, "4x32", 4, "2048+512"),
+    (3, 4000, 1, 32, "16x64", 1, "2048+512"),
 ])
 def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, digits,
-                                            block, monkeypatch):
+                                            block, step, monkeypatch):
     """On a TPU the Booster takes the segment path whatever the width, with
     no warning; the spans carry G and the planes a group, the digits of the
     histogram kernel's one-hot with the features a matmul takes, which form
@@ -375,6 +389,9 @@ def test_the_gate_resolves_seg_at_any_width(f, max_bin, groups, planes, digits,
     assert booster._seg_span_args() == {
         "seg_groups": groups, "seg_group_planes": planes,
         "hist_digits": digits, "hist_feature_block": block,
+        # long steps and the 512-row tile for a window's end (PR 38);
+        # "512" alone where the form is the full one-hot
+        "hist_step": step,
         # the default hist_acc=auto takes the kernel's int8 form on a TPU;
         # no quantized gradients (PR 33)
         "hist_int8": True, "grad_quant_bins": 0}
@@ -440,3 +457,53 @@ def test_lgb_train_on_a_grouped_row_equals_the_ordered_path(how):
                         if l.startswith(("split_feature=", "threshold=", "left_child=",
                                          "right_child=", "num_leaves=", "leaf_count="))]
     assert models["seg"] == models["ordered"]
+
+
+@pytest.mark.parametrize("step", (4, 16, 64))
+def test_long_step_row_share_comes_from_the_trees(step, monkeypatch):
+    """``hist/long_step_row_share`` in ``Booster.telemetry()`` (and, through
+    ``health()``, on ``GET /metrics``): of the rows the trees histogrammed
+    (the root and the smaller child of every split, by the model's counts)
+    the share in whole STEP-row steps, computed when asked; ``hist_step`` on
+    the spans comes from the same function."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.obs.registry import get_session
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(700, 5)).astype(np.float32)
+    y = (x[:, 0] + x[:, 1] > 0).astype(np.float32)
+    booster = lgb.train(
+        {"objective": "binary", "num_leaves": 7, "hist_mode": "seg",
+         "min_data_in_leaf": 5, "verbosity": -1},
+        lgb.Dataset(x, y), num_boost_round=3)
+    assert booster._hist_step() == 2048 == seg.hist_step(
+        5, seg.hist_bpad(255), seg.hist_sub(5, False), seg.padded_rows(700))
+    assert booster._seg_span_args()["hist_step"] == "2048+512"
+    monkeypatch.setattr(type(booster), "_hist_step", lambda self: step)
+    rows = long_rows = 0
+    for tree in booster.dump_model()["tree_info"]:
+        def walk(node):
+            nonlocal rows, long_rows
+            if "leaf_index" in node:
+                return node["leaf_count"]
+            kids = [walk(node["left_child"]), walk(node["right_child"])]
+            rows += min(kids)
+            long_rows += min(kids) // step * step
+            return node["internal_count"]
+        root = walk(tree["tree_structure"])
+        rows += root
+        long_rows += root // step * step
+    want = long_rows / rows
+    assert 0 < want < 1
+    assert booster.telemetry()["gauges"]["hist/long_step_row_share"] == want
+    ses = get_session()
+    was = ses.enabled
+    ses.configure(enabled=True)
+    try:
+        booster.health()
+        assert ses.gauges["hist/long_step_row_share"] == want
+    finally:
+        ses.reset()
+        ses.configure(enabled=was)
+    monkeypatch.setattr(type(booster), "_hist_step", lambda self: 0)  # off the path
+    assert "hist/long_step_row_share" not in booster.telemetry()["gauges"]

@@ -590,6 +590,30 @@ def test_seg_hist_batch_cpu_windowed(packed_big):
 _WINDOWS = [(0, 1500), (133, 513), (700, 0), (1000, 37)]
 
 
+def _long_windows(step):
+    """Windows that cross from the kernel's long steps into its TILE-row tail
+    and sit on its edges (``step`` = ``seg.hist_step``): one row short of a
+    long step (the tail loop alone), a long step and 5 rows, a start off a
+    128-column boundary, three long steps and a ragged tail, a start off a
+    boundary with ``off + cnt`` an exact multiple of the step (no tail, so no
+    read past the last long step), empty, short, exactly one aligned step."""
+    return [(0, step - 1), (5, step), (130, step + 1), (77, 3 * step + 37),
+            (393, 2 * step - 9), (700, 0), (3000, 37), (128, step)]
+
+
+def _case_windows(rows, f, num_bins, wide=False):
+    """(windows, table rows, index of the window the K = 1 grid takes):
+    ``rows`` "short" is the 1,500-row table every window of which runs the
+    TILE-row loop alone; "long" a table of three long steps and more."""
+    from lightgbm_tpu.ops.pallas import seg
+
+    if rows == "short":
+        return _WINDOWS, 1500, 1
+    step = seg.hist_step(f, seg.hist_bpad(num_bins), seg.hist_sub(f, wide))
+    assert step > seg.TILE  # else the case would not reach the long loop
+    return _long_windows(step), 3 * step + 600, 3
+
+
 def _hist_case(f, num_bins, wide=False, n=1500, seed=3):
     rng = np.random.default_rng(seed + f)
     n_pad = padded_rows(n)
@@ -603,14 +627,17 @@ def _hist_case(f, num_bins, wide=False, n=1500, seed=3):
     ), n_pad
 
 
-def _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized, wide=False):
-    """(two-digit, H = 1) results of a K=4 grid, and of a K=1 grid over the
-    window off a 128-column boundary with the last plane group dead."""
+def _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized, wide=False,
+                windows=_WINDOWS, alone=1):
+    """(two-digit, H = 1) results of a grid over ``windows``, and of a K=1
+    grid over window ``alone`` with the last plane group dead.  The H = 1
+    form runs TILE-row steps alone: the kernel as it was before the long
+    step."""
     from lightgbm_tpu.ops.pallas.seg import (
         QMAX, hist_bpad, hist_ngroups, seg_hist_pallas_batch,
     )
 
-    scal = jnp.asarray(_WINDOWS, jnp.int32)
+    scal = jnp.asarray(windows, jnp.int32)
     scales = jnp.asarray([5.0 / QMAX, 0.25 / QMAX], jnp.float32)
     ng = hist_ngroups(f, hist_bpad(num_bins))
     live = jnp.ones((ng,), jnp.int32).at[ng - 1].set(int(ng == 1))
@@ -620,7 +647,8 @@ def _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized, wide=False):
     def run():
         return [np.asarray(a) for a in (
             seg_hist_pallas_batch(seg, scal, scales, **kw),
-            seg_hist_pallas_batch(seg, scal[1:2], scales, live, **kw),
+            seg_hist_pallas_batch(seg, scal[alone:alone + 1], scales, live,
+                                  **kw),
         )]
 
     got = run()
@@ -638,26 +666,33 @@ def _assert_forms_agree(got, want, quantized):
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("f,num_bins", [
-    (1, 256), (7, 256), (8, 256), (28, 256), (67, 256),
-    (1, 128), (7, 100), (8, 128), (28, 128), (67, 127),
+@pytest.mark.parametrize("f,num_bins,rows", [
+    (1, 256, "short"), (7, 256, "short"), (8, 256, "short"),
+    (28, 256, "short"), (67, 256, "short"),
+    (1, 128, "short"), (7, 100, "short"), (8, 128, "short"),
+    (28, 128, "short"), (67, 127, "short"),
+    (7, 256, "long"), (28, 256, "long"), (11, 128, "long"),
 ])
 def test_two_digit_onehot_equals_full_onehot_and_reference(
-        f, num_bins, quantized, full_onehot):
+        f, num_bins, rows, quantized, full_onehot):
     """bpad 256 -> (8, 32) and bpad 128 -> (4, 32): F = 1, 7 (a feature block
     that is not full), 8, 28 (a last program of 4 features), 67 (of 3);
     K = 4 and K = 1; a window off a 128-column boundary, one of cnt = 0; a
-    dead plane group.  int8 sums are integers and equal exactly."""
+    dead plane group.  "long": windows on every edge of a long step
+    (``_long_windows``), K = 8, against the H = 1 form, which keeps the
+    TILE-row loop alone.  int8 sums are integers and equal exactly."""
     from lightgbm_tpu.ops.pallas.seg import (
         hist_bpad, hist_digits, hist_group, hist_ngroups, seg_hist_ref,
     )
 
     bpad = hist_bpad(num_bins)
     assert hist_digits(bpad) == {256: (8, 32), 128: (4, 32)}[bpad]
-    seg, n_pad = _hist_case(f, num_bins)
-    got, want = _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized)
+    windows, n, alone = _case_windows(rows, f, num_bins)
+    seg, n_pad = _hist_case(f, num_bins, n=n)
+    got, want = _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized,
+                            windows=windows, alone=alone)
     _assert_forms_agree(got, want, quantized)
-    for i, (st, cnt) in enumerate(_WINDOWS):
+    for i, (st, cnt) in enumerate(windows):
         ref = np.asarray(seg_hist_ref(
             seg, jnp.asarray([st, cnt], jnp.int32), f=f, num_bins=num_bins,
             n_pad=n_pad))
@@ -665,35 +700,41 @@ def test_two_digit_onehot_equals_full_onehot_and_reference(
         if not quantized:  # three-term bf16 split: ~26-bit addends
             assert np.abs(got[0][i] - ref).max() <= 5e-6 * max(
                 1e-9, np.abs(ref).max())
-    # K = 1 is K = 4's member: the dead group's features zero, the live whole
+    # K = 1 is the grid's member: the dead group's features zero, the live whole
     ng, gb = hist_ngroups(f, bpad), hist_group(f, bpad)
     live_f = gb * (ng - 1) if ng > 1 else f
-    np.testing.assert_array_equal(got[1][0][:, :live_f], got[0][1][:, :live_f])
+    np.testing.assert_array_equal(
+        got[1][0][:, :live_f], got[0][alone][:, :live_f])
     assert (got[1][0][:, live_f:] == 0).all()
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("f,num_bins,digits", [
-    (9, 500, (8, 64)), (4, 700, (12, 64)), (5, 1000, (16, 64)),
-    (3, 1100, (9, 128)), (3, 2000, (16, 128)), (3, 4000, (1, 4096)),
+@pytest.mark.parametrize("f,num_bins,digits,rows", [
+    (9, 500, (8, 64), "short"), (4, 700, (12, 64), "short"),
+    (5, 1000, (16, 64), "short"), (3, 1100, (9, 128), "short"),
+    (3, 2000, (16, 128), "short"), (3, 4000, (1, 4096), "short"),
+    (5, 1000, (16, 64), "long"), (3, 2000, (16, 128), "long"),
 ])
-def test_wide_two_digit_onehot_equals_full_onehot(f, num_bins, digits,
+def test_wide_two_digit_onehot_equals_full_onehot(f, num_bins, digits, rows,
                                                    quantized, full_onehot):
     """u16 bin planes: the widths the factoring takes (a high digit of up
-    to 16 values) and the one past them, which IS the full one-hot."""
+    to 16 values) and the one past them, which IS the full one-hot; "long":
+    the long step's edges at bpad 1024 and 2048."""
     from lightgbm_tpu.ops.pallas.seg import hist_bpad, hist_digits, seg_hist_ref
 
     assert hist_digits(hist_bpad(num_bins)) == digits
-    seg, n_pad = _hist_case(f, num_bins, wide=True)
+    windows, n, alone = _case_windows(rows, f, num_bins, wide=True)
+    seg, n_pad = _hist_case(f, num_bins, wide=True, n=n)
     got, want = _both_forms(full_onehot, seg, n_pad, f, num_bins, quantized,
-                            wide=True)
+                            wide=True, windows=windows, alone=alone)
     _assert_forms_agree(got, want, quantized)
-    ref = np.asarray(seg_hist_ref(
-        seg, jnp.asarray(_WINDOWS[1], jnp.int32), f=f, num_bins=num_bins,
-        n_pad=n_pad, wide=True))
-    np.testing.assert_array_equal(got[0][1][2], ref[2])
-    if not quantized:
-        assert np.abs(got[0][1] - ref).max() <= 5e-6 * np.abs(ref).max()
+    for i in (1, 3):
+        ref = np.asarray(seg_hist_ref(
+            seg, jnp.asarray(windows[i], jnp.int32), f=f, num_bins=num_bins,
+            n_pad=n_pad, wide=True))
+        np.testing.assert_array_equal(got[0][i][2], ref[2])
+        if not quantized:
+            assert np.abs(got[0][i] - ref).max() <= 5e-6 * np.abs(ref).max()
 
 
 @pytest.mark.parametrize("bpad,digits,block", [
@@ -716,3 +757,46 @@ def test_hist_digits_come_from_bpad_alone(bpad, digits, block):
     assert brows == block * low
     assert arows == (32 if high == 1 else seg.MXU_ROWS)
     assert high == 1 or block * seg._digit_rows(bpad) <= seg.MXU_ROWS
+
+
+# every shape tools/aot_check.py compiles a histogram caller at: the six
+# cells' (28 and 67 one-group, 2,000 grouped), bpad 128, u16 widths, grouped
+# int8 and u16, the widest one-group row, and two H = 1 widths
+@pytest.mark.parametrize("f,num_bins,wide,step", [
+    (28, 256, False, 4096), (67, 256, False, 4096), (2000, 256, False, 4096),
+    (28, 127, False, 2048), (242, 256, False, 2048), (500, 256, False, 4096),
+    (4, 1024, True, 4096), (9, 1000, True, 4096), (200, 512, True, 4096),
+    (3, 2000, True, 4096), (83, 256, False, 2048), (3, 4000, True, 512),
+    (121, 8192, True, 512),
+])
+def test_hist_step_comes_from_static_shapes(f, num_bins, wide, step):
+    """STEP is the largest of 8, 4, 2 and 1 TILE whose scratch fits the
+    budget beside the partition's: 4096 at the cells' shapes, 2048 at bpad
+    128 and past 48 planes a tile, TILE (the kernel as it was) where the
+    form is the full one-hot; the scratch the callers allocate is that
+    wide, and ``seg_vmem_ok`` reckons from the same function."""
+    from lightgbm_tpu.ops.pallas import seg
+    from lightgbm_tpu.ops.pallas.partition import partition_scratch_bytes
+
+    bpad = seg.hist_bpad(num_bins)
+    grouped = seg.plane_groups(f, wide) > 1
+    sub = seg.hist_sub(f, wide, grouped)
+    assert seg.hist_step(f, bpad, sub) == step
+    # a DMA is a static slice of the packed matrix: no step is longer than it
+    for n in (300, 1500, 3000, 5000):
+        n_pad = seg.padded_rows(n)
+        bounded = seg.hist_step(f, bpad, sub, n_pad)
+        assert bounded <= max(seg.TILE, min(step, n_pad))
+        assert bounded == step or 2 * bounded > n_pad or bounded == seg.TILE
+    assert (step == seg.TILE) == (seg.hist_digits(bpad)[0] == 1)
+    refs = seg.hist_scratch(f, bpad, sub, quantized=True, grouped=grouped,
+                            step=step)
+    assert [r.shape[-1] for r in refs[:4]] == [step] * 4
+    assert seg.hist_scratch_bytes(f, bpad, sub) == seg.hist_scratch_bytes(
+        f, bpad, sub, step)
+    assert (seg.hist_scratch_bytes(f, bpad, sub)
+            + partition_scratch_bytes(seg.LANES) <= seg.SEG_VMEM_BUDGET)
+    assert seg.seg_vmem_ok(f, num_bins)
+    if seg.TILE < step < 8 * seg.TILE:  # the next candidate does not fit
+        assert (seg.hist_scratch_bytes(f, bpad, sub, 2 * step)
+                + partition_scratch_bytes(seg.LANES) > seg.SEG_VMEM_BUDGET)

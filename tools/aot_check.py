@@ -68,12 +68,18 @@ def s(shape, dtype):
 
 
 CHECKS = {}
+NOTES = {}  # entry name -> a note for its OK line
+_ENTRY = [None]  # the entry that is running (its NOTES key)
 
 
 def check(name):
     def deco(f):
-        CHECKS[name] = f
-        return f
+        def run(topo):
+            _ENTRY[0] = name
+            return f(topo)
+
+        CHECKS[name] = run
+        return run
 
     return deco
 
@@ -220,6 +226,7 @@ def _c25(topo):
 
 @check("seg_hist_pallas grouped epsilon shape f=2000 b=256 (250 feature groups)")
 def _c26(topo):
+    NOTES[_ENTRY[0]] = _hist_step_note(_EPSILON_F)
     n_pad = padded_rows(_EPSILON_ROWS)
     return compile_on_topo(
         topo, seg_hist_pallas,
@@ -248,9 +255,25 @@ def _c28(topo):
     )
 
 
-def _hist_batch(topo, f, rows, num_bins=256, k=1, quantized=False, wide=False):
+def _hist_step_note(f, num_bins=256, wide=False, long=True):
+    """The step the histogram kernel resolves to at this shape
+    (``seg.hist_step``: long steps where the form has two digits, the
+    TILE-row loop alone where it is the full one-hot), for the OK line."""
+    from lightgbm_tpu.ops.pallas.seg import (
+        TILE, hist_bpad, hist_step, hist_sub, plane_groups,
+    )
+
+    step = hist_step(
+        f, hist_bpad(num_bins), hist_sub(f, wide, plane_groups(f, wide) > 1))
+    assert (step > TILE) == long, (f, num_bins, wide, step)
+    return f"hist_step {step}+{TILE}" if long else f"hist_step {TILE}"
+
+
+def _hist_batch(topo, f, rows, num_bins=256, k=1, quantized=False, wide=False,
+                long=True):
     from lightgbm_tpu.ops.pallas.seg import seg_hist_pallas_batch
 
+    NOTES[_ENTRY[0]] = _hist_step_note(f, num_bins, wide, long)
     n_pad = padded_rows(rows)
     return compile_on_topo(
         topo, seg_hist_pallas_batch,
@@ -286,7 +309,12 @@ def _c32(topo):
 
 @check("seg_hist_pallas_batch u16 wide f=3 b=4000 (H = 1: the full one-hot)")
 def _c33(topo):
-    return _hist_batch(topo, 3, 5000, num_bins=4000, wide=True)
+    return _hist_batch(topo, 3, 5000, num_bins=4000, wide=True, long=False)
+
+
+@check("seg_hist_pallas_batch criteo67-quant.fit shape int8 f=67 8M rows")
+def _c34(topo):
+    return _hist_batch(topo, 67, _CRITEO_ROWS, quantized=True)
 
 
 @check("seg_partition_pallas bits-fed (gl_vec) f=28 10.5M rows")
@@ -385,6 +413,7 @@ def _c16(topo):
 
 @check("fused_grow_step_pallas criteo67.fit-eval shape K=1 bf16 f=67 8M rows")
 def _c22(topo):
+    NOTES[_ENTRY[0]] = _hist_step_note(67)
     return _fused_step(topo, 1, False, f=67, rows=_CRITEO_ROWS)
 
 
@@ -921,7 +950,9 @@ def main(selected=None):
                 ma = compiled.memory_analysis()
                 mem = (f"  (arguments {ma.argument_size_in_bytes / 1e9:.2f} GB"
                        f" + temporaries {ma.temp_size_in_bytes / 1e9:.2f} GB)")
-            print(f"OK   {name}" + (f"  (flops={flops:.3g})" if flops else "") + mem)
+            note = f"  ({NOTES[name]})" if name in NOTES else ""
+            print(f"OK   {name}" + (f"  (flops={flops:.3g})" if flops else "")
+                  + note + mem)
         except Exception as e:
             failures.append(name)
             print(f"FAIL {name}: {type(e).__name__}")
