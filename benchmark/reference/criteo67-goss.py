@@ -34,7 +34,6 @@ summed, at the same nodes of the same trees.
 
 from __future__ import annotations
 
-from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict
 
 import numpy as np
@@ -47,7 +46,6 @@ GOSS_STREAM = 0x80000000
 # (a float32 score summed over up to 16 trees, a float32 sigmoid) lies within
 # a few 1e-6 of the float64 one
 BAND = 1e-5
-WALK_THREADS = 8
 
 
 def fmix32(x: np.ndarray) -> np.ndarray:
@@ -88,12 +86,6 @@ def goss_bag(g: np.ndarray, h: np.ndarray, params: Dict[str, Any], iteration: in
     weight = np.where(is_top, 1.0, (n - top_k) / other_k)
     open_rows = (np.abs(metric - threshold) <= BAND * threshold) & ~drawn
     return is_top | drawn, weight, open_rows
-
-
-def walk(tree: gbdt.Tree, blocks) -> np.ndarray:
-    """``gbdt.walk``, a block a thread."""
-    with ThreadPoolExecutor(max_workers=WALK_THREADS) as ex:
-        return np.concatenate(list(ex.map(lambda b: gbdt.walk(tree, [b]), blocks)))
 
 
 def _leaf_values(leaf_of_row, g, h, n_leaves: int, params) -> np.ndarray:
@@ -137,7 +129,16 @@ def follow_model(tree_dumps, *, blocks, y, params, recipe, valid_blocks=None,
     if valid_blocks is not None:
         raise ValueError("the criteo67-goss reference follows training alone")
     trees = [gbdt.tree_from_dump(t) for t in tree_dumps]
-    cols, values = gbdt.levels_of(blocks, recipe)
+    pool = gbdt.RowPool.for_table(sum(b.shape[0] for b in blocks), blocks[0].shape[1])
+    try:
+        return _follow(trees, blocks, y, params, recipe, control, detail, pool)
+    finally:
+        if pool is not None:
+            pool.close()
+
+
+def _follow(trees, blocks, y, params, recipe, control, detail, pool) -> Dict[str, float]:
+    cols, values = gbdt.levels_of(blocks, recipe, pool)
     y = np.asarray(y, np.float64)
     bias = gbdt.init_score(y)
     score = np.full(len(y), bias)
@@ -145,8 +146,8 @@ def follow_model(tree_dumps, *, blocks, y, params, recipe, valid_blocks=None,
     judged = {0, warm, len(trees) - 1}
     worst: Dict[str, float] = {}
     for k, tree in enumerate(trees):
-        g, h = gbdt.gradients(score, y)
-        leaf_of_row = walk(tree, blocks)
+        g, h = gbdt.gradients(score, y, pool)
+        leaf_of_row = gbdt.walk(tree, blocks, pool)
         b = bias if k == 0 else 0.0  # the first tree carries the bias in its leaves
         if k >= warm:
             in_bag, weight, open_rows = goss_bag(g, h, params, k)
@@ -161,7 +162,7 @@ def follow_model(tree_dumps, *, blocks, y, params, recipe, valid_blocks=None,
         if k in judged:
             cols_b = cols if rows is None else [c[rows] for c in cols]
             nums = gbdt.judge_tree(tree, leaf_b, cols_b, values, g_b, h_b, params, b,
-                                   control=control, detail=detail)
+                                   control=control, detail=detail, pool=pool)
             if detail is not None:
                 detail[-1].update(tree=k, in_bag_rows=len(leaf_b), open_rows=len(leaf_open))
         else:

@@ -30,6 +30,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from benchmark.reference.rows import RowPool, row_blocks, sums_worth_processes
+
 
 @dataclasses.dataclass
 class Tree:
@@ -102,9 +104,20 @@ def init_score(y: np.ndarray) -> float:
     return float(np.log(p / (1.0 - p)))
 
 
-def gradients(score: np.ndarray, y: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-    p = 1.0 / (1.0 + np.exp(-score))
-    return p - y, p * (1.0 - p)
+def gradients(score: np.ndarray, y: np.ndarray,
+              pool: Optional[RowPool] = None) -> Tuple[np.ndarray, np.ndarray]:
+    """Binary log loss's g and h of every row; with ``pool`` a block of rows
+    a thread (elementwise: the same numbers)."""
+    if pool is None:
+        p = 1.0 / (1.0 + np.exp(-score))
+        return p - y, p * (1.0 - p)
+    g, h = np.empty_like(score), np.empty_like(score)
+
+    def one(s):
+        g[s], h[s] = gradients(score[s], y[s])
+
+    pool.map_blocks(one, row_blocks(len(score)))
+    return g, h
 
 
 def logloss(score: np.ndarray, y: np.ndarray) -> float:
@@ -125,30 +138,31 @@ def round_bfloat16(a: np.ndarray) -> np.ndarray:
 # ------------------------------------------------------------- the walk
 
 
-def walk(tree: Tree, blocks: Sequence[np.ndarray]) -> np.ndarray:
+def _walk_block(tree: Tree, x: np.ndarray) -> np.ndarray:
+    n = x.shape[0]
+    if len(tree.feature) == 0:
+        return np.zeros(n, np.int32)
+    node = np.zeros(n, np.int64)  # internal index, or ~leaf once < 0
+    live = np.arange(n)
+    while live.size:
+        nd = node[live]
+        v = x[live, tree.feature[nd]].astype(np.float64)
+        nxt = np.where(v <= tree.threshold[nd], tree.left[nd], tree.right[nd])
+        node[live] = nxt
+        live = live[nxt >= 0]
+    return (~node).astype(np.int32)
+
+
+def walk(tree: Tree, blocks: Sequence[np.ndarray], pool: Optional[RowPool] = None) -> np.ndarray:
     """Leaf index of every row, from the raw values: left iff
-    ``float64(x) <= threshold``."""
-    out = []
-    for x in blocks:
-        n = x.shape[0]
-        if len(tree.feature) == 0:
-            out.append(np.zeros(n, np.int32))
-            continue
-        node = np.zeros(n, np.int64)  # internal index, or ~leaf once < 0
-        rows = np.arange(n)
-        live = rows
-        while live.size:
-            nd = node[live]
-            v = x[live, tree.feature[nd]].astype(np.float64)
-            nxt = np.where(v <= tree.threshold[nd], tree.left[nd], tree.right[nd])
-            node[live] = nxt
-            live = live[nxt >= 0]
-        out.append((~node).astype(np.int32))
-    return np.concatenate(out)
+    ``float64(x) <= threshold``; a block a thread of ``pool``."""
+    if pool is None:
+        return np.concatenate([_walk_block(tree, x) for x in blocks])
+    return np.concatenate(pool.map_blocks(lambda x: _walk_block(tree, x), blocks))
 
 
-def predict(tree: Tree, blocks: Sequence[np.ndarray]) -> np.ndarray:
-    return tree.leaf_value[walk(tree, blocks)]
+def predict(tree: Tree, blocks: Sequence[np.ndarray], pool: Optional[RowPool] = None) -> np.ndarray:
+    return tree.leaf_value[walk(tree, blocks, pool)]
 
 
 # ------------------------------------------------------- sums per node
@@ -156,9 +170,13 @@ def predict(tree: Tree, blocks: Sequence[np.ndarray]) -> np.ndarray:
 
 def leaf_sums(level_cols: Sequence[np.ndarray], leaf_of_row: np.ndarray,
               g: np.ndarray, h: np.ndarray, n_leaves: int, n_levels: int,
-              counts: bool = True):
+              counts: bool = True, pool: Optional[RowPool] = None):
     """[n_leaves, F, n_levels] sums of g, h and (unless ``counts`` is off)
-    row counts: one pass per feature over (leaf, level) keys."""
+    row counts: one pass per feature over (leaf, level) keys; with ``pool``
+    (where the rows outnumber the bins) a group of features a worker
+    process, the same sums to the last bit."""
+    if pool is not None and sums_worth_processes(len(leaf_of_row), n_leaves, n_levels):
+        return pool.leaf_sums(level_cols, leaf_of_row, g, h, n_leaves, n_levels, counts)
     f = len(level_cols)
     G = np.empty((n_leaves, f, n_levels))
     H = np.empty((n_leaves, f, n_levels))
@@ -335,20 +353,21 @@ def _rms(a: np.ndarray) -> float:
 
 def judge_tree(tree: Tree, leaf_of_row: np.ndarray, level_cols, values, g, h,
                params: Dict[str, Any], bias: float,
-               control: Optional[str] = None, detail: Optional[list] = None) -> Dict[str, float]:
+               control: Optional[str] = None, detail: Optional[list] = None,
+               pool: Optional[RowPool] = None) -> Dict[str, float]:
     """The numbers of one tree, judged against float64 sums over the rows
     that reach each node.  With ``control`` the judged values are not the
     tree's own but those the same arithmetic gives from rounded per-row
     stats at the same nodes (it need not grow a tree of its own)."""
     n_levels = len(values)
-    GL, HL, CL = leaf_sums(level_cols, leaf_of_row, g, h, tree.n_leaves, n_levels)
+    GL, HL, CL = leaf_sums(level_cols, leaf_of_row, g, h, tree.n_leaves, n_levels, pool=pool)
     GN, HN, CN = node_sums(tree, GL, HL, CL)
     if control is not None:
         if control != "bfloat16":
             raise ValueError(control)
         gq, hq = round_bfloat16(g), round_bfloat16(h)
         GLq, HLq, _ = leaf_sums(level_cols, leaf_of_row, gq, hq, tree.n_leaves, n_levels,
-                                counts=False)
+                                counts=False, pool=pool)
         GNq, HNq, _ = node_sums(tree, GLq, HLq, CL)
 
     # every row passes feature 0's levels exactly once: its row of sums is the node's total
@@ -420,7 +439,8 @@ def follow(trees: Sequence[Tree], blocks: Sequence[np.ndarray], y: np.ndarray,
            valid_blocks: Optional[Sequence[np.ndarray]] = None,
            valid_y: Optional[np.ndarray] = None,
            valid_metric: Optional[Sequence[float]] = None,
-           control: Optional[str] = None, detail: Optional[list] = None) -> Dict[str, float]:
+           control: Optional[str] = None, detail: Optional[list] = None,
+           pool: Optional[RowPool] = None) -> Dict[str, float]:
     """Worst numbers over ``trees`` (the first trees the timed path grew),
     each judged with the scores of the trees before it."""
     y = np.asarray(y, np.float64)
@@ -433,14 +453,15 @@ def follow(trees: Sequence[Tree], blocks: Sequence[np.ndarray], y: np.ndarray,
         vscore_low = round_bfloat16(vscore)
     worst: Dict[str, float] = {}
     for k, tree in enumerate(trees):
-        g, h = gradients(score, y)
-        leaf_of_row = walk(tree, blocks)
+        g, h = gradients(score, y, pool)
+        leaf_of_row = walk(tree, blocks, pool)
         nums = judge_tree(tree, leaf_of_row, level_cols, values, g, h, params,
-                          bias if k == 0 else 0.0, control=control, detail=detail)
+                          bias if k == 0 else 0.0, control=control, detail=detail,
+                          pool=pool)
         # the first tree carries the bias in its leaves; the score already has it
         score += tree.leaf_value[leaf_of_row] - (bias if k == 0 else 0.0)
         if vscore is not None:
-            step = predict(tree, valid_blocks) - (bias if k == 0 else 0.0)
+            step = predict(tree, valid_blocks, pool) - (bias if k == 0 else 0.0)
             vscore += step
             ref = logloss(vscore, vy)
             if control is not None:
@@ -457,22 +478,32 @@ def follow(trees: Sequence[Tree], blocks: Sequence[np.ndarray], y: np.ndarray,
 # ------------------------------------------- what the comparison calls
 
 
-def levels_of(blocks, recipe):
+def levels_of(blocks, recipe, pool: Optional[RowPool] = None):
     """Per feature, the grid level of every row (the reference's own
-    reading of the raw values; nothing of the program's binning)."""
+    reading of the raw values; nothing of the program's binning); a block
+    a thread of ``pool``."""
     g = np.float32(recipe["grid"])
     h = int(recipe["half_levels"])
     dtype = np.uint8 if 2 * h < 256 else np.int16
     f = blocks[0].shape[1]
-    cols = [np.empty(sum(b.shape[0] for b in blocks), dtype) for _ in range(f)]
-    at = 0
-    for b in blocks:
-        k = (np.rint(b * g).astype(np.int16) + h).astype(dtype)
-        for j in range(f):
-            cols[j][at: at + b.shape[0]] = k[:, j]
-        at += b.shape[0]
+    cols = np.empty((f, sum(b.shape[0] for b in blocks)), dtype)
+    starts = np.cumsum([0] + [b.shape[0] for b in blocks[:-1]])
+
+    def one(i):
+        # a few thousand rows at a time, so that the transpose reads from cache
+        b, at = blocks[i], starts[i]
+        for s in range(0, b.shape[0], 4096):
+            x = b[s: s + 4096]
+            k = (np.rint(x * g).astype(np.int16) + h).astype(dtype)
+            cols[:, at + s: at + s + x.shape[0]] = k.T
+
+    if pool is None:
+        for i in range(len(blocks)):
+            one(i)
+    else:
+        pool.map_blocks(one, range(len(blocks)))
     values = (np.arange(-h, h + 1, dtype=np.float32) / g).astype(np.float64)
-    return cols, values
+    return list(cols), values
 
 
 def follow_model(tree_dumps, *, blocks, y, params, recipe, valid_blocks=None,
@@ -480,7 +511,13 @@ def follow_model(tree_dumps, *, blocks, y, params, recipe, valid_blocks=None,
     """The numbers ``correct`` is decided by, for the first trees of the
     model the timed path produced (``dump_model()`` tree structures)."""
     trees = [tree_from_dump(t) for t in tree_dumps]
-    cols, values = levels_of(blocks, recipe)
-    return follow(trees, blocks, y, cols, values, params,
-                       valid_blocks=valid_blocks, valid_y=valid_y,
-                       valid_metric=valid_metric, control=control, detail=detail)
+    pool = RowPool.for_table(sum(b.shape[0] for b in blocks), blocks[0].shape[1])
+    try:
+        cols, values = levels_of(blocks, recipe, pool)
+        return follow(trees, blocks, y, cols, values, params,
+                      valid_blocks=valid_blocks, valid_y=valid_y,
+                      valid_metric=valid_metric, control=control, detail=detail,
+                      pool=pool)
+    finally:
+        if pool is not None:
+            pool.close()

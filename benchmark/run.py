@@ -252,6 +252,10 @@ def _path_checks(checks: Checks, booster, logs: Logs, launches, clock: WindowClo
     checks.add("compiles_in_window", compiles.count - clock.compiles_before_window, 0)
     checks.add("degraded", float(bool(booster.degraded)), 0)
     checks.add("fallback_warnings", len(logs.fallbacks()), 0)
+    if "mesh_devices" in expect:
+        mesh = getattr(booster, "_mesh", None)
+        checks.add("mesh_devices_missing",
+                   expect["mesh_devices"] - (mesh.size if mesh is not None else 0), 0)
     if rehearse:  # the CPU resolves other programs; they are not the cell's
         return
     mode = expect.get("hist_mode", "seg")
@@ -263,10 +267,6 @@ def _path_checks(checks: Checks, booster, logs: Logs, launches, clock: WindowClo
     else:
         wrong = len(launches)
     checks.add("wrong_program_launches", wrong, 0)
-    if "mesh_devices" in expect:
-        mesh = getattr(booster, "_mesh", None)
-        checks.add("mesh_devices_missing",
-                   expect["mesh_devices"] - (mesh.size if mesh is not None else 0), 0)
 
 
 def _traced_metrics(cell, manifest, facts, device, trace_dir, keep_trace, rehearse):

@@ -68,12 +68,15 @@ def test_quant_cell_is_the_issue_s():
 def test_the_benchmark_has_five_cells_on_one_chip():
     """``higgs.fit-eval`` was measured with this PR and left queued: its rate
     spread 0.61 % and 0.37 % in two sets of six runs, and a new cell is
-    admitted under half the 1 % bound (PERF.md section 7)."""
+    admitted under half the 1 % bound (PERF.md section 7).  A prefix, not the
+    whole list: later cells, on one chip or on four, are appended after these
+    five."""
     m = contract.Manifest()
-    assert m.workload_names() == [
+    names = m.workload_names()
+    assert names[:5] == [
         "criteo67.fit-eval", "higgs.fit", "epsilon.fit-eval", "criteo67.fit",
         "criteo67-quant.fit"]
-    assert all(m.cell(n).chips == 1 for n in m.workload_names())
+    assert all(m.cell(n).chips == 1 for n in names[:5])
 
 
 @pytest.mark.parametrize("old", ["higgs.fit", "criteo67.fit", "criteo67.fit-eval",
